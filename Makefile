@@ -3,7 +3,7 @@
 
 PYTHON ?= python
 
-.PHONY: test test-all dryrun bench smoke capture aot real-data lint \
+.PHONY: test test-all dryrun bench smoke aot real-data lint \
 	trace-demo health-demo zero-demo compress-demo analyze-demo \
 	lint-demo monitor-demo profile-demo goodput-demo registry-demo \
 	tune-demo mem-demo curves-demo chaos-demo comms-demo data-demo \
@@ -18,19 +18,14 @@ test:
 test-all:
 	$(PYTHON) -m pytest tests/ -x -q
 
-# The driver's multi-chip validation: compiles + runs every parallelism
-# family's full train step on an 8-virtual-device CPU mesh.
+# Multi-device validation: compiles + runs every parallelism family's
+# full train step on an 8-virtual-device CPU mesh.
 dryrun:
-	$(PYTHON) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
+	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+	  $(PYTHON) -m tpu_ddp.tools.dryrun 8
 
 bench:
 	$(PYTHON) bench.py
-
-# Opportunistic on-chip evidence: probes the (intermittently available)
-# TPU runtime and, when it's up, records each bench leg into
-# benchmarks/bench_tpu.json + attempts.jsonl. No-op when wedged.
-capture:
-	$(PYTHON) benchmarks/capture_tpu.py
 
 # Deviceless AOT evidence: compiles all flagship programs with the real
 # XLA:TPU + Mosaic toolchain (no chip needed); exits nonzero on any

@@ -4,16 +4,16 @@
 The committed training-quality artifact (``benchmarks/recipe_demo/``) shows
 the framework recipe beating the reference recipe on BOTH time-to-threshold
 and final accuracy — but it ran on the virtual CPU mesh, and the verdict
-asked for the demo "ideally run during a chip window". This tool converts
-one chip window into exactly that: the same two-arm comparison (same task,
+asked for the demo run on a chip. This tool does exactly that: the same
+two-arm comparison (same task,
 model, knobs — see ``benchmarks/recipe_demo.py``) executed with
 ``--device tpu``, written to ``benchmarks/recipe_demo_tpu/`` so the CPU
 artifact stays untouched for comparison.
 
-Grant discipline (shared with bench.py / capture_tpu.py / tpu_curve.py):
-probe the backend first in a cheap child and exit 0 doing nothing when the
-runtime is wedged; run the demo in ONE child process (a single pool client)
-and TERM it gracefully on timeout — never SIGKILL a grant-holding child.
+One process per chip (shared with bench.py / tpu_curve.py): this parent is
+stdlib-only and never imports jax; the demo runs in ONE child, which holds
+the chip, is TERMed gracefully on timeout, and — ``--device tpu`` — fails
+loudly when there is no TPU. This tool then exits non-zero too.
 
 Usage: ``python benchmarks/tpu_recipe.py [--timeout 2400] [--epochs 32]``
 """
@@ -34,8 +34,8 @@ import bench  # noqa: E402  (stdlib-only at module level)
 
 
 def _on_term(signum, frame):
-    # the demo child and probes both register in bench._ACTIVE_CHILD via
-    # run_grant_safe_child; a TERM mid-demo must not orphan the pool grant
+    # the demo child registers in bench._ACTIVE_CHILD via run_child; a
+    # TERM mid-demo must not orphan a child that holds the chip
     child = bench._ACTIVE_CHILD
     if child is not None:
         bench._terminate_gracefully(child, grace=20)
@@ -50,15 +50,6 @@ def main() -> None:
     args = ap.parse_args()
     signal.signal(signal.SIGTERM, _on_term)
 
-    ok, info = bench._probe_backend(dict(os.environ), timeout=75.0)
-    if not ok or (isinstance(info, dict) and info.get("backend") == "cpu"):
-        print(f"tpu_recipe: runtime unavailable; nothing attempted: {info}",
-              flush=True)
-        bench._record_attempt("tpu_recipe_probe", ok=False, info=info)
-        return
-    print(f"tpu_recipe: chip up: {info}", flush=True)
-    bench._record_attempt("tpu_recipe_probe", ok=True, info=info)
-
     # Same arms/knobs as the committed CPU artifact (recipe_demo.py
     # defaults + the committed invocation: tiny flagship config, hard
     # synthetic task) so the two summaries differ only in device_kind.
@@ -68,8 +59,7 @@ def main() -> None:
         "--device", "tpu",
         "--out-dir", _OUT_DIR,
         "--model", "netresdeep",
-        "--common", "--n-chans1 16 --n-blocks 2 "
-                    "--compilation-cache-dir /tmp/tpu_ddp_xla_cache",
+        "--common", "--n-chans1 16 --n-blocks 2",
         "--size", "4096",
         "--epochs", str(args.epochs),
         # GLOBAL batch 256 on the single chip = the committed CPU
@@ -84,37 +74,23 @@ def main() -> None:
     stale = os.path.join(_OUT_DIR, "summary.json")
     if os.path.exists(stale):
         os.unlink(stale)
-    out, err, wall = bench.run_grant_safe_child(demo_argv, args.timeout)
-    summary = None
+    out, err, wall = bench.run_child(demo_argv, args.timeout)
     try:
         with open(os.path.join(_OUT_DIR, "summary.json")) as f:
-            summary = json.load(f)
+            json.load(f)
     except (OSError, json.JSONDecodeError):
         # A TERM'd/crashed child can leave a truncated summary.json
         # (recipe_demo writes it non-atomically); it must not survive to
-        # satisfy capture_loop.sh's existence check as phase-complete.
+        # be read as this run's result.
         if os.path.exists(stale):
             os.unlink(stale)
-    if err is None and summary is None:
-        err = ("demo exited 0 but wrote no summary.json: "
-               + " | ".join(out.strip().splitlines()[-4:]))
-    if summary is None and err is not None and "timed out" in err:
-        bench._record_attempt("tpu_recipe", ok=False, error=err,
-                              wall_s=round(wall, 1))
-        print("tpu_recipe: timed out", flush=True)
-        return
-    bench._record_attempt(
-        "tpu_recipe", ok=err is None, error=err, wall_s=round(wall, 1),
-        result=None if summary is None else {
-            "backend": summary.get("backend"),
-            "device_kind": summary.get("device_kind"),
-            "epochs_to_threshold": summary.get("epochs_to_threshold"),
-            "final_accuracy_delta_framework_minus_reference": summary.get(
-                "final_accuracy_delta_framework_minus_reference"),
-        },
-    )
+        if err is None:
+            err = ("demo exited 0 but wrote no summary.json: "
+                   + " | ".join(out.strip().splitlines()[-4:]))
     print(f"tpu_recipe: {'ok' if err is None else err} [{wall:.0f}s]",
           flush=True)
+    if err is not None:
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

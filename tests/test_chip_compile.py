@@ -1,0 +1,167 @@
+"""The main path's kernels and the DP step, compiled at REAL widths for a
+described (not attached) v5e chip — in tier-1, at no chip time.
+
+Interpret mode accepts tilings Mosaic refuses (ViT-B/16's 196 tokens were
+one), so the CPU suite alone cannot say that a kernel will build on the
+chip. The TPU's compiler is installed here: ``jax.experimental.topologies``
+describes a ``v5e:2x2`` slice, and ``jit(...).lower(shapes).compile()``
+raises what the chip's compiler would raise. Each case asserts the
+``tpu_custom_call`` count or the collective it expects in the compiled
+text. Nothing runs: a compile that passes is not a chip run.
+
+Code that asks jax for its platform still sees the CPU here, so the kernels
+are steered with ``interpret=False`` — in the test, not through an option
+of the program. One libtpu process at a time: two collide on its lock file.
+"""
+
+import importlib.util
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+
+CUSTOM_CALL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu not installed: the v5e topology cannot be "
+                    "described")
+    from jax.experimental import topologies
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # e.g. another process holds libtpu's lock file
+        pytest.skip(f"the v5e topology cannot be described: {e}")
+    assert len(desc.devices) == 4
+    return desc
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A deviceless compile is written to the persistent cache but cannot
+    be read back without a chip (the next one warns and compiles again):
+    keep the cache off around these, as conftest keeps it off everywhere."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _one_chip(topo):
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+def _text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+# ---- flash attention ---------------------------------------------------------
+
+@pytest.mark.parametrize("shape,causal", [
+    ((4, 2048, 8, 128), False),   # the bench shape
+    ((4, 2048, 8, 128), True),
+    ((64, 196, 12, 64), False),   # ViT-B/16's own: one whole-axis block
+    ((2, 1000, 4, 64), True),     # no tiling: padded to 1024 and masked
+])
+def test_flash_compiles_at_real_widths(topo, shape, causal):
+    from tpu_ddp.ops.flash_attention import flash_attention
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=_one_chip(topo))
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, 128, 128, False, causal=causal)
+
+    bwd = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                   (0, 1, 2))
+    assert _text(fwd, x, x, x).count(CUSTOM_CALL) == 1
+    # fwd recompute + dQ kernel + dK/dV kernel, exactly
+    assert _text(bwd, x, x, x).count(CUSTOM_CALL) == 3
+
+
+# ---- the int8 ring's quantize / dequantize ----------------------------------
+
+def test_fused_quant_compiles_at_real_size(topo):
+    from tpu_ddp.ops.fused_quant import fused_dequant, fused_quant
+
+    n, block = 2_359_296, 256  # a 3x3x512x512 conv kernel
+    one = _one_chip(topo)
+    x = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one)
+    payload = {
+        "q": jax.ShapeDtypeStruct((n,), jnp.int8, sharding=one),
+        "scale": jax.ShapeDtypeStruct((n // block,), jnp.float32,
+                                      sharding=one),
+    }
+    quant = _text(lambda v: fused_quant(v, block, interpret=False), x)
+    assert quant.count(CUSTOM_CALL) == 1
+    dequant = _text(
+        lambda p, acc: fused_dequant(p, block, n, add_to=acc,
+                                     interpret=False), payload, x)
+    assert dequant.count(CUSTOM_CALL) == 1
+
+
+# ---- the fused optimizer update ---------------------------------------------
+
+@pytest.mark.parametrize("name,opt", [
+    ("sgd+momentum", dict(lr=1e-2, momentum=0.9)),
+    ("adamw", dict(lr=1e-3, optimizer="adamw", weight_decay=0.05)),
+])
+def test_fused_update_compiles_at_real_leaves(topo, name, opt):
+    from tpu_ddp.ops.fused_update import FusedUpdate
+    from tpu_ddp.train.optim import make_optimizer
+
+    tx = make_optimizer(**opt, kernels=True)
+    fused = FusedUpdate(tx.fused.recipe, interpret=False)  # not the mirror
+    one = _one_chip(topo)
+    params = {
+        "dense": {"kernel": jax.ShapeDtypeStruct((768, 3072), jnp.float32)},
+        "conv": {"kernel": jax.ShapeDtypeStruct((3, 3, 512, 512),
+                                                jnp.float32)},
+    }
+    opt_state = jax.eval_shape(tx.init, params)
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), t)
+    text = _text(fused.apply, place(params), place(opt_state), place(params))
+    # one kernel launch per leaf
+    assert text.count(CUSTOM_CALL) == 2, name
+
+
+# ---- the DP step on four described chips ------------------------------------
+
+@pytest.mark.parametrize("zero1,wanted", [
+    (False, ("all-reduce",)),
+    # XLA may lower the psum_scatter as an all-reduce and a slice
+    (True, ("reduce-scatter|all-reduce", "all-gather")),
+])
+def test_dp_step_compiles_for_four_chips(topo, zero1, wanted):
+    from tpu_ddp.analysis.explain import abstract_batch
+    from tpu_ddp.models import NetResDeep
+    from tpu_ddp.parallel import MeshSpec, create_mesh
+    from tpu_ddp.train import make_optimizer
+    from tpu_ddp.train.strategy import build_abstract_step
+
+    mesh = create_mesh(MeshSpec(data=-1), topo.devices)
+    assert mesh.shape["data"] == 4
+    model = NetResDeep()  # the reference model, full size
+    tx = make_optimizer(lr=1e-2, momentum=0.9,
+                        zero1_axis="data" if zero1 else None)
+    step, state = build_abstract_step("dp", model, tx, mesh, zero1=zero1)
+    batch = abstract_batch(mesh, 32, 32)
+    assert batch["image"].sharding == NamedSharding(mesh, P("data"))
+    compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
+    for pattern in wanted:
+        assert re.search(rf" ({pattern})(-start)?\(", text), pattern
+    # per-device bytes: a quarter of the global batch, the whole params
+    assert compiled.memory_analysis().argument_size_in_bytes > 0

@@ -6,7 +6,7 @@ The expensive fixtures are REAL runs on the virtual CPU mesh, shared
 module-wide:
 
 - ``recipe`` — three seeded baselines of one recipe + a clean fourth
-  seed + an injected lr×10 divergence (momentum 0.9 makes the lr×10
+  seed + an injected lr×30 divergence (momentum 0.9 makes the lr×30
   run leave the envelope while staying finite).
 - ``incident_dir`` — a kill→``--resume`` run (the test_ledger pattern):
   extraction must stitch both lives and dedup the replayed steps.
@@ -88,13 +88,13 @@ def _run(run_dir, **overrides):
 @pytest.fixture(scope="module")
 def recipe(tmp_path_factory):
     """{name: run_dir} for 3 baseline seeds, a clean 4th seed, and the
-    injected lr×10 divergence."""
+    injected lr×30 divergence."""
     root = tmp_path_factory.mktemp("curves")
     reset_default_registry()
     dirs = {}
     for seed in (0, 1, 2, 3):
         dirs[f"s{seed}"] = _run(str(root / f"s{seed}"), seed=seed)
-    dirs["lr10"] = _run(str(root / "lr10"), seed=7, lr=0.1)
+    dirs["lr30"] = _run(str(root / "lr30"), seed=7, lr=0.3)
     reset_default_registry()
     return dirs
 
@@ -216,7 +216,7 @@ def test_quality_digest_sensitive_to_learning_knobs():
 def test_run_meta_quality_digest_stamped(curves):
     qs = {curves[f"s{i}"]["quality_digest"] for i in range(4)}
     assert len(qs) == 1 and None not in qs
-    assert curves["lr10"]["quality_digest"] not in qs  # lr is recipe
+    assert curves["lr30"]["quality_digest"] not in qs  # lr is recipe
     run_ids = {curves[f"s{i}"]["run_id"] for i in range(4)}
     assert len(run_ids) == 4  # seed folds into run_id, not quality
 
@@ -314,8 +314,8 @@ def test_clean_seed_stays_quiet(band, curves):
     assert judge_curve(dict(curves["s3"]), band) == []
 
 
-def test_lr10_trips_the_envelope(band, curves):
-    candidate = dict(curves["lr10"])
+def test_lr30_trips_the_envelope(band, curves):
+    candidate = dict(curves["lr30"])
     findings = judge_curve(candidate, band)
     rules = {f.rule for f in findings}
     assert "CRV002" in rules           # loss left the envelope
@@ -401,9 +401,9 @@ def test_crv004_nonfinite():
 def test_diff_verdict_both_ways(curves):
     same = diff_curves(curves["s0"], dict(curves["s0"]))
     assert same["verdict"] == "pass" and same["max_loss_drift"] == 0.0
-    drifted = diff_curves(curves["s0"], curves["lr10"], tolerance=0.05)
+    drifted = diff_curves(curves["s0"], curves["lr30"], tolerance=0.05)
     assert drifted["verdict"] == "fail"
-    reverse = diff_curves(curves["lr10"], curves["s0"], tolerance=0.05)
+    reverse = diff_curves(curves["lr30"], curves["s0"], tolerance=0.05)
     assert reverse["verdict"] == "fail"
     assert drifted["max_loss_drift"] == pytest.approx(
         reverse["max_loss_drift"])
@@ -540,7 +540,7 @@ def test_compare_gates_curves_both_directions(band, curves):
     from tpu_ddp.analysis.regress import compare, normalize_artifact
 
     clean = dict(curves["s3"])
-    bad = dict(curves["lr10"])
+    bad = dict(curves["lr30"])
     judge_curve(clean, band)
     judge_curve(bad, band)
     old = normalize_artifact(curve_artifact(clean))
@@ -589,7 +589,7 @@ def test_cli_exit_codes(tmp_path, recipe, curves, capsys):
     assert curves_main([recipe["s3"], "--against", reg,
                         "--allow-dirty"]) == 0
     capsys.readouterr()
-    rc = curves_main([recipe["lr10"], "--against", reg, "--allow-dirty",
+    rc = curves_main([recipe["lr30"], "--against", reg, "--allow-dirty",
                       "--band-quality", curves["s0"]["quality_digest"],
                       "--json"])
     assert rc == 1
@@ -598,7 +598,7 @@ def test_cli_exit_codes(tmp_path, recipe, curves, capsys):
     assert art["band"]["n_runs"] == 3
 
     assert curves_main(["diff", recipe["s0"], recipe["s0"]]) == 0
-    assert curves_main(["diff", recipe["s0"], recipe["lr10"]]) == 1
+    assert curves_main(["diff", recipe["s0"], recipe["lr30"]]) == 1
     assert curves_main(["diff", recipe["s0"],
                         str(tmp_path / "nope")]) == 2
     # a future-schema artifact refuses loudly, never misjudges

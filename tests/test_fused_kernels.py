@@ -67,13 +67,15 @@ def test_quant_roundtrip_bitwise(block, tail):
     assert not _tree_bitwise(a_f, a_r)
 
 
-def test_unsupported_block_falls_back():
-    """A non-lane-aligned block takes the reference path verbatim."""
+def test_unsupported_block_raises():
+    """A non-lane-aligned block is refused by name — the kernel asked for
+    is never quietly swapped for the reference."""
     assert not supports_block(64)
     x = jnp.arange(200, dtype=jnp.float32)
-    got = jax.jit(lambda v: fused_quant(v, 64))(x)
-    want = jax.jit(lambda v: _reference_quant(v, 64))(x)
-    assert not _tree_bitwise(got, want)
+    with pytest.raises(ValueError, match="block=64"):
+        fused_quant(x, 64)
+    with pytest.raises(ValueError, match="block=64"):
+        fused_dequant(_reference_quant(x, 64), 64, 200)
 
 
 # ---- error feedback with kernels on --------------------------------------
@@ -360,7 +362,7 @@ def _trainer_end_state(kernels):
         optimizer="adamw", weight_decay=0.05, grad_clip_norm=1.0,
         ema_decay=0.99, schedule="cosine", warmup_steps=1,
         prefetch_depth=0, log_every_epochs=99,
-        zero1=True, grad_compress="int8", grad_compress_block=64,
+        zero1=True, grad_compress="int8", grad_compress_block=128,
         grad_compress_error_feedback=True, kernels=kernels,
         n_chans1=4, n_blocks=1, mem_sample_steps=0,
     ).validate()

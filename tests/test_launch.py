@@ -74,8 +74,9 @@ def test_main_requires_a_command():
 
 def test_launch_module_stays_light():
     """The launcher must not create a jax backend at import or parse time —
-    it runs on pool-granted single-client TPU hosts where the children need
-    the grant (module docstring). Source-level guard: no jax import."""
+    a chip belongs to one process, and a launcher that held the host's
+    chips would leave none for its children (module docstring).
+    Source-level guard: no jax import."""
     src = open(os.path.join(_REPO, "tpu_ddp", "cli", "launch.py")).read()
     assert "import jax" not in src
 

@@ -110,21 +110,14 @@ def test_dp_grad_sync_exactness(devices):
 
     mesh = create_mesh(MeshSpec(data=-1))
 
-    from tpu_ddp.train.steps import GRAD_SYNC_IN_AD
-
     def shard_grads(params, batch):
-        # The library's sync formulation (see tpu_ddp.train.steps): on
-        # modern jax, pmean the per-shard loss BEFORE grad — its AD
-        # transpose + the unvarying-params psum produce the globally
-        # averaged gradient. On the 0.4.x shim, grad the local loss and
-        # pmean the grads explicitly (same math; what steps.py executes).
-        if GRAD_SYNC_IN_AD:
-            def global_loss(p, b):
-                return jax.lax.pmean(loss_no_bn(p, b), "data")
+        # The library's sync formulation (see tpu_ddp.train.steps): pmean
+        # the per-shard loss BEFORE grad — its AD transpose + the
+        # unvarying-params psum produce the globally averaged gradient.
+        def global_loss(p, b):
+            return jax.lax.pmean(loss_no_bn(p, b), "data")
 
-            return jax.grad(global_loss)(params, batch)
-        local = jax.grad(loss_no_bn)(params, batch)
-        return jax.tree.map(lambda g: jax.lax.pmean(g, "data"), local)
+        return jax.grad(global_loss)(params, batch)
 
     dp_grads = jax.jit(
         jax.shard_map(

@@ -415,9 +415,11 @@ def _synthetic_anatomy():
 
 
 def test_per_op_attribution_sums_to_measured_span():
-    att = per_op_attribution(_synthetic_anatomy(), 0.010)
-    assert att["chip"] == "v5e"  # cpu has no peak: documented fallback
-    assert any("no published peak" in n for n in att["notes"])
+    # cpu has no published peak: refused, no chip is assumed for it
+    refused = per_op_attribution(_synthetic_anatomy(), 0.010)
+    assert set(refused) == {"note"} and "--chip" in refused["note"]
+    att = per_op_attribution(_synthetic_anatomy(), 0.010, chip="v5e")
+    assert att["chip"] == "v5e" and not att["notes"]
     ops = {r["op"] for r in att["ops"]}
     assert {"compute (fused math)", "hbm traffic",
             "all-reduce/f32/data/g4", "all-gather/f32/data/g4"} == ops
@@ -435,7 +437,7 @@ def test_per_op_attribution_explicit_chip_and_no_measurement():
     att = per_op_attribution(_synthetic_anatomy(), None, chip="v4")
     assert att["chip"] == "v4" and not att["notes"]
     assert all("attributed_s" not in r for r in att["ops"])
-    empty = per_op_attribution({"device_kind": "cpu"}, 0.01)
+    empty = per_op_attribution({"device_kind": "TPU v5 lite"}, 0.01)
     assert empty["ops"] == [] and empty["notes"]
 
 

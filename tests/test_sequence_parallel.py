@@ -67,22 +67,15 @@ def test_sp_grads_match_non_sp(devices):
 
     from jax import lax
 
-    from tpu_ddp.parallel.sequence_parallel import GRAD_SYNC_IN_AD
-
     def sp_loss(params, b):
         logits = sp_model.apply({"params": params}, b["image"], train=True)
         loss = cross_entropy_loss(logits, b["label"], b.get("mask"))
         # the library's sync formulation (parallel/sequence_parallel.py):
-        # AD-of-pmean on modern jax, explicit grad collectives on the shim
-        return lax.pmean(loss, "data") if GRAD_SYNC_IN_AD else loss
+        # AD of the pmean'd loss
+        return lax.pmean(loss, "data")
 
     def sp_grads_fn(p, b):
-        g = jax.grad(sp_loss)(p, b)
-        if not GRAD_SYNC_IN_AD:
-            g = jax.tree.map(
-                lambda x: lax.pmean(lax.pmean(x, "sequence"), "data"), g
-            )
-        return g
+        return jax.grad(sp_loss)(p, b)
 
     specs = {"image": P("data", "sequence"), "label": P("data"), "mask": P("data")}
     sp_grads = jax.jit(

@@ -349,26 +349,24 @@ def test_checkpoint_completion_side_telemetry(tmp_path, devices):
     assert "checkpoint" in spans and "checkpoint_wait" in spans
 
 
-def test_compilation_cache_counters(tmp_path, devices):
+def test_compilation_cache_counters(compile_cache, devices):
     """PR-3 satellite: with the persistent compilation cache enabled
-    (TrainConfig.compilation_cache_dir / --compilation-cache-dir), cache
+    (``enable_compile_cache``, every entry point's first call), cache
     traffic surfaces as jax/cache/* counters in the default registry —
     what `trace summarize` prints in its counters snapshot — so warm
     starts are measurable, not vibes."""
     import jax
 
+    from tpu_ddp.parallel.runtime import enable_compile_cache
     from tpu_ddp.telemetry.jax_hooks import install_jax_hooks
     from tpu_ddp.telemetry.registry import (
         default_registry,
         reset_default_registry,
     )
-    from tpu_ddp.train.trainer import apply_compilation_cache
 
-    prev_dir = jax.config.jax_compilation_cache_dir
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
-        apply_compilation_cache(str(tmp_path / "xla-cache"))
-        # the helper floors at 1s (TPU compiles); CPU test compiles are
+        assert enable_compile_cache() == compile_cache
+        # jax only caches compiles of 1 s and more; CPU test compiles are
         # sub-ms, so drop the floor to force cache traffic here
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         reset_default_registry()
@@ -381,15 +379,6 @@ def test_compilation_cache_counters(tmp_path, devices):
         assert snap.get("jax/cache/cache_misses", 0) >= 1
         assert snap.get("jax/cache/cache_hits", 0) >= 1
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prev_min)
-        try:  # un-latch again so later tests re-evaluate with prev config
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass
         reset_default_registry()
 
 

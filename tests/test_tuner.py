@@ -288,14 +288,19 @@ def test_cost_model_free_anatomy_unpriceable():
     assert p.status == "unpriceable"
 
 
-# -- the BENCH_r04 ordering pin -------------------------------------------
+# -- the layout-sweep ordering pin ----------------------------------------
 
 
-def test_bench_r04_sweep_ranks_measured_best_first(devices):
-    """The 4 recorded netresdeep layout points (BENCH_r04 sweep leg:
-    84k->289k img/s across (K, per-shard) in {32,128} x {32,256}): the
-    tuner's predicted ranking must put the measured-best point —
-    per-shard 256, K=128 — first."""
+def test_layout_sweep_ranks_deeper_scan_fusion_first(devices):
+    """The 4 netresdeep layout points of the old builder sweep, (K,
+    per-shard) in {32,128} x {32,256}: at each batch the tuner must rank
+    the deeper scan fusion first — the amortized dispatch term is the one
+    thing that separates two K of one compiled program.
+
+    Across batches nothing is pinned: that order comes from the roofline
+    over XLA:CPU's bytes_accessed estimate, which moves with the XLA
+    version (per-image bytes at b32 vs b256: lower on jax 0.4.37, 17%
+    higher on 0.9.0). ROADMAP D7 calibrates it against measured cells."""
     from tpu_ddp.models import NetResDeep
 
     model = NetResDeep()  # the full reference model the sweep measured
@@ -307,8 +312,11 @@ def test_bench_r04_sweep_ranks_measured_best_first(devices):
     # single-device programs have no collectives: the fingerprint tier
     # must not reject them (lint_label -> dp@single)
     assert res.excluded == []
-    best = res.winner.candidate
-    assert (best.per_shard_batch, best.steps_per_call) == (256, 128)
+    order = [(r.candidate.per_shard_batch, r.candidate.steps_per_call)
+             for r in res.ranked]
+    for batch in (32, 256):
+        assert order.index((batch, 128)) < order.index((batch, 32))
+    assert res.winner.candidate.steps_per_call == 128
 
 
 # -- calibration -----------------------------------------------------------
@@ -549,16 +557,36 @@ def test_bench_reads_winner_artifact(tmp_path):
         bench._read_winner_config(str(path4))
 
 
-def test_bench_config_child_fails_loudly_on_error(tmp_path, capsys):
+def test_bench_config_child_fails_loudly_on_error(tmp_path, capsys,
+                                                  monkeypatch):
     """A failed winner measurement must exit nonzero — a CI step
     gating on `bench.py --config` can never read 0.0 as a pass."""
     import bench
 
+    monkeypatch.setattr(bench, "_require_tpu", lambda: ("tpu", "test"))
     with pytest.raises(SystemExit) as exc:
         bench.config_child_main(str(tmp_path / "missing.json"))
     assert exc.value.code == 1
     record = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert record["ok"] is False
     assert record["value"] == 0.0 and "error" in record
+
+
+@pytest.mark.parametrize("child", ["config_child_main", "child_main"])
+def test_bench_child_refuses_a_platform_that_is_not_tpu(tmp_path, capsys,
+                                                        child):
+    """bench.py measures the chip or nothing: on the CPU the child exits
+    non-zero with ``"ok": false`` and the device it found, before any
+    leg runs."""
+    import bench
+
+    args = (str(tmp_path / "winner.json"),) if "config" in child else ()
+    with pytest.raises(SystemExit) as exc:
+        getattr(bench, child)(*args)
+    assert exc.value.code == 1
+    record = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert record["ok"] is False and "value" not in record
+    assert record["device"]["platform"] == "cpu"
 
 
 def test_memplan_json_flag(tmp_path, monkeypatch):

@@ -478,7 +478,11 @@ def test_zero1_checkpoint_roundtrip(tmp_path, devices, first, second):
     ref_opt = ref.state.opt_state
     b_opt = (b._zero1.deshard_opt_state(b.state.opt_state)
              if b._zero1 is not None else b.state.opt_state)
-    _trees_close(ref_opt, b_opt, atol=1e-4)
+    # the momentum buffers of this deep tied-block model amplify the
+    # reduction-order difference between the two layouts: 2e-6 after one
+    # epoch, 4e-4 after two (measured on jax 0.9.0's XLA:CPU with NO
+    # checkpoint in between; a same-layout resume is exact to 0.0)
+    _trees_close(ref_opt, b_opt, atol=1e-3)
 
 
 @pytest.mark.slow  # ~22s; test_ema covers the trainer EMA path — make test-all
@@ -538,13 +542,11 @@ def test_zero1_sharded_clip_matches_optax(devices):
             shards = jax.tree.map(shard, tree)
             clipped, _ = clip_by_global_norm_sharded(
                 max_norm, "data").update(shards, None)
-            return jax.tree.map(
-                lambda x: lax.all_gather(x, "data", axis=0, tiled=True),
-                clipped,
-            )
+            return clipped
 
+        # out_specs P("data") reassembles the shards in axis order
         out = jax.jit(jax.shard_map(
-            body, mesh=mesh, in_specs=(P(),), out_specs=P()))(full)
+            body, mesh=mesh, in_specs=(P(),), out_specs=P("data")))(full)
         for k in full:
             np.testing.assert_allclose(
                 np.asarray(out[k])[: full[k].size], np.asarray(ref[k]),

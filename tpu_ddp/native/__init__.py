@@ -1,93 +1,75 @@
 """ctypes bindings for the native host data-path library.
 
-Builds ``libcifar_codec.so`` from the in-tree C++ source on first import
-(g++ is part of the toolchain; no pybind11 in this image, so the binding is
-a plain C ABI + ctypes). Every entry point has a numpy fallback — importing
-this package NEVER fails because of a missing/broken toolchain; check
-``AVAILABLE`` to know which path is live.
+Builds the library from the in-tree C++ sources on first import (g++ is
+part of the toolchain; no pybind11 in this image, so the binding is a plain
+C ABI + ctypes) into ``_build/`` beside them — a fixed, git-ignored
+directory in the checkout — under a name keyed by a hash of the sources.
+Only a library built from exactly the tracked sources is ever loaded: no
+prebuilt ``.so`` beside them, and no file times as evidence of freshness
+(a copied tree keeps neither). Every entry point has a numpy fallback —
+importing this package NEVER fails because of a missing/broken toolchain;
+check ``AVAILABLE`` to know which path is live.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
-import tempfile
 
 import numpy as np
 
 log = logging.getLogger(__name__)
 
+_DIR = os.path.dirname(os.path.abspath(__file__))
 _SRCS = [
-    os.path.join(os.path.dirname(__file__), "cifar_codec.cpp"),
-    os.path.join(os.path.dirname(__file__), "prefetcher.cpp"),
+    os.path.join(_DIR, "cifar_codec.cpp"),
+    os.path.join(_DIR, "prefetcher.cpp"),
 ]
-# headers count toward staleness, not toward the compile line
-_HDRS = [os.path.join(os.path.dirname(__file__), "parallel_for.h")]
-_LIB_NAME = "libcifar_codec.so"
+# headers count toward the build key, not toward the compile line
+_HDRS = [os.path.join(_DIR, "parallel_for.h")]
+_BUILD_DIR = os.path.join(_DIR, "_build")
 
 AVAILABLE = False
 _lib = None
 
 
-def _user_cache_dir() -> str:
-    """Per-user, 0700 cache dir — never a world-writable shared /tmp path
-    (another user could otherwise pre-plant a .so that CDLL would execute)."""
-    xdg = os.environ.get("XDG_CACHE_HOME")
-    if xdg:  # unset OR empty both fall through to the per-uid tmp dir
-        path = os.path.join(xdg, "tpu_ddp_native")
-    else:
-        path = os.path.join(
-            tempfile.gettempdir(), f"tpu_ddp_native_{os.getuid()}"
-        )
-    os.makedirs(path, mode=0o700, exist_ok=True)
-    if os.stat(path).st_uid != os.getuid():
-        raise OSError(f"cache dir {path} owned by another user")
-    os.chmod(path, 0o700)  # makedirs mode is umask-masked / ignored if it existed
-    return path
+def _library_path() -> str:
+    """``_build/libcifar_codec-<hash of the sources>.so``."""
+    digest = hashlib.sha256()
+    for path in _SRCS + _HDRS:
+        digest.update(os.path.basename(path).encode())
+        with open(path, "rb") as f:
+            digest.update(f.read())
+    return os.path.join(
+        _BUILD_DIR, f"libcifar_codec-{digest.hexdigest()[:16]}.so")
 
 
 def _build_and_load():
     global AVAILABLE, _lib
-    # Prefer a prebuilt .so next to the source; else build into a per-user
-    # cache dir.
+    out = _library_path()
+    # Build to a process-unique temp name, then rename atomically so a
+    # concurrent importer never dlopens a half-written file.
+    tmp_out = f"{out}.{os.getpid()}.tmp"
     try:
-        cache = _user_cache_dir()
-    except OSError as e:
-        log.warning("native cifar_codec cache unusable (%s); numpy fallback", e)
-        return
-    candidates = [
-        os.path.join(os.path.dirname(__file__), _LIB_NAME),
-        os.path.join(cache, _LIB_NAME),
-    ]
-    src_mtime = max(os.path.getmtime(s) for s in _SRCS + _HDRS)
-    for path in candidates:
-        if os.path.exists(path) and os.path.getmtime(path) >= src_mtime:
-            try:
-                _lib = ctypes.CDLL(path)
-                break
-            except OSError:
-                pass
-    if _lib is None:
-        out = candidates[1]
-        # Build to a process-unique temp name, then rename atomically so a
-        # concurrent importer never dlopens a half-written file.
-        tmp_out = f"{out}.{os.getpid()}.tmp"
-        cmd = [
-            "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-            "-o", tmp_out, *_SRCS, "-lpthread",
-        ]
-        try:
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        if not os.path.exists(out):
+            os.makedirs(_BUILD_DIR, exist_ok=True)
+            subprocess.run(
+                ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
+                 "-o", tmp_out, *_SRCS, "-lpthread"],
+                check=True, capture_output=True, timeout=120,
+            )
             os.replace(tmp_out, out)
-            _lib = ctypes.CDLL(out)
-        except Exception as e:  # toolchain missing/failed -> numpy fallback
-            log.warning("native cifar_codec build failed (%s); numpy fallback", e)
-            if os.path.exists(tmp_out):
-                os.unlink(tmp_out)
-            return
-    try:  # a stale/foreign prebuilt .so must degrade to numpy, not raise
+        _lib = ctypes.CDLL(out)
+    except (OSError, subprocess.SubprocessError) as e:
+        # toolchain missing/failed, or an unwritable checkout
+        log.warning("native cifar_codec build failed (%s); numpy fallback", e)
+        if os.path.exists(tmp_out):
+            os.unlink(tmp_out)
+        return
+    try:  # an unusable library must degrade to numpy, not raise
         _lib.cifar_decode_normalize.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
             ctypes.c_void_p, ctypes.c_void_p,

@@ -19,20 +19,22 @@ on or off (pinned by ``tests/test_fused_kernels.py``).
 
 Same house rules as ``flash_attention.py``: ``interpret=None`` resolves
 to compiled-on-TPU / interpret-on-CPU via ``_resolve_interpret``; under
-a shard_map on a check_vma jax the interpreter cannot run (vma-carrying
-avals), so the jnp reference path is taken there; shapes the TPU tiling
-cannot serve (``block % 128 != 0``) also fall back to the reference.
+a shard_map the interpreter cannot run (vma-carrying avals), so the jnp
+reference path is taken there (CPU only — a TPU never interprets). A
+block the TPU tiling cannot serve (``block % 128 != 0``) raises: the
+kernel asked for is the kernel run, never a quiet reference.
 """
 
 from __future__ import annotations
 
-import jax
-
-import tpu_ddp.compat  # noqa: F401  (jax.shard_map/typeof shims)
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from tpu_ddp.ops.flash_attention import _resolve_interpret
+from tpu_ddp.ops.flash_attention import (
+    _interpreted_under_shard_map,
+    _resolve_interpret,
+    _sds,
+)
 
 LANE = 128
 #: sublane multiple for f32 tiles — block rows per grid step are padded
@@ -45,6 +47,13 @@ _MAX_ROWS = 256
 def supports_block(block: int) -> bool:
     """The TPU tiling serves a block iff it fills whole lanes."""
     return block % LANE == 0
+
+
+def _require_block(block: int) -> None:
+    if not supports_block(block):
+        raise ValueError(
+            f"fused int8 quantize kernels need a block that fills whole "
+            f"lanes (a multiple of {LANE}), got block={block}")
 
 
 def _rows_plan(nb: int):
@@ -68,15 +77,14 @@ def _quant_kernel(x_ref, q_ref, s_ref):
 
 def fused_quant(x, block: int, *, interpret=None) -> dict:
     """``quantize_chunk(x, "int8", block)`` as one fused pass: 1-D f32
-    chunk -> ``{"q": int8 (nb*block,), "scale": f32 (nb,)}``. Falls back
-    to the jnp reference off the supported tilings."""
+    chunk -> ``{"q": int8 (nb*block,), "scale": f32 (nb,)}``."""
     from tpu_ddp.parallel.compression import quantize_chunk
 
+    _require_block(block)
     interpret = _resolve_interpret(interpret)
     size = x.shape[0]
     nb = -(-size // block)
-    if (not supports_block(block)
-            or (interpret and bool(getattr(jax.typeof(x), "vma", None)))):
+    if _interpreted_under_shard_map(x, interpret):
         return quantize_chunk(x, "int8", block)
     pad = nb * block - size
     if pad:
@@ -93,8 +101,8 @@ def fused_quant(x, block: int, *, interpret=None) -> dict:
         out_specs=[pl.BlockSpec((br, block), lambda i: (i, 0)),
                    pl.BlockSpec((br, LANE), lambda i: (i, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((nb_pad, block), jnp.int8),
-            jax.ShapeDtypeStruct((nb_pad, LANE), jnp.float32),
+            _sds((nb_pad, block), jnp.int8, xb),
+            _sds((nb_pad, LANE), jnp.float32, xb),
         ],
         interpret=interpret,
     )(xb)
@@ -122,19 +130,14 @@ def fused_dequant(payload: dict, block: int, size: int, *,
     """``dequantize_chunk(payload, "int8", block, size)`` as one fused
     pass — with ``add_to`` given, the ring-hop accumulate ``add_to +
     dequant(payload)`` rides in the same pass (one read of each operand,
-    one write). Falls back to the jnp reference off the supported
-    tilings."""
-    from tpu_ddp.parallel.compression import dequantize_chunk
-
+    one write)."""
+    _require_block(block)
     interpret = _resolve_interpret(interpret)
     nb = -(-size // block)
     q = payload["q"]
     scale = payload["scale"]
-    if (not supports_block(block)
-            or (interpret
-                and bool(getattr(jax.typeof(q), "vma", None)))):
-        d = dequantize_chunk(payload, "int8", block, size)
-        return d if add_to is None else add_to + d
+    if _interpreted_under_shard_map(q, interpret):
+        return _reference_dequant(payload, block, size, add_to=add_to)
     br, nb_pad = _rows_plan(nb)
     qb = q.reshape(nb, block)
     sb = jnp.broadcast_to(scale[:, None], (nb, LANE))
@@ -164,7 +167,7 @@ def fused_dequant(payload: dict, block: int, size: int, *,
         grid=(nb_pad // br,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb_pad, block), jnp.float32),
+        out_shape=_sds((nb_pad, block), jnp.float32, *operands),
         interpret=interpret,
     )(*operands)
     return out[:nb].reshape(-1)[:size]

@@ -64,9 +64,8 @@ kernel-machinery path unit tests and ``ops bench`` exercise (asserting
 allclose everywhere and bitwise where the program shape permits:
 moments, fresh-state steps, quantization). On TPU the compiled kernel's
 proof is statistical, not bitwise: ``curves --against`` the XLA path.
-Under shard_map on a check_vma jax the interpreter cannot run
-(vma-carrying avals), so ``interpret=True`` also falls back to the
-mirror there.
+Under shard_map the interpreter cannot run (vma-carrying avals), so
+``interpret=True`` also falls back to the mirror there.
 """
 
 from __future__ import annotations
@@ -75,15 +74,17 @@ import dataclasses
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
-
-import tpu_ddp.compat  # noqa: F401  (jax.shard_map/typeof shims)
 import jax.numpy as jnp
 import optax
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_ddp.ops.flash_attention import _resolve_interpret
+from tpu_ddp.ops.flash_attention import (
+    _interpreted_under_shard_map,
+    _resolve_interpret,
+    _sds,
+)
 from tpu_ddp.parallel.runtime import is_tpu_device
 
 LANE = 128
@@ -244,12 +245,9 @@ def _fused_leaf(g, p, m, v, e, smem, start, *, kind, momentum, wd,
         if x is not None:
             operands.append(x)
             in_specs.append(tile_spec())
-    out_shapes = [jax.ShapeDtypeStruct((rows_pad, LANE), g.dtype),
-                  jax.ShapeDtypeStruct((rows_pad, LANE), p.dtype)]
-    for x in (m2, v2, e2):
-        if x is not None:
-            out_shapes.append(
-                jax.ShapeDtypeStruct((rows_pad, LANE), x.dtype))
+    # every output mixes every operand: it varies over their union
+    out_shapes = [_sds((rows_pad, LANE), x.dtype, *operands)
+                  for x in (g2, p2, m2, v2, e2) if x is not None]
     outs = pl.pallas_call(
         _build_kernel(kind=kind, momentum=momentum, wd=wd,
                       wd_apply=wd_apply, has_clip=has_clip,
@@ -390,9 +388,7 @@ class FusedUpdate:
             use_ref = not is_tpu_device()
         else:
             interpret = _resolve_interpret(self.interpret)
-            use_ref = interpret and any(
-                bool(getattr(jax.typeof(x), "vma", None))
-                for x in g_leaves[:1])
+            use_ref = _interpreted_under_shard_map(g_leaves[0], interpret)
 
         # moment leaves align with the TRAINABLE grad leaves in DFS
         # order (frozen positions are MaskedNode: zero-leaf nodes)

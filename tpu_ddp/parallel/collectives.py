@@ -16,10 +16,9 @@ from __future__ import annotations
 import functools
 
 import jax
-
-import tpu_ddp.compat  # noqa: F401  (jax.shard_map/typeof shims)
 import jax.numpy as jnp
 from jax import lax
+from jax._src.lax.parallel import all_gather_invariant
 
 # ---- ring hop hook (the comms-observatory / chaos seam) ------------------
 #
@@ -294,8 +293,13 @@ def ring_all_reduce(x, axis: str, *, mode: str = "f32", block: int = 256,
         e = chunk - _dequant(payload, mode, block, s, kernels)
         idx = lax.axis_index(axis)
         err = lax.dynamic_update_slice(err, e, (idx * s,))
+    # the invariant gather: every device receives the same bytes, and the
+    # shard_map checker types the result as replicated over ``axis`` — so
+    # the synced grads (and the params updated from them) leave the step
+    # under a P() out_spec
     gathered = jax.tree.map(
-        lambda t: lax.all_gather(t, axis, axis=0, tiled=False), payload)
+        lambda t: all_gather_invariant(t, axis, axis=0, tiled=False),
+        payload)
     rows = jnp.stack([
         _dequant(jax.tree.map(lambda t: t[i], gathered),
                  mode, block, s, kernels)

@@ -40,13 +40,10 @@ from __future__ import annotations
 import dataclasses
 
 import jax
-
-import tpu_ddp.compat  # noqa: F401  (jax.shard_map shims + all_gather rule)
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_ddp.compat import GRAD_SYNC_IN_AD
 from tpu_ddp.parallel.mesh import DATA_AXIS
 
 #: Wire modes the config surface accepts ("none" = feature off).
@@ -214,13 +211,9 @@ class GradCompressor:
 
     def varying(self, params):
         """Params as differentiation input (same convention as
-        ``Zero1Partition.varying``): on modern check_vma jax the
-        replicated params are pcast to varying so AD yields LOCAL
-        gradients — the compressed ring IS the sync; identity on the
-        shimmed 0.4.x runtime (whose builders differentiate the local
-        loss anyway)."""
-        if not GRAD_SYNC_IN_AD:
-            return params
+        ``Zero1Partition.varying``): the replicated params are pcast to
+        varying so AD yields LOCAL gradients — the compressed ring IS the
+        sync."""
         return jax.tree.map(
             lambda p: lax.pcast(p, (self.axis,), to="varying"), params
         )

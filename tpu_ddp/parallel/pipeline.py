@@ -30,15 +30,12 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
-
-import tpu_ddp.compat  # noqa: F401  (jax.shard_map/typeof shims)
 import jax.numpy as jnp
 import optax
 from flax import linen as nn
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpu_ddp.compat import GRAD_SYNC_IN_AD
 from tpu_ddp.health.stats import (
     HealthConfig,
     assemble_stats,
@@ -262,9 +259,8 @@ def make_pp_train_step(
         # The tick body makes the carry vary over the pipeline axis (stage
         # index, ppermute); shard_map's varying-axes tracking requires the
         # initial carry to carry the same marking.
-        if hasattr(lax, "pcast"):
-            act = lax.pcast(act, (data_axis, pipe_axis), to="varying")
-            outs = lax.pcast(outs, (pipe_axis,), to="varying")
+        act = lax.pcast(act, (data_axis, pipe_axis), to="varying")
+        outs = lax.pcast(outs, (pipe_axis,), to="varying")
 
         def tick(carry, t):
             act, outs = carry
@@ -293,40 +289,12 @@ def make_pp_train_step(
     def compute_loss(params, batch):
         logits = forward(params, batch["image"])
         loss = loss_fn(logits, batch["label"], batch.get("mask"))
-        if GRAD_SYNC_IN_AD:
-            loss = lax.pmean(loss, data_axis)
-        else:
-            # SHIMMED: old jax transposes forward's logits psum back to a
-            # psum, so the n_stages identical per-stage loss seeds re-sum
-            # into an n_stages over-count of every cotangent; pre-scaling
-            # the differentiated value cancels it (metric rescaled below)
-            loss = loss / n_stages
-        return loss, logits
+        return lax.pmean(loss, data_axis), logits
 
     def shard_step(state: TrainState, batch):
         (loss, logits), grads = jax.value_and_grad(compute_loss, has_aux=True)(
             state.params, batch
         )
-        if not GRAD_SYNC_IN_AD:
-            loss = loss * n_stages
-            # the explicit version of what AD-of-pmean inserts on modern
-            # jax (mirrors the 1F1B manual backward): stage-sharded
-            # `blocks` grads only DDP-average over data; replicated params
-            # (embed/head) are each nonzero on exactly one stage, so their
-            # grads psum over the pipeline axis first
-            grads = {
-                k: (
-                    jax.tree.map(lambda g: lax.pmean(g, data_axis), v)
-                    if k == "blocks"
-                    else jax.tree.map(
-                        lambda g: lax.pmean(
-                            lax.psum(g, pipe_axis), data_axis
-                        ), v,
-                    )
-                )
-                for k, v in grads.items()
-            }
-            loss = lax.pmean(loss, data_axis)
         updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
         correct, count = masked_accuracy(logits, batch["label"], batch.get("mask"))
@@ -411,11 +379,8 @@ def _pcast_varying(tree, axes):
     """pcast every leaf to varying over whichever of ``axes`` it lacks —
     shared by the 1F1B carry init and its param-tree preparation (leaves
     derived from stage-sharded params are already pipeline-varying)."""
-    if not hasattr(lax, "pcast"):
-        return tree
-
     def one(x):
-        have = set(getattr(jax.typeof(x), "vma", ()) or ())
+        have = jax.typeof(x).vma
         need = tuple(a for a in axes if a not in have)
         return lax.pcast(x, need, to="varying") if need else x
 
@@ -515,11 +480,8 @@ def make_pp_1f1b_train_step(
         def seed_like(x, ref):
             # vjp cotangent seeds must carry the primal output's varying
             # axes (fresh ones()/zeros() are device-invariant)
-            if not hasattr(lax, "pcast"):
-                return x
-            have = set(getattr(jax.typeof(x), "vma", ()) or ())
-            need = tuple(a for a in (getattr(jax.typeof(ref), "vma", ())
-                                     or ()) if a not in have)
+            have = jax.typeof(x).vma
+            need = tuple(a for a in jax.typeof(ref).vma if a not in have)
             return lax.pcast(x, need, to="varying") if need else x
 
         zero_g_blocks = jax.tree.map(jnp.zeros_like, stage_blocks)

@@ -32,8 +32,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-import tpu_ddp.compat  # noqa: F401  (jax.shard_map/typeof shims)
 import jax.numpy as jnp
 from jax import lax
 
@@ -175,26 +173,18 @@ def _fold_lse(lse):
 
 def _use_kernels(q, block_q, block_k, interpret, kv_mask=None) -> bool:
     from tpu_ddp.ops.flash_attention import (
-        _mask_tileable,
-        _plan,
+        _interpreted_under_shard_map,
         _resolve_interpret,
+        _unpadded_plan,
     )
 
-    interp = _resolve_interpret(interpret)
-    plan = _plan(q.shape, block_q, block_k)
-    if plan is None:
-        return False
     # interpret-mode pallas under shard_map trips the hlo-interpreter vma
-    # check (see ops/flash_attention.py::_flash_forward) — jnp path there
-    if interp and bool(getattr(jax.typeof(q), "vma", None)):
+    # check (see ops/flash_attention.py) — the jnp tile there, CPU only
+    if _interpreted_under_shard_map(q, _resolve_interpret(interpret)):
         return False
-    # the compiled masked kernel additionally needs a Mosaic-legal mask
-    # block; _flash_forward falls back to jnp in that case and returns
-    # lse=None, which the ring's kernel path cannot consume — gate here so
-    # the whole ring takes the jnp tile instead
-    if (kv_mask is not None and not interp
-            and not _mask_tileable(q.shape[1], plan[1])):
-        return False
+    # the kernels were asked for: a local block they cannot tile raises
+    # (naming the shape) rather than quietly taking the jnp tile
+    _unpadded_plan(q.shape, block_q, block_k, kv_mask is not None)
     return True
 
 
@@ -330,7 +320,7 @@ def _rf_bwd(axis_name, block_q, block_k, interpret, causal, res, g):
     # NaN in the accumulator before any hop.
     def _zeros_like_varying(x):
         z = jnp.zeros(x.shape, f32)
-        vma = tuple(getattr(jax.typeof(x), "vma", ()) or ())
+        vma = tuple(jax.typeof(x).vma)
         return lax.pcast(z, vma, to="varying") if vma else z
 
     dq = _zeros_like_varying(q)

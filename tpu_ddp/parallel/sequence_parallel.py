@@ -19,13 +19,10 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
-
-import tpu_ddp.compat  # noqa: F401  (jax.shard_map/typeof shims)
 import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpu_ddp.compat import GRAD_SYNC_IN_AD
 from tpu_ddp.health.stats import HealthConfig, guard_step, health_stats
 from tpu_ddp.parallel.mesh import DATA_AXIS, SEQUENCE_AXIS
 from tpu_ddp.train.losses import cross_entropy_loss
@@ -75,11 +72,10 @@ def make_sp_train_step(
         # AD). Over `data_axis` ONLY: the SP model's mean-pool pmean already
         # made the loss invariant over `seq_axis`, and shard_map's
         # varying-axes tracking inserts the correct sequence-axis psums for
-        # the distributed attention partials during the transpose. SHIMMED
-        # jax: both collectives move to the explicit grad sync below.
+        # the distributed attention partials during the transpose.
         # zero1/compress: the data sync is the (ring) reduce-scatter —
         # the loss stays local.
-        if GRAD_SYNC_IN_AD and zero1 is None and compress is None:
+        if zero1 is None and compress is None:
             return lax.pmean(loss, data_axis)
         return loss
 
@@ -91,20 +87,7 @@ def make_sp_train_step(
         else:
             p_in = state.params
         loss, grads = jax.value_and_grad(compute_loss)(p_in, batch)
-        data_local = zero1 is not None or compress is not None
-        if not GRAD_SYNC_IN_AD:
-            # On old jax, psum transposes to psum: the n_seq identical
-            # replicated-loss seeds re-sum through the model's pooling
-            # psum, so every partial arrives n_seq-fold — pmean (not
-            # psum) over the ring both sums the per-shard partials and
-            # cancels that factor; then DDP-average over data (zero1/
-            # compress: over data the average moves into the ring).
-            seq_done = jax.tree.map(
-                lambda g: lax.pmean(g, seq_axis), grads)
-            grads = (seq_done if data_local else jax.tree.map(
-                lambda g: lax.pmean(g, data_axis), seq_done))
-            loss = lax.pmean(loss, data_axis)
-        elif data_local:
+        if zero1 is not None or compress is not None:
             loss = lax.pmean(loss, data_axis)
         ef = compress is not None and compress.config.error_feedback
         want_err = compress is not None and (ef or health is not None)
@@ -124,8 +107,7 @@ def make_sp_train_step(
         new_residual = err_state if ef else state.grad_residual
         metrics = {"loss": loss}
         if health is not None:
-            # grads are synced over BOTH mesh axes by this point (either
-            # sync mode; zero1's shards are seq-complete and data-
+            # grads are synced over BOTH mesh axes by this point (zero1's shards are seq-complete and data-
             # scattered, psum'd back to globals inside health_stats), so
             # the stats are true globals — same schema as the DP step
             err_sq = compress.error_sq(err_state) if want_err else None

@@ -40,11 +40,12 @@ shards exactly. Global-norm clipping needs the cross-shard psum this module
 provides (``clip_by_global_norm_sharded``). LAMB's per-layer trust ratios
 need whole-leaf norms and are rejected at config validation.
 
-Old/new jax: on the shimmed 0.4.x runtime the builders differentiate the
-LOCAL loss and this module's reduce-scatter IS the gradient sync; on modern
-check_vma jax the builders pcast the params to varying first (``varying``)
-so AD produces local gradients without inserting its own psum — same
-convention as ``GRAD_SYNC_IN_AD`` (tpu_ddp.compat).
+Gradient sync: the builders pcast the params to varying first
+(``varying``) so AD produces LOCAL gradients without inserting its own
+psum, and this module's reduce-scatter IS the sync. The updated shards come
+back through ``all_gather_invariant``, whose result shard_map's checker
+types as replicated over the gathered axis — what the ``P()`` out_spec of
+the params claims.
 """
 
 from __future__ import annotations
@@ -53,14 +54,12 @@ import dataclasses
 from typing import Optional
 
 import jax
-
-import tpu_ddp.compat  # noqa: F401  (shard_map shims + all_gather rep rule)
 import jax.numpy as jnp
 import optax
 from jax import lax
+from jax._src.lax.parallel import all_gather_invariant
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_ddp.compat import GRAD_SYNC_IN_AD
 from tpu_ddp.health.stats import assemble_stats, per_layer_sq, tree_nonfinite, tree_sq
 from tpu_ddp.parallel.mesh import DATA_AXIS
 from tpu_ddp.parallel.partitioning import _path_str
@@ -248,17 +247,14 @@ class Zero1Partition:
         (the once-per-step all-gather)."""
 
         def ag(x):
-            return lax.all_gather(x, self.axis, axis=0, tiled=True)
+            return all_gather_invariant(x, self.axis, axis=0, tiled=True)
 
         return self.unflatten(jax.tree.map(ag, shard_tree))
 
     def varying(self, params):
-        """Params as differentiation input: on modern (check_vma) jax the
-        replicated params are pcast to varying OUTSIDE the grad closure so
-        AD yields LOCAL gradients (no automatic psum — the reduce-scatter
-        is the sync); identity on shimmed 0.4.x."""
-        if not GRAD_SYNC_IN_AD:
-            return params
+        """Params as differentiation input: the replicated params are
+        pcast to varying OUTSIDE the grad closure so AD yields LOCAL
+        gradients (no automatic psum — the reduce-scatter is the sync)."""
         return jax.tree.map(
             lambda p: lax.pcast(p, (self.axis,), to="varying"), params
         )

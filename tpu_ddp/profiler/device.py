@@ -20,9 +20,9 @@ Two independent halves:
   in proportion. The result reads "of the measured 12.1 ms step, ~1.8 ms
   sits in ``all-gather/f32/data/g8``, 2.3× what the roofline predicts".
   Deviceless-safe: the math needs only the anatomy (which compiles on
-  the CPU CI mesh) and a chip spec — a host with no published peak (the
-  CPU mesh) is attributed against v5e with a note, exactly like
-  ``tpu-ddp analyze --chip``.
+  the CPU CI mesh) and a chip spec. A device with no published peak (the
+  CPU mesh) is refused with a note unless ``--chip`` names the chip to
+  attribute against — no chip is assumed.
 
 ``per_op_attribution`` is pure stdlib over an anatomy record;
 ``attribution_for_bundle`` is the jax-backed convenience that rebuilds
@@ -40,10 +40,6 @@ log = logging.getLogger(__name__)
 
 #: bump on any breaking change to the attribution record shape
 ATTRIBUTION_SCHEMA_VERSION = 1
-
-#: chip the attribution falls back to when the recorded device kind has
-#: no published peak (the CPU test mesh) and no --chip was passed
-_FALLBACK_CHIP = "v5e"
 
 
 # -- device trace arming ---------------------------------------------------
@@ -92,7 +88,9 @@ def per_op_attribution(anatomy, measured_step_s: Optional[float],
     ``attributed_s = measured_step_s * share`` plus ``vs_model`` (the
     measured-over-predicted ratio, the "this collective runs 2.3× the
     ring model" verdict). Attributed times sum to the measured span by
-    construction. Stdlib + the chip-spec table only.
+    construction. Stdlib + the chip-spec table only. A device kind with
+    no published peak (and no ``chip`` to stand for it) returns
+    ``{"note": ...}`` — the shape ``attribution_for_bundle`` degrades to.
     """
     from tpu_ddp.analysis.roofline import chip_spec
 
@@ -101,11 +99,8 @@ def per_op_attribution(anatomy, measured_step_s: Optional[float],
     kind = chip or rec.get("device_kind")
     spec = chip_spec(kind)
     if spec is None or spec.peak_bf16_flops is None:
-        notes.append(
-            f"no published peak for {kind!r}: attributing against "
-            f"{_FALLBACK_CHIP} (pass --chip to choose)"
-        )
-        spec = chip_spec(_FALLBACK_CHIP)
+        return {"note": f"no published peak for {kind!r}: pass --chip to "
+                        "name the chip to attribute against"}
 
     rows: List[Dict[str, object]] = []
     flops = rec.get("flops")

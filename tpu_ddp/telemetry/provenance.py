@@ -106,7 +106,6 @@ QUALITY_DIGEST_EXCLUDED = (
     "jsonl_path",
     "tensorboard_dir",
     "profile_dir",
-    "compilation_cache_dir",
     "plot_curves",
     "dump_predictions",
     # run-local observability/process wiring (no effect on the update rule)

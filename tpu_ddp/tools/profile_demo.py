@@ -185,11 +185,20 @@ def check_report(run_dir: str) -> bool:
     from tpu_ddp.cli.main import main as cli_main
     from tpu_ddp.telemetry.summarize import summarize
 
+    # the CPU mesh has no published peak: without --chip the join must
+    # REFUSE with a note (no chip is assumed), with it the table renders
     buf = io.StringIO()
     with contextlib.redirect_stdout(buf):
-        rc = cli_main(["profile", run_dir])
+        cli_main(["profile", run_dir])
+    refused = buf.getvalue()
+    buf = io.StringIO()
+    with contextlib.redirect_stdout(buf):
+        rc = cli_main(["profile", run_dir, "--chip", "v5e"])
     out = buf.getvalue()
     ok = True
+    if "pass --chip" not in refused:
+        _fail("expected the no-published-peak refusal without --chip")
+        ok = False
     if rc != 0:
         _fail(f"tpu-ddp profile exited {rc}")
         ok = False
@@ -198,10 +207,6 @@ def check_report(run_dir: str) -> bool:
         ok = False
     if "per-op attribution" not in out or "note: per-op attribution" in out:
         _fail("per-op attribution table did not render:\n" + out[-2000:])
-        ok = False
-    # on the CPU mesh the join must DEGRADE (v5e fallback note), not err
-    if "attributing against v5e" not in out:
-        _fail("expected the documented cpu->v5e attribution note")
         ok = False
     summary = summarize(run_dir)
     if "profiler:" not in summary or "capture window(s)" not in summary:

@@ -20,14 +20,11 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
-
-import tpu_ddp.compat  # noqa: F401  (jax.shard_map/typeof shims)
 import jax.numpy as jnp
 import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpu_ddp.compat import GRAD_SYNC_IN_AD
 from tpu_ddp.health.stats import HealthConfig, guard_step, health_stats
 from tpu_ddp.parallel.mesh import DATA_AXIS, SEQUENCE_AXIS
 from tpu_ddp.train.optim import apply_optimizer
@@ -85,10 +82,9 @@ def make_lm_train_step(
             loss = _token_nll(logits[:, :-1], tokens[:, 1:]).mean()
             # pmean BEFORE differentiation: AD of the averaged loss emits
             # the cross-shard grad psum (the DDP semantics, exactly as in
-            # train/steps.py). SHIMMED jax: sync moves to the explicit
-            # grad pmean below. zero1/compress: the sync is the (ring)
-            # reduce-scatter — the loss stays local in both modes.
-            if GRAD_SYNC_IN_AD and zero1 is None and compress is None:
+            # train/steps.py). zero1/compress: the sync is the (ring)
+            # reduce-scatter — the loss stays local.
+            if zero1 is None and compress is None:
                 return lax.pmean(loss, data_axis)
             return loss
 
@@ -99,7 +95,7 @@ def make_lm_train_step(
         else:
             p_in = state.params
         loss, grads = jax.value_and_grad(compute_loss)(p_in)
-        if not GRAD_SYNC_IN_AD or zero1 is not None or compress is not None:
+        if zero1 is not None or compress is not None:
             loss = lax.pmean(loss, data_axis)
         ef = compress is not None and compress.config.error_feedback
         want_err = compress is not None and (ef or health is not None)
@@ -116,9 +112,6 @@ def make_lm_train_step(
             if compress is not None:
                 grads, err_state = compress.all_reduce_mean(
                     grads, residual, with_error=want_err)
-            elif not GRAD_SYNC_IN_AD:
-                grads = jax.tree.map(
-                    lambda g: lax.pmean(g, data_axis), grads)
             new_params, updates, new_opt = apply_optimizer(
                 tx, grads, state.opt_state, state.params)
         new_residual = err_state if ef else state.grad_residual
@@ -211,18 +204,12 @@ def make_sp_lm_train_step(
             # global mean over valid positions == the DP step's mean over
             # (B, T-1); then DDP-average over data
             loss = loss_sum / count  # already seq-invariant (psum above)
-            if GRAD_SYNC_IN_AD:
-                # zero1/compress: keep the loss data-LOCAL (the ring
-                # reduce-scatter is the data-axis sync); seq invariance
-                # already holds
-                if zero1 is not None or compress is not None:
-                    return loss
-                return lax.pmean(loss, data_axis)
-            # SHIMMED: old jax transposes the loss_sum psum back to a psum,
-            # so the n_seq identical per-shard loss seeds re-sum into an
-            # n_seq over-count of every cotangent; pre-scaling the
-            # differentiated value cancels it (the metric is rescaled below)
-            return loss / n_seq
+            # zero1/compress: keep the loss data-LOCAL (the ring
+            # reduce-scatter is the data-axis sync); seq invariance
+            # already holds
+            if zero1 is not None or compress is not None:
+                return loss
+            return lax.pmean(loss, data_axis)
 
         if zero1 is not None:
             p_in = zero1.varying(state.params)
@@ -231,17 +218,7 @@ def make_sp_lm_train_step(
         else:
             p_in = state.params
         loss, grads = jax.value_and_grad(compute_loss)(p_in)
-        data_local = zero1 is not None or compress is not None
-        if not GRAD_SYNC_IN_AD:
-            # each (data, seq) shard's AD yields its local partial of the
-            # replicated params' gradient: sum the partials over the
-            # sequence ring, then DDP-average over data (zero1/compress:
-            # the data half of the sync moves into the ring below)
-            seq_sync = (lax.psum if data_local else
-                        lambda g, ax: lax.pmean(lax.psum(g, ax), data_axis))
-            grads = jax.tree.map(lambda g: seq_sync(g, seq_axis), grads)
-            loss = lax.pmean(loss * n_seq, data_axis)
-        elif data_local:
+        if zero1 is not None or compress is not None:
             loss = lax.pmean(loss, data_axis)
         ef = compress is not None and compress.config.error_feedback
         want_err = compress is not None and (ef or health is not None)

@@ -33,14 +33,11 @@ import functools
 from typing import Callable, Optional
 
 import jax
-
-import tpu_ddp.compat  # noqa: F401  (jax.shard_map/typeof shims)
 import jax.numpy as jnp
 import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpu_ddp.compat import GRAD_SYNC_IN_AD
 from tpu_ddp.health.stats import HealthConfig, guard_step, health_stats
 from tpu_ddp.parallel.mesh import DATA_AXIS
 from tpu_ddp.train.losses import (
@@ -51,15 +48,10 @@ from tpu_ddp.train.losses import (
 from tpu_ddp.train.optim import apply_optimizer
 from tpu_ddp.train.state import TrainState
 
-# GRAD_SYNC_IN_AD (tpu_ddp.compat): where the DDP gradient sync lives.
-# Modern jax (check_vma shard_map): pmean the per-shard loss BEFORE
-# differentiation — AD's transpose of the replicated-params pbroadcast IS
-# the cross-shard psum, and XLA overlaps it with the backward pass. Old
-# jax (SHIMMED): that rep machinery cannot trace grad-of-pmean, so the
-# builders differentiate the LOCAL loss and pmean the gradients
-# explicitly — identical math (pmean is linear, so pmean-of-grads ==
-# grad-of-pmean'd-loss), just without the automatic backward/comm
-# interleaving.
+# Where the DDP gradient sync lives: the builders pmean the per-shard loss
+# BEFORE differentiation — AD's transpose of the replicated-params
+# pbroadcast IS the cross-shard psum (shard_map's check_vma rewrite), and
+# XLA overlaps it with the backward pass.
 
 
 def resolve_remat(model, remat: bool):
@@ -186,7 +178,7 @@ def _make_shard_step(
                     + (1.0 - batch["_mix_lam"])
                     * loss_fn(logits, batch["_mix_label"], batch.get("mask")))
         loss, aux = combine_aux_loss(task, mutated, aux_weight)
-        # Gradient sync lives HERE on modern jax: pmean-ing the per-shard
+        # Gradient sync lives HERE: pmean-ing the per-shard
         # loss before differentiation makes reverse-mode AD produce the
         # globally *averaged* gradient — the pmean's transpose scatters
         # cotangent 1/num_shards to every shard, and differentiating w.r.t.
@@ -195,14 +187,13 @@ def _make_shard_step(
         # global mean loss, the exact semantics of DDP's NCCL allreduce-mean
         # (main.py:63), with the collective visible to XLA for backward/comm
         # overlap. (An explicit post-hoc pmean on grads would then DOUBLE-
-        # count: AD has already summed.) On SHIMMED jax the sync is instead
-        # the explicit grad pmean in shard_step — see GRAD_SYNC_IN_AD.
+        # count: AD has already summed.)
         # Under zero1 the sync is the reduce-scatter in sharded_update, so
-        # the loss must stay LOCAL in both modes (modern jax differentiates
-        # w.r.t. pcast-varying params instead — zero1.varying below).
+        # the loss must stay LOCAL (AD differentiates w.r.t. pcast-varying
+        # params instead — zero1.varying below).
         # Under --grad-compress the sync is the quantized ring, which AD
         # cannot own either — same local-loss convention.
-        if GRAD_SYNC_IN_AD and zero1 is None and compress is None:
+        if zero1 is None and compress is None:
             loss = lax.pmean(loss, data_axis)
         return loss, (mutated.get("batch_stats", batch_stats), logits, task, aux)
 
@@ -270,21 +261,18 @@ def _make_shard_step(
                 )
         else:
             if compress is not None:
-                # the quantized ring replaces the pmean in BOTH jax sync
-                # modes (the loss stayed local above)
+                # the quantized ring replaces the pmean (the loss stayed
+                # local above)
                 with jax.named_scope("tpu_ddp.grad_compress_ring"):
                     grads, err_state = compress.all_reduce_mean(
                         grads, residual, with_error=want_err)
-            elif not GRAD_SYNC_IN_AD:
-                grads = jax.tree.map(
-                    lambda g: lax.pmean(g, data_axis), grads)
             with jax.named_scope("tpu_ddp.optimizer_update"):
                 new_params, updates, new_opt_state = apply_optimizer(
                     tx, grads, state.opt_state, state.params)
         new_residual = err_state if ef else state.grad_residual
         if health is not None:
             # grads/updates are the synchronized values in EVERY sync mode
-            # (AD-of-pmean'd-loss, the explicit pmean, the dequantized
+            # (AD-of-pmean'd-loss, the dequantized
             # ring output, or the zero1 shards whose shard-local norms are
             # psum'd over data), so every shard computes identical global
             # stats in-graph.
@@ -519,7 +507,7 @@ def make_grad_accum_train_step(
         # grad sync, as in _make_shard_step (zero1/compress: the sync is
         # the (ring) reduce-scatter AFTER accumulation — the loss stays
         # local, ONE compressed collective per accumulated batch)
-        if GRAD_SYNC_IN_AD and zero1 is None and compress is None:
+        if zero1 is None and compress is None:
             loss = lax.pmean(loss, data_axis)
         return loss, (mutated.get("batch_stats", batch_stats), logits, task, aux)
 
@@ -535,9 +523,7 @@ def make_grad_accum_train_step(
             batch,
         )
         grad_fn = jax.value_and_grad(compute_loss, has_aux=True)
-        scattered = zero1 is not None and getattr(
-            zero1, "scattered_params", False)
-        if scattered:
+        if getattr(zero1, "scattered_params", False):
             # ZeRO-3: gather ONCE, outside the scan — every microbatch
             # reuses the same streamed params (they only change at the
             # update), and grads accumulate in the gathered (original)
@@ -550,10 +536,11 @@ def make_grad_accum_train_step(
             p_in = compress.varying(state.params)
         else:
             p_in = state.params
-        # under zero3 state.params are flat shards — the accumulator must
-        # match the GRADIENT shapes, i.e. the differentiation input's
-        zero_grads = jax.tree.map(
-            jnp.zeros_like, p_in if scattered else state.params)
+        # the accumulator takes the differentiation input's shapes (under
+        # zero3 state.params are flat shards) AND its varying type: under
+        # zero1/compress the grads are LOCAL, and zeros_like keeps p_in's
+        # varying-over-data marking so the scan carry types match
+        zero_grads = jax.tree.map(jnp.zeros_like, p_in)
 
         def accum(carry, micro):
             grads_acc, stats, correct, count, loss_sum, aux_sum = carry
@@ -571,8 +558,7 @@ def make_grad_accum_train_step(
         # Values computed from shard-local data (metric scalars, fresh BN
         # stats) are VARYING over the data axis under shard_map; the carry
         # inits (zeros / the replicated incoming stats) must match that
-        # type. Gradients stay unvarying: AD of the pmean'd loss inserts
-        # the psum.
+        # type. (The grad accumulator got its type from p_in above.)
         zero = lax.pcast(jnp.zeros(()), (data_axis,), to="varying")
         stats0 = jax.tree.map(
             lambda s: lax.pcast(s, (data_axis,), to="varying"),
@@ -600,9 +586,6 @@ def make_grad_accum_train_step(
             if compress is not None:  # one compressed ring per step
                 grads, err_state = compress.all_reduce_mean(
                     grads, residual, with_error=want_err)
-            elif not GRAD_SYNC_IN_AD:  # _make_shard_step: explicit sync
-                grads = jax.tree.map(
-                    lambda g: lax.pmean(g, data_axis), grads)
             new_params, updates, new_opt_state = apply_optimizer(
                 tx, grads, state.opt_state, state.params)
         new_residual = err_state if ef else state.grad_residual

@@ -27,34 +27,12 @@ import numpy as np
 from tpu_ddp.data.loader import ShardedBatchLoader
 from tpu_ddp.metrics import MetricLogger, Throughput
 from tpu_ddp.parallel.mesh import DATA_AXIS, MeshSpec, batch_sharding, create_mesh
+from tpu_ddp.parallel.runtime import enable_compile_cache
 from tpu_ddp.train.optim import make_optimizer
 from tpu_ddp.train.state import create_train_state
 from tpu_ddp.train.steps import make_eval_step, make_train_step
 
 log = logging.getLogger(__name__)
-
-
-def apply_compilation_cache(cache_dir: str) -> None:
-    """Enable the persistent XLA compilation cache. Must run before the
-    first trace/compile (the Trainer applies it at construction, ahead of
-    any step build). The 1s floor caches even fast compiles: the CLI's
-    models recompile identically run over run, so any hit is pure win.
-    Cache traffic lands in the ``jax/cache/*`` telemetry counters
-    (telemetry/jax_hooks.py bridges jax.monitoring), so ``tpu-ddp trace
-    summarize`` shows the warm-start wins in its counters snapshot."""
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    # jax latches its cache-enabled decision at the FIRST compile of the
-    # process (compilation_cache._cache_checked): if anything compiled
-    # before this call — a library embedder, an earlier Trainer without a
-    # cache dir — the new config would be silently ignored. Un-latch so
-    # the next compile re-evaluates it.
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # internals moved: the config updates still apply
-        pass
 
 
 @dataclasses.dataclass
@@ -238,12 +216,6 @@ class TrainConfig:
                                           # (the endpoint is UNauthenti-
                                           # cated — see docs/monitoring.md
                                           # before opening this up)
-    compilation_cache_dir: Optional[str] = None  # persistent XLA compile
-                                          # cache (jax_compilation_cache_dir,
-                                          # applied before the first trace):
-                                          # repeat runs skip recompiles;
-                                          # hits/misses surface as
-                                          # jax/cache/* telemetry counters
     telemetry_dir: Optional[str] = None   # run dir for the structured
                                           # telemetry sinks (per-host JSONL
                                           # + Chrome trace + heartbeats);
@@ -626,8 +598,7 @@ class Trainer:
         the dataset loader — used by the k-fold driver and tests."""
         self.config = config
         config.validate()
-        if config.compilation_cache_dir:
-            apply_compilation_cache(config.compilation_cache_dir)
+        enable_compile_cache()  # before the first trace
         devices = jax.devices()
         if config.n_devices:
             devices = devices[: config.n_devices]
@@ -1556,18 +1527,11 @@ class Trainer:
         img_tail = loader.images.shape[1:]
         lbl_tail = loader.labels.shape[1:]
         # Copy UNLESS the backend is known to complete a real H2D copy by
-        # block_until_ready (TPU/GPU — incl. experimental TPU platforms
-        # whose backend name differs but whose device kind says TPU): any
-        # backend that may zero-copy-alias host memory (CPU does, and
-        # ignores may_alias=False) would otherwise see slot reuse corrupt
-        # batches the compiled step hasn't consumed yet. Unknown backends
-        # fail SAFE (copy).
-        from tpu_ddp.parallel.runtime import is_tpu_device
-
-        real_h2d = (
-            jax.default_backend() in ("tpu", "gpu", "cuda", "rocm")
-            or is_tpu_device()
-        )
+        # block_until_ready (TPU/GPU): any backend that may zero-copy-alias
+        # host memory (CPU does, and ignores may_alias=False) would
+        # otherwise see slot reuse corrupt batches the compiled step hasn't
+        # consumed yet. Unknown backends fail SAFE (copy).
+        real_h2d = jax.default_backend() in ("tpu", "gpu", "cuda", "rocm")
         host_copy = pf.reusable_slots and not real_h2d
 
         def submissions():
@@ -1728,26 +1692,16 @@ class Trainer:
                else {}),
         )
 
-    def lint_preflight(self, *, raise_on_error: bool = True):
-        """Run the static graph lint (``tpu_ddp/analysis/lint.py``) over
-        the REAL jitted train step(s) — not the abstract twin — so the
-        verdict applies to the exact program this run trains with.
-
-        Cost: one EXTRA ahead-of-time compile per linted program (the
-        AOT path does not seed jit's dispatch cache, so step 1 still
-        compiles) — ``--compilation-cache-dir`` makes the second compile
-        a cache hit, which is the recommended pairing. Returns the
-        findings; with ``raise_on_error`` (the ``--lint-on-start`` path)
-        an error finding refuses the launch."""
-        import jax as _jax
-
-        from tpu_ddp.analysis.explain import run_strategy_label
-        from tpu_ddp.analysis.lint import lint_program, render_findings
+    def abstract_step_inputs(self) -> tuple:
+        """``(state, batch)`` as ShapeDtypeStructs carrying the layouts
+        ``self.train_step`` runs them in — what an ahead-of-time lowering
+        of the REAL jitted step takes (``lint_preflight``; chip_smoke.py
+        reads the compiled text for its kernels and collectives)."""
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         c = self.config
-        from jax.sharding import NamedSharding, PartitionSpec as _P
-
-        replicated = NamedSharding(self.mesh, _P())
+        replicated = NamedSharding(self.mesh, P())
 
         def _aval(x):
             # dp keeps the replicated state uncommitted (single-device
@@ -1757,10 +1711,9 @@ class Trainer:
             sh = getattr(x, "sharding", None)
             if not isinstance(sh, NamedSharding):
                 sh = replicated
-            return _jax.ShapeDtypeStruct(_jax.numpy.shape(x), x.dtype,
-                                         sharding=sh)
+            return jax.ShapeDtypeStruct(jnp.shape(x), x.dtype, sharding=sh)
 
-        state = _jax.tree.map(_aval, self.state)
+        state = jax.tree.map(_aval, self.state)
         gb = c.per_shard_batch * self.data_size
         shard_of = (self.batch_sharding.get
                     if isinstance(self.batch_sharding, dict)
@@ -1768,17 +1721,36 @@ class Trainer:
         # label avals must mirror the run's loss: bce trains on multi-hot
         # float targets (N, C), ce on class indices (N,)
         label_shape, label_dtype = (
-            ((gb, c.num_classes), _jax.numpy.float32) if c.loss == "bce"
-            else ((gb,), _jax.numpy.int32))
+            ((gb, c.num_classes), jnp.float32) if c.loss == "bce"
+            else ((gb,), jnp.int32))
         batch = {
-            "image": _jax.ShapeDtypeStruct(
-                (gb, 32, 32, 3), _jax.numpy.float32,
-                sharding=shard_of("image")),
-            "label": _jax.ShapeDtypeStruct(
+            "image": jax.ShapeDtypeStruct(
+                (gb, 32, 32, 3), jnp.float32, sharding=shard_of("image")),
+            "label": jax.ShapeDtypeStruct(
                 label_shape, label_dtype, sharding=shard_of("label")),
-            "mask": _jax.ShapeDtypeStruct(
+            "mask": jax.ShapeDtypeStruct(
                 (gb,), bool, sharding=shard_of("mask")),
         }
+        return state, batch
+
+    def lint_preflight(self, *, raise_on_error: bool = True):
+        """Run the static graph lint (``tpu_ddp/analysis/lint.py``) over
+        the REAL jitted train step(s) — not the abstract twin — so the
+        verdict applies to the exact program this run trains with.
+
+        Cost: one EXTRA ahead-of-time compile per linted program (the
+        AOT path does not seed jit's dispatch cache, so step 1 still
+        compiles) — the persistent compilation cache makes the second
+        compile a cache hit. Returns the
+        findings; with ``raise_on_error`` (the ``--lint-on-start`` path)
+        an error finding refuses the launch."""
+        import jax as _jax
+
+        from tpu_ddp.analysis.explain import run_strategy_label
+        from tpu_ddp.analysis.lint import lint_program, render_findings
+
+        c = self.config
+        state, batch = self.abstract_step_inputs()
         label = run_strategy_label(self.run_meta)
         findings, _ = lint_program(
             self.train_step, state, batch, self.mesh, strategy=label,
