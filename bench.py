@@ -3,9 +3,10 @@
 
 A measurement of the chip or nothing: the bench child exits non-zero, with
 ``{"ok": false, ...}`` as its last line, when the default platform is not
-``tpu``, and after the last leg when any leg recorded an ``error``. There
-is no CPU fallback and no replay of an earlier capture — a number printed
-here was measured by this run, on the device it names.
+``tpu``, and after the last leg when any leg recorded an ``error`` or was
+skipped at the deadline. There is no CPU fallback and no replay of an
+earlier capture — a number printed here was measured by this run, on the
+device it names.
 
 - **One process per chip**: the parent is stdlib-only and NEVER imports
   jax (a parent that has touched jax holds the chip, and the child could
@@ -13,7 +14,8 @@ here was measured by this run, on the device it names.
   with the child's code.
 - **Hard cap**: the child runs under one deadline (``TOTAL_BUDGET_S``,
   default 540s); at the deadline the parent TERMs, then KILLs, and exits
-  124. Legs that would start inside the last minute are skipped.
+  124. Legs that would start inside the last minute are skipped, named in
+  ``skipped_legs``, and make the run fail (``"ok": false``, exit 1).
 - **Print early**: the child *streams* to stdout (inherited fd,
   PYTHONUNBUFFERED) and prints the headline JSON line the moment the
   flagship number exists, then again after every leg — a kill
@@ -172,20 +174,6 @@ def _bench_flagship() -> dict:
     point = _scan_point(
         NetResDeep(), make_optimizer(lr=1e-2),
         steps_per_call=32, per_shard=32, seed=0,
-    )
-    return {"model": "netresdeep", "dtype": "float32", **point}
-
-
-def _bench_flagship_point(steps_per_call: int, per_shard: int) -> dict:
-    """ONE flagship fusion-grid row at the given (K, per-shard) point — the
-    dispatch-amortization sweep unit."""
-    from tpu_ddp.models import NetResDeep
-    from tpu_ddp.train import make_optimizer
-
-    point = _scan_point(
-        NetResDeep(), make_optimizer(lr=1e-2),
-        steps_per_call=steps_per_call, per_shard=per_shard, seed=0,
-        target_seconds=6.0,
     )
     return {"model": "netresdeep", "dtype": "float32", **point}
 
@@ -454,26 +442,6 @@ def _bench_compute_point(per_shard: int) -> dict:
     }
 
 
-def _bench_compute_fused() -> dict:
-    """Scan-fused variant of the headline config: K optimizer steps per
-    dispatch on ResNet-50 bf16 CIFAR (per-shard 256). The headline leg pays
-    one host dispatch per ~29 ms step; this measures what fusing K=8 steps
-    recovers — the tuned configuration the trainer's --steps-per-call flag
-    exposes for the compute-bound family, with the same measurement
-    discipline as the headline (same optimizer knobs, same seed)."""
-    import jax.numpy as jnp
-
-    from tpu_ddp.models.zoo import MODEL_REGISTRY
-    from tpu_ddp.train import make_optimizer
-
-    model = MODEL_REGISTRY["resnet50"](num_classes=10, dtype=jnp.bfloat16)
-    point = _scan_point(
-        model, make_optimizer(lr=1e-1, momentum=0.9),
-        steps_per_call=8, per_shard=256, seed=1, max_calls=20,
-    )
-    return {"model": "resnet50", "dtype": "bfloat16", **point}
-
-
 def _image224_point(model, tx, *, num_classes: int, per_shard: int,
                     seed: int, max_calls: int) -> dict:
     """ONE unfused 224x224 measurement point: the single implementation of
@@ -514,46 +482,6 @@ def _image224_point(model, tx, *, num_classes: int, per_shard: int,
         "per_shard_batch": per_shard,
         "n_chips": n_chips,
     }
-
-
-def _bench_wrn_compute() -> dict:
-    """WideResNet-28-10 bf16 at CIFAR shape (per-shard 128): the
-    throughput of the model family the 93% accuracy pathway actually
-    recommends (BASELINE.md; 36.5M params of 3x3 convs at width 640 —
-    far better MXU tiling than ResNet-50's 1x1-heavy CIFAR stack)."""
-    import jax.numpy as jnp
-
-    from tpu_ddp.models.zoo import MODEL_REGISTRY
-    from tpu_ddp.train import make_optimizer
-
-    model = MODEL_REGISTRY["wrn28_10"](num_classes=10, dtype=jnp.bfloat16)
-    tx = make_optimizer(lr=1e-1, momentum=0.9, weight_decay=5e-4)
-    point = _cifar_compute_point(model, tx, per_shard=128, seed=7,
-                                 max_calls=30)
-    return {"model": "wrn28_10", "dtype": "bfloat16", **point}
-
-
-def _bench_resnet50_imagenet() -> dict:
-    """ResNet-50 bf16 at 224x224 with the ImageNet stem (7x7/2 + max-pool):
-    BASELINE.md item 4's scale-out config ("multi-host v4-32 ResNet-50
-    ImageNet"), measured per-chip. CIFAR's 32x32 maps under-tile the MXU
-    (the committed headline's known ceiling); at 224x224 the conv tiles are
-    MXU-shaped, so this row is the framework's conv compute capability the
-    way `vit_compute` is its matmul capability. Synthetic images — this
-    measures the train step, not a dataset."""
-    import jax.numpy as jnp
-
-    from tpu_ddp.models.zoo import MODEL_REGISTRY
-    from tpu_ddp.train import make_optimizer
-
-    model = MODEL_REGISTRY["resnet50"](
-        num_classes=1000, cifar_stem=False, dtype=jnp.bfloat16
-    )
-    point = _image224_point(
-        model, make_optimizer(lr=1e-1, momentum=0.9),
-        num_classes=1000, per_shard=64, seed=5, max_calls=30,
-    )
-    return {"model": "resnet50", "dtype": "bfloat16", **point}
 
 
 def _bench_attention() -> dict:
@@ -673,86 +601,6 @@ def _attention_op_microbench() -> dict:
     }
 
 
-def _vit_step_point(model_name: str) -> dict:
-    """ONE vit_s4-family train-step rate (bf16, per-shard 128, CIFAR shape):
-    the single-compile unit behind the dense-vs-MoE comparison (round-4
-    verdict item 10).
-    Measurement discipline (batch build, fencing, rate math, MFU) is
-    _cifar_compute_point's — the same rows as every other compute leg."""
-    import jax.numpy as jnp
-
-    from tpu_ddp.models.zoo import MODEL_REGISTRY
-    from tpu_ddp.train import make_optimizer
-
-    model = MODEL_REGISTRY[model_name](num_classes=10, dtype=jnp.bfloat16)
-    tx = make_optimizer(lr=1e-2, momentum=0.9)
-    return {
-        "model": model_name, "dtype": "bfloat16",
-        **_cifar_compute_point(model, tx, per_shard=128, seed=11,
-                               max_calls=30),
-    }
-
-
-def _bench_dense_step() -> dict:
-    """Dense half of EP's on-chip measurement: the vit_s4 train step whose
-    routed twin is `moe_step`. See _vit_step_point."""
-    return _vit_step_point("vit_s4")
-
-
-def _bench_moe_step() -> dict:
-    """MoE half of EP's on-chip measurement: what the GShard dense-dispatch
-    formulation (router + one-hot dispatch/combine einsums + stacked expert
-    matmuls, E=8) costs end-to-end on one chip. A single chip cannot shard
-    the expert axis, but the routing-formulation cost is the locally-
-    measurable half of the EP story (the all-to-all half is covered by the
-    EP dryrun + AOT legs)."""
-    return _vit_step_point("vit_moe_s4")
-
-
-def _bench_attention_causal() -> dict:
-    """Causal flash at the attention_op shape (T=2048, bf16, B=4, H=8,
-    D=128): the decoder-regime row. The kernel skips above-diagonal tiles
-    via pl.when, so this should beat the non-causal flash row by up to 2x.
-    Same q/k/v seed as attention_op for comparability."""
-    from tpu_ddp.ops.flash_attention import flash_attention
-
-    B, T, H, D = 4, 2048, 8, 128
-    q, k, v = _attn_qkv(B, T, H, D, seed=3)
-    rate = _time_attn_impl(
-        lambda a, b, c: flash_attention(a, b, c, causal=True), q, k, v)
-    return {
-        "shape": [B, T, H, D], "dtype": "bfloat16", "impl": "flash_causal",
-        "calls_per_sec": round(rate, 2),
-    }
-
-
-def _longseq_point(impl_name: str) -> dict:
-    """ONE T=8192 attention fwd+bwd timing point — SP's on-chip measurement
-    (round-4 verdict item 10). T=8192 is the per-device ring tile of the
-    131K-token / 16-device pod leg (131072 / 16); one chip can't run the
-    ring, but the ring's compute is this exact tile, so its rate here is
-    the per-hop cost the AOT'd pod program schedules. B=1 bounds the
-    reference's T^2 score materialization (~1 GiB fwd)."""
-    from tpu_ddp.ops.flash_attention import _reference, flash_attention
-
-    B, T, H, D = 1, 8192, 8, 128
-    q, k, v = _attn_qkv(B, T, H, D, seed=5)
-    fn = {"full": _reference, "flash": flash_attention}[impl_name]
-    return {
-        "shape": [B, T, H, D], "dtype": "bfloat16", "impl": impl_name,
-        "ring_context": "per-device tile of the 131072-token/16-device ring",
-        "calls_per_sec": round(_time_attn_impl(fn, q, k, v), 2),
-    }
-
-
-def _bench_longseq_full() -> dict:
-    return _longseq_point("full")
-
-
-def _bench_longseq_flash() -> dict:
-    return _longseq_point("flash")
-
-
 def _read_winner_config(path: str) -> dict:
     """The TrainConfig field dict out of a tuner artifact: either the
     ``--emit-config`` winner shape ({"tune_winner_schema_version",
@@ -824,16 +672,13 @@ def config_child_main(path: str) -> None:
 
     platform, kind = _require_tpu()
     enable_compile_cache()
-    try:
-        from tpu_ddp.telemetry.provenance import artifact_provenance
+    from tpu_ddp.telemetry.provenance import artifact_provenance
 
-        provenance = artifact_provenance(
-            descriptor={"artifact": "bench.py --config",
-                        "config_path": os.path.basename(path)},
-            device_kind=kind, jax_version=jax.__version__,
-        )
-    except Exception:
-        provenance = None
+    provenance = artifact_provenance(
+        descriptor={"artifact": "bench.py --config",
+                    "config_path": os.path.basename(path)},
+        device_kind=kind, jax_version=jax.__version__,
+    )
     try:
         row = _bench_tune_winner(path)
         result = {
@@ -852,8 +697,7 @@ def config_child_main(path: str) -> None:
             "unit": "images/sec/chip",
             "error": traceback.format_exc(limit=2).strip(),
         }
-    if provenance:
-        result["provenance"] = provenance
+    result["provenance"] = provenance
     _emit(result)
     if "error" in result:
         # a failed winner measurement must fail the invocation: a CI
@@ -866,7 +710,7 @@ def child_main() -> None:
     """Runs the bench configs in priority order, emitting the headline JSON
     line as soon as the flagship number exists. Exits non-zero when the
     platform is not a TPU, and — after every leg has had its turn — when
-    any leg recorded an ``error``."""
+    any leg recorded an ``error`` or was skipped at the deadline."""
     import traceback
 
     import jax
@@ -886,16 +730,12 @@ def child_main() -> None:
     # Provenance header (same fields as a run dir's metadata): which
     # commit produced this record, which logical bench config (the
     # deterministic digest keys the perf-registry series), which chip.
-    try:
-        from tpu_ddp.telemetry.provenance import artifact_provenance
+    from tpu_ddp.telemetry.provenance import artifact_provenance
 
-        provenance = artifact_provenance(
-            descriptor={"artifact": "bench.py",
-                        "n_chips": len(jax.devices())},
-            device_kind=kind, jax_version=jax.__version__,
-        )
-    except Exception:
-        provenance = None
+    provenance = artifact_provenance(
+        descriptor={"artifact": "bench.py", "n_chips": len(jax.devices())},
+        device_kind=kind, jax_version=jax.__version__,
+    )
     try:
         flagship = _bench_flagship()
     except Exception:
@@ -914,10 +754,9 @@ def child_main() -> None:
         "backend": backend,
         "device_kind": kind,
         "flagship": {k: v for k, v in flagship.items() if k != "error"},
+        "provenance": provenance,
     }
-    if provenance:
-        headline["provenance"] = provenance
-    failed = []
+    failed, skipped = [], []
     if "error" in flagship:
         headline["error"] = flagship["error"]
         failed.append("flagship")
@@ -928,12 +767,15 @@ def child_main() -> None:
         # Each completed leg re-emits the updated result line immediately:
         # a child killed at the deadline still leaves every finished
         # sub-bench in the output. A leg that raises records its error and
-        # the rest still run — but the child then exits non-zero.
+        # the rest still run; a leg the deadline left no room for is
+        # recorded as skipped — either way the child then exits non-zero:
+        # an incomplete record is not a whole one.
         print(f"bench child: leg {key} starting "
               f"({deadline - time.time():.0f}s left)",
               file=sys.stderr, flush=True)
         if time.time() >= deadline - 60:
             r = {"skipped": "deadline"}
+            skipped.append(key)
         else:
             try:
                 r = fn()
@@ -971,11 +813,13 @@ def child_main() -> None:
     # stack can't reach on 32x32 inputs
     _leg("vit_compute", _bench_vit_compute)
     _promote_compute_headline(out)
-    out["ok"] = not failed
+    out["ok"] = not (failed or skipped)
     if failed:
         out["failed_legs"] = failed
+    if skipped:
+        out["skipped_legs"] = skipped
     _emit(out)
-    if failed:
+    if not out["ok"]:
         raise SystemExit(1)
 
 

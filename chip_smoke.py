@@ -33,10 +33,12 @@ nothing else):
   ``make_train_step`` (no CLI path feeds 224x224 images) — the largest
   model the repo supports; it also checks that ``block_until_ready`` does
   not return before the work is done.
-- ``kernels_direct``: flash attention forward and backward (causal and
-  not), the int8 quantize/dequantize pair and the fused optimizer update,
-  each against its jnp reference within a stated tolerance and each found
-  as a ``tpu_custom_call`` in its compiled program.
+- ``kernels_direct``: flash attention forward and backward at each kind of
+  plan the kernel makes (tiled, causal and not; ViT-B/16's 196 tokens as
+  one whole-axis block; a length padded to a block multiple and masked),
+  the int8 quantize/dequantize pair and the fused optimizer update, each
+  against its jnp reference within a stated tolerance and each found as a
+  ``tpu_custom_call`` in its compiled program.
 - ``kernels_cli``: a ``vit_s4 --attention flash`` run and a ``--kernels``
   run through the CLI entry, with the same custom-call assertion on the
   step the ``Trainer`` compiled.
@@ -82,6 +84,17 @@ UPDATE_RTOL, UPDATE_ATOL = 1e-5, 1e-6
 ZERO1_VS_DP_TOL = 1e-2
 
 CUSTOM_CALL = "tpu_custom_call"
+
+#: (shape, causal): one case for each kind of plan ``flash_attention``'s
+#: ``_plan`` makes — 128x128 tiles that divide T (the old bench's shape);
+#: ViT-B/16's own 196 tokens as one whole-axis block; and a T no block
+#: divides, zero-padded to 1024 with the padding masked through ``kv_mask``
+FLASH_CASES = (
+    ((4, 2048, 8, 128), False),
+    ((4, 2048, 8, 128), True),
+    ((64, 196, 12, 64), False),
+    ((2, 1000, 4, 64), True),
+)
 
 
 class SmokeFailure(Exception):
@@ -398,13 +411,15 @@ def _check_flash(shape, dtype, causal: bool, require: bool) -> dict:
         for a, b in zip(jax.jit(grads(kernel))(q, k, v, g),
                         jax.jit(grads(reference))(q, k, v, g)))
     check(fwd_err < FLASH_FWD_TOL and bwd_err < FLASH_BWD_TOL,
-          f"flash causal={causal}: fwd {fwd_err} bwd {bwd_err} against "
-          f"its reference")
+          f"flash {shape} causal={causal}: fwd {fwd_err} bwd {bwd_err} "
+          f"against its reference")
     calls = (_custom_calls(kernel, q, k, v),
              _custom_calls(grads(kernel), q, k, v, g))
     if require:  # fwd: one kernel; bwd: fwd recompute + dQ + dK/dV
-        check(calls == (1, 3), f"flash causal={causal}: {calls} kernels")
-    return {"fwd_rel_err": fwd_err, "bwd_rel_err": bwd_err,
+        check(calls == (1, 3),
+              f"flash {shape} causal={causal}: {calls} kernels")
+    return {"shape": list(shape), "causal": causal,
+            "fwd_rel_err": fwd_err, "bwd_rel_err": bwd_err,
             "custom_calls_fwd_bwd": list(calls)}
 
 
@@ -493,7 +508,7 @@ def _check_update(name: str, leaf_shapes, require: bool, **opt) -> dict:
     return {"custom_calls": calls}
 
 
-def kernels_direct(*, flash_shape=(4, 2048, 8, 128), dtype="bfloat16",
+def kernels_direct(*, flash_cases=FLASH_CASES, dtype="bfloat16",
                    quant_elements: int = 2_359_296, quant_block: int = 256,
                    update_leaves=((768, 3072), (3, 3, 512, 512)),
                    require_custom_call: bool = True) -> dict:
@@ -503,10 +518,10 @@ def kernels_direct(*, flash_shape=(4, 2048, 8, 128), dtype="bfloat16",
     dt = jnp.dtype(dtype)
     req = require_custom_call
     return {
-        "flash_shape": list(flash_shape), "flash_dtype": dtype,
+        "flash_dtype": dtype,
         "flash_tolerance_fwd_bwd": [FLASH_FWD_TOL, FLASH_BWD_TOL],
-        "flash": _check_flash(flash_shape, dt, False, req),
-        "flash_causal": _check_flash(flash_shape, dt, True, req),
+        "flash": [_check_flash(tuple(shape), dt, causal, req)
+                  for shape, causal in flash_cases],
         "fused_quant": _check_quant(quant_elements, quant_block, req),
         "update_leaves": [list(s) for s in update_leaves],
         "fused_update_sgd_momentum": _check_update(

@@ -22,6 +22,7 @@ if "xla_force_host_platform_device_count" not in flags:
 # test must not depend on what an earlier run left in the checkout's cache.
 # Tests of the cache itself turn it on through the ``compile_cache`` fixture.
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
 
 import jax  # noqa: E402
 
@@ -62,3 +63,26 @@ def devices():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual devices, got {len(devs)}"
     return devs
+
+
+@pytest.fixture(scope="session")
+def topo():
+    """A DESCRIBED (not attached) ``v5e:2x2`` slice: the TPU's compiler is
+    installed here, so ``jit(...).lower(shapes).compile()`` against these
+    four devices raises what the chip's compiler would raise and returns
+    its cost analysis — at no chip time. Nothing can run on them. One
+    libtpu process at a time: two collide on its lock file in /tmp."""
+    import importlib.util
+
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu not installed: the v5e topology cannot be "
+                    "described")
+    from jax.experimental import topologies
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # e.g. another process holds libtpu's lock file
+        pytest.skip(f"the v5e topology cannot be described: {e}")
+    assert len(desc.devices) == 4
+    return desc

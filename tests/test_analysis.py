@@ -693,16 +693,35 @@ def test_run_meta_rebuild_refuses_scan_fused(devices):
 
 
 def test_run_meta_rebuild_honors_health(devices):
-    """--health on compiles the norm reductions and their metric outputs
-    into the step: the rebuild must carry them, or every health-enabled
-    run mis-attributes. (Not a collective count: the DP grads are already
-    synchronized, and XLA combines what psums there are into one op.)"""
-    from tpu_ddp.analysis.explain import anatomy_for_run_meta
+    """--health on compiles the numerics flight recorder into the step:
+    the rebuild must carry it, or every health-enabled run mis-attributes.
 
-    on = anatomy_for_run_meta(_meta({"health": "on"}), jax.devices())
-    off = anatomy_for_run_meta(_meta(), jax.devices())
-    assert on.flops > off.flops
-    assert on.output_bytes > off.output_bytes
+    Pinned on the rebuilt program's OUTPUTS — ``metrics["health"]`` with
+    the recorder's norms and sentinels, absent when off — not on a
+    collective count: plain DP's grads are already synchronized when the
+    stats read them, and XLA folds what psums there are into the gradient
+    all-reduce (one op on jax 0.9.0, health on or off)."""
+    from tpu_ddp.analysis.explain import (
+        anatomy_for_run_meta,
+        compiled_for_run_meta,
+    )
+    from tpu_ddp.analysis.hlo import compile_cache_stats
+
+    def metrics_of(meta):
+        tree = compiled_for_run_meta(meta, jax.devices()).out_tree
+        _, metrics = tree.unflatten(list(range(tree.num_leaves)))
+        return metrics
+
+    on_meta = _meta({"health": "on"})
+    assert "health" not in metrics_of(_meta())
+    assert {"grad_norm", "param_norm", "update_norm", "update_ratio",
+            "grads_finite", "updates_finite", "all_finite"} <= set(
+                metrics_of(on_meta)["health"])
+    # the anatomy a health-enabled run is attributed against is of that
+    # same compiled program: a cache hit, not a second (health-less) build
+    misses = compile_cache_stats()["misses"]
+    assert anatomy_for_run_meta(on_meta, jax.devices()).flops > 0
+    assert compile_cache_stats()["misses"] == misses
 
 
 def test_run_strategy_label():

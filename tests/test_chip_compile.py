@@ -14,34 +14,16 @@ are steered with ``interpret=False`` — in the test, not through an option
 of the program. One libtpu process at a time: two collide on its lock file.
 """
 
-import importlib.util
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 CUSTOM_CALL = "tpu_custom_call"
 
-
-@pytest.fixture(scope="module")
-def topo():
-    if importlib.util.find_spec("libtpu") is None:
-        pytest.skip("libtpu not installed: the v5e topology cannot be "
-                    "described")
-    from jax.experimental import topologies
-
-    try:
-        desc = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # e.g. another process holds libtpu's lock file
-        pytest.skip(f"the v5e topology cannot be described: {e}")
-    assert len(desc.devices) == 4
-    return desc
+# the ``topo`` fixture (the described v5e:2x2 slice) is conftest's
 
 
 @pytest.fixture(scope="module", autouse=True)

@@ -77,19 +77,37 @@ def test_phase_full_width_step(devices):
     assert info["steps"] == 3 and info["block_until_ready_honest"]
 
 
+#: one tiny case for each kind of plan the flash kernel makes: tiles that
+#: divide T, one whole-axis block (ViT-B/16's 196 tokens), padded and masked
+_TINY_FLASH = (((1, 256, 2, 64), False), ((1, 256, 2, 64), True),
+               ((2, 196, 2, 64), False), ((1, 600, 2, 64), True))
+
+
 def test_phase_kernels_direct(devices):
     """Interpreted on the CPU: the numerics checks run, the custom-call
     assertion (a chip-compile fact) is steered off."""
+    from tpu_ddp.ops.flash_attention import _plan
+
+    plans = [_plan(shape, 128, 128, masked=False)
+             for shape, _ in chip_smoke.FLASH_CASES + _TINY_FLASH]
+    kinds = [("tiled" if p.t_pad == s[1] and p.bq < s[1] else
+              "whole" if p.t_pad == s[1] else "padded")
+             for p, (s, _) in zip(plans, chip_smoke.FLASH_CASES + _TINY_FLASH)]
+    # the chip's cases and the tiny ones walk the same three paths
+    assert kinds == ["tiled", "tiled", "whole", "padded"] * 2
+
     info = chip_smoke.kernels_direct(
-        flash_shape=(1, 128, 2, 64), dtype="float32", quant_elements=4096,
+        flash_cases=_TINY_FLASH, dtype="float32", quant_elements=4096,
         quant_block=128, update_leaves=((24, 40), (3, 3, 4, 8)),
         require_custom_call=False)
-    assert info["flash"]["fwd_rel_err"] < 1e-5
-    assert info["flash_causal"]["bwd_rel_err"] < 1e-4
+    assert [(tuple(f["shape"]), f["causal"])
+            for f in info["flash"]] == list(_TINY_FLASH)
+    for f in info["flash"]:
+        assert f["fwd_rel_err"] < 1e-5 and f["bwd_rel_err"] < 1e-4, f
     assert info["fused_quant"]["payload_steps_differing"] == 0.0
     with pytest.raises(chip_smoke.SmokeFailure, match="kernel"):
         chip_smoke.kernels_direct(
-            flash_shape=(1, 128, 2, 64), dtype="float32",
+            flash_cases=_TINY_FLASH[:1], dtype="float32",
             quant_elements=4096, quant_block=128,
             update_leaves=((24, 40),))  # no tpu_custom_call on the CPU
 

@@ -288,35 +288,60 @@ def test_cost_model_free_anatomy_unpriceable():
     assert p.status == "unpriceable"
 
 
-# -- the layout-sweep ordering pin ----------------------------------------
+# -- the BENCH_r04 ordering pin -------------------------------------------
+
+#: the four netresdeep layout points of the one chip sweep the repo has
+#: (BENCH_r04.json, a builder capture of 2026-07-31 on one v5e chip), as
+#: (per-shard batch, K), in measured order: 289k, 278k, 97k, 84k img/s
+_R04_MEASURED_ORDER = [(256, 128), (256, 32), (32, 128), (32, 32)]
 
 
-def test_layout_sweep_ranks_deeper_scan_fusion_first(devices):
-    """The 4 netresdeep layout points of the old builder sweep, (K,
-    per-shard) in {32,128} x {32,256}: at each batch the tuner must rank
-    the deeper scan fusion first — the amortized dispatch term is the one
-    thing that separates two K of one compiled program.
+@pytest.mark.parametrize("compiled_for", [
+    "described_v5e",
+    pytest.param("xla_cpu", marks=pytest.mark.xfail(
+        strict=True,
+        reason="ROADMAP D7: on jax 0.9.0 XLA:CPU's bytes_accessed per "
+               "image is 17% higher at b256 than at b32, so a roofline "
+               "over the CPU compile — what `tpu-ddp tune` prices from on "
+               "a CPU host — ranks the measured-slowest batch first")),
+])
+def test_bench_r04_sweep_ranks_measured_best_first(compiled_for, request,
+                                                   devices):
+    """The 4 recorded netresdeep layout points (BENCH_r04 sweep leg:
+    84k->289k img/s across (K, per-shard) in {32,128} x {32,256}): the
+    tuner's predicted ranking must put the measured-best point —
+    per-shard 256, K=128 — first, and the rest in their measured order.
 
-    Across batches nothing is pinned: that order comes from the roofline
-    over XLA:CPU's bytes_accessed estimate, which moves with the XLA
-    version (per-image bytes at b32 vs b256: lower on jax 0.4.37, 17%
-    higher on 0.9.0). ROADMAP D7 calibrates it against measured cells."""
+    ``tune`` prices the program compiled for the devices it is given.
+    Given one chip of the described v5e slice it reads the TPU compiler's
+    own cost analysis and agrees with the measurement; given a CPU device
+    it does not (the strict xfail keeps that visible until D7 moves the
+    CLI's default off the CPU compile)."""
     from tpu_ddp.models import NetResDeep
 
+    if compiled_for == "described_v5e":
+        target = list(request.getfixturevalue("topo").devices)[:1]
+    else:
+        target = devices[:1]
     model = NetResDeep()  # the full reference model the sweep measured
     cands = enumerate_grid(model, 1, batches=[32, 256],
                            steps_per_call=[32, 128], strategies=["dp"])
     assert len(cands) == 4
     res = tune(model=model, model_name="netresdeep",
-               devices=devices[:1], chip="v5e", candidates=cands)
+               devices=target, chip="v5e", candidates=cands)
     # single-device programs have no collectives: the fingerprint tier
     # must not reject them (lint_label -> dp@single)
     assert res.excluded == []
-    order = [(r.candidate.per_shard_batch, r.candidate.steps_per_call)
-             for r in res.ranked]
-    for batch in (32, 256):
-        assert order.index((batch, 128)) < order.index((batch, 32))
-    assert res.winner.candidate.steps_per_call == 128
+    # the rendered table says so when it priced another compiler's program
+    from tpu_ddp.tuner.cli import render_result
+
+    assert res.compiled_for == target[0].device_kind
+    assert (("not calibrated" in render_result(res))
+            == (compiled_for == "xla_cpu"))
+    best = res.winner.candidate
+    assert (best.per_shard_batch, best.steps_per_call) == (256, 128)
+    assert [(r.candidate.per_shard_batch, r.candidate.steps_per_call)
+            for r in res.ranked] == _R04_MEASURED_ORDER
 
 
 # -- calibration -----------------------------------------------------------
@@ -587,6 +612,40 @@ def test_bench_child_refuses_a_platform_that_is_not_tpu(tmp_path, capsys,
     record = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert record["ok"] is False and "value" not in record
     assert record["device"]["platform"] == "cpu"
+
+
+@pytest.mark.parametrize("fault", ["deadline", "error"])
+def test_bench_child_fails_an_incomplete_record(capsys, monkeypatch, fault):
+    """A leg the deadline left no room for, like a leg that raised, is
+    named in the record and fails the run: ``"ok": false``, exit 1 — an
+    incomplete record must not read as a whole one."""
+    import bench
+
+    legs = [n for n in dir(bench)
+            if n.startswith("_bench_") or n == "_attention_op_microbench"]
+    row = {"images_per_sec_per_chip": 1.0, "mfu": None}
+    for name in legs:
+        monkeypatch.setattr(bench, name, lambda *a, **k: dict(row))
+    monkeypatch.setattr(bench, "_require_tpu", lambda: ("tpu", "test"))
+    if fault == "deadline":  # the flagship runs, no later leg has room
+        monkeypatch.setenv(bench._DEADLINE_ENV, "0")
+    else:
+        def boom():
+            raise RuntimeError("leg blew up")
+        monkeypatch.setattr(bench, "_bench_zero1", boom)
+    with pytest.raises(SystemExit) as exc:
+        bench.child_main()
+    assert exc.value.code == 1
+    record = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert record["ok"] is False
+    assert record["provenance"]["device_kind"] == "test"
+    if fault == "deadline":
+        assert "failed_legs" not in record
+        assert len(record["skipped_legs"]) == 7
+        assert record["compute_bound"] == {"skipped": "deadline"}
+    else:
+        assert record["failed_legs"] == ["zero1_weight_update_sharding"]
+        assert "skipped_legs" not in record
 
 
 def test_memplan_json_flag(tmp_path, monkeypatch):

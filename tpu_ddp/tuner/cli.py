@@ -158,6 +158,20 @@ def _human_time(s: Optional[float]) -> str:
 
 def render_result(result, *, top: int = 0) -> str:
     """The ranked table + exclusions, human-form."""
+    from tpu_ddp.analysis.roofline import chip_spec
+
+    note = []
+    compiled_spec = chip_spec(result.compiled_for)
+    if compiled_spec is None or compiled_spec.key != result.chip:
+        # the roofline is over ANOTHER compiler's cost analysis: its
+        # fusion and layout choices, not the target chip's. On jax 0.9.0
+        # XLA:CPU's bytes per image rank per-shard batch 32 above 256 for
+        # netresdeep, against the one chip sweep on record (docs/tuning.md)
+        note = [
+            f"note: programs compiled for {result.compiled_for!r}, priced "
+            f"on {result.chip}: the order ACROSS batch sizes follows that "
+            "compiler's bytes-accessed estimate and is not calibrated — "
+            "confirm it with --validate-top on the target"]
     lines = [
         f"tune: model={result.model_name} chip={result.chip} "
         f"devices={result.n_devices} dtype={result.compute_dtype} "
@@ -169,6 +183,7 @@ def render_result(result, *, top: int = 0) -> str:
         f"[{result.comms_calibration_source}], data "
         f"[{result.data_calibration_source}], ops "
         f"[{result.ops_calibration_source}])",
+        *note,
         "",
     ]
     rows = result.ranked[:top] if top else result.ranked

@@ -139,6 +139,9 @@ class TuneResult:
     compiled_programs: int
     image_size: int = 32
     overlap: str = "overlapped"
+    # device kind the candidate programs were COMPILED for (the devices
+    # `tune` was given): the cost analysis priced is that compiler's
+    compiled_for: str = "unknown"
     # HBM-cap calibration (docs/memory.md): measured-over-planned peak
     # from `tpu-ddp mem` evidence, multiplied into every candidate's
     # compiled peak before the over_hbm verdict
@@ -572,4 +575,5 @@ def tune(
         ranked=ranked, excluded=excluded,
         compiled_programs=len(audits),
         image_size=image_size, overlap=overlap,
+        compiled_for=devices[0].device_kind,
     )
