@@ -146,9 +146,9 @@ monitor-demo:
 # with an injected slow input pipeline — DWT001 must fire in a watch-side
 # alert engine, the capture_profile action must auto-arm a capture over
 # POST /profile, the bundle's host top stacks must contain the injected
-# stall frame, and `tpu-ddp profile` must render it plus the per-op
-# attribution table (deviceless anatomy join; jax.profiler absence
-# degrades to a note). Exits nonzero on any miss
+# stall frame, and `tpu-ddp profile` must render it and point at the run's
+# program map (jax.profiler absence degrades to a note). Exits nonzero on
+# any miss
 # (tpu_ddp/tools/profile_demo.py).
 PROFILE_DEMO_DIR ?= /tmp/tpu_ddp_profile_demo
 profile-demo:

@@ -116,6 +116,23 @@ def _recompile(run_dir):
                 "gauges": {}}}) + "\n")
 
 
+def _warm_start(run_dir):
+    """What a warm start of ResNet-50 on the chip leaves (PERF.md): every
+    program a load from the persistent cache, no miss. ``jax/compilations``
+    counts real compilations only, so it reads 0 here."""
+    write_fleet(run_dir)
+    with open(os.path.join(str(run_dir), "trace-p0.jsonl"), "a") as f:
+        f.write(json.dumps({
+            "schema_version": 1, "type": "counters", "ts_s": 50.0,
+            "pid": 0, "attrs": {
+                "counters": {"jax/cache/cache_hits": 221,
+                             "jax/cache/compile_requests_use_cache": 221,
+                             "jax/cache/tasks_using_cache": 1,
+                             "jax/cache_loads": 221,
+                             "jax/compilations": 0},
+                "gauges": {}}}) + "\n")
+
+
 def _injected_nan(run_dir):
     write_fleet(run_dir, nan_host=2)
 
@@ -168,6 +185,7 @@ def _zero3_serialized(run_dir):
 
 FAULT_MATRIX = [
     ("clean", _clean, None),
+    ("warm_start_all_cache_loads", _warm_start, None),
     ("data_stall", _data_stall, "DIA001"),
     ("comm_stall", _comm_stall, "DIA002"),
     ("hbm_pressure", _hbm, "DIA003"),

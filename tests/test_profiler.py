@@ -37,10 +37,7 @@ from tpu_ddp.profiler.capture import (
     post_profile_trigger,
     read_bundle_meta,
 )
-from tpu_ddp.profiler.device import (
-    measured_step_from_meta,
-    per_op_attribution,
-)
+from tpu_ddp.profiler.device import measured_step_from_meta
 from tpu_ddp.profiler.host import (
     HostSampler,
     frame_shares,
@@ -397,50 +394,6 @@ def test_monitor_config_rejects_negative_cap():
         MonitorConfig(max_auto_profiles=-1).validate()
 
 
-# -- per-op attribution ----------------------------------------------------
-
-def _synthetic_anatomy():
-    return {
-        "device_kind": "cpu", "strategy": "dp", "model": "m",
-        "flops": 1e9, "bytes_accessed": 2e8,
-        "collectives": [
-            {"kind": "all-reduce", "dtype": "f32", "axis": "data",
-             "group_size": 4, "count": 1, "payload_bytes": 1_000_000,
-             "wire_bytes": 1_500_000},
-            {"kind": "all-gather", "dtype": "f32", "axis": "data",
-             "group_size": 4, "count": 2, "payload_bytes": 400_000,
-             "wire_bytes": 300_000},
-        ],
-    }
-
-
-def test_per_op_attribution_sums_to_measured_span():
-    # cpu has no published peak: refused, no chip is assumed for it
-    refused = per_op_attribution(_synthetic_anatomy(), 0.010)
-    assert set(refused) == {"note"} and "--chip" in refused["note"]
-    att = per_op_attribution(_synthetic_anatomy(), 0.010, chip="v5e")
-    assert att["chip"] == "v5e" and not att["notes"]
-    ops = {r["op"] for r in att["ops"]}
-    assert {"compute (fused math)", "hbm traffic",
-            "all-reduce/f32/data/g4", "all-gather/f32/data/g4"} == ops
-    assert sum(r["attributed_s"] for r in att["ops"]) == \
-        pytest.approx(0.010, rel=1e-9)
-    assert sum(r["share"] for r in att["ops"]) == pytest.approx(1.0)
-    assert att["measured_vs_model"] == pytest.approx(
-        0.010 / att["model_step_s"])
-    # rows are model-time ranked
-    model_times = [r["model_s"] for r in att["ops"]]
-    assert model_times == sorted(model_times, reverse=True)
-
-
-def test_per_op_attribution_explicit_chip_and_no_measurement():
-    att = per_op_attribution(_synthetic_anatomy(), None, chip="v4")
-    assert att["chip"] == "v4" and not att["notes"]
-    assert all("attributed_s" not in r for r in att["ops"])
-    empty = per_op_attribution({"device_kind": "TPU v5 lite"}, 0.01)
-    assert empty["ops"] == [] and empty["notes"]
-
-
 # -- straggler diff --------------------------------------------------------
 
 def _fleet_shares(straggler_host=2):
@@ -513,14 +466,14 @@ def test_profile_cli_renders_fleet_and_diff(tmp_path):
             run_dir, host, rule="STR001", alert_host=2,
             extra_frame=("_injected_input_stall (demo.py:7)"
                          if host == 2 else None))
-    rc, out, _ = _run_cli([run_dir, "--no-ops"])
+    rc, out, _ = _run_cli([run_dir])
     assert rc == 0
     assert "trigger: alert STR001 host 2" in out
     assert "straggler diff: host 2" in out
     assert "_injected_input_stall" in out
     assert "device note: jax.profiler trace unavailable" in out
     # --host narrows rendering but the diff still spans the fleet
-    rc, out, _ = _run_cli([run_dir, "--no-ops", "--host", "2"])
+    rc, out, _ = _run_cli([run_dir, "--host", "2"])
     assert rc == 0 and out.count("profile bundle:") == 1
     assert "straggler diff: host 2" in out
 
@@ -534,11 +487,13 @@ def test_profile_cli_exit_codes(tmp_path):
     # single-bundle target renders without a diff, writes --json
     bundle = _write_bundle(str(tmp_path), 0)
     report_path = str(tmp_path / "report.json")
-    rc, out, _ = _run_cli([bundle, "--no-ops", "--json", report_path])
+    rc, out, _ = _run_cli([bundle, "--json", report_path])
     assert rc == 0 and "straggler diff" not in out
     with open(report_path) as f:
         report = json.load(f)
     assert report["bundles"][0]["meta"]["process_index"] == 0
+    # no run directory above a lone bundle held a program map
+    assert report["bundles"][0]["program_map"] is None
 
 
 # -- alert history + watch integration -------------------------------------
@@ -616,10 +571,9 @@ def test_train_config_profile_guards(tmp_path):
 @pytest.mark.slow  # heavyweight compile - make test-all (tier-1 870s budget)
 def test_trainer_config_window_end_to_end(tmp_path):
     """--profile-steps on a real (tiny) run: the bundle lands, carries
-    the run metadata + measured window phases, the per-op attribution
-    joins devicelessly, and trace summarize surfaces the counters."""
+    the run metadata + measured window phases, the report points at the
+    run's program map, and trace summarize surfaces the counters."""
     from tpu_ddp.cli.main import main as cli_main
-    from tpu_ddp.profiler.device import attribution_for_bundle
     from tpu_ddp.telemetry.summarize import summarize
     from tpu_ddp.train.trainer import TrainConfig, Trainer
 
@@ -643,11 +597,6 @@ def test_trainer_config_window_end_to_end(tmp_path):
     assert meta["measured_phases"]["compiled_step"]["count"] == 2
     assert meta["run_meta"]["strategy"] == "dp"
 
-    att = attribution_for_bundle(meta)
-    assert "ops" in att and att["ops"], att
-    assert sum(r["attributed_s"] for r in att["ops"]) == pytest.approx(
-        att["measured_step_s"], rel=1e-9)
-
     assert "profiler: 1 capture window(s)" in summarize(run_dir)
 
     out = io.StringIO()
@@ -656,7 +605,7 @@ def test_trainer_config_window_end_to_end(tmp_path):
     assert rc == 0
     text = out.getvalue()
     assert "host top stacks" in text
-    assert "per-op attribution" in text
+    assert "program map:" in text and "train_step (jit_shard_step)" in text
 
 
 @pytest.mark.slow  # ~16s; the config-window e2e keeps the fast lane — make test-all

@@ -322,6 +322,71 @@ def _parse_collective_line(line: str, mesh_shape, defs):
     return kind, per_dtype, groups, pairs, g, axis
 
 
+_MODULE_RE = re.compile(r"^HloModule\s+([\w.\-]+)")
+_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_OPCODE_RE = re.compile(r"^\s*([\w\-]+)\(")
+#: attributes that name a computation inlined into its caller: a fusion's
+#: body, a reducer, a comparator. Their instructions never run as
+#: operations of their own, so a device trace never prints them.
+_INLINED_RE = re.compile(
+    r"(?:to_apply|select|scatter|comparator)=(%[\w.\-]+)")
+_FUSION_CALLS_RE = re.compile(r"\bcalls=(%[\w.\-]+)")
+_HEADER_RE = re.compile(r"^(?:ENTRY\s+)?(%[\w.\-]+)\s.*\{\s*$")
+
+
+def program_instructions(hlo_text: str) -> Tuple[str, List[Dict[str, Any]]]:
+    """``(module name, instructions)`` of an optimised HLO text: the name a
+    device trace prints for the program (``jit_shard_step``) and, for every
+    instruction a trace can print, ``{"name", "opcode", "op_name",
+    "operands", "body_op_names"}``: the name without its ``%``
+    (``fusion.11``), the ``op_name`` of its ``metadata`` ("" where the
+    compiler left none), the names of its operands and, for a fusion, the
+    distinct ``op_name``s of the instructions fused into it. Instructions
+    inside a fusion's body or a reducer are not listed themselves: they
+    run as part of their caller, which is."""
+    module = ""
+    computations: Dict[str, List[Dict[str, Any]]] = {}
+    inlined = set()
+    current: List[Dict[str, Any]] = []
+    for line in hlo_text.splitlines():
+        line = _INDEX_COMMENT_RE.sub("", line)
+        m = _DEF_RE.match(line)
+        if m is None:
+            header = _HEADER_RE.match(line)
+            if header:
+                current = computations.setdefault(header.group(1), [])
+            elif not module:
+                named = _MODULE_RE.match(line)
+                module = named.group(1) if named else ""
+            continue
+        rest = m.group(2)[len(_result_type(m.group(2))):]
+        found = _OPCODE_RE.match(rest)
+        opcode = found.group(1) if found else ""
+        op_name = _OP_NAME_RE.search(rest)
+        calls = _FUSION_CALLS_RE.findall(rest) if opcode == "fusion" else []
+        inlined.update(calls)
+        if opcode not in ("fusion", "call"):
+            inlined.update(_INLINED_RE.findall(rest))
+        operands = _operand_segment(rest, found.end() - 1) if found else ""
+        current.append({
+            "name": m.group(1).lstrip("%"),
+            "opcode": opcode,
+            "op_name": op_name.group(1) if op_name else "",
+            "operands": [n.lstrip("%") for n in _NAME_RE.findall(operands)],
+            "calls": calls,
+        })
+    out = []
+    for name, instructions in computations.items():
+        if name in inlined:
+            continue
+        for ins in instructions:
+            body = {inner["op_name"] for called in ins.pop("calls")
+                    for inner in computations.get(called, ())}
+            ins["body_op_names"] = sorted(body - {""})
+            out.append(ins)
+    return module, out
+
+
 def collective_schedule(
     hlo_text: str, mesh_shape: Optional[Dict[str, int]] = None,
 ) -> List[ScheduledCollective]:

@@ -27,8 +27,9 @@ Subcommands:
   (docs/monitoring.md).
 - ``tpu-ddp profile <run_dir>`` — render anomaly-profiler capture
   bundles (``<run_dir>/profiles/``): trigger/alert provenance, host
-  top stacks (folded-stack sampler), measured-vs-predicted per-op
-  attribution, and the cross-host straggler diff (docs/profiling.md).
+  top stacks (folded-stack sampler), where the device trace and the
+  run's program map are, and the cross-host straggler diff
+  (docs/profiling.md).
 - ``tpu-ddp goodput <run_dir>`` — cross-incarnation goodput ledger:
   stitches every kill→``--resume`` life of a logical run into one
   timeline, classifies every wall-clock second into the badput
@@ -109,8 +110,7 @@ Subcommands:
   as a ready-to-run TrainConfig + CLI line. ``--validate-top K`` runs
   short measured trials and re-ranks (docs/tuning.md).
 
-``trace summarize``, ``health``, ``watch``, ``profile`` (modulo its
-lazy per-op join), ``mem`` (modulo its lazy plan rebuild; ``--no-plan``
+``trace summarize``, ``health``, ``watch``, ``profile``, ``mem`` (modulo its lazy plan rebuild; ``--no-plan``
 is import-free), ``curves``, ``registry``, and ``bench compare`` are
 stdlib-only
 end to end (no jax import): records are summarized wherever they land —
@@ -188,8 +188,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         from tpu_ddp.monitor.watch import main as watch_main
 
         return watch_main(argv[1:])
-    # profile is stdlib-only too, except the per-op attribution join
-    # (lazy jax; --no-ops keeps it import-free)
+    # profile is stdlib-only too
     if argv[:1] == ["profile"]:
         from tpu_ddp.profiler.report import main as profile_main
 
@@ -295,7 +294,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     sub.add_parser(
         "profile",
         help="render anomaly-profiler capture bundles: host top stacks, "
-             "per-op attribution, straggler diff "
+             "device trace and program map, straggler diff "
              "(tpu-ddp profile --help)",
     )
     sub.add_parser(

@@ -44,6 +44,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpu_ddp.telemetry.phases import kernel_scope
+
 LANE = 128
 _SUBLANES = 8
 # Longest sequence served by ONE whole-axis block when the requested blocks
@@ -299,28 +301,29 @@ def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
                                      lambda i, j, kk: (i, 0, kk),
                                      memory_space=pltpu.VMEM))
         args.append(_fold_mask(kv_mask, H))
-    out, lse = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, n_k=n_k, bq=bq, bk=bk,
-                          causal=causal, has_mask=has_mask),
-        out_shape=[
-            _sds((B * H, T, d_pad), q.dtype, qf),
-            _sds((B * H, T, LANE), jnp.float32, qf),
-        ],
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bq, d_pad), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, LANE), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d_pad), jnp.float32),  # acc
-            pltpu.VMEM((bq, LANE), jnp.float32),   # running max
-            pltpu.VMEM((bq, LANE), jnp.float32),   # running denom
-        ],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope(kernel_scope("flash_fwd")):
+        out, lse = pl.pallas_call(
+            functools.partial(_kernel, scale=scale, n_k=n_k, bq=bq, bk=bk,
+                              causal=causal, has_mask=has_mask),
+            out_shape=[
+                _sds((B * H, T, d_pad), q.dtype, qf),
+                _sds((B * H, T, LANE), jnp.float32, qf),
+            ],
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, bq, d_pad), lambda i, j, kk: (i, j, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, bq, LANE), lambda i, j, kk: (i, j, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, d_pad), jnp.float32),  # acc
+                pltpu.VMEM((bq, LANE), jnp.float32),   # running max
+                pltpu.VMEM((bq, LANE), jnp.float32),   # running denom
+            ],
+            interpret=interpret,
+        )(*args)
     return _unfold(out, q.shape), lse
 
 
@@ -455,15 +458,16 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
                                      lambda i, j, kk: (i, 0, kk),
                                      memory_space=pltpu.VMEM))
         args.append(mask_f)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, n_k=n_k, **kparams),
-        out_shape=_sds((B * H, T, d_pad), q.dtype, gf),
-        grid=(B * H, n_q, n_k),  # kv innermost: dq carry in scratch
-        in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((bq, d_pad), jnp.float32)],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope(kernel_scope("flash_dq")):
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, n_k=n_k, **kparams),
+            out_shape=_sds((B * H, T, d_pad), q.dtype, gf),
+            grid=(B * H, n_q, n_k),  # kv innermost: dq carry in scratch
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((bq, d_pad), jnp.float32)],
+            interpret=interpret,
+        )(*args)
 
     q_inner = pl.BlockSpec((1, bq, d_pad), lambda i, j, qq: (i, qq, 0),
                            memory_space=pltpu.VMEM)
@@ -478,21 +482,22 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
                                      lambda i, j, qq: (i, 0, j),
                                      memory_space=pltpu.VMEM))
         args.append(mask_f)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, n_q=n_q, **kparams),
-        out_shape=[
-            _sds((B * H, T, d_pad), k.dtype, gf),
-            _sds((B * H, T, d_pad), v.dtype, gf),
-        ],
-        grid=(B * H, n_k, n_q),  # q innermost: dk/dv carry in scratch
-        in_specs=in_specs,
-        out_specs=[kv_spec, kv_spec],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d_pad), jnp.float32),
-            pltpu.VMEM((bk, d_pad), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope(kernel_scope("flash_dkv")):
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, n_q=n_q, **kparams),
+            out_shape=[
+                _sds((B * H, T, d_pad), k.dtype, gf),
+                _sds((B * H, T, d_pad), v.dtype, gf),
+            ],
+            grid=(B * H, n_k, n_q),  # q innermost: dk/dv carry in scratch
+            in_specs=in_specs,
+            out_specs=[kv_spec, kv_spec],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d_pad), jnp.float32),
+                pltpu.VMEM((bk, d_pad), jnp.float32),
+            ],
+            interpret=interpret,
+        )(*args)
     shape = q.shape
     return _unfold(dq, shape), _unfold(dk, shape), _unfold(dv, shape)
 

@@ -27,6 +27,7 @@ kernel asked for is the kernel run, never a quiet reference.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
@@ -35,6 +36,7 @@ from tpu_ddp.ops.flash_attention import (
     _resolve_interpret,
     _sds,
 )
+from tpu_ddp.telemetry.phases import kernel_scope
 
 LANE = 128
 #: sublane multiple for f32 tiles — block rows per grid step are padded
@@ -94,18 +96,19 @@ def fused_quant(x, block: int, *, interpret=None) -> dict:
     if nb_pad != nb:
         xb = jnp.concatenate(
             [xb, jnp.zeros((nb_pad - nb, block), xb.dtype)])
-    q, s = pl.pallas_call(
-        _quant_kernel,
-        grid=(nb_pad // br,),
-        in_specs=[pl.BlockSpec((br, block), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((br, block), lambda i: (i, 0)),
-                   pl.BlockSpec((br, LANE), lambda i: (i, 0))],
-        out_shape=[
-            _sds((nb_pad, block), jnp.int8, xb),
-            _sds((nb_pad, LANE), jnp.float32, xb),
-        ],
-        interpret=interpret,
-    )(xb)
+    with jax.named_scope(kernel_scope("fused_quant")):
+        q, s = pl.pallas_call(
+            _quant_kernel,
+            grid=(nb_pad // br,),
+            in_specs=[pl.BlockSpec((br, block), lambda i: (i, 0))],
+            out_specs=[pl.BlockSpec((br, block), lambda i: (i, 0)),
+                       pl.BlockSpec((br, LANE), lambda i: (i, 0))],
+            out_shape=[
+                _sds((nb_pad, block), jnp.int8, xb),
+                _sds((nb_pad, LANE), jnp.float32, xb),
+            ],
+            interpret=interpret,
+        )(xb)
     return {"q": q[:nb].reshape(-1), "scale": s[:nb, 0]}
 
 
@@ -162,14 +165,15 @@ def fused_dequant(payload: dict, block: int, size: int, *,
     if acc is not None:
         in_specs.append(pl.BlockSpec((br, block), lambda i: (i, 0)))
         operands.append(acc)
-    out = pl.pallas_call(
-        _make_dequant_kernel(acc is not None),
-        grid=(nb_pad // br,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((br, block), lambda i: (i, 0)),
-        out_shape=_sds((nb_pad, block), jnp.float32, *operands),
-        interpret=interpret,
-    )(*operands)
+    with jax.named_scope(kernel_scope("fused_dequant")):
+        out = pl.pallas_call(
+            _make_dequant_kernel(acc is not None),
+            grid=(nb_pad // br,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((br, block), lambda i: (i, 0)),
+            out_shape=_sds((nb_pad, block), jnp.float32, *operands),
+            interpret=interpret,
+        )(*operands)
     return out[:nb].reshape(-1)[:size]
 
 

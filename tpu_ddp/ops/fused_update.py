@@ -86,6 +86,7 @@ from tpu_ddp.ops.flash_attention import (
     _sds,
 )
 from tpu_ddp.parallel.runtime import is_tpu_device
+from tpu_ddp.telemetry.phases import kernel_scope
 
 LANE = 128
 _SUBLANES = 8
@@ -248,18 +249,19 @@ def _fused_leaf(g, p, m, v, e, smem, start, *, kind, momentum, wd,
     # every output mixes every operand: it varies over their union
     out_shapes = [_sds((rows_pad, LANE), x.dtype, *operands)
                   for x in (g2, p2, m2, v2, e2) if x is not None]
-    outs = pl.pallas_call(
-        _build_kernel(kind=kind, momentum=momentum, wd=wd,
-                      wd_apply=wd_apply, has_clip=has_clip,
-                      max_norm=max_norm, step_const=step_const,
-                      ema_decay=ema_decay, b1=b1, b2=b2, eps=eps,
-                      mask_size=mask_size, br=br),
-        grid=(rows_pad // br,),
-        in_specs=in_specs,
-        out_specs=[tile_spec() for _ in out_shapes],
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(*operands)
+    with jax.named_scope(kernel_scope("fused_update")):
+        outs = pl.pallas_call(
+            _build_kernel(kind=kind, momentum=momentum, wd=wd,
+                          wd_apply=wd_apply, has_clip=has_clip,
+                          max_norm=max_norm, step_const=step_const,
+                          ema_decay=ema_decay, b1=b1, b2=b2, eps=eps,
+                          mask_size=mask_size, br=br),
+            grid=(rows_pad // br,),
+            in_specs=in_specs,
+            out_specs=[tile_spec() for _ in out_shapes],
+            out_shape=out_shapes,
+            interpret=interpret,
+        )(*operands)
     outs = [o.reshape(-1)[:n] for o in outs]
     it = iter(outs)
     u, p_new = next(it), next(it)

@@ -46,6 +46,12 @@ from tpu_ddp.health.stats import (
 )
 from tpu_ddp.models.vit import TransformerBlock
 from tpu_ddp.parallel.mesh import DATA_AXIS, PIPELINE_AXIS
+from tpu_ddp.telemetry.phases import (
+    FORWARD_BACKWARD_SCOPE,
+    GRAD_SYNC_SCOPE,
+    METRICS_SCOPE,
+    OPTIMIZER_SCOPE,
+)
 from tpu_ddp.train.losses import cross_entropy_loss, masked_accuracy
 from tpu_ddp.train.state import TrainState
 
@@ -292,17 +298,22 @@ def make_pp_train_step(
         return lax.pmean(loss, data_axis), logits
 
     def shard_step(state: TrainState, batch):
-        (loss, logits), grads = jax.value_and_grad(compute_loss, has_aux=True)(
-            state.params, batch
-        )
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        correct, count = masked_accuracy(logits, batch["label"], batch.get("mask"))
-        metrics = {
-            "loss": loss,
-            "accuracy": lax.psum(correct, data_axis)
-            / jnp.maximum(lax.psum(count, data_axis), 1.0),
-        }
+        with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+            (loss, logits), grads = jax.value_and_grad(
+                compute_loss, has_aux=True
+            )(state.params, batch)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            updates, new_opt_state = tx.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(METRICS_SCOPE):
+            correct, count = masked_accuracy(
+                logits, batch["label"], batch.get("mask"))
+            metrics = {
+                "loss": loss,
+                "accuracy": lax.psum(correct, data_axis)
+                / jnp.maximum(lax.psum(count, data_axis), 1.0),
+            }
         if health is not None:
             hstats = _pp_health_stats(
                 health, loss=loss, grads=grads, params=state.params,
@@ -570,36 +581,43 @@ def make_pp_1f1b_train_step(
             return (act_next, cot_next, buf, g_blocks, g_embed, g_head,
                     loss_sum, logits_buf), None
 
-        carry, _ = lax.scan(cycle, carry0, jnp.arange(n_cycles))
+        # the schedule's own vjp calls carry the AD markers that tell a
+        # stage's forward from its backward (telemetry/phases.py)
+        with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+            carry, _ = lax.scan(cycle, carry0, jnp.arange(n_cycles))
         (_, _, _, g_blocks, g_embed, g_head, loss_sum, logits_buf) = carry
 
         # replicated-param grads: nonzero on exactly one stage -> psum over
         # the pipeline axis recovers the unique contribution everywhere;
         # then DDP-average over data. Stage-local block grads only average
         # over data.
-        g_embed = jax.tree.map(lambda g: lax.psum(g, pipe_axis), g_embed)
-        g_head = jax.tree.map(lambda g: lax.psum(g, pipe_axis), g_head)
-        grads = {
-            "blocks": jax.tree.map(
-                lambda g: lax.pmean(g, data_axis), g_blocks),
-            **{k: jax.tree.map(lambda g: lax.pmean(g, data_axis), v)
-               for k, v in (("patch_embed", g_embed["patch_embed"]),
-                            ("pos_embed", g_embed["pos_embed"]),
-                            ("ln_f", g_head["ln_f"]),
-                            ("head", g_head["head"]))},
-        }
-        updates, new_opt_state = tx.update(grads, state.opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope(GRAD_SYNC_SCOPE):
+            g_embed = jax.tree.map(lambda g: lax.psum(g, pipe_axis), g_embed)
+            g_head = jax.tree.map(lambda g: lax.psum(g, pipe_axis), g_head)
+            grads = {
+                "blocks": jax.tree.map(
+                    lambda g: lax.pmean(g, data_axis), g_blocks),
+                **{k: jax.tree.map(lambda g: lax.pmean(g, data_axis), v)
+                   for k, v in (("patch_embed", g_embed["patch_embed"]),
+                                ("pos_embed", g_embed["pos_embed"]),
+                                ("ln_f", g_head["ln_f"]),
+                                ("head", g_head["head"]))},
+            }
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            updates, new_opt_state = tx.update(
+                grads, state.opt_state, params)
+            new_params = optax.apply_updates(params, updates)
 
-        loss = lax.pmean(lax.psum(loss_sum, pipe_axis), data_axis)
-        logits = lax.psum(logits_buf, pipe_axis).reshape(
-            local, model.num_classes)
-        correct, count = masked_accuracy(logits, labels, mask)
-        metrics = {
-            "loss": loss,
-            "accuracy": lax.psum(correct, data_axis)
-            / jnp.maximum(lax.psum(count, data_axis), 1.0),
-        }
+        with jax.named_scope(METRICS_SCOPE):
+            loss = lax.pmean(lax.psum(loss_sum, pipe_axis), data_axis)
+            logits = lax.psum(logits_buf, pipe_axis).reshape(
+                local, model.num_classes)
+            correct, count = masked_accuracy(logits, labels, mask)
+            metrics = {
+                "loss": loss,
+                "accuracy": lax.psum(correct, data_axis)
+                / jnp.maximum(lax.psum(count, data_axis), 1.0),
+            }
         if health is not None:
             hstats = _pp_health_stats(
                 health, loss=loss, grads=grads, params=params,

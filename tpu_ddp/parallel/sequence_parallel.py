@@ -25,6 +25,14 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from tpu_ddp.health.stats import HealthConfig, guard_step, health_stats
 from tpu_ddp.parallel.mesh import DATA_AXIS, SEQUENCE_AXIS
+from tpu_ddp.telemetry.phases import (
+    FORWARD_BACKWARD_SCOPE,
+    GRAD_COMPRESS_SCOPE,
+    HEALTH_SCOPE,
+    LOSS_SCOPE,
+    METRICS_SCOPE,
+    OPTIMIZER_SCOPE,
+)
 from tpu_ddp.train.losses import cross_entropy_loss
 from tpu_ddp.train.optim import apply_optimizer
 from tpu_ddp.train.state import TrainState
@@ -67,7 +75,8 @@ def make_sp_train_step(
 
     def compute_loss(params, batch):
         logits = model.apply({"params": params}, batch["image"], train=True)
-        loss = loss_fn(logits, batch["label"], batch.get("mask"))
+        with jax.named_scope(LOSS_SCOPE):
+            loss = loss_fn(logits, batch["label"], batch.get("mask"))
         # Gradient sync (see tpu_ddp.train.steps on why the pmean precedes
         # AD). Over `data_axis` ONLY: the SP model's mean-pool pmean already
         # made the loss invariant over `seq_axis`, and shard_map's
@@ -86,47 +95,56 @@ def make_sp_train_step(
             p_in = compress.varying(state.params)
         else:
             p_in = state.params
-        loss, grads = jax.value_and_grad(compute_loss)(p_in, batch)
+        with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+            loss, grads = jax.value_and_grad(compute_loss)(p_in, batch)
         if zero1 is not None or compress is not None:
-            loss = lax.pmean(loss, data_axis)
+            with jax.named_scope(METRICS_SCOPE):
+                loss = lax.pmean(loss, data_axis)
         ef = compress is not None and compress.config.error_feedback
         want_err = compress is not None and (ef or health is not None)
         residual = state.grad_residual if ef else None
         err_state = None
         if zero1 is not None:
-            new_params, new_opt_state, gshards, ushards, err_state = (
-                zero1.sharded_update(grads, state.params, state.opt_state,
-                                     residual=residual, with_error=want_err)
-            )
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                new_params, new_opt_state, gshards, ushards, err_state = (
+                    zero1.sharded_update(
+                        grads, state.params, state.opt_state,
+                        residual=residual, with_error=want_err)
+                )
         else:
             if compress is not None:
-                grads, err_state = compress.all_reduce_mean(
-                    grads, residual, with_error=want_err)
-            new_params, updates, new_opt_state = apply_optimizer(
-                tx, grads, state.opt_state, state.params)
+                with jax.named_scope(GRAD_COMPRESS_SCOPE):
+                    grads, err_state = compress.all_reduce_mean(
+                        grads, residual, with_error=want_err)
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                new_params, updates, new_opt_state = apply_optimizer(
+                    tx, grads, state.opt_state, state.params)
         new_residual = err_state if ef else state.grad_residual
         metrics = {"loss": loss}
         if health is not None:
-            # grads are synced over BOTH mesh axes by this point (zero1's shards are seq-complete and data-
-            # scattered, psum'd back to globals inside health_stats), so
-            # the stats are true globals — same schema as the DP step
-            err_sq = compress.error_sq(err_state) if want_err else None
-            if zero1 is not None:
-                hstats = zero1.health_stats(
-                    loss=loss, grad_shards=gshards, params=state.params,
-                    update_shards=ushards, per_layer=health.per_layer,
-                    compress_error_sq=err_sq,
+            # grads are synced over BOTH mesh axes by this point (zero1's
+            # shards are seq-complete and data-scattered, psum'd back to
+            # globals inside health_stats), so the stats are true globals —
+            # same schema as the DP step
+            with jax.named_scope(HEALTH_SCOPE):
+                err_sq = compress.error_sq(err_state) if want_err else None
+                if zero1 is not None:
+                    hstats = zero1.health_stats(
+                        loss=loss, grad_shards=gshards, params=state.params,
+                        update_shards=ushards, per_layer=health.per_layer,
+                        compress_error_sq=err_sq,
+                    )
+                else:
+                    hstats = health_stats(
+                        loss=loss, grads=grads, params=state.params,
+                        updates=updates, per_layer=health.per_layer,
+                        compress_error_sq=err_sq,
+                    )
+                (new_params, new_opt_state, new_residual) = guard_step(
+                    health, hstats,
+                    (new_params, new_opt_state, new_residual),
+                    (state.params, state.opt_state, state.grad_residual),
                 )
-            else:
-                hstats = health_stats(
-                    loss=loss, grads=grads, params=state.params,
-                    updates=updates, per_layer=health.per_layer,
-                    compress_error_sq=err_sq,
-                )
-            (new_params, new_opt_state, new_residual) = guard_step(
-                health, hstats, (new_params, new_opt_state, new_residual),
-                (state.params, state.opt_state, state.grad_residual),
-            )
             metrics["health"] = hstats
         return (
             state.replace(

@@ -37,6 +37,13 @@ from tpu_ddp.parallel.partitioning import (
     specs_for_params,
     train_state_shardings,
 )
+from tpu_ddp.telemetry.phases import (
+    FORWARD_BACKWARD_SCOPE,
+    GRAD_ACCUM_SCOPE,
+    HEALTH_SCOPE,
+    LOSS_SCOPE,
+    OPTIMIZER_SCOPE,
+)
 from tpu_ddp.train.losses import cross_entropy_loss
 from tpu_ddp.train.state import TrainState
 
@@ -140,13 +147,16 @@ def make_sharded_train_step(
     def compute_loss(params, batch_stats, batch):
         logits, mutated = apply_model(params, batch_stats, batch["image"])
         new_stats = mutated.get("batch_stats", batch_stats)
-        task = loss_fn(logits, batch["label"], batch.get("mask"))
-        loss, aux = combine_aux_loss(task, mutated, aux_weight)
+        with jax.named_scope(LOSS_SCOPE):
+            task = loss_fn(logits, batch["label"], batch.get("mask"))
+            loss, aux = combine_aux_loss(task, mutated, aux_weight)
         return loss, (new_stats, task, aux)
 
     def _finish(state, new_stats, task, aux, grads):
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            updates, new_opt_state = tx.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         metrics = {"loss": task}
         if aux is not None:
             metrics["aux_loss"] = aux
@@ -155,15 +165,16 @@ def make_sharded_train_step(
             # reductions lower to the same sharded-reduce + all-reduce the
             # partitioner picks for the update itself, so the stats are
             # computed where the (possibly ZeRO-scattered) values live
-            hstats = health_stats(
-                loss=task, grads=grads, params=state.params,
-                updates=updates, per_layer=health.per_layer,
-            )
-            new_params, new_stats, new_opt_state = guard_step(
-                health, hstats,
-                (new_params, new_stats, new_opt_state),
-                (state.params, state.batch_stats, state.opt_state),
-            )
+            with jax.named_scope(HEALTH_SCOPE):
+                hstats = health_stats(
+                    loss=task, grads=grads, params=state.params,
+                    updates=updates, per_layer=health.per_layer,
+                )
+                new_params, new_stats, new_opt_state = guard_step(
+                    health, hstats,
+                    (new_params, new_stats, new_opt_state),
+                    (state.params, state.batch_stats, state.opt_state),
+                )
             metrics["health"] = hstats
         return (
             state.replace(
@@ -176,9 +187,10 @@ def make_sharded_train_step(
         )
 
     def step_fn(state: TrainState, batch):
-        (_, (new_stats, task, aux)), grads = jax.value_and_grad(
-            compute_loss, has_aux=True
-        )(state.params, state.batch_stats, batch)
+        with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+            (_, (new_stats, task, aux)), grads = jax.value_and_grad(
+                compute_loss, has_aux=True
+            )(state.params, state.batch_stats, batch)
         return _finish(state, new_stats, task, aux, grads)
 
     def accum_step_fn(state: TrainState, batch):
@@ -206,12 +218,14 @@ def make_sharded_train_step(
 
         def accum(carry, micro):
             grads_acc, stats, loss_sum, aux_sum = carry
-            (_, (new_stats, task, aux)), grads = grad_fn(
-                state.params, stats, micro)
+            with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+                (_, (new_stats, task, aux)), grads = grad_fn(
+                    state.params, stats, micro)
             aux_term = aux if aux_present else jnp.zeros(())
+            with jax.named_scope(GRAD_ACCUM_SCOPE):
+                grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
             return (
-                jax.tree.map(jnp.add, grads_acc, grads), new_stats,
-                loss_sum + task, aux_sum + aux_term,
+                grads_acc, new_stats, loss_sum + task, aux_sum + aux_term,
             ), None
 
         (grads_acc, new_stats, loss_sum, aux_sum), _ = jax.lax.scan(
@@ -219,7 +233,8 @@ def make_sharded_train_step(
             (zero_grads, state.batch_stats, jnp.zeros(()), jnp.zeros(())),
             micros,
         )
-        grads = jax.tree.map(lambda g: g / A, grads_acc)
+        with jax.named_scope(GRAD_ACCUM_SCOPE):
+            grads = jax.tree.map(lambda g: g / A, grads_acc)
         return _finish(
             state, new_stats, loss_sum / A,
             aux_sum / A if aux_present else None, grads,

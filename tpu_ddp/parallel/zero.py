@@ -63,6 +63,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tpu_ddp.health.stats import assemble_stats, per_layer_sq, tree_nonfinite, tree_sq
 from tpu_ddp.parallel.mesh import DATA_AXIS
 from tpu_ddp.parallel.partitioning import _path_str
+from tpu_ddp.telemetry.phases import GRAD_COMPRESS_SCOPE, GRAD_SYNC_SCOPE
 
 
 @dataclasses.dataclass(frozen=True)
@@ -203,8 +204,9 @@ class Zero1Partition:
         ``residual``/``with_error`` thread the error-feedback state
         through it. ``err_state`` is None on the uncompressed path."""
         if self.compress is not None:
-            return self.compress.reduce_scatter_mean_flat(
-                self.flatten(grads), residual, with_error=with_error)
+            with jax.named_scope(GRAD_COMPRESS_SCOPE):
+                return self.compress.reduce_scatter_mean_flat(
+                    self.flatten(grads), residual, with_error=with_error)
         n = self.n_shards
 
         def rs(g):
@@ -212,7 +214,8 @@ class Zero1Partition:
                 g, self.axis, scatter_dimension=0, tiled=True
             ) / n
 
-        return jax.tree.map(rs, self.flatten(grads)), None
+        with jax.named_scope(GRAD_SYNC_SCOPE):
+            return jax.tree.map(rs, self.flatten(grads)), None
 
     def local_shard(self, flat_tree):
         """This shard's slice of a replicated flat tree (params enter the

@@ -1,4 +1,4 @@
-"""Anomaly-triggered profiler: capture windows, host stacks, per-op joins.
+"""Anomaly-triggered profiler: capture windows, host stacks, device traces.
 
 The monitor (``tpu_ddp/monitor/``) can say *that* a run is slow — a host
 straggles (STR001), throughput collapsed (THR001), the loop is
@@ -17,14 +17,12 @@ evidence for *why* a live run is slow:
   DWT001 data-wait alert into the actual Python frame burning the time,
   on any backend.
 - ``device``   — ``jax.profiler.trace`` arming for the window (degrading
-  to a note where unsupported), and the measured-vs-predicted **per-op
-  attribution**: the window's measured ``compiled_step`` span time
-  distributed over the PR 5 ``StepAnatomy`` cost-model op/collective
-  inventory — the roofline joined at op granularity, deviceless-safe.
+  to a note where unsupported). The trace's operations are named by the
+  run's program map (``telemetry/program_map.py``), not modelled here.
 - ``report``   — ``tpu-ddp profile <run_dir>``: renders bundles (trigger
-  provenance, top stacks, per-op table) and, across >= 2 hosts, the
-  straggler diff — the frames the flagged host shows that the fleet
-  median doesn't.
+  provenance, top stacks, where the device trace and the program map are)
+  and, across >= 2 hosts, the straggler diff — the frames the flagged host
+  shows that the fleet median doesn't.
 
 Module-level stdlib-only (jax imports are lazy), so the watch/report
 side runs wherever the run dir lands. See ``docs/profiling.md``.
@@ -38,7 +36,6 @@ from tpu_ddp.profiler.capture import (
     post_profile_trigger,
     read_bundle_meta,
 )
-from tpu_ddp.profiler.device import per_op_attribution
 from tpu_ddp.profiler.host import HostSampler, frame_shares, top_frames
 from tpu_ddp.profiler.report import straggler_diff
 
@@ -49,7 +46,6 @@ __all__ = [
     "frame_shares",
     "list_bundles",
     "parse_profile_steps",
-    "per_op_attribution",
     "post_profile_trigger",
     "read_bundle_meta",
     "straggler_diff",

@@ -19,8 +19,7 @@ While a window is open the manager runs the three capture sources:
 the host stack sampler (``profiler/host.py``), ``jax.profiler.trace``
 when the backend supports it (``profiler/device.py`` — absence degrades
 to a note, never an error), and a telemetry span listener that records
-the window's measured per-phase times (what the per-op attribution
-distributes). When the window closes it writes a schema-versioned
+the window's measured per-phase times. When the window closes it writes a schema-versioned
 **bundle** to ``<run_dir>/profiles/step_<start>-p<i>/``::
 
     meta.json            # trigger provenance, window, measured phases,

@@ -4,9 +4,9 @@ Reads the bundles a run's :class:`~tpu_ddp.profiler.capture.CaptureManager`
 wrote under ``<run_dir>/profiles/`` and renders, per bundle: the trigger
 provenance (which alert/config/POST armed it), the window's measured
 per-phase times, the host sampler's top stacks (the frame burning the
-time), the device-trace note/path, and the measured-vs-predicted per-op
-attribution table (``profiler/device.py`` — the one jax-backed section,
-degrading to a note without a backend).
+time), the device-trace note/path and, where the run wrote one, the
+program map that names the phase and module of each operation in that
+trace (``telemetry/program_map.py``).
 
 Given bundles from **two or more hosts** it also computes the straggler
 diff: the frames the flagged host's self-time profile shows that the
@@ -16,8 +16,7 @@ host comes from ``--host``, else the alert provenance recorded in a
 bundle, else the host whose frame-share vector diverges most from the
 fleet median.
 
-Stdlib-only except the per-op table (lazy jax, skippable via
-``--no-ops``), like every read-back CLI in-tree.
+Stdlib-only, like every read-back CLI in-tree.
 """
 
 from __future__ import annotations
@@ -36,7 +35,7 @@ from tpu_ddp.profiler.capture import (
 from tpu_ddp.profiler.host import frame_shares, parse_folded
 
 #: bump on breaking changes to the ``--json`` report shape
-REPORT_SCHEMA_VERSION = 1
+REPORT_SCHEMA_VERSION = 2
 
 #: a frame must gain at least this much self-time share over the fleet
 #: median to make the straggler diff
@@ -131,7 +130,7 @@ def _fmt_s(v: Optional[float]) -> str:
 
 
 def render_bundle(bundle_dir: str, meta: dict, *, top: int = 15,
-                  ops: Optional[dict] = None) -> str:
+                  program_map: Optional[dict] = None) -> str:
     trigger = meta.get("trigger") or {}
     window = meta.get("window") or {}
     sources = meta.get("sources") or {}
@@ -184,38 +183,48 @@ def render_bundle(bundle_dir: str, meta: dict, *, top: int = 15,
         lines.append("host top stacks: no samples recorded (window "
                      "shorter than a sampler tick?)")
 
-    if ops is not None:
+    if program_map is not None:
         lines.append("")
-        lines.extend(render_ops(ops))
+        lines.extend(render_program_map(program_map))
     return "\n".join(lines)
 
 
-def render_ops(ops: dict) -> List[str]:
-    """The per-op attribution table (or its degradation note)."""
-    if ops.get("note"):
-        return [f"per-op attribution: note: {ops['note']}"]
-    measured = ops.get("measured_step_s")
-    vs = ops.get("measured_vs_model")
-    lines = [
-        "per-op attribution (measured "
-        + (_fmt_s(measured) + "/step" if measured else "n/a")
-        + (f" = {vs:.1f}x the roofline model"
-           if isinstance(vs, (int, float)) else "")
-        + f", chip {ops.get('chip')}):"
-    ]
-    header = (f"  {'op':<34} {'model':>10} {'share':>6} "
-              f"{'attributed':>11}")
-    lines += [header, "  " + "-" * (len(header) - 2)]
-    for row in ops.get("ops") or []:
+def program_map_summary(run_dir: str, process_index: int) -> Optional[dict]:
+    """Where the host's newest program map is and what it holds, None
+    where the run wrote none (telemetry off, or a tree before the map)."""
+    from tpu_ddp.telemetry.program_map import (
+        newest_program_map_file,
+        read_program_maps,
+    )
+
+    path = newest_program_map_file(run_dir, process_index)
+    if path is None:
+        return None
+    try:
+        records = read_program_maps(path)
+    except (OSError, ValueError) as e:
+        return {"path": path, "note": str(e), "programs": []}
+    return {"path": path, "programs": [
+        {"program": r.get("program"), "module": r.get("module"),
+         "instructions": len(r.get("instructions") or {}),
+         "phases": r.get("phases"),
+         "mixed_fusions": r.get("mixed_fusions")} for r in records]}
+
+
+def render_program_map(summary: dict) -> List[str]:
+    """Where to read the device trace's operations by phase and module."""
+    lines = [f"program map: {summary['path']}"]
+    if summary.get("note"):
+        lines.append(f"  note: {summary['note']}")
+    for prog in summary["programs"]:
+        phases = "  ".join(f"{k} {v}" for k, v in
+                           (prog.get("phases") or {}).items() if v)
         lines.append(
-            f"  {row['op']:<34} {_fmt_s(row.get('model_s')):>10} "
-            f"{row.get('share', 0):>6.0%} "
-            f"{_fmt_s(row.get('attributed_s')):>11}"
-        )
-    for note in ops.get("notes") or []:
-        lines.append(f"  note: {note}")
-    if not ops.get("ops"):
-        lines.append("  (no rows)")
+            f"  {prog['program']} ({prog['module']}): "
+            f"{prog['instructions']} instructions  {phases}")
+    lines.append("  join the device trace's XLA Ops events with "
+                 "instructions[<name>] by phase and module "
+                 "(docs/profiling.md)")
     return lines
 
 
@@ -228,7 +237,7 @@ def render_diff(diff: dict) -> List[str]:
         lines.append("  no frame exceeds the fleet median by >= "
                      f"{DIFF_MIN_SHARE_DELTA:.0%} — the flagged host's "
                      "host-side profile matches the fleet (look at the "
-                     "device trace / per-op table instead)")
+                     "device trace beside the program map instead)")
         return lines
     for row in diff["frames"][:10]:
         lines.append(
@@ -244,8 +253,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="tpu-ddp profile",
         description="render anomaly-profiler capture bundles: trigger "
-                    "provenance, host top stacks, per-op attribution, "
-                    "and a cross-host straggler diff (docs/profiling.md)",
+                    "provenance, host top stacks, where the device trace "
+                    "and its program map are, and a cross-host straggler "
+                    "diff (docs/profiling.md)",
     )
     ap.add_argument("path", help="run dir (holding profiles/*/) or one "
                                  "bundle dir")
@@ -254,13 +264,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "straggler-diff target")
     ap.add_argument("--top", type=int, default=15,
                     help="host stack rows per bundle")
-    ap.add_argument("--chip", default=None,
-                    help="chip spec for the per-op attribution (v2..v6e; "
-                         "default: the recorded device kind, CPU falls "
-                         "back to v5e with a note)")
-    ap.add_argument("--no-ops", action="store_true",
-                    help="skip the per-op attribution join (stays "
-                         "stdlib-only: no jax import, no recompile)")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write the full report JSON here")
     args = ap.parse_args(list(argv) if argv is not None else None)
@@ -296,16 +299,15 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             flagged_from_alert = trigger["host"]
         if args.host is not None and host != args.host:
             continue
-        ops = None
-        if not args.no_ops:
-            from tpu_ddp.profiler.device import attribution_for_bundle
-
-            ops = attribution_for_bundle(meta, chip=args.chip)
+        # <run_dir>/profiles/<bundle>: the map lies two levels up
+        program_map = program_map_summary(
+            os.path.dirname(os.path.dirname(
+                os.path.abspath(bundle_dir))), host)
         rendered.append(render_bundle(bundle_dir, meta, top=args.top,
-                                      ops=ops))
+                                      program_map=program_map))
         report["bundles"].append({
             "path": bundle_dir, "meta": meta,
-            "ops": ops,
+            "program_map": program_map,
         })
 
     if not rendered:

@@ -114,15 +114,16 @@ class Telemetry:
         ))
 
     def emit_counters(self, step: Optional[int] = None, *,
-                      name: str = "counters") -> None:
+                      name: str = "counters", tables: bool = False) -> None:
         """Snapshot the registry into the sinks (JSONL record + Chrome "C"
         series). Call at natural boundaries (epoch end, run end); the
         Trainer's mid-epoch cadence passes ``name="counters_snapshot"``
         so readers can tell a periodic tail from a clean-shutdown
-        snapshot."""
+        snapshot. ``tables`` adds the per-row tables (per-function compile
+        accounting); only the run-end record carries them."""
         if not self.enabled:
             return
-        snap = self.registry.snapshot()
+        snap = self.registry.snapshot(tables=tables)
         self._emit(Event(
             name=name,
             kind=COUNTERS,
@@ -181,7 +182,7 @@ class Telemetry:
             # an ENDED host (trace goes quiet because the run finished)
             # from a LOST one (trace goes quiet because the host died)
             self.instant("run_end")
-            self.emit_counters()
+            self.emit_counters(tables=True)
         for sink in self.sinks:
             try:
                 sink.close()

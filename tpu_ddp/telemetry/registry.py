@@ -97,6 +97,25 @@ class Histogram:
         }
 
 
+class Table:
+    """Sums by (row, column): seconds and counts per jitted function, where
+    one counter a cell would flood the namespace. Only the run-end
+    snapshot carries tables (``Registry.snapshot(tables=True)``)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._rows: Dict[str, Dict[str, float]] = {}
+
+    def add(self, row: str, column: str, n: float = 1) -> None:
+        with self._lock:
+            cells = self._rows.setdefault(row, {})
+            cells[column] = cells.get(column, 0.0) + n
+
+    def rows(self) -> Dict[str, Dict[str, float]]:
+        with self._lock:
+            return {row: dict(cells) for row, cells in self._rows.items()}
+
+
 class Registry:
     """Named metric namespace; get-or-create accessors are thread-safe."""
 
@@ -105,6 +124,7 @@ class Registry:
         self._counters: Dict[str, Counter] = {}
         self._gauges: Dict[str, Gauge] = {}
         self._histograms: Dict[str, Histogram] = {}
+        self._tables: Dict[str, Table] = {}
 
     def counter(self, name: str) -> Counter:
         with self._lock:
@@ -118,19 +138,28 @@ class Registry:
         with self._lock:
             return self._histograms.setdefault(name, Histogram())
 
-    def snapshot(self) -> Dict[str, dict]:
-        """Point-in-time view of every metric, JSON-serializable."""
+    def table(self, name: str) -> Table:
+        with self._lock:
+            return self._tables.setdefault(name, Table())
+
+    def snapshot(self, tables: bool = False) -> Dict[str, dict]:
+        """Point-in-time view of every metric, JSON-serializable; with
+        ``tables`` the per-row tables too (the run-end record)."""
         with self._lock:
             counters = dict(self._counters)
             gauges = dict(self._gauges)
             histograms = dict(self._histograms)
-        return {
+            table_objs = dict(self._tables) if tables else {}
+        snap = {
             "counters": {k: c.value for k, c in counters.items()},
             "gauges": {
                 k: g.value for k, g in gauges.items() if g.value is not None
             },
             "histograms": {k: h.summary() for k, h in histograms.items()},
         }
+        if table_objs:
+            snap["tables"] = {k: t.rows() for k, t in table_objs.items()}
+        return snap
 
 
 _default: Optional[Registry] = None

@@ -27,6 +27,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from tpu_ddp.health.stats import HealthConfig, guard_step, health_stats
 from tpu_ddp.parallel.mesh import DATA_AXIS, SEQUENCE_AXIS
+from tpu_ddp.telemetry.phases import (
+    FORWARD_BACKWARD_SCOPE,
+    GRAD_COMPRESS_SCOPE,
+    METRICS_SCOPE,
+    OPTIMIZER_SCOPE,
+)
 from tpu_ddp.train.optim import apply_optimizer
 from tpu_ddp.train.state import TrainState
 from tpu_ddp.train.steps import _bind_compressor, state_specs_for
@@ -94,26 +100,31 @@ def make_lm_train_step(
             p_in = compress.varying(state.params)
         else:
             p_in = state.params
-        loss, grads = jax.value_and_grad(compute_loss)(p_in)
+        with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+            loss, grads = jax.value_and_grad(compute_loss)(p_in)
         if zero1 is not None or compress is not None:
-            loss = lax.pmean(loss, data_axis)
+            with jax.named_scope(METRICS_SCOPE):
+                loss = lax.pmean(loss, data_axis)
         ef = compress is not None and compress.config.error_feedback
         want_err = compress is not None and (ef or health is not None)
         residual = state.grad_residual if ef else None
         err_state = None
         if zero1 is not None:
-            new_params, new_opt, gshards, ushards, err_state = (
-                zero1.sharded_update(
-                    grads, state.params, state.opt_state,
-                    residual=residual, with_error=want_err,
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                new_params, new_opt, gshards, ushards, err_state = (
+                    zero1.sharded_update(
+                        grads, state.params, state.opt_state,
+                        residual=residual, with_error=want_err,
+                    )
                 )
-            )
         else:
             if compress is not None:
-                grads, err_state = compress.all_reduce_mean(
-                    grads, residual, with_error=want_err)
-            new_params, updates, new_opt = apply_optimizer(
-                tx, grads, state.opt_state, state.params)
+                with jax.named_scope(GRAD_COMPRESS_SCOPE):
+                    grads, err_state = compress.all_reduce_mean(
+                        grads, residual, with_error=want_err)
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                new_params, updates, new_opt = apply_optimizer(
+                    tx, grads, state.opt_state, state.params)
         new_residual = err_state if ef else state.grad_residual
         metrics = {"loss": loss}
         if health is not None:
@@ -217,26 +228,31 @@ def make_sp_lm_train_step(
             p_in = compress.varying(state.params)
         else:
             p_in = state.params
-        loss, grads = jax.value_and_grad(compute_loss)(p_in)
+        with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+            loss, grads = jax.value_and_grad(compute_loss)(p_in)
         if zero1 is not None or compress is not None:
-            loss = lax.pmean(loss, data_axis)
+            with jax.named_scope(METRICS_SCOPE):
+                loss = lax.pmean(loss, data_axis)
         ef = compress is not None and compress.config.error_feedback
         want_err = compress is not None and (ef or health is not None)
         residual = state.grad_residual if ef else None
         err_state = None
         if zero1 is not None:
-            new_params, new_opt, gshards, ushards, err_state = (
-                zero1.sharded_update(
-                    grads, state.params, state.opt_state,
-                    residual=residual, with_error=want_err,
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                new_params, new_opt, gshards, ushards, err_state = (
+                    zero1.sharded_update(
+                        grads, state.params, state.opt_state,
+                        residual=residual, with_error=want_err,
+                    )
                 )
-            )
         else:
             if compress is not None:
-                grads, err_state = compress.all_reduce_mean(
-                    grads, residual, with_error=want_err)
-            new_params, updates, new_opt = apply_optimizer(
-                tx, grads, state.opt_state, state.params)
+                with jax.named_scope(GRAD_COMPRESS_SCOPE):
+                    grads, err_state = compress.all_reduce_mean(
+                        grads, residual, with_error=want_err)
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                new_params, updates, new_opt = apply_optimizer(
+                    tx, grads, state.opt_state, state.params)
         new_residual = err_state if ef else state.grad_residual
         metrics = {"loss": loss}
         if health is not None:

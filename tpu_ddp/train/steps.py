@@ -40,6 +40,18 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from tpu_ddp.health.stats import HealthConfig, guard_step, health_stats
 from tpu_ddp.parallel.mesh import DATA_AXIS
+from tpu_ddp.telemetry.phases import (
+    FORWARD_BACKWARD_SCOPE,
+    FORWARD_SCOPE,
+    GRAD_ACCUM_SCOPE,
+    GRAD_COMPRESS_SCOPE,
+    HEALTH_SCOPE,
+    INPUT_SCOPE,
+    LOSS_SCOPE,
+    METRICS_SCOPE,
+    OPTIMIZER_SCOPE,
+    STATS_SYNC_SCOPE,
+)
 from tpu_ddp.train.losses import (
     combine_aux_loss,
     cross_entropy_loss,
@@ -170,14 +182,17 @@ def _make_shard_step(
 
     def compute_loss(params, batch_stats, batch):
         logits, mutated = apply_model(params, batch_stats, batch["image"])
-        task = loss_fn(logits, batch["label"], batch.get("mask"))
-        if mixup_alpha > 0:
-            # hard-label mixup: blend the two CE terms by the same lambda
-            # the images were blended with (data/augment.py::mixup)
-            task = (batch["_mix_lam"] * task
-                    + (1.0 - batch["_mix_lam"])
-                    * loss_fn(logits, batch["_mix_label"], batch.get("mask")))
-        loss, aux = combine_aux_loss(task, mutated, aux_weight)
+        with jax.named_scope(LOSS_SCOPE):
+            task = loss_fn(logits, batch["label"], batch.get("mask"))
+            if mixup_alpha > 0:
+                # hard-label mixup: blend the two CE terms by the same
+                # lambda the images were blended with
+                # (data/augment.py::mixup)
+                task = (batch["_mix_lam"] * task
+                        + (1.0 - batch["_mix_lam"])
+                        * loss_fn(logits, batch["_mix_label"],
+                                  batch.get("mask")))
+            loss, aux = combine_aux_loss(task, mutated, aux_weight)
         # Gradient sync lives HERE: pmean-ing the per-shard
         # loss before differentiation makes reverse-mode AD produce the
         # globally *averaged* gradient — the pmean's transpose scatters
@@ -198,26 +213,32 @@ def _make_shard_step(
         return loss, (mutated.get("batch_stats", batch_stats), logits, task, aux)
 
     def shard_step(state: TrainState, batch: Batch):
-        if augment or mixup_alpha > 0:
-            key = jax.random.fold_in(jax.random.key(augment_seed), state.step)
-            key = jax.random.fold_in(key, lax.axis_index(data_axis))
-        if augment:
-            from tpu_ddp.data.augment import random_crop_flip
+        # Every part of the step sits in a scope of telemetry/phases.py:
+        # jax writes the scope path into each operation's metadata, the
+        # Trainer's program map reads it back from the compiled program,
+        # and a device trace is split by phase and module from that map.
+        with jax.named_scope(INPUT_SCOPE):
+            if augment or mixup_alpha > 0:
+                key = jax.random.fold_in(
+                    jax.random.key(augment_seed), state.step)
+                key = jax.random.fold_in(key, lax.axis_index(data_axis))
+            if augment:
+                from tpu_ddp.data.augment import random_crop_flip
 
-            batch = dict(batch, image=random_crop_flip(key, batch["image"]))
-        if mixup_alpha > 0:
-            from tpu_ddp.data.augment import mixup
+                batch = dict(
+                    batch, image=random_crop_flip(key, batch["image"]))
+            if mixup_alpha > 0:
+                from tpu_ddp.data.augment import mixup
 
-            # distinct stream from crop/flip (same key would correlate them)
-            mixed, perm, lam = mixup(
-                jax.random.fold_in(key, 1), batch["image"],
-                alpha=mixup_alpha, valid=batch.get("mask"),
-            )
-            batch = dict(batch, image=mixed,
-                         _mix_label=batch["label"][perm], _mix_lam=lam)
+                # distinct stream from crop/flip (same key would correlate
+                # them)
+                mixed, perm, lam = mixup(
+                    jax.random.fold_in(key, 1), batch["image"],
+                    alpha=mixup_alpha, valid=batch.get("mask"),
+                )
+                batch = dict(batch, image=mixed,
+                             _mix_label=batch["label"][perm], _mix_lam=lam)
         grad_fn = jax.value_and_grad(compute_loss, has_aux=True)
-        # named scopes label the XLA ops so a jax.profiler device trace
-        # (and the telemetry Chrome trace next to it) read the same phases
         if zero1 is not None:
             if getattr(zero1, "scattered_params", False):
                 # ZeRO-3: params enter the step as flat 1/N shards; the
@@ -236,11 +257,13 @@ def _make_shard_step(
             p_in = compress.varying(state.params)
         else:
             p_in = state.params
-        with jax.named_scope("tpu_ddp.forward_backward"):
+        with jax.named_scope(FORWARD_BACKWARD_SCOPE):
             (_, (new_stats, logits, task, aux)), grads = grad_fn(
                 p_in, state.batch_stats, batch
             )
-        new_stats = jax.tree.map(lambda s: lax.pmean(s, data_axis), new_stats)
+        with jax.named_scope(STATS_SYNC_SCOPE):
+            new_stats = jax.tree.map(
+                lambda s: lax.pmean(s, data_axis), new_stats)
         # error feedback reads/writes state.grad_residual; the error is
         # also computed (without being carried) whenever health wants the
         # compression-drift stat
@@ -252,7 +275,7 @@ def _make_shard_step(
             # ZeRO-1: reduce-scatter IS the gradient sync; the optimizer
             # consumes only this shard's slice of grads/params/opt state
             # and the updated params come back via one all-gather.
-            with jax.named_scope("tpu_ddp.optimizer_update"):
+            with jax.named_scope(OPTIMIZER_SCOPE):
                 new_params, new_opt_state, gshards, ushards, err_state = (
                     zero1.sharded_update(
                         grads, state.params, state.opt_state,
@@ -263,39 +286,41 @@ def _make_shard_step(
             if compress is not None:
                 # the quantized ring replaces the pmean (the loss stayed
                 # local above)
-                with jax.named_scope("tpu_ddp.grad_compress_ring"):
+                with jax.named_scope(GRAD_COMPRESS_SCOPE):
                     grads, err_state = compress.all_reduce_mean(
                         grads, residual, with_error=want_err)
-            with jax.named_scope("tpu_ddp.optimizer_update"):
+            with jax.named_scope(OPTIMIZER_SCOPE):
                 new_params, updates, new_opt_state = apply_optimizer(
                     tx, grads, state.opt_state, state.params)
         new_residual = err_state if ef else state.grad_residual
         if health is not None:
-            # grads/updates are the synchronized values in EVERY sync mode
-            # (AD-of-pmean'd-loss, the dequantized
-            # ring output, or the zero1 shards whose shard-local norms are
-            # psum'd over data), so every shard computes identical global
-            # stats in-graph.
-            err_sq = (compress.error_sq(err_state)
-                      if want_err else None)
-            if zero1 is not None:
-                hstats = zero1.health_stats(
-                    loss=lax.pmean(task, data_axis), grad_shards=gshards,
-                    params=state.params, update_shards=ushards,
-                    per_layer=health.per_layer, compress_error_sq=err_sq,
+            with jax.named_scope(HEALTH_SCOPE):
+                # grads/updates are the synchronized values in EVERY sync
+                # mode (AD-of-pmean'd-loss, the dequantized ring output, or
+                # the zero1 shards whose shard-local norms are psum'd over
+                # data), so every shard computes identical global stats
+                # in-graph.
+                err_sq = (compress.error_sq(err_state)
+                          if want_err else None)
+                if zero1 is not None:
+                    hstats = zero1.health_stats(
+                        loss=lax.pmean(task, data_axis), grad_shards=gshards,
+                        params=state.params, update_shards=ushards,
+                        per_layer=health.per_layer, compress_error_sq=err_sq,
+                    )
+                else:
+                    hstats = health_stats(
+                        loss=lax.pmean(task, data_axis), grads=grads,
+                        params=state.params, updates=updates,
+                        per_layer=health.per_layer, compress_error_sq=err_sq,
+                    )
+                (new_params, new_stats, new_opt_state,
+                 new_residual) = guard_step(
+                    health, hstats,
+                    (new_params, new_stats, new_opt_state, new_residual),
+                    (state.params, state.batch_stats, state.opt_state,
+                     state.grad_residual),
                 )
-            else:
-                hstats = health_stats(
-                    loss=lax.pmean(task, data_axis), grads=grads,
-                    params=state.params, updates=updates,
-                    per_layer=health.per_layer, compress_error_sq=err_sq,
-                )
-            (new_params, new_stats, new_opt_state, new_residual) = guard_step(
-                health, hstats,
-                (new_params, new_stats, new_opt_state, new_residual),
-                (state.params, state.batch_stats, state.opt_state,
-                 state.grad_residual),
-            )
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,
@@ -303,18 +328,19 @@ def _make_shard_step(
             opt_state=new_opt_state,
             grad_residual=new_residual,
         )
-        metrics = {"loss": lax.pmean(task, data_axis)}
-        if health is not None:
-            metrics["health"] = hstats
-        if aux is not None:
-            metrics["aux_loss"] = lax.pmean(aux, data_axis)
-        if compute_accuracy:
-            correct, count = masked_accuracy(
-                logits, batch["label"], batch.get("mask")
-            )
-            metrics["accuracy"] = lax.psum(correct, data_axis) / jnp.maximum(
-                lax.psum(count, data_axis), 1.0
-            )
+        with jax.named_scope(METRICS_SCOPE):
+            metrics = {"loss": lax.pmean(task, data_axis)}
+            if health is not None:
+                metrics["health"] = hstats
+            if aux is not None:
+                metrics["aux_loss"] = lax.pmean(aux, data_axis)
+            if compute_accuracy:
+                correct, count = masked_accuracy(
+                    logits, batch["label"], batch.get("mask")
+                )
+                metrics["accuracy"] = (
+                    lax.psum(correct, data_axis)
+                    / jnp.maximum(lax.psum(count, data_axis), 1.0))
         return new_state, metrics
 
     return shard_step
@@ -502,8 +528,9 @@ def make_grad_accum_train_step(
 
     def compute_loss(params, batch_stats, micro):
         logits, mutated = apply_model(params, batch_stats, micro["image"])
-        task = loss_fn(logits, micro["label"], micro.get("mask"))
-        loss, aux = combine_aux_loss(task, mutated, aux_weight)
+        with jax.named_scope(LOSS_SCOPE):
+            task = loss_fn(logits, micro["label"], micro.get("mask"))
+            loss, aux = combine_aux_loss(task, mutated, aux_weight)
         # grad sync, as in _make_shard_step (zero1/compress: the sync is
         # the (ring) reduce-scatter AFTER accumulation — the loss stays
         # local, ONE compressed collective per accumulated batch)
@@ -544,11 +571,15 @@ def make_grad_accum_train_step(
 
         def accum(carry, micro):
             grads_acc, stats, correct, count, loss_sum, aux_sum = carry
-            (_, (new_stats, logits, task, aux)), grads = grad_fn(
-                p_in, stats, micro
-            )
-            grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
-            c, n = masked_accuracy(logits, micro["label"], micro.get("mask"))
+            with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+                (_, (new_stats, logits, task, aux)), grads = grad_fn(
+                    p_in, stats, micro
+                )
+            with jax.named_scope(GRAD_ACCUM_SCOPE):
+                grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
+            with jax.named_scope(METRICS_SCOPE):
+                c, n = masked_accuracy(
+                    logits, micro["label"], micro.get("mask"))
             aux_term = jnp.zeros(()) if aux is None else aux
             return (
                 grads_acc, new_stats, correct + c, count + n,
@@ -569,8 +600,11 @@ def make_grad_accum_train_step(
             (zero_grads, stats0, zero, zero, zero, zero),
             micros,
         )
-        grads = jax.tree.map(lambda g: g / accum_steps, grads_acc)
-        new_stats = jax.tree.map(lambda s: lax.pmean(s, data_axis), new_stats)
+        with jax.named_scope(GRAD_ACCUM_SCOPE):
+            grads = jax.tree.map(lambda g: g / accum_steps, grads_acc)
+        with jax.named_scope(STATS_SYNC_SCOPE):
+            new_stats = jax.tree.map(
+                lambda s: lax.pmean(s, data_axis), new_stats)
         ef = compress is not None and compress.config.error_feedback
         want_err = compress is not None and (ef or health is not None)
         residual = state.grad_residual if ef else None
@@ -578,41 +612,46 @@ def make_grad_accum_train_step(
         if zero1 is not None:
             # ONE reduce-scatter for the whole accumulated batch: the
             # microbatch mean above commutes with the cross-shard average.
-            new_params, new_opt_state, gshards, ushards, err_state = (
-                zero1.sharded_update(grads, state.params, state.opt_state,
-                                     residual=residual, with_error=want_err)
-            )
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                new_params, new_opt_state, gshards, ushards, err_state = (
+                    zero1.sharded_update(
+                        grads, state.params, state.opt_state,
+                        residual=residual, with_error=want_err)
+                )
         else:
             if compress is not None:  # one compressed ring per step
-                grads, err_state = compress.all_reduce_mean(
-                    grads, residual, with_error=want_err)
-            new_params, updates, new_opt_state = apply_optimizer(
-                tx, grads, state.opt_state, state.params)
+                with jax.named_scope(GRAD_COMPRESS_SCOPE):
+                    grads, err_state = compress.all_reduce_mean(
+                        grads, residual, with_error=want_err)
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                new_params, updates, new_opt_state = apply_optimizer(
+                    tx, grads, state.opt_state, state.params)
         new_residual = err_state if ef else state.grad_residual
         if health is not None:
-            # same guarantees as _make_shard_step: grads/updates are the
-            # synchronized values the optimizer consumed (the accumulated
-            # average), so the stats are the true full-batch numbers
-            err_sq = compress.error_sq(err_state) if want_err else None
-            if zero1 is not None:
-                hstats = zero1.health_stats(
-                    loss=lax.pmean(loss_sum / accum_steps, data_axis),
-                    grad_shards=gshards, params=state.params,
-                    update_shards=ushards, per_layer=health.per_layer,
-                    compress_error_sq=err_sq,
+            with jax.named_scope(HEALTH_SCOPE):
+                # same guarantees as _make_shard_step: grads/updates are the
+                # synchronized values the optimizer consumed (the accumulated
+                # average), so the stats are the true full-batch numbers
+                err_sq = compress.error_sq(err_state) if want_err else None
+                if zero1 is not None:
+                    hstats = zero1.health_stats(
+                        loss=lax.pmean(loss_sum / accum_steps, data_axis),
+                        grad_shards=gshards, params=state.params,
+                        update_shards=ushards, per_layer=health.per_layer,
+                        compress_error_sq=err_sq,
+                    )
+                else:
+                    hstats = health_stats(
+                        loss=lax.pmean(loss_sum / accum_steps, data_axis),
+                        grads=grads, params=state.params, updates=updates,
+                        per_layer=health.per_layer, compress_error_sq=err_sq,
+                    )
+                (new_params, new_stats, new_opt_state, new_residual) = guard_step(
+                    health, hstats,
+                    (new_params, new_stats, new_opt_state, new_residual),
+                    (state.params, state.batch_stats, state.opt_state,
+                     state.grad_residual),
                 )
-            else:
-                hstats = health_stats(
-                    loss=lax.pmean(loss_sum / accum_steps, data_axis),
-                    grads=grads, params=state.params, updates=updates,
-                    per_layer=health.per_layer, compress_error_sq=err_sq,
-                )
-            (new_params, new_stats, new_opt_state, new_residual) = guard_step(
-                health, hstats,
-                (new_params, new_stats, new_opt_state, new_residual),
-                (state.params, state.batch_stats, state.opt_state,
-                 state.grad_residual),
-            )
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,
@@ -620,13 +659,14 @@ def make_grad_accum_train_step(
             opt_state=new_opt_state,
             grad_residual=new_residual,
         )
-        metrics = {"loss": lax.pmean(loss_sum / accum_steps, data_axis)}
-        if health is not None:
-            metrics["health"] = hstats
-        if compute_accuracy:
-            metrics["accuracy"] = lax.psum(correct, data_axis) / jnp.maximum(
-                lax.psum(count, data_axis), 1.0
-            )
+        with jax.named_scope(METRICS_SCOPE):
+            metrics = {"loss": lax.pmean(loss_sum / accum_steps, data_axis)}
+            if health is not None:
+                metrics["health"] = hstats
+            if compute_accuracy:
+                metrics["accuracy"] = lax.psum(correct, data_axis) / jnp.maximum(
+                    lax.psum(count, data_axis), 1.0
+                )
         return new_state, metrics
 
     state_specs = state_specs_for(zero1, compress, data_axis)
@@ -653,28 +693,32 @@ def make_eval_step(
 
     def shard_eval(state: TrainState, batch: Batch):
         variables = {"params": state.params, "batch_stats": state.batch_stats}
-        logits = model.apply(variables, batch["image"], train=False)
-        mask = batch.get("mask")
-        loss = loss_fn(logits, batch["label"], mask)
-        shard_count = (
-            mask.astype(jnp.float32).sum()
-            if mask is not None
-            else jnp.asarray(float(logits.shape[0]))
-        )
-        if compute_accuracy:
-            correct, _ = masked_accuracy(logits, batch["label"], mask)
-        else:
-            correct = jnp.zeros(())
-        return {
-            "correct": lax.psum(correct, data_axis),
-            "count": lax.psum(shard_count, data_axis),
-            # EXACT sum of per-sample losses: the per-shard (masked-mean)
-            # loss re-weighted by ITS OWN real count before the psum — with
-            # drop_last=False padding, shards hold different real counts, so
-            # a pmean over shard means would mis-weight exactly the way the
-            # reference's val loop mis-measured (ppe_main_ddp.py:160-166).
-            "loss_sum": lax.psum(loss * shard_count, data_axis),
-        }
+        with jax.named_scope(FORWARD_SCOPE):
+            logits = model.apply(variables, batch["image"], train=False)
+            mask = batch.get("mask")
+            with jax.named_scope(LOSS_SCOPE):
+                loss = loss_fn(logits, batch["label"], mask)
+        with jax.named_scope(METRICS_SCOPE):
+            shard_count = (
+                mask.astype(jnp.float32).sum()
+                if mask is not None
+                else jnp.asarray(float(logits.shape[0]))
+            )
+            if compute_accuracy:
+                correct, _ = masked_accuracy(logits, batch["label"], mask)
+            else:
+                correct = jnp.zeros(())
+            return {
+                "correct": lax.psum(correct, data_axis),
+                "count": lax.psum(shard_count, data_axis),
+                # EXACT sum of per-sample losses: the per-shard
+                # (masked-mean) loss re-weighted by ITS OWN real count
+                # before the psum — with drop_last=False padding, shards
+                # hold different real counts, so a pmean over shard means
+                # would mis-weight exactly the way the reference's val loop
+                # mis-measured (ppe_main_ddp.py:160-166).
+                "loss_sum": lax.psum(loss * shard_count, data_axis),
+            }
 
     sharded = jax.shard_map(
         shard_eval,
@@ -698,7 +742,8 @@ def make_predict_step(
 
     def shard_predict(state: TrainState, batch: Batch):
         variables = {"params": state.params, "batch_stats": state.batch_stats}
-        return model.apply(variables, batch["image"], train=False)
+        with jax.named_scope(FORWARD_SCOPE):
+            return model.apply(variables, batch["image"], train=False)
 
     sharded = jax.shard_map(
         shard_predict,
@@ -732,7 +777,9 @@ def make_auto_train_step(
         logits, mutated = model.apply(
             variables, batch["image"], train=True, mutable=["batch_stats"]
         )
-        return loss_fn(logits, batch["label"], batch.get("mask")), mutated["batch_stats"]
+        with jax.named_scope(LOSS_SCOPE):
+            loss = loss_fn(logits, batch["label"], batch.get("mask"))
+        return loss, mutated["batch_stats"]
 
     @functools.partial(
         jax.jit,
@@ -741,11 +788,13 @@ def make_auto_train_step(
         donate_argnums=(0,),
     )
     def step(state: TrainState, batch: Batch):
-        (loss, new_stats), grads = jax.value_and_grad(compute_loss, has_aux=True)(
-            state.params, state.batch_stats, batch
-        )
-        new_params, updates, new_opt_state = apply_optimizer(
-            tx, grads, state.opt_state, state.params)
+        with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+            (loss, new_stats), grads = jax.value_and_grad(
+                compute_loss, has_aux=True
+            )(state.params, state.batch_stats, batch)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            new_params, updates, new_opt_state = apply_optimizer(
+                tx, grads, state.opt_state, state.params)
         return (
             state.replace(
                 step=state.step + 1,

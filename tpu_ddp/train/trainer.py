@@ -925,6 +925,24 @@ class Trainer:
             self._init_dp_steps(loss_fn, with_acc)
         else:
             self._init_strategy_steps(loss_fn, with_acc)
+        # The program map (telemetry/program_map.py): with telemetry on,
+        # the phase and module of every instruction of each step program,
+        # written once the program has settled. It holds the jitted
+        # callables built just above, not the attributes: a caller may
+        # stand a probe in ``train_step`` before ``run``. With telemetry
+        # off nothing is built, lowered or written.
+        self._program_map = None
+        if self.telemetry.enabled:
+            from tpu_ddp.telemetry.program_map import ProgramMapExporter
+
+            programs = {"single": ("train_step", self.train_step)}
+            if self.multi_step is not None:
+                programs["stacked"] = ("multi_step", self.multi_step)
+            self._program_map = ProgramMapExporter(
+                config.telemetry_dir, programs,
+                process_index=self.process_index,
+                incarnation=self.incarnation,
+            )
         self._prefetcher = None   # built lazily on first epoch
         self.history: dict = {"epoch": [], "train_loss": []}
         self.logger = MetricLogger(
@@ -2105,6 +2123,12 @@ class Trainer:
                         )
                     step_losses.append(epoch_metrics["loss"])
                     n_steps += 1
+                if (self._program_map is not None
+                        and not self._program_map.done):
+                    # while the device runs the step just dispatched; over
+                    # within the first dispatches of the run
+                    self._program_map.after_dispatch(
+                        kind, self.state, dev_batch)
                 if track_step:
                     host_step += (
                         self.steps_per_call if kind == "stacked" else 1

@@ -2,8 +2,8 @@
 
 The roofline is a lower bound — real steps carry launch gaps, imperfect
 overlap, and compiler scheduling the cost model can't see. The PR 8
-profiler measures exactly that gap (its per-op attribution reports the
-whole-step measured-over-model ratio), and the PR 5 analyzer's run-dir
+profiler measures exactly that gap (a capture window's measured
+per-step span time), and the PR 5 analyzer's run-dir
 join records it as ``roofline_fraction`` (= predicted/measured). This
 module turns that evidence into one number per CHIP KIND — the median
 measured-over-predicted ratio — which ``price.py`` multiplies into
@@ -11,11 +11,8 @@ every prediction:
 
 - **profile bundles** (``<run_dir>/profiles/*/meta.json``): the
   window's measured per-step time over the roofline prediction of the
-  bundle's own recorded program (rebuilt via ``anatomy_for_run_meta``,
-  same path as ``tpu-ddp profile``'s per-op table). Note the ratio here
-  is against the OVERLAPPED roofline — the profiler's own
-  ``measured_vs_model`` is the serial-sum cousin, so it is recomputed
-  rather than reused;
+  bundle's own recorded program (rebuilt via ``anatomy_for_run_meta``).
+  The ratio is against the OVERLAPPED roofline;
 - **analyze --json run-dir artifacts**: ``1 / measured.roofline_fraction``;
 - **registry entries**: archived ``tune --json`` artifacts whose
   ``--validate-top`` trials recorded ``measured_vs_model`` ratios.
