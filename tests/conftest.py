@@ -58,6 +58,23 @@ def compile_cache(tmp_path, monkeypatch):
     compilation_cache.reset_cache()
 
 
+@pytest.fixture
+def fresh_registry():
+    """The process-wide telemetry registry emptied, with jax's compile events
+    counted into it (``jax/functions`` among them) from now on; yields
+    ``default_registry``."""
+    from tpu_ddp.telemetry.jax_hooks import install_jax_hooks
+    from tpu_ddp.telemetry.registry import (
+        default_registry,
+        reset_default_registry,
+    )
+
+    reset_default_registry()
+    assert install_jax_hooks()
+    yield default_registry
+    reset_default_registry()
+
+
 @pytest.fixture(scope="session")
 def devices():
     devs = jax.devices()

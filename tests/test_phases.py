@@ -254,20 +254,6 @@ def test_the_export_waits_for_the_jit_cache_to_settle(tmp_path):
 
 # -- per-function compile accounting -----------------------------------------------
 
-@pytest.fixture
-def fresh_registry():
-    from tpu_ddp.telemetry.jax_hooks import install_jax_hooks
-    from tpu_ddp.telemetry.registry import (
-        default_registry,
-        reset_default_registry,
-    )
-
-    reset_default_registry()
-    assert install_jax_hooks()
-    yield default_registry
-    reset_default_registry()
-
-
 def test_seconds_go_to_the_function_by_name(fresh_registry):
     from tpu_ddp.telemetry.jax_hooks import FUNCTIONS_TABLE, paused
 

@@ -26,7 +26,13 @@ import numpy as np
 
 from tpu_ddp.data.loader import ShardedBatchLoader
 from tpu_ddp.metrics import MetricLogger, Throughput
-from tpu_ddp.parallel.mesh import DATA_AXIS, MeshSpec, batch_sharding, create_mesh
+from tpu_ddp.parallel.mesh import (
+    DATA_AXIS,
+    MeshSpec,
+    batch_sharding,
+    create_mesh,
+    replicated_sharding,
+)
 from tpu_ddp.parallel.runtime import enable_compile_cache
 from tpu_ddp.train.optim import make_optimizer
 from tpu_ddp.train.state import create_train_state
@@ -993,8 +999,6 @@ class Trainer:
                             "unreadable best metadata %s (%s); treating "
                             "best accuracy as unset", meta, e)
             if config.resume and self.checkpointer.latest_step() is not None:
-                from tpu_ddp.parallel.mesh import replicated_sharding
-
                 # Checkpoints are ALWAYS the de-sharded, device-count-
                 # independent layout — _ckpt_state below: zero1 opt
                 # shards gathered back to the original optax layout, the
@@ -1003,7 +1007,9 @@ class Trainer:
                 # run's checkpoint and vice versa, AND a checkpoint cut
                 # on one device count resumes on another (the elastic
                 # re-mesh path, docs/resilience.md). Restore through the
-                # de-sharded template, then re-scatter onto THIS mesh.
+                # de-sharded template, then re-scatter onto THIS mesh;
+                # _place_state below lays the rest out in the TRAINING
+                # layout (fsdp/tp/pp/ep scattered, dp/sp replicated).
                 restored = self._restore_checkpoint(self._ckpt_state())
                 if (self._compress is not None
                         and restored.grad_residual is not None):
@@ -1011,23 +1017,28 @@ class Trainer:
                         grad_residual=self._compress.shard_residual(
                             restored.grad_residual, self.mesh))
                 if self._zero1 is not None:
-                    self.state = self._zero1.shard_state(restored, self.mesh)
-                else:
-                    # Lay restored arrays back out in the TRAINING layout:
-                    # the sharded strategies (fsdp/tp/pp/ep) resume
-                    # scattered, the replicated ones (dp/sp) resume
-                    # replicated — the state shardings already carry the
-                    # right layout (incl. the residual's P(data)), this
-                    # device_put just pins the invariant.
-                    self.state = jax.device_put(
-                        restored,
-                        self.state_shardings
-                        or replicated_sharding(self.mesh),
-                    )
+                    restored = self._zero1.shard_state(restored, self.mesh)
+                self.state = restored
                 self.resumed_step = int(self.state.step)
                 self.logger.log_text(
                     f"resumed from step {self.resumed_step}"
                 )
+        self.state = self._place_state(self.state)
+
+    def _place_state(self, state):
+        """``state`` as a step returns it: every leaf (``step`` included) a
+        committed array on the mesh, laid out by ``state_shardings`` where
+        a builder set one (zero1/zero3, the error-feedback residual, the
+        sharded strategies) and replicated otherwise. A copy, bit for bit;
+        a leaf that is there already is kept as it is.
+
+        The one place that knows a state's layout, called once, whichever
+        branch made the state (fresh, pretrained, resumed): the first call
+        of a step then takes the kind of argument every later call does,
+        its own output, so jit traces, lowers and loads the step once per
+        ``Trainer``. One uncommitted leaf is enough for a second round."""
+        return jax.device_put(
+            state, self.state_shardings or replicated_sharding(self.mesh))
 
     def _restore_checkpoint(self, template):
         """``Checkpointer.restore`` with grad-residual tolerance: the
@@ -1145,17 +1156,13 @@ class Trainer:
         prefetch schedule)."""
         config = self.config
         if config.pretrained_dir:
-            from tpu_ddp.parallel.mesh import replicated_sharding
             from tpu_ddp.train.finetune import load_pretrained_for_finetune
 
-            self.state = jax.device_put(
-                load_pretrained_for_finetune(
-                    config.pretrained_dir,
-                    self.model,
-                    self.tx,
-                    rng=jax.random.key(config.seed),
-                ),
-                replicated_sharding(self.mesh),
+            self.state = load_pretrained_for_finetune(
+                config.pretrained_dir,
+                self.model,
+                self.tx,
+                rng=jax.random.key(config.seed),
             )
         elif config.zero1 or config.zero3:
             # Fresh zero1/zero3 init: the SAME init recipe as
@@ -1169,12 +1176,13 @@ class Trainer:
             # model init being the unavoidable floor).
             import jax.numpy as jnp
 
-            from tpu_ddp.parallel.mesh import replicated_sharding
             from tpu_ddp.parallel.zero import Zero1Partition, Zero3Partition
             from tpu_ddp.train.state import TrainState, init_model_variables
 
             params, batch_stats = init_model_variables(
                 self.model, jax.random.key(config.seed))
+            # on the mesh before the scatters below read them; the step and
+            # the batch statistics are left to _place_state
             params = jax.device_put(params, replicated_sharding(self.mesh))
             cls = Zero3Partition if config.zero3 else Zero1Partition
             self._zero1 = cls(
@@ -1185,8 +1193,7 @@ class Trainer:
             self.state = TrainState(
                 step=jnp.zeros((), jnp.int32),
                 params=params,
-                batch_stats=jax.device_put(
-                    batch_stats, replicated_sharding(self.mesh)),
+                batch_stats=batch_stats,
                 opt_state=opt_state,
             )
         else:
