@@ -2,9 +2,13 @@
 
 The adapter builds a ``TrainConfig`` from the cell's two data files, constructs
 the ``Trainer`` the way ``tpu_ddp/cli/train.py`` does, gives it the benchmark's
-seeded training set and seeded weights, hands it back its own step callable
-wrapped in a ``StepProbe``, and calls ``Trainer.run``. It never iterates the
-loader and never calls the step itself.
+seeded training set (the generator the mix's ``dataset.kind`` names) and seeded
+weights, hands it back its own step callable wrapped in a ``StepProbe``, and
+calls ``Trainer.run``. It never iterates the loader and never calls the step
+itself. What a batch holds, what the loss is and which optimizer steps it are
+not known here: the probe copies batches as they are fed, and the record
+carries the optimizer the files state (``record["optimizer"]``) for the
+configuration's reference file to follow.
 
 The probe is the only seam: every dispatch the ``Trainer`` makes goes through
 it. It reads the first three steps for ``correct`` (set-up), opens the window
@@ -15,7 +19,10 @@ drain (the preemption flag). A window is whole epochs: the same work in every
 run of a cell.
 
 From ``tpu_ddp`` it takes ``TrainConfig``, ``Trainer`` and, in a traced run,
-the telemetry's span stream. Nothing else.
+the telemetry's span stream. Nothing else. ``StepProbe`` takes nothing of the
+``Trainer`` but the flag that ends its loop, so an adapter that drives a step
+another way (a new file under ``adapters/``) stands the same probe in front of
+its own step callable.
 """
 
 from __future__ import annotations
@@ -30,6 +37,9 @@ from chipbench import datagen
 
 #: steps of set-up whose inputs and outputs ``correct`` reads
 CHECK_STEPS = 3
+#: rows of every array of the training set that the ``Trainer`` is given as
+#: its held-out set; no cell evaluates, so they are never read
+HELD_OUT = 64
 #: the traced slice: at most this many seconds and this many dispatches
 TRACE_SECONDS = 3.0
 TRACE_DISPATCHES = 300
@@ -87,11 +97,38 @@ def install_weights(trainer, ref_params: dict, names: dict):
     trainer.state = trainer.state.replace(params=params)
 
 
+def optimizer_fields(opt_state, fields) -> dict:
+    """field -> tree, for each of ``fields`` that names a field of a named
+    tuple anywhere in the optimizer's state (optax: ``mu`` of
+    ``ScaleByAdamState``); the first found wins."""
+    found = {}
+
+    def visit(node):
+        if isinstance(node, tuple) and hasattr(node, "_fields"):
+            for field in node._fields:
+                if field in fields:
+                    found.setdefault(field, getattr(node, field))
+        if isinstance(node, (tuple, list)):
+            for child in node:
+                visit(child)
+
+    visit(opt_state)
+    if set(found) != set(fields):
+        raise ValueError(f"the optimizer's state has no field "
+                         f"{sorted(set(fields) - set(found))}")
+    return found
+
+
 class StepProbe:
-    """Stands where the ``Trainer``'s step callable stood."""
+    """Stands where the program's step callable stood: ``inner(state, batch)
+    -> (state, metrics)`` with ``state.params`` a tree of the leaves ``names``
+    maps, ``batch`` a dict of arrays and ``metrics["loss"]`` a scalar.
+    ``trainer`` is whatever drives it: its loop leaves at the next batch
+    boundary once ``_preempted`` is set. ``state_fields`` names the fields of
+    ``state.opt_state`` to copy out after the first step."""
 
     def __init__(self, trainer, inner, *, open_at, seconds, trace_dir,
-                 real_per_step, names, counters):
+                 real_per_step, names, counters, state_fields=()):
         self.epoch_steps = len(real_per_step)
         self.trainer, self.inner = trainer, inner
         self.open_at, self.seconds = open_at, seconds
@@ -99,12 +136,13 @@ class StepProbe:
         self.real_per_step = real_per_step
         self.names = {v: k for k, v in names.items()}  # path -> ref name
         self.counters = counters
+        self.state_fields = tuple(state_fields)
         self.steps = 0                  # optimizer steps dispatched so far
         self.check = {"batches": [], "losses": []}
         self.marks = []                 # set-up timeline (name, time)
         self.t_open = self.t_close = None
         self.stamps, self.losses = [], []
-        self.images = 0
+        self.examples = 0
         self.compiles_at_open = self.compiles_at_close = None
         self.spans = []                 # (name, start, end) on perf_counter
         self.tracing = False
@@ -130,6 +168,11 @@ class StepProbe:
         self.check["losses"].append(float(np.asarray(metrics["loss"])))
         if self.steps == 1:
             self.check["params1"] = self._host_params(state.params)
+            if self.state_fields:
+                self.check["state1"] = {
+                    field: self._host_params(tree) for field, tree in
+                    optimizer_fields(state.opt_state,
+                                     self.state_fields).items()}
         if self.steps == CHECK_STEPS:
             self.check["params3"] = self._host_params(state.params)
 
@@ -198,7 +241,7 @@ class StepProbe:
         if in_window:
             self.stamps.append(t0)
             self.losses.append(metrics["loss"])
-            self.images += real
+            self.examples += real
             if self.trace_dir is not None:
                 # the traced slice: bounded, wherever in the epoch it ends
                 due = (t1 - self.t_open >= min(self.seconds, TRACE_SECONDS)
@@ -214,11 +257,58 @@ class StepProbe:
         return state, metrics
 
 
+    # -- what the harness reads of the run --------------------------------
+
+    def record(self, ctx, *, chips, shards, marks) -> dict:
+        """The run record's keys that come from the probe: the window, the
+        check, the set-up timeline (``marks`` are the adapter's own, before
+        the first step) and the device's memory peak. The adapter adds what
+        only it knows (``trainer_init_s``, ``optimizer``, ...)."""
+        import jax
+
+        losses = np.asarray(
+            [np.asarray(x) for x in jax.device_get(self.losses)], np.float64)
+        peak = 0
+        for dev in jax.local_devices()[:chips]:
+            stats = dev.memory_stats() or {}
+            # in_use counts live arrays only; reserved also holds a running
+            # program's temporaries (2.6 GB against 0.39 GB for ResNet-50
+            # b256, my chip run, PR 23), so the high-water mark is the larger
+            peak = max(peak, int(stats.get("peak_bytes_in_use", 0)),
+                       int(stats.get("peak_bytes_reserved", 0)))
+        timeline, last = {}, ctx.t_start
+        for name, t in marks + self.marks + [("window_open", self.t_open)]:
+            timeline[name], last = round(t - last, 3), t
+        ctx.say(f"set-up timeline (s, each since the one before): {timeline}")
+        return {
+            "chips": chips,
+            "shards": shards,
+            "steps_per_call": 1,
+            "setup_s": self.t_open - ctx.t_start,
+            "t_open": self.t_open,
+            "window_s": self.t_close - self.t_open,
+            "examples": self.examples,
+            "dispatches": len(self.stamps),
+            "steps": len(self.stamps),
+            "stamps": self.stamps,
+            "nonfinite_steps": int(
+                np.size(losses) - np.isfinite(losses).sum()),
+            "last_loss": float(losses.reshape(-1)[-1]),
+            "compiles_in_window": (self.compiles_at_close
+                                   - self.compiles_at_open),
+            "memory_peak_bytes": peak,
+            "check": self.check,
+            "host_spans": self.spans,
+            "trace_dir": self.trace_dir,
+        }
+
+
 def run(ctx) -> dict:
     """Drive one cell; returns the run record the harness and the per-layer
     readers read."""
     import jax
 
+    from chipbench.reference import common
     from tpu_ddp.train.trainer import TrainConfig, Trainer
 
     cfg, traffic = ctx.config, ctx.traffic
@@ -226,7 +316,7 @@ def run(ctx) -> dict:
     seed = datagen.fold_seed(ctx.seed)
     shards = int(traffic["mesh"]["data"])
     marks = [("imports", time.perf_counter())]
-    images, labels = datagen.make_dataset(traffic["dataset"], seed)
+    data = ctx.dataset.make(traffic["dataset"], seed)
     marks.append(("dataset", time.perf_counter()))
     ref_params = ctx.reference.init_params(arch, seed)
     marks.append(("weights", time.perf_counter()))
@@ -237,8 +327,9 @@ def run(ctx) -> dict:
         per_shard_batch=int(traffic["per_shard_batch"]),
         steps_per_call=int(traffic.get("steps_per_call", 1)),
         n_devices=int(traffic["chips"]), mesh=dict(traffic["mesh"]),
-        seed=seed, epochs=EPOCHS, num_classes=int(arch["num_classes"]),
-    )
+        seed=seed, epochs=EPOCHS)
+    if "num_classes" in arch:
+        fields["num_classes"] = int(arch["num_classes"])
     trace_dir = None
     if ctx.trace:
         trace_dir = os.path.join(ctx.scratch_dir, "profile")
@@ -247,8 +338,8 @@ def run(ctx) -> dict:
                       telemetry_dir=os.path.join(ctx.scratch_dir, "telemetry"))
     t0 = time.perf_counter()
     trainer = Trainer(
-        TrainConfig(**fields), train_data=(images, labels),
-        test_data=(images[:64], labels[:64]))
+        TrainConfig(**fields), train_data=data,
+        test_data=tuple(a[:HELD_OUT] for a in data))
     names = ctx.reference.program_names(arch)
     install_weights(trainer, ref_params, names)
     del ref_params
@@ -256,8 +347,8 @@ def run(ctx) -> dict:
     marks.append(("trainer_init", time.perf_counter()))
 
     loader = trainer.train_loader
-    real = datagen.real_images_per_step(
-        len(images), shards, int(traffic["per_shard_batch"]))
+    real = datagen.real_examples_per_step(
+        len(data[0]), shards, int(traffic["per_shard_batch"]))
     if len(real) != loader.steps_per_epoch:
         raise RuntimeError(
             f"the loader makes {loader.steps_per_epoch} steps an epoch, the "
@@ -274,7 +365,8 @@ def run(ctx) -> dict:
         open_at=(loader.steps_per_epoch if open_at is None
                  else max(open_at, CHECK_STEPS)),
         trace_dir=trace_dir, real_per_step=real, names=names,
-        counters=ctx.counters)
+        counters=ctx.counters,
+        state_fields=common.task(ctx.reference).OPTIMIZER_STATE)
     trainer.train_step = probe
     if ctx.trace:
         trainer.telemetry.add_span_listener(probe.on_span)
@@ -282,47 +374,15 @@ def run(ctx) -> dict:
     if not probe.done:
         raise RuntimeError("Trainer.run came back before the window closed")
 
-    losses = np.asarray(
-        [np.asarray(x) for x in jax.device_get(probe.losses)], np.float64)
-    peak = 0
-    for dev in jax.local_devices()[: int(traffic["chips"])]:
-        stats = dev.memory_stats() or {}
-        # in_use counts live arrays only; reserved also holds a running
-        # program's temporaries (2.6 GB against 0.39 GB for ResNet-50 b256,
-        # my chip run, PR 23), so the high-water mark is the larger
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)),
-                   int(stats.get("peak_bytes_reserved", 0)))
-    marks += probe.marks + [("window_open", probe.t_open)]
-    timeline, last = {}, ctx.t_start
-    for name, t in marks:
-        timeline[name], last = round(t - last, 3), t
-    ctx.say(f"set-up timeline (s, each since the one before): {timeline}")
-    record = {
-        "chips": int(traffic["chips"]),
-        "shards": shards,
-        "steps_per_call": 1,
+    record = probe.record(ctx, chips=int(traffic["chips"]), shards=shards,
+                          marks=marks)
+    record.update({
         "global_batch": loader.global_batch,
         "trainer_init_s": trainer_init_s,
-        "setup_s": probe.t_open - ctx.t_start,
-        "t_open": probe.t_open,
-        "window_s": probe.t_close - probe.t_open,
-        "images": probe.images,
-        "dispatches": len(probe.stamps),
-        "steps": len(probe.stamps),
-        "stamps": probe.stamps,
-        "nonfinite_steps": int(np.size(losses) - np.isfinite(losses).sum()),
-        "last_loss": float(losses.reshape(-1)[-1]),
-        "compiles_in_window": (probe.compiles_at_close
-                               - probe.compiles_at_open),
-        "memory_peak_bytes": peak,
-        "check": probe.check,
-        "host_spans": probe.spans,
-        "trace_dir": trace_dir,
         "trainer_result": {key: result.get(key) for key in (
             "images_per_sec_per_chip", "mean_step_seconds")},
-        "sgd": {"lr": float(fields.get("lr", 1e-2)),
-                "momentum": float(fields.get("momentum", 0.0))},
-    }
+        "optimizer": common.optimizer_of(fields),
+    })
     # free the program's state before the reference runs
     del trainer, probe, result
     return record

@@ -13,7 +13,7 @@ runs never run this; PERF.md records what it printed.
 
 ``--read control`` leaves the program out: the control is the reference
 against itself, so it needs the cell's sizes (rows, shards, seeded weights and
-images) and one chip, not the cell's chips. ``--read sound`` leaves the
+training set) and one chip, not the cell's chips. ``--read sound`` leaves the
 control out. Together they read a four-chip cell at the least cost: the
 control on one chip, the program alone on four.
 """
@@ -31,33 +31,31 @@ import types
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from chipbench import run as harness  # noqa: E402
-from chipbench.reference.common import ONE_NOTCH_LOWER  # noqa: E402
+from chipbench.reference import common  # noqa: E402
 
 
 def seeded_record(loaded, seed) -> dict:
     """What ``reference_numbers`` reads of a run record, without the program:
     the seeded weights and the first ``CHECK_STEPS`` batches of the seeded
-    training set in shard-major order, at the cell's batch and shards."""
+    training set in shard-major order, at the cell's batch and shards, as the
+    configuration's task lays a batch out (``common.batches`` by default)."""
     import numpy as np
 
     from chipbench import datagen
 
     cfg, traffic = loaded["config"], loaded["traffic"]
     folded = datagen.fold_seed(seed)
-    images, labels = datagen.make_dataset(traffic["dataset"], folded)
+    data = loaded["dataset"].make(traffic["dataset"], folded)
     shards = int(traffic["mesh"]["data"])
-    rows = shards * int(traffic["per_shard_batch"])
-    steps = loaded["adapter"].CHECK_STEPS
-    batches = [{"image": images[i * rows:(i + 1) * rows],
-                "label": labels[i * rows:(i + 1) * rows],
-                "mask": np.ones(rows, bool)} for i in range(steps)]
+    batches = common.task(loaded["reference"]).batches(
+        data, rows=shards * int(traffic["per_shard_batch"]),
+        steps=loaded["adapter"].CHECK_STEPS)
     params = {k: np.asarray(v) for k, v in
               loaded["reference"].init_params(cfg, folded).items()}
-    train = cfg["train_config"]
     return {"check": {"params0": params, "batches": batches},
             "shards": shards,
-            "sgd": {"lr": float(train.get("lr", 1e-2)),
-                    "momentum": float(train.get("momentum", 0.0))}}
+            "optimizer": common.optimizer_of(
+                {**cfg["train_config"], **traffic.get("overlays", {})})}
 
 
 def by_group(numbers, reference) -> dict:
@@ -90,7 +88,7 @@ def main(argv=None, *, roots=None, bench_path=None, device_check=True):
     if device_check:
         harness.check_device(chips, harness.load_json(
             harness.find(roots, "peaks.json")))
-    lower = ONE_NOTCH_LOWER[loaded["config"]["precision"]]
+    lower = common.ONE_NOTCH_LOWER[loaded["config"]["precision"]]
     scratch = os.path.join(harness.REPO, ".chipbench_runs", args.workload)
     os.makedirs(scratch, exist_ok=True)
     rows = []
@@ -99,9 +97,9 @@ def main(argv=None, *, roots=None, bench_path=None, device_check=True):
         ctx = types.SimpleNamespace(
             cell=loaded["cell"], config=loaded["config"],
             traffic=loaded["traffic"], reference=loaded["reference"],
-            seed=seed, seconds=0.0, trace=False, counters=counters,
-            scratch_dir=scratch, t_start=t0, say=harness.say,
-            open_after_steps=0)
+            dataset=loaded["dataset"], seed=seed, seconds=0.0, trace=False,
+            counters=counters, scratch_dir=scratch, t_start=t0,
+            say=harness.say, open_after_steps=0)
         if args.read == "control":
             record = seeded_record(loaded, seed)
         else:
@@ -112,7 +110,8 @@ def main(argv=None, *, roots=None, bench_path=None, device_check=True):
         readers = []
         if args.read != "control":
             row["losses"] = record["check"]["losses"]
-            readers.append(("sound", lambda: harness.program_numbers(record)))
+            readers.append(("sound", lambda: harness.program_numbers(
+                common.task(loaded["reference"]), record)))
         if args.read != "sound":
             readers.append(("control", lambda: harness.reference_numbers(
                 loaded, record, lower)))

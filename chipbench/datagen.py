@@ -1,20 +1,15 @@
-"""Traffic generation: the training set a cell trains on, made from ``--seed``.
+"""Traffic generation: what every training set of a cell shares.
 
-One general generator reads the ``dataset`` block of a traffic file
-(``chipbench/traffic/<mix>.json``). The only kind today is
-``class_gaussians``: CIFAR-10-shaped float32 images drawn as class-conditional
-Gaussians (a class centre per channel plus N(0, 0.3) noise), the distribution
-of the program's ``tpu_ddp.data.cifar10.synthetic_cifar10`` (copied here so the
-yardstick does not move when the program's generator does; the original is
-listed under Open questions in PERF.md).
-
-The same seed gives the same arrays on every platform: everything is drawn on
-the host with numpy's PCG64, in bulk, in float32.
+The training set itself is drawn by the generator the mix's ``dataset.kind``
+names, ``datasets/<kind>.py::make(spec, seed)`` (``spec`` is the traffic
+file's ``dataset`` block, ``seed`` the folded ``--seed``), a file the harness
+finds as it finds every other (``run.py::load_cell``) and hands to the adapter
+as ``ctx.dataset``. It returns a tuple of arrays whose first axis is the
+example, so a training set of another task (token sequences) is a new file.
+Here are the seed's fold and the sampler's arithmetic.
 """
 
 from __future__ import annotations
-
-import numpy as np
 
 #: numpy seeds and the program's ``TrainConfig.seed`` take any whole number;
 #: the fold keeps a driver seed of a little over 2**31 inside 32 bits, which every consumer of the seed takes, before
@@ -28,36 +23,8 @@ def fold_seed(seed: int) -> int:
     return int(seed) % SEED_MODULUS
 
 
-def class_gaussians(size: int, image_size: int, channels: int,
-                    num_classes: int, seed: int):
-    """(images float32 (size, H, W, C), labels int32 (size,))."""
-    rng = np.random.default_rng([fold_seed(seed), 0xC1FA])
-    labels = rng.integers(0, num_classes, size=size).astype(np.int32)
-    centers = rng.standard_normal(
-        (num_classes, 1, 1, channels), dtype=np.float32)
-    images = rng.standard_normal(
-        (size, image_size, image_size, channels), dtype=np.float32)
-    images *= np.float32(0.3)
-    images += centers[labels]
-    return images, labels
-
-
-KINDS = {"class_gaussians": class_gaussians}
-
-
-def make_dataset(spec: dict, seed: int):
-    """``spec`` is a traffic file's ``dataset`` block."""
-    kind = spec["kind"]
-    if kind not in KINDS:
-        raise ValueError(
-            f"unknown dataset kind {kind!r}; known: {sorted(KINDS)}")
-    return KINDS[kind](
-        int(spec["size"]), int(spec["image_size"]),
-        int(spec.get("channels", 3)), int(spec["num_classes"]), seed)
-
-
-def real_images_per_step(size: int, shards: int, per_shard_batch: int):
-    """Unmasked images of each step of one epoch, by the sampler's own
+def real_examples_per_step(size: int, shards: int, per_shard_batch: int):
+    """Unmasked examples of each step of one epoch, by the sampler's own
     arithmetic (DistributedSampler semantics: every shard holds
     ceil(size/shards) rows, wrap-padded duplicates are trained on and count;
     only the short last batch of the epoch is padded and masked)."""

@@ -1,5 +1,7 @@
 """What the plain references share: precisions, primitive layers, the loss,
-SGD, and the three-step reading that ``correct`` compares.
+SGD, the three-step reading that ``correct`` compares, and the defaults of
+what a configuration's reference file may say of its task and its optimizer
+(``task``, at the end).
 
 Plain ``jax.numpy`` in float32 under ``highest`` matmul precision. Imports
 nothing of ``tpu_ddp``. A *precision* is data (a name from ``PRECISIONS``): the
@@ -9,6 +11,8 @@ the same code and not as a second code path.
 """
 
 from __future__ import annotations
+
+import types
 
 import jax
 import jax.numpy as jnp
@@ -164,3 +168,108 @@ def three_steps(forward, params, batches, *, shards, lr, momentum,
             losses.append(loss_sum / shards)
     return {"losses": losses, "params_after_first": after_first,
             "params": params}
+
+
+# -- the task and the optimizer: the reference file's own, or these ----------
+#
+# What ``correct`` and ``step_mfu`` need to know of a task (what a batch
+# holds, what its loss is, which rows are examples, what an example costs) and
+# of an optimizer (how it steps, where its first gradient can be read) they
+# ask of the configuration's reference file. A file that gives none of the
+# names below is an image classifier under SGD, the defaults here.
+
+#: fields of the optimizer's state (optax names, e.g. ``("mu",)``) that the
+#: probe copies out after the first step, as ``check["state1"]``
+OPTIMIZER_STATE = ()
+
+
+def optimizer_of(train_config: dict) -> dict:
+    """Name and hyperparameters of the optimizer a configuration's
+    ``train_config`` (with the mix's overlays) states; what it leaves out is
+    the program's ``TrainConfig`` default."""
+    return {"name": str(train_config.get("optimizer", "sgd")),
+            "lr": float(train_config.get("lr", 1e-2)),
+            "momentum": float(train_config.get("momentum", 0.0)),
+            "weight_decay": float(train_config.get("weight_decay", 0.0))}
+
+
+def default_follow(ref):
+    def follow(arch, check, *, shards, optimizer, precision):
+        """The reference's steps over ``check["batches"]`` as fed: here
+        ``three_steps`` over ``image`` / ``label`` / ``mask`` under SGD."""
+        if optimizer["name"] != "sgd" or optimizer["weight_decay"]:
+            raise ValueError(
+                f"the default follows plain SGD, not {optimizer}: the "
+                "configuration's reference file gives its own follow()")
+        fed = [(b["image"], b["label"], b["mask"]) for b in check["batches"]]
+        return three_steps(
+            lambda p, x, prec: ref.forward(arch, p, x, prec),
+            check["params0"], fed, shards=shards, lr=optimizer["lr"],
+            momentum=optimizer["momentum"], precision=precision)
+    return follow
+
+
+def first_gradient(optimizer, params0, params1, state1=None) -> dict:
+    """The first step's gradient as the optimizer got it, leaf -> float64
+    array; one rule for the program's side and the reference's. SGD's first
+    update is linear in it: ``(p0 - p1) / lr`` (``three_steps`` says why it
+    is read so)."""
+    del state1
+    if optimizer["name"] != "sgd":
+        raise ValueError(
+            f"(p0 - p1) / lr is not the gradient under {optimizer['name']}: "
+            "the configuration's reference file gives its own "
+            "first_gradient() and names the OPTIMIZER_STATE it reads")
+    return {k: (np.asarray(params0[k]).astype("float64")
+                - np.asarray(params1[k], "float64")) / optimizer["lr"]
+            for k in params0}
+
+
+def rows(batch):
+    """The examples of one batch as fed, first axis the example: they must
+    all differ over the checked steps."""
+    return batch["image"]
+
+
+def batches(data, *, rows, steps):
+    """The first ``steps`` batches of ``rows`` examples of a training set as
+    the program's loader would hand them to the step, for a control that is
+    read without the program (``control.py --read control``)."""
+    images, labels = data
+    return [{"image": images[i * rows:(i + 1) * rows],
+             "label": labels[i * rows:(i + 1) * rows],
+             "mask": np.ones(rows, bool)} for i in range(steps)]
+
+
+def default_train_flops_per_example(ref):
+    def train_flops_per_example(arch, traffic) -> float:
+        """Required FLOPs of training on one example, from shapes: here the
+        contractions of the reference's forward jaxpr over one
+        ``(1, image_size, image_size, channels)`` image, times three
+        (``chipbench/flops.py``)."""
+        from chipbench import flops
+
+        del traffic
+        shapes = {k: jax.ShapeDtypeStruct(s, jnp.float32)
+                  for k, (s, _) in ref.param_shapes(arch).items()}
+        side = arch["image_size"]
+        image = jax.ShapeDtypeStruct(
+            (1, side, side, arch.get("channels", 3)), jnp.float32)
+        macs = flops.forward_macs(
+            lambda p, x: ref.forward(arch, p, x), shapes, image)
+        return flops.train_flops_per_image(macs)
+    return train_flops_per_example
+
+
+def task(ref) -> types.SimpleNamespace:
+    """``follow``, ``first_gradient``, ``rows``, ``batches``,
+    ``train_flops_per_example`` and ``OPTIMIZER_STATE`` of the reference
+    module ``ref``: its own where it gives them, else the defaults above."""
+    defaults = {
+        "follow": default_follow(ref), "first_gradient": first_gradient,
+        "rows": rows, "batches": batches,
+        "train_flops_per_example": default_train_flops_per_example(ref),
+        "OPTIMIZER_STATE": OPTIMIZER_STATE}
+    return types.SimpleNamespace(**{
+        name: getattr(ref, name, default)
+        for name, default in defaults.items()})

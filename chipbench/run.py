@@ -8,13 +8,16 @@ found by the names in ``BENCHMARK.json`` (see ``chipbench/README.md``):
     configs/<config>.json      sizes, precision, how it is run (``adapter``)
     traffic/<mix>.json         batch, dataset, chips, mesh, overlays
     limits/<cell>.json         the limits of ``correct`` and what they came from
-    reference/<config>.py      the plain float32 reference
+    reference/<config>.py      the plain float32 reference, and what it says
+                               of its task and its optimizer
+    datasets/<kind>.py         the generator of the mix's ``dataset.kind``
     adapters/<kind>.py         how a configuration is driven
     layer_metrics/<metric>.py  the reader of one per-layer metric
 
 The last line of standard output is the result object; everything else
-(sample counts, medians, cache traffic, each number of ``correct`` beside its
-limit) goes on earlier lines. A run that cannot measure (no TPU, the wrong
+(sample counts, medians, cache traffic) goes on earlier lines; each number
+``correct`` compared stands beside its limit under the result's last key and
+on the last lines of standard error. A run that cannot measure (no TPU, the wrong
 number of chips, a device that is not in ``peaks.json``, a compilation inside
 the window, a share above 105%) exits non-zero and prints no result.
 """
@@ -29,6 +32,7 @@ import argparse
 import gc
 import importlib.util
 import json
+import math
 import os
 import statistics
 import sys
@@ -40,6 +44,9 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 SHARE_CEILING = 105.0  # percent; a share above it is a fault, not a number
+#: the rate's name, kept from the first cells; it counts the examples trained
+#: between the window's fences, whatever a cell's example is
+RATE = "images_per_s_per_chip"
 
 
 class Refused(Exception):
@@ -48,6 +55,12 @@ class Refused(Exception):
 
 def say(*parts):
     print("chipbench:", *parts, flush=True)
+
+
+def say_compared(*parts):
+    """Each number ``correct`` compared, beside its limit: the last lines of
+    standard error (nothing is written there after them)."""
+    print("chipbench:", *parts, file=sys.stderr, flush=True)
 
 
 # -- files by name -----------------------------------------------------------
@@ -91,8 +104,11 @@ def load_cell(bench: dict, workload: str, roots, repo=REPO) -> dict:
         raise Refused(f"{workload}: BENCHMARK.json says {cell['chips']} "
                       f"chips, the mix {traffic['chips']}")
     safe = cell["config"].replace("-", "_").replace(".", "_")
+    kind = traffic["dataset"]["kind"]
     return {
         "cell": cell, "config": config, "traffic": traffic,
+        "dataset": load_module(find(roots, "datasets", kind + ".py"),
+                               f"chipbench_dataset_{kind}"),
         "limits": load_json(find(roots, "limits", workload + ".json")),
         "reference": load_module(
             find(roots, "reference",
@@ -203,48 +219,47 @@ def end_to_end(record: dict) -> dict:
         f"median_ms={statistics.median(intervals)!r} "
         f"max_ms={max(intervals)!r}")
     return {
-        "images_per_s_per_chip":
-            record["images"] / record["window_s"] / record["chips"],
+        RATE: record["examples"] / record["window_s"] / record["chips"],
         "step_ms_p95": percentile(intervals, 95),
         "setup_s": record["setup_s"],
     }
 
 
-def program_numbers(record) -> dict:
+def program_numbers(task, record) -> dict:
     """Losses, first gradient and three-step update of the timed path, as
     leaf norms, from what the probe read in set-up. The gradient is the one
-    the optimizer got, worked out from the state after one step of SGD:
-    ``(p0 - p1) / lr`` (the first step's velocity is the gradient itself)."""
-    check, lr = record["check"], record["sgd"]["lr"]
+    the optimizer got, worked out from its state after one step by the
+    task's rule (``reference/common.py::first_gradient``), the same rule the
+    reference's side is read by."""
+    check = record["check"]
     p0 = check["params0"]
-    grad = {k: (p0[k].astype("float64") - check["params1"][k]) / lr
-            for k in p0}
+    grad = task.first_gradient(record["optimizer"], p0, check["params1"],
+                               check.get("state1"))
     update = {k: check["params3"][k].astype("float64") - p0[k] for k in p0}
     return {"losses": check["losses"], "grad": grad, "update": update}
 
 
 def reference_numbers(loaded, record, precision=None) -> dict:
-    """The same numbers from the plain reference, following the same three
-    steps from the same seeded weights on the same rows, in float32 at
-    ``highest`` (``precision``: the control's, one notch below the
-    configuration's)."""
+    """The same numbers from the plain reference, following the same steps
+    from the same seeded weights on the same batches as they were fed, by
+    the configuration's own loss and optimizer, in float32 at ``highest``
+    (``precision``: the control's, one notch below the configuration's)."""
     import numpy as np
 
     from chipbench.reference import common
 
-    ref, arch = loaded["reference"], loaded["config"]
-    check, sgd = record["check"], record["sgd"]
-    batches = [(b["image"], b["label"], b["mask"]) for b in check["batches"]]
-    followed = common.three_steps(
-        lambda p, x, prec: ref.forward(arch, p, x, prec),
-        check["params0"], batches, shards=record["shards"], lr=sgd["lr"],
-        momentum=sgd["momentum"], precision=precision or "float32_highest")
+    ref, check = loaded["reference"], record["check"]
+    task = common.task(ref)
+    followed = task.follow(
+        loaded["config"], check, shards=record["shards"],
+        optimizer=record["optimizer"],
+        precision=precision or "float32_highest")
     p0 = check["params0"]
     update = {k: np.asarray(followed["params"][k], "float64") - p0[k]
               for k in p0}
-    grad = {k: (p0[k].astype("float64")
-                - np.asarray(followed["params_after_first"][k], "float64"))
-            / sgd["lr"] for k in p0}
+    grad = task.first_gradient(
+        record["optimizer"], p0, followed["params_after_first"],
+        followed.get("state_after_first"))
     return {"losses": followed["losses"], "grad": grad, "update": update,
             "output_leaves": ref.OUTPUT_LEAVES}
 
@@ -255,30 +270,43 @@ def gaps(numbers: dict, ref_numbers: dict) -> dict:
     return compare.readings(numbers, ref_numbers)
 
 
-def rows_all_differ(record) -> bool:
+def repeated_rows(task, record) -> int:
+    """Rows of the checked steps that repeat an earlier one; 0 is sound."""
     rows = [row.tobytes() for b in record["check"]["batches"]
-            for row in b["image"]]
+            for row in task.rows(b)]
     say(f"check: {len(record['check']['batches'])} steps, {len(rows)} rows, "
         f"{len(set(rows))} distinct")
-    return len(set(rows)) == len(rows)
+    return len(rows) - len(set(rows))
 
 
-def decide_correct(loaded, record) -> bool:
+def decide_correct(loaded, record):
     """Follow the timed path's first three steps with the plain reference
     and compare (``chipbench/compare.py``). Runs after the window has closed
-    and the program's state is freed; not part of ``setup_s``."""
+    and the program's state is freed; not part of ``setup_s``. Returns the
+    verdict and each number compared beside its limit."""
     from chipbench import compare
+    from chipbench.reference import common
 
     t0 = time.perf_counter()
-    if not rows_all_differ(record):
-        say("correct: the first steps' rows do not all differ: FAILED")
-        return False
-    program = program_numbers(record)
+    task = common.task(loaded["reference"])
+    repeated = repeated_rows(task, record)
+    compared = {"repeated_rows": {"value": repeated, "limit": 0}}
+    say_compared(f"correct: repeated_rows = {repeated} limit 0 "
+                 f"{'FAILED' if repeated else 'ok'}")
+    if repeated:
+        return False, compared
+    program = program_numbers(task, record)
     reference = reference_numbers(loaded, record)
     say(f"check: losses {program['losses']!r} reference "
         f"{reference['losses']!r} ({time.perf_counter() - t0:.2f} s)")
-    return compare.decide(gaps(program, reference),
-                          loaded["limits"]["limits"], out=say)
+    read = gaps(program, reference)
+    limits = loaded["limits"]["limits"]
+    # json has no inf or nan: a reading that is not finite goes as text
+    compared.update({
+        name: {"value": value if math.isfinite(value) else repr(value),
+               "limit": limits[name]}
+        for name, (value, _) in read.items()})
+    return compare.decide(read, limits, out=say_compared), compared
 
 
 def per_layer(bench, workload, roots, record, reduced) -> dict:
@@ -325,8 +353,8 @@ def run_cell(workload, seed, seconds, trace, *, bench_path=None, roots=None,
     ctx = types.SimpleNamespace(
         cell=loaded["cell"], config=loaded["config"],
         traffic=loaded["traffic"], reference=loaded["reference"],
-        seed=seed, seconds=seconds, trace=trace, counters=counters,
-        scratch_dir=scratch, t_start=T_START, say=say)
+        dataset=loaded["dataset"], seed=seed, seconds=seconds, trace=trace,
+        counters=counters, scratch_dir=scratch, t_start=T_START, say=say)
     record = loaded["adapter"].run(ctx)
     gc.collect()
     record["compile_s"] = counters.compile_seconds
@@ -334,8 +362,8 @@ def run_cell(workload, seed, seconds, trace, *, bench_path=None, roots=None,
         f"compile_s={counters.compile_seconds!r} cache={counters.cache} "
         f"trainer_init_s={record['trainer_init_s']!r}")
     say(f"window: {record['window_s']!r} s, {record['steps']} steps, "
-        f"{record['images']} images, last loss {record['last_loss']!r}, "
-        f"the Trainer's own figures {record['trainer_result']}")
+        f"{record['examples']} examples, last loss {record['last_loss']!r}, "
+        f"the program's own figures {record['trainer_result']}")
     if record["compiles_in_window"]:
         raise Refused(f"{record['compiles_in_window']} compilations inside "
                       "the window: not steady state")
@@ -352,20 +380,27 @@ def run_cell(workload, seed, seconds, trace, *, bench_path=None, roots=None,
             raise Refused(f"the traced slice cannot be reduced: {e}")
         record["peak_flops_per_s"] = peaks.get(device["kind"], {}).get(
             "bf16_flops_per_s")
-        record["train_flops_per_image"] = train_flops_per_image(loaded)
+        record["train_flops_per_example"] = train_flops_per_example(loaded)
         result["metrics"] = per_layer(bench, workload, roots, record, reduced)
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         idle = 100.0 * (1.0 - reduced["busy_s"] / reduced["window_s"])
         say(f"traced slice: {reduced['steps']} steps in "
             f"{reduced['window_s']!r} s, device idle {idle!r}%, the slice's "
-            f"own rate {reduced['images_per_s_per_chip']!r} images/s/chip")
+            f"own rate {reduced['examples_per_s_per_chip']!r} "
+            "examples/s/chip")
         if not -5.0 <= idle <= 100.0:
             raise Refused(f"idle share {idle!r}% is not a share")
         result["breakdown"] = {"device_ops": reduced["device_ops"][:10],
                                "idle_gaps": reduced["idle_gaps"][:10]}
     else:
         values = end_to_end(record)
+        # an example that is a sequence: its mix states what it holds, and
+        # the rate in those units is printed beside the rate in examples
+        for unit, n in loaded["traffic"]["dataset"].get(
+                "example_holds", {}).items():
+            say(f"{unit}_per_s_per_chip={values[RATE] * n!r} "
+                f"({n} {unit} an example)")
         units = {m["name"]: m["unit"] for m in bench["end_to_end"]}
         result["metrics"] = {
             name: {"value": values[name], "unit": units[name]}
@@ -373,27 +408,25 @@ def run_cell(workload, seed, seconds, trace, *, bench_path=None, roots=None,
             if metric_reports_in(
                 next(m for m in bench["end_to_end"] if m["name"] == name),
                 workload, bench)}
-    result["correct"] = bool(decide_correct(loaded, record)
-                             and record["nonfinite_steps"] == 0)
+    ok, compared = decide_correct(loaded, record)
+    result["correct"] = bool(ok and record["nonfinite_steps"] == 0)
+    say_compared(f"correct = {result['correct']} "
+                 f"({record['nonfinite_steps']} steps with a loss not finite)")
     result["device"] = device
+    # each number compared beside its limit: last in the line
+    result["compared"] = compared
     return result
 
 
-def train_flops_per_image(loaded) -> float:
-    import jax
-    import jax.numpy as jnp
+def train_flops_per_example(loaded) -> float:
+    """Required FLOPs of training on one example of this cell, from shapes,
+    by the configuration's own count where its reference file gives one."""
+    from chipbench.reference import common
 
-    from chipbench import flops
-
-    ref, arch = loaded["reference"], loaded["config"]
-    shapes = {k: jax.ShapeDtypeStruct(s, jnp.float32)
-              for k, (s, _) in ref.param_shapes(arch).items()}
-    side = arch["image_size"]
-    image = jax.ShapeDtypeStruct((1, side, side, arch.get("channels", 3)),
-                                 jnp.float32)
-    macs = flops.forward_macs(
-        lambda p, x: ref.forward(arch, p, x), shapes, image)
-    return flops.train_flops_per_image(macs)
+    flops = float(common.task(loaded["reference"]).train_flops_per_example(
+        loaded["config"], loaded["traffic"]))
+    say(f"train_flops_per_example={flops!r}")
+    return flops
 
 
 def main(argv=None, **kwargs) -> int:

@@ -235,8 +235,8 @@ def reduce_run(record: dict, say=print) -> dict:
         dispatches=record["dispatches"],
         steps_per_call=record["steps_per_call"], t_open=record["t_open"],
         host_spans=record["host_spans"])
-    out["images_per_s_per_chip"] = (
-        record["images"] / record["window_s"] / record["chips"])
+    out["examples_per_s_per_chip"] = (
+        record["examples"] / record["window_s"] / record["chips"])
     say(f"trace: {os.path.getsize(path)} bytes, chips={out['chips']} "
         f"programs={out['programs']} steps={out['steps']} "
         f"clock_aligned={out['clock_aligned']} "

@@ -66,7 +66,7 @@ def test_four_shards_agree_with_the_per_shard_reference(tmp_path):
 def test_the_program_one_notch_lower_is_not_correct(tmp_path, capsys):
     result = run_tiny(tmp_path, compute_dtype="bfloat16")
     assert result["correct"] is False
-    assert "grad_gap" in capsys.readouterr().out
+    assert "grad_gap" in capsys.readouterr().err
 
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_the_batch"])
@@ -162,9 +162,11 @@ def test_the_command_refuses_to_measure_without_a_tpu():
 def test_same_seed_same_data_and_the_sampler_arithmetic():
     spec = {"kind": "class_gaussians", "size": 100, "image_size": 32,
             "channels": 3, "num_classes": 10}
-    a, la = datagen.make_dataset(spec, 2**31 + 5)
-    b, lb = datagen.make_dataset(spec, 2**31 + 5)
-    c, _ = datagen.make_dataset(spec, 2**31 + 6)
+    make = harness.load_module(os.path.join(
+        harness.HERE, "datasets", "class_gaussians.py"), "gaussians").make
+    a, la = make(spec, 2**31 + 5)
+    b, lb = make(spec, 2**31 + 5)
+    c, _ = make(spec, 2**31 + 6)
     assert np.array_equal(a, b) and np.array_equal(la, lb)
     assert not np.array_equal(a, c)
     assert a.dtype == np.float32 and a.shape == (100, 32, 32, 3)
@@ -176,7 +178,7 @@ def test_same_seed_same_data_and_the_sampler_arithmetic():
             np.zeros((size, 1), np.float32), np.zeros(size, np.int32),
             world_size=shards, per_shard_batch=batch)
         masks = [int(m.sum()) for _, m in loader.epoch_index_batches(1)]
-        assert datagen.real_images_per_step(size, shards, batch) == masks
+        assert datagen.real_examples_per_step(size, shards, batch) == masks
 
 
 def test_percentile_is_numpys():

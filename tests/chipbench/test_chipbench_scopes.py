@@ -167,8 +167,8 @@ def test_the_tiny_cell_writes_what_the_readers_read(tmp_path, capsys,
     loaded = harness.load_cell(bench, CELL, roots + [harness.HERE])
     ctx = types.SimpleNamespace(
         cell=loaded["cell"], config=loaded["config"],
-        traffic=loaded["traffic"], reference=loaded["reference"], seed=7,
-        seconds=0.3, trace=True, counters=harness.Counters().install(),
+        traffic=loaded["traffic"], reference=loaded["reference"],
+        dataset=loaded["dataset"], seed=7, seconds=0.3, trace=True, counters=harness.Counters().install(),
         scratch_dir=str(tmp_path / "runs"), t_start=time.perf_counter(),
         say=harness.say)
     record = loaded["adapter"].run(ctx)
