@@ -1,0 +1,17 @@
+"""``flash_fwd_roofline``: the share of its roofline that the flash attention
+forward kernel (``tpu_ddp.kernel.flash_fwd``, ``ops/flash_attention.py``)
+reaches over a step's calls, window and full layers together (each on an
+earlier line): the larger of its operations over the chip's bf16 peak and
+its bytes over the memory bandwidth, both from shapes
+(``chipbench/kernel_costs.py``), over the kernel's device time in the
+traced slice. None where the traced program calls no such kernel."""
+
+from chipbench import kernel_costs
+
+NAME, UNIT, SOURCE = "flash_fwd_roofline", "%", "device_trace"
+LAYER = "kernels"
+MOVES = "images_per_s_per_chip"
+
+
+def read(run):
+    return kernel_costs.flash_roofline(run, "flash_fwd")

@@ -72,6 +72,48 @@ def test_flash_compiles_at_real_widths(topo, shape, causal):
     assert _text(bwd, x, x, x).count(CUSTOM_CALL) == 3
 
 
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, 0)],
+                         ids=["sliding_layer", "full_layer"])
+def test_windowed_grouped_flash_compiles_at_the_decoder_widths(topo, heads,
+                                                               window):
+    """A Laguna-XS.2 layer's attention at the benchmark cell's shapes: 8,192
+    positions, heads of 128 over 8 key-value heads, blocks of 512."""
+    from tpu_ddp.ops.flash_attention import flash_attention
+
+    one = _one_chip(topo)
+    q = jax.ShapeDtypeStruct((2, 8192, heads, 128), jnp.bfloat16,
+                             sharding=one)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 128), jnp.bfloat16, sharding=one)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, 512, 512, False, causal=True,
+                               window=window)
+
+    bwd = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                   (0, 1, 2))
+    assert _text(fwd, q, kv, kv).count(CUSTOM_CALL) == 1
+    assert _text(bwd, q, kv, kv).count(CUSTOM_CALL) == 3
+
+
+def test_grouped_expert_products_compile_at_the_decoder_widths(topo):
+    """``models/moe.py::grouped_matmul`` over 32 held experts of width 512
+    on a hidden size of 2,048, a row for each of 16,384 tokens' 8 choices:
+    the compiler's own grouped-product kernel, forward and backward."""
+    from tpu_ddp.models.moe import grouped_matmul
+
+    one = _one_chip(topo)
+    rows = jax.ShapeDtypeStruct((131072, 2048), jnp.bfloat16, sharding=one)
+    weights = jax.ShapeDtypeStruct((32, 2048, 1024), jnp.bfloat16,
+                                   sharding=one)
+    sizes = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one)
+    text = _text(grouped_matmul, rows, weights, sizes)
+    assert "ragged-dot" in text and CUSTOM_CALL in text
+    bwd = jax.grad(
+        lambda x, w, n: grouped_matmul(x, w, n).astype(jnp.float32).sum(),
+        (0, 1))
+    assert _text(bwd, rows, weights, sizes).count("ragged-dot") >= 2
+
+
 # ---- the int8 ring's quantize / dequantize ----------------------------------
 
 def test_fused_quant_compiles_at_real_size(topo):

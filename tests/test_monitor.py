@@ -660,7 +660,10 @@ def test_monitor_port_validation():
 
 def test_watch_on_real_trainer_run_dir(tmp_path, capsys):
     """End to end: a real (single-host) run dir aggregates cleanly —
-    steps/sec present, no stragglers (no quorum), no alerts."""
+    steps/sec present, no stragglers (no quorum). Which other alerts fire
+    is the machine's business: under six test workers the input-wait rule
+    (DWT001) has fired here on a loaded machine, and says nothing of
+    aggregation."""
     from tpu_ddp.train.trainer import Trainer
 
     trainer = Trainer(_short_config(
@@ -679,7 +682,9 @@ def test_watch_on_real_trainer_run_dir(tmp_path, capsys):
     assert hosts[0]["phase_p50_s"].get("compiled_step") is not None
     assert hosts[0]["ended"] is True  # close() wrote the run_end marker
     assert report["snapshot"]["run_id"] == trainer.run_meta["run_id"]
-    assert report["alerts"] == []
+    assert hosts[0]["steps_per_sec"] > 0
+    assert report["snapshot"]["stragglers"] == []
+    assert "STR001" not in {a.get("rule") for a in report["alerts"]}
 
 
 # -- heartbeat read-back helpers ------------------------------------------

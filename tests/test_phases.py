@@ -44,6 +44,17 @@ STEP = "jit(shard_step)/shard_map/"
     (STEP + "tpu_ddp.forward_backward/transpose(jvp(ViT))/block_2/attn/"
      "tpu_ddp.kernel.flash_dq/pallas_call", "custom-call",
      ("backward", "kernel.flash_dq")),
+    # a scope inside the model names the module, of a kernel inside it too
+    (STEP + "tpu_ddp.forward_backward/jvp(SparseDecoder)/layer_2/moe/"
+     "tpu_ddp.module.moe_route/router/dot_general", "fusion",
+     ("forward", "moe_route")),
+    (STEP + "tpu_ddp.forward_backward/transpose(jvp(SparseDecoder))/layer_1/"
+     "checkpoint/rematted_computation/attn/tpu_ddp.module.attention_window/"
+     "tpu_ddp.kernel.flash_dkv/pallas_call", "custom-call",
+     ("backward", "attention_window")),
+    (STEP + "tpu_ddp.forward_backward/jvp(SparseDecoder)/layer_1/moe/"
+     "tpu_ddp.module.moe_experts/tpu_ddp.kernel.grouped_matmul/pallas_call",
+     "custom-call", ("forward", "moe_experts")),
     # the loss sits in the forward scope but under no model
     (STEP + "tpu_ddp.forward_backward/jvp(tpu_ddp.loss)/jit(log_softmax)/"
      "reduce_max", "fusion", ("forward", "loss")),
@@ -150,6 +161,30 @@ def test_a_dp_builder_names_every_phase_it_has(devices, layout, scopes):
                    if r["opcode"].startswith(phases.COLLECTIVE_OPCODES)]
     assert collectives and all(r["phase"] == "grad_sync"
                                for r in collectives)
+
+
+def test_a_kernel_the_compiler_emits_takes_its_users_phase_and_module():
+    """XLA:TPU's grouped product for ``lax.ragged_dot`` is a custom call
+    that keeps the compiler's name for it and none of the program's scopes:
+    it is mapped by where its result goes. A parameter or a reducer's ``eq``
+    with a bare name is no custom call and stays as it was."""
+    moe = ("jit(shard_step)/tpu_ddp.forward_backward/transpose(jvp("
+           "SparseDecoder))/layer_1/moe/tpu_ddp.module.moe_experts/select_n")
+    text = f'''HloModule jit_shard_step, is_scheduled=true
+
+ENTRY %main.9 (p.1: f32[8]) -> f32[8] {{
+  %p.1 = f32[8]{{0}} parameter(0), metadata={{op_name="state.params"}}
+  %compare.4 = f32[8]{{0}} negate(%p.1), metadata={{op_name="eq"}}
+  %ragged-dot-none.1 = f32[8]{{0}} custom-call(%compare.4), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-none"}}
+  ROOT %fusion.2 = f32[8]{{0}} fusion(%ragged-dot-none.1), kind=kLoop, calls=%fused, metadata={{op_name="{moe}"}}
+}}
+'''
+    rows = build_record(text, program="train_step")["instructions"]
+    assert rows["ragged-dot-none.1"] == {
+        "op_name": "ragged-dot-none", "opcode": "custom-call",
+        "phase": "backward", "module": "moe_experts", "inherited": True}
+    assert rows["compare.4"]["phase"] == "other"
+    assert "inherited" not in rows["compare.4"]
 
 
 def test_kernel_calls_carry_their_scope():

@@ -8,6 +8,7 @@ Flag surface = union of the reference's hardcoded constants (``main.py:19,
 from __future__ import annotations
 
 import argparse
+import json
 
 from tpu_ddp.parallel.runtime import (
     enable_compile_cache,
@@ -142,6 +143,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--aux-weight", type=float, default=0.01,
                    help="MoE load-balance loss weight (MoE models only)")
     p.add_argument("--model", default="netresdeep")
+    p.add_argument("--model-overrides", type=json.loads, default=None,
+                   metavar="JSON",
+                   help="keyword arguments of the model's registry factory "
+                        "beyond a classifier's, as a JSON object: one "
+                        "chip's share of a decoder, e.g. laguna_xs2: "
+                        '{"num_layers": 5, "experts_held": 32, '
+                        '"expert_offset": 0, "vocab_rows": 12544}')
     p.add_argument("--attention", choices=["full", "flash"], default="full",
                    help="flash = the Pallas blockwise online-softmax kernel "
                         "(forward AND backward in-kernel), ViT-family "
@@ -444,6 +452,7 @@ def config_from_args(args) -> TrainConfig:
         download=args.download,
         dataset=args.dataset,
         synthetic_data=args.synthetic_data,
+        model_overrides=args.model_overrides,
         epochs=args.epochs,
         per_shard_batch=per_shard,
         lr=args.lr,
@@ -648,7 +657,7 @@ def _run_and_report(args, config, trainer) -> dict:
     # Final test-set eval — the measurement the reference never takes
     # (SURVEY.md §6: no eval loop exists upstream).
     acc, loss = trainer.evaluate()
-    if args.loss == "ce":
+    if args.loss == "ce" and trainer.task.accuracy:
         trainer.logger.log_text(
             f"final test accuracy: {acc:.4f}, test loss: {loss:.4f}"
         )

@@ -75,7 +75,9 @@ def shard_indices(
 
 class ShardedBatchLoader:
     """Yields dict batches {image, label, mask} of fixed global shape
-    (world_size * per_shard_batch, ...).
+    (world_size * per_shard_batch, ...). The two arrays are any two whose
+    first axis is the example; ``keys`` names them in a batch (a decoder's
+    are ``tokens`` and ``loss_mask``, both (N, T)).
 
     ``per_shard_batch`` mirrors the reference's per-process ``batch_size=32``
     (``main.py:61``): global batch = 32 * world_size, scaling with device
@@ -100,6 +102,7 @@ class ShardedBatchLoader:
         telemetry=None,
         observer=None,
         host_augment: Optional[Callable] = None,
+        keys: Tuple[str, str] = ("image", "label"),
     ):
         """exclude_sampler_pad: also mask out the sampler-level wrap-pad
         duplicates (the samples DistributedSampler repeats to even out
@@ -138,6 +141,9 @@ class ShardedBatchLoader:
             f"{world_size} devices not divisible by {process_count} hosts"
         )
         self.images, self.labels = images, labels
+        # what a batch calls the two arrays: the model's task says
+        # (train/tasks.py); the arrays keep their first names here
+        self.keys = tuple(keys)
         self.world_size = world_size
         self.per_shard_batch = per_shard_batch
         self.shuffle = shuffle
@@ -253,7 +259,7 @@ class ShardedBatchLoader:
     def _stage_collate(
         self, images: np.ndarray, labels: np.ndarray, mask: np.ndarray
     ) -> Dict[str, np.ndarray]:
-        return {"image": images, "label": labels, "mask": mask}
+        return {self.keys[0]: images, self.keys[1]: labels, "mask": mask}
 
     def _stage_shard(self, batch: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
         # device-layout prep: contiguous C-order rows for the h2d copy.

@@ -117,8 +117,11 @@ class DataDigestWriter:
         self._f.flush()
 
     def record(self, step: int, batch: Dict[str, np.ndarray]) -> str:
+        # the batch's two arrays in the loader's order, whatever the
+        # task calls them (image/label, tokens/loss_mask), then the row mask
+        first, second = (v for k, v in batch.items() if k != "mask")
         digest, n_real = batch_digest(
-            batch["image"], batch["label"], batch["mask"], seed=self.seed
+            first, second, batch["mask"], seed=self.seed
         )
         self._emit(
             {"type": "digest", "step": int(step), "n_real": n_real, "digest": digest}

@@ -1,0 +1,253 @@
+"""Sparse decoder: pre-norm layers of grouped-query attention (full or
+windowed, a head count and rotary settings per layer, a head-wise output
+gate) and a feed-forward that is a dense SwiGLU or routed experts with a
+shared expert (``models/moe.py::DroplessMoE``); RMSNorm, no biases, untied
+embedding and head. One flax module, ``SparseDecoder``, described by a
+``DecoderSpec``; ``laguna_xs2`` registers poolside's Laguna-XS.2 at its
+published sizes.
+
+    y = x + Attn(RMSNorm(x));  z = y + FFN(RMSNorm(y));  head(RMSNorm(z_last))
+
+Input ``tokens`` (B, T) int32, output float32 logits (B, T, vocabulary rows
+held). The sequence length is the data's. What the model trains on is its
+``task`` (``train/tasks.py``): tokens in, masked next-token loss out.
+
+One chip of an expert-parallel deployment holds a share of each layer:
+``experts_held`` of the routed experts from ``expert_offset`` up
+(``parallel.expert_parallel.ExpertShare``), ``vocab_rows`` of the embedding
+and the head, and the first ``num_layers`` layers. Those are the registry
+factory's overrides (``TrainConfig.model_overrides``); every width stays.
+
+Parameters are float32; ``dtype`` is what activations and matrix-unit
+operands are held in. The router, normalisation statistics and the rotary
+tables are float32 whatever ``dtype``; the head's logits come out float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Callable, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from tpu_ddp.models.moe import DroplessMoE, SwiGLU
+from tpu_ddp.models.zoo import register
+from tpu_ddp.telemetry.phases import module_scope
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """Rotary position settings of one kind of layer: ``dims`` leading
+    dimensions of a head are rotated (half against half), frequencies
+    ``theta ** (-2i / dims)``. ``yarn`` = (factor, original positions,
+    beta_fast, beta_slow, cos/sin scale) blends each frequency between itself
+    and itself over ``factor`` (arXiv:2309.00071), computed once for every
+    length."""
+
+    dims: int
+    theta: float
+    yarn: Optional[Tuple[float, int, float, float, float]] = None
+
+    def tables(self, length: int):
+        """(cos, sin), each (length, dims / 2) float32; float64 on the host,
+        so that position 8,191 is as exact as position 0."""
+        exponents = np.arange(0, self.dims, 2, dtype=np.float64) / self.dims
+        inv_freq = self.theta ** -exponents
+        scale = 1.0
+        if self.yarn is not None:
+            factor, original, beta_fast, beta_slow, scale = self.yarn
+
+            def turns_at(rotations):
+                return self.dims * math.log(
+                    original / (rotations * 2 * math.pi)) / (
+                        2 * math.log(self.theta))
+
+            low = max(math.floor(turns_at(beta_fast)), 0)
+            high = min(math.ceil(turns_at(beta_slow)), self.dims - 1)
+            ramp = np.clip(
+                (np.arange(self.dims // 2) - low) / max(high - low, 1e-3),
+                0.0, 1.0)
+            inv_freq = (inv_freq / factor) * ramp + inv_freq * (1 - ramp)
+        angles = np.arange(length, dtype=np.float64)[:, None] * inv_freq
+        return (jnp.asarray(np.cos(angles) * scale, jnp.float32),
+                jnp.asarray(np.sin(angles) * scale, jnp.float32))
+
+
+def rotate(x, cos, sin):
+    """``x`` (B, T, H, D): its first ``2 * cos.shape[-1]`` dimensions rotated,
+    the rest passed; in float32, back in ``x``'s type."""
+    half = cos.shape[-1]
+    x32 = x.astype(jnp.float32)
+    a, b, rest = x32[..., :half], x32[..., half:2 * half], x32[..., 2 * half:]
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, rest], axis=-1).astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    heads: int           # query heads
+    window: int          # 0 = full causal attention
+    rotary: Rotary
+    sparse: bool         # routed experts + shared expert, else dense SwiGLU
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderSpec:
+    vocab_rows: int
+    hidden: int
+    head_dim: int
+    kv_heads: int
+    layers: Tuple[LayerSpec, ...]
+    dense_width: int
+    num_experts: int     # outputs of the router
+    experts_held: int
+    expert_offset: int
+    top_k: int
+    expert_width: int
+    shared_width: int
+    routed_scaling: float
+    norm_eps: float = 1e-6
+
+
+def reference_attention(q, k, v, *, causal=True, window=0):
+    """Fused jnp attention (``ops/flash_attention._reference``): every score
+    at once, for sequences a CPU test can hold."""
+    from tpu_ddp.ops.flash_attention import _reference
+
+    return _reference(q, k, v, causal=causal, window=window)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal attention of ``spec.heads`` query heads over ``kv_heads``
+    shared key-value heads, rotary positions, an optional window, and a
+    head-wise output gate ``o_h = sigmoid(x w_h) * attn_h`` before the output
+    projection."""
+
+    spec: LayerSpec
+    kv_heads: int
+    head_dim: int
+    dtype: jnp.dtype = jnp.float32
+    attention_impl: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        B, T, C = x.shape
+        H, KV, D = self.spec.heads, self.kv_heads, self.head_dim
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
+        q = rotate(dense(H * D, "q")(x).reshape(B, T, H, D), cos, sin)
+        k = rotate(dense(KV * D, "k")(x).reshape(B, T, KV, D), cos, sin)
+        v = dense(KV * D, "v")(x).reshape(B, T, KV, D)
+        attend = self.attention_impl or reference_attention
+        kind = "attention_window" if self.spec.window else "attention_full"
+        with jax.named_scope(module_scope(kind)):
+            o = attend(q, k, v, causal=True, window=self.spec.window)
+        o = o * nn.sigmoid(dense(H, "gate")(x))[..., None]
+        return dense(C, "o")(o.reshape(B, T, H * D))
+
+
+class DecoderLayer(nn.Module):
+    spec: LayerSpec
+    model: DecoderSpec
+    dtype: jnp.dtype = jnp.float32
+    attention_impl: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        m = self.model
+        norm = lambda name: nn.RMSNorm(  # noqa: E731
+            epsilon=m.norm_eps, dtype=self.dtype, name=name)
+        x = x + GroupedQueryAttention(
+            self.spec, m.kv_heads, m.head_dim, dtype=self.dtype,
+            attention_impl=self.attention_impl, name="attn",
+        )(norm("attn_norm")(x), cos, sin)
+        h = norm("mlp_norm")(x)
+        if not self.spec.sparse:
+            return x + SwiGLU(m.dense_width, dtype=self.dtype, name="mlp")(h)
+        from tpu_ddp.parallel.expert_parallel import ExpertShare
+
+        return x + DroplessMoE(
+            ExpertShare(m.num_experts, m.experts_held, m.expert_offset),
+            top_k=m.top_k, expert_width=m.expert_width,
+            shared_width=m.shared_width, scaling=m.routed_scaling,
+            dtype=self.dtype, name="moe",
+        )(h)
+
+
+class SparseDecoder(nn.Module):
+    spec: DecoderSpec
+    dtype: jnp.dtype = jnp.float32
+    #: ``(q, k, v, *, causal, window) -> o`` on (B, T, H, D) queries and
+    #: (B, T, KV, D) keys and values; None = the fused jnp reference
+    attention_impl: Optional[Callable] = None
+    #: recompute each layer in the backward pass (``resolve_remat``)
+    remat: bool = False
+    #: what the model reads from a batch and which loss it takes
+    task = "next_token"
+    #: (block_q, block_k) of ``--attention flash``: a decoder's sequences
+    #: are long, and a grid step of 128 x 128 is mostly its own overhead
+    flash_blocks = (512, 512)
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False):
+        del train  # no dropout, no batch statistics
+        s = self.spec
+        x = nn.Embed(s.vocab_rows, s.hidden, dtype=self.dtype,
+                     name="embed")(tokens)
+        tables = {}
+        layer_cls = nn.remat(DecoderLayer) if self.remat else DecoderLayer
+        for i, layer in enumerate(s.layers):
+            if layer.rotary not in tables:
+                tables[layer.rotary] = layer.rotary.tables(tokens.shape[1])
+            x = layer_cls(layer, s, dtype=self.dtype,
+                          attention_impl=self.attention_impl,
+                          name=f"layer_{i}")(x, *tables[layer.rotary])
+        x = nn.RMSNorm(epsilon=s.norm_eps, dtype=self.dtype,
+                       name="final_norm")(x)
+        # operands in ``dtype``, logits accumulated and kept in float32
+        return nn.Dense(
+            s.vocab_rows, use_bias=False, dtype=self.dtype, name="head",
+            dot_general=functools.partial(
+                jax.lax.dot_general, preferred_element_type=jnp.float32),
+        )(x).astype(jnp.float32)
+
+
+# -- poolside/Laguna-XS.2 ----------------------------------------------------
+# https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json
+
+_LAGUNA_FULL = Rotary(dims=64, theta=500000.0,
+                      yarn=(64.0, 4096, 64.0, 1.0, 1.4158883083359672))
+_LAGUNA_SLIDING = Rotary(dims=128, theta=10000.0)
+
+
+def laguna_xs2_spec(*, num_layers: int = 40, experts_held: int = 256,
+                    expert_offset: int = 0,
+                    vocab_rows: int = 100352) -> DecoderSpec:
+    """The published model: 40 layers, ``full, sliding, sliding, sliding``
+    ten times (48 query heads in a full layer, 64 in a sliding one, window
+    512), layer 0 dense (width 8,192), the others 256 routed experts of
+    width 512 with 8 a token (times 2.5) and one shared expert of width 512.
+    The arguments are one chip's share; no width changes."""
+    layers = tuple(
+        LayerSpec(heads=48, window=0, rotary=_LAGUNA_FULL, sparse=i > 0)
+        if i % 4 == 0 else
+        LayerSpec(heads=64, window=512, rotary=_LAGUNA_SLIDING, sparse=True)
+        for i in range(num_layers))
+    return DecoderSpec(
+        vocab_rows=vocab_rows, hidden=2048, head_dim=128, kv_heads=8,
+        layers=layers, dense_width=8192, num_experts=256,
+        experts_held=experts_held, expert_offset=expert_offset, top_k=8,
+        expert_width=512, shared_width=512, routed_scaling=2.5)
+
+
+@register("laguna_xs2")
+def laguna_xs2(num_classes: int = 10, bn_cross_replica_axis=None,
+               dtype=jnp.float32, **share):
+    del num_classes, bn_cross_replica_axis  # a classifier's
+    return SparseDecoder(laguna_xs2_spec(**share), dtype=dtype)

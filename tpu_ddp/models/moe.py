@@ -21,6 +21,7 @@ Expert-parallel layout rules live in ``tpu_ddp.parallel.expert_parallel``.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import flax.linen as nn
@@ -256,3 +257,163 @@ def vit_moe_s4_top2(num_classes: int = 10, bn_cross_replica_axis=None,
     return MoEViT(patch_size=4, hidden_dim=192, depth=6, num_heads=3,
                   num_classes=num_classes, num_experts=8, top_k=2,
                   dtype=dtype)
+
+
+# -- routing without drops ---------------------------------------------------
+#
+# The layer of a sparse decoder (``models/decoder.py``): a router over all
+# ``num_experts``, the grouped products over the experts held here, and a
+# shared expert. Nothing has a capacity: every (token, choice) that lands on
+# a held expert is computed, whatever the imbalance. Beside ``MoEMlp`` (dense
+# one-hot dispatch, capacity, drops), which the ViT family keeps.
+
+def _spread_rows(x, order, k):
+    return jnp.take(x, order // k, axis=0)
+
+
+def _collect_rows(y, inverse, k):
+    rows = jnp.take(y, inverse, axis=0).astype(jnp.float32)
+    return rows.reshape(-1, k, y.shape[-1]).sum(axis=1).astype(y.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _spread(x, order, inverse, k):
+    """Row ``a`` of the result is ``x[order[a] // k]``: each of ``x``'s rows
+    repeated ``k`` times (one per choice), in the order ``order`` gives. The
+    transpose of ``_collect``; both ways are gathers, never a scatter."""
+    return _spread_rows(x, order, k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _collect(y, order, inverse, k):
+    """Rows of ``y`` put back where ``order`` took them from and summed over
+    each token's ``k`` choices, in float32: ``(R, C) -> (R // k, C)``."""
+    return _collect_rows(y, inverse, k)
+
+
+_spread.defvjp(
+    lambda x, order, inverse, k: (_spread_rows(x, order, k),
+                                  (order, inverse)),
+    lambda k, res, g: (_collect_rows(g, res[1], k), None, None))
+_collect.defvjp(
+    lambda y, order, inverse, k: (_collect_rows(y, inverse, k),
+                                  (order, inverse)),
+    lambda k, res, g: (_spread_rows(g, res[0], k), None, None))
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs`` (R, K) rows in groups of ``group_sizes`` (G,) against ``rhs``
+    (G, K, N): row ``r`` of group ``g`` times ``rhs[g]``. Rows past the
+    groups' sum belong to no group: what comes out for them is unspecified
+    and never read. ``jax.lax.ragged_dot``: on a TPU the compiler emits its
+    own grouped-product kernel for it (a Mosaic custom call that walks only
+    the row tiles of real groups, forward and both backward products), named
+    here like every kernel call (``tpu_ddp.kernel.grouped_matmul``)."""
+    from tpu_ddp.telemetry.phases import kernel_scope
+
+    with jax.named_scope(kernel_scope("grouped_matmul")):
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate(x)) * up(x))``, no biases, float32 weights."""
+
+    width: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
+        h = nn.silu(dense(self.width, "gate")(x)) * dense(self.width, "up")(x)
+        return dense(x.shape[-1], "down")(h)
+
+
+class DroplessMoE(nn.Module):
+    """Top-k routed SwiGLU experts without a capacity, the share of them
+    that lives here, and a shared expert.
+
+    ``share`` (``parallel.expert_parallel.ExpertShare``: ``num_experts``,
+    ``held``, ``offset``) says which experts this layer holds. The router
+    scores all ``num_experts`` in float32 (sigmoid, the ``top_k`` largest,
+    normalised to sum to one, times ``scaling``); the (token, choice) pairs
+    that name a held expert are sorted by expert, pushed through the grouped
+    products, weighted and summed back onto their tokens; the shared expert
+    (``shared_width`` > 0) is added for every token. With a share smaller
+    than the whole the result is partial: what the other shares' experts
+    would add is theirs to add. ``sum over shares of (y - shared) + shared``
+    is the whole layer.
+
+    Static shapes without drops: the sorted buffer has a row for every
+    (token, choice), the held ones first; the grouped products compute only
+    the rows of held experts, however many those are.
+
+    Sows ``counters/expert_load``: (held,) int32, the (token, choice) pairs
+    each held expert got this call. Stacked expert weights: ``w_gate``,
+    ``w_up`` (held, C, F), ``w_down`` (held, F, C), so that expert
+    parallelism is a ``PartitionSpec`` on the leading axis.
+    """
+
+    share: object
+    top_k: int
+    expert_width: int
+    shared_width: int = 0
+    scaling: float = 1.0
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):  # (B, T, C) -> (B, T, C)
+        from tpu_ddp.telemetry.phases import module_scope
+
+        B, T, C = x.shape
+        E, held, offset = (self.share.num_experts, self.share.held,
+                           self.share.offset)
+        K, F = self.top_k, self.expert_width
+        xf = x.reshape(B * T, C).astype(self.dtype)
+        stacked = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
+        w_gate = self.param("w_gate", stacked, (held, C, F), jnp.float32)
+        w_up = self.param("w_up", stacked, (held, C, F), jnp.float32)
+        w_down = self.param("w_down", stacked, (held, F, C), jnp.float32)
+
+        with jax.named_scope(module_scope("moe_route")):
+            logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST,
+                              name="router")(xf.astype(jnp.float32))
+            scores, ids = jax.lax.top_k(jax.nn.sigmoid(logits), K)  # (N, K)
+            weights = (scores / scores.sum(axis=-1, keepdims=True)
+                       * self.scaling)
+            self.sow("intermediates", "expert_ids", ids)
+
+        with jax.named_scope(module_scope("moe_dispatch")):
+            local = ids.reshape(-1) - offset                    # (N*K,)
+            group = jnp.where((local >= 0) & (local < held), local, held)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            inverse = jnp.argsort(order).astype(jnp.int32)
+            load = jnp.sum(group[:, None] == jnp.arange(held)[None, :],
+                           axis=0, dtype=jnp.int32)             # (held,)
+            self.sow("counters", "expert_load", load)
+            # the sorted buffer's first ``landed`` rows are real. What a
+            # grouped product leaves in the others is unspecified (on the
+            # chip: whatever the memory held, NaN included), so each of its
+            # results is selected to zero there before anything reads it,
+            # forward and (the select's transpose) backward
+            real = (jnp.arange(order.shape[0]) < load.sum())[:, None]
+            keep = lambda a: jnp.where(real, a, 0)  # noqa: E731
+            rows = keep(_spread(xf, order, inverse, K))
+
+        with jax.named_scope(module_scope("moe_experts")):
+            w_in = jnp.concatenate([w_gate, w_up], axis=-1).astype(self.dtype)
+            h = keep(grouped_matmul(rows, w_in, load))
+            h = nn.silu(h[:, :F]) * h[:, F:]
+            out = keep(grouped_matmul(h, w_down.astype(self.dtype), load))
+
+        with jax.named_scope(module_scope("moe_combine")):
+            w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
+            y = _collect(out * w_sorted.astype(out.dtype), order, inverse, K)
+
+        if self.shared_width:
+            with jax.named_scope(module_scope("moe_shared")):
+                y = y + SwiGLU(self.shared_width, dtype=self.dtype,
+                               name="shared")(xf)
+        return y.reshape(B, T, C)

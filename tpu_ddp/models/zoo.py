@@ -7,6 +7,11 @@ from __future__ import annotations
 
 MODEL_REGISTRY: dict = {}
 
+#: models whose module is imported when the model is first asked for
+#: (``train/trainer.py::build_model``), not with the package: a run that
+#: trains another model never pays for their imports
+LAZY_MODELS = {"laguna_xs2": "tpu_ddp.models.decoder"}
+
 
 def register(name: str):
     def deco(factory):

@@ -30,6 +30,18 @@ work at large T) and masks diagonal-straddling tiles in-register;
 key output exactly 0 with zero gradients (the ``NEG`` finite -inf + safe
 l/lse discipline below). Both compose, both differentiate through the
 Pallas backward kernels.
+
+The decoder regime proper (a window, and key-value heads shared by groups
+of query heads): ``window=W`` lets row ``i`` see columns ``i - W < j <= i``
+(it implies ``causal``), and ``k``/``v`` may carry fewer heads than ``q``
+(``H % KV == 0``; query heads ``g*H/KV .. (g+1)*H/KV - 1`` read key-value
+head ``g``). The grid's inner dimension is the *band*: for a q block only
+the kv blocks it can see are visited (``_Band``), in the forward and in
+both backward kernels, so a tile wholly outside the band costs neither a
+product nor a copy, and under ``causal`` the blocks above the diagonal are
+no longer streamed. The dK/dV kernel sums over the query heads of its
+group in its inner dimension. Tiles that no mask edge crosses skip the
+mask arithmetic.
 """
 
 from __future__ import annotations
@@ -58,32 +70,43 @@ _WHOLE_AXIS_MAX = 512
 NEG = -1e30
 
 
-def _bhqk_visibility(Tq: int, Tk: int, causal: bool, kv_mask):
+def _bhqk_visibility(Tq: int, Tk: int, causal: bool, kv_mask,
+                     window: int = 0):
     """(…, Tq, Tk)-broadcastable bool visibility for full-tile jnp paths
     ((B,H,Tq,Tk) score layouts), or None when everything is visible. The
     ONE implementation shared by _reference and the ring's jnp tile/bwd
     fallbacks — these must stay numerically identical to each other (and
     to the kernels' per-tile _tile_visibility)."""
     vis = None
-    if causal:
+    if causal or window:
         rows = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 1)
-        vis = (cols <= rows)[None, None]
+        vis = cols <= rows
+        if window:
+            vis = jnp.logical_and(vis, cols > rows - window)
+        vis = vis[None, None]
     if kv_mask is not None:
         km = (kv_mask > 0)[:, None, None, :]
         vis = km if vis is None else jnp.logical_and(vis, km)
     return vis
 
 
-def _reference(q, k, v, causal: bool = False, kv_mask=None):
+def _reference(q, k, v, causal: bool = False, kv_mask=None,
+               window: int = 0):
     """Fused jnp attention, the numerics ground truth for the kernels.
-    ``causal`` masks col > row (self-aligned square tiles); ``kv_mask``
-    (B, Tk), nonzero = attend, masks key/value columns. Rows with no
-    visible key (possible under kv_mask) output exactly 0 — the
-    multiplicative-mask convention the kernels implement."""
+    ``causal`` masks col > row (self-aligned square tiles); ``window``
+    also masks col <= row - window; ``kv_mask`` (B, Tk), nonzero = attend,
+    masks key/value columns. ``k``/``v`` with fewer heads than ``q`` are
+    shared by groups of query heads. Rows with no visible key (possible
+    under kv_mask) output exactly 0 — the multiplicative-mask convention
+    the kernels implement."""
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    vis = _bhqk_visibility(s.shape[-2], s.shape[-1], causal, kv_mask)
+    vis = _bhqk_visibility(s.shape[-2], s.shape[-1], causal, kv_mask,
+                           window)
     if vis is not None:
         s = jnp.where(vis, s, NEG)
     p = jax.nn.softmax(s, axis=-1)
@@ -95,9 +118,9 @@ def _reference(q, k, v, causal: bool = False, kv_mask=None):
 
 
 def _tile_visibility(s_shape, q_blk: int, kv_blk: int, causal: bool,
-                     mask_row):
+                     mask_row, window: int = 0):
     """(bq, bk) bool visibility for one tile, or None when everything is
-    visible. ``q_blk``/``kv_blk`` are the grid indices of the tile;
+    visible. ``q_blk``/``kv_blk`` are the block indices of the tile;
     ``mask_row`` is the (1, bk) f32 kv-mask slab or None."""
     bq, bk = s_shape
     vis = None
@@ -105,47 +128,133 @@ def _tile_visibility(s_shape, q_blk: int, kv_blk: int, causal: bool,
         rows = q_blk * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cols = kv_blk * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         vis = cols <= rows
+        if window:
+            vis = jnp.logical_and(vis, cols > rows - window)
     if mask_row is not None:
         mvis = mask_row > 0.0  # (1, bk) broadcasts over rows
         vis = mvis if vis is None else jnp.logical_and(vis, mvis)
     return vis
 
 
-def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, n_k: int, bq: int,
-            bk: int, causal: bool, has_mask: bool):
-    """One (q-block, kv-block) tile. The kv-block index is the innermost
-    grid dim, so for a fixed q block the kernel runs n_k times back-to-back
-    with VMEM scratch (acc/m/l) carrying the online-softmax state — only one
-    (bq, d) + (bk, d) tile pair is resident per step; K/V stream from HBM
-    block-by-block via the BlockSpec pipeline. The final tile also writes
-    the row logsumexp (lane-broadcast) — the backward's residual.
+def _least(a, b):
+    """min of two block indices, Python ints or traced."""
+    if isinstance(a, int) and isinstance(b, int):
+        return min(a, b)
+    return jnp.minimum(a, b)
 
-    ``causal`` skips tiles entirely above the diagonal via pl.when (the
-    matmuls are predicated out; the BlockSpec copies still stream) and
-    masks the diagonal-straddling tiles in-register. ``has_mask`` threads a
-    (1, bk) kv-mask slab applied multiplicatively to p, so fully-masked
-    rows accumulate exact zeros (l == 0, handled at finalize)."""
+
+class _Band(NamedTuple):
+    """Which tiles hold a visible (row, column) pair: the kv blocks a q
+    block visits and, the other way round, the q blocks a kv block is seen
+    by. The bounds are plain arithmetic on a block index, so they serve the
+    kernels' program ids, the BlockSpec index maps and, on Python ints, the
+    static width of the grid's inner dimension."""
+
+    causal: bool
+    window: int  # 0 = none
+    bq: int
+    bk: int
+    n_q: int
+    n_k: int
+
+    def kv_lo(self, j):
+        if not self.window:
+            return 0 * j
+        return -_least(self.window - 1 - j * self.bq, 0) // self.bk
+
+    def kv_hi(self, j):
+        if not self.causal:
+            return 0 * j + self.n_k - 1
+        return _least((j * self.bq + self.bq - 1) // self.bk, self.n_k - 1)
+
+    def q_lo(self, jk):
+        if not self.causal:
+            return 0 * jk
+        return _least(jk * self.bk // self.bq, self.n_q - 1)
+
+    def q_hi(self, jk):
+        if not self.window:
+            return 0 * jk + self.n_q - 1
+        return _least(
+            (jk * self.bk + self.bk + self.window - 2) // self.bq,
+            self.n_q - 1)
+
+    @property
+    def kv_width(self) -> int:
+        """kv blocks the widest q block visits."""
+        return max(self.kv_hi(j) - self.kv_lo(j) + 1
+                   for j in range(self.n_q))
+
+    @property
+    def q_width(self) -> int:
+        return max(self.q_hi(j) - self.q_lo(j) + 1
+                   for j in range(self.n_k))
+
+    def edge_crosses(self, q_blk, kv_blk):
+        """Does a mask edge (the diagonal, the window's far side) cross this
+        tile? One it does not cross is, inside the band, wholly visible."""
+        crosses = kv_blk * self.bk + self.bk - 1 > q_blk * self.bq
+        if self.window:
+            crosses = jnp.logical_or(
+                crosses,
+                kv_blk * self.bk <= q_blk * self.bq + self.bq - 1
+                - self.window)
+        return crosses
+
+
+def _band_dispatch(band: _Band, has_mask: bool, q_blk, kv_blk, visible,
+                   compute):
+    """Run ``compute(masked)`` for a tile inside the band: with the mask
+    arithmetic where an edge crosses the tile (or a kv mask is given), and
+    without it elsewhere."""
+    if has_mask or not band.causal:
+        pl.when(visible)(functools.partial(compute, has_mask or band.causal))
+        return
+    crosses = band.edge_crosses(q_blk, kv_blk)
+    pl.when(jnp.logical_and(visible, crosses))(
+        functools.partial(compute, True))
+    pl.when(jnp.logical_and(visible, jnp.logical_not(crosses)))(
+        functools.partial(compute, False))
+
+
+def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, band: _Band,
+            width: int, has_mask: bool):
+    """One (q-block, kv-block) tile. The position in the band is the
+    innermost grid dim, so for a fixed q block the kernel runs ``width``
+    times back-to-back with VMEM scratch (acc/m/l) carrying the
+    online-softmax state — only one (bq, d) + (bk, d) tile pair is resident
+    per step; K/V stream from HBM block-by-block via the BlockSpec pipeline.
+    The final step also writes the row logsumexp (lane-broadcast) — the
+    backward's residual.
+
+    Step ``t`` of q block ``j`` is kv block ``kv_lo(j) + t``; steps past
+    ``kv_hi(j)`` (a q block whose band is narrower than the widest) do
+    nothing, and their index map repeats the last block, so nothing is
+    copied for them either. ``has_mask`` threads a (1, bk) kv-mask slab
+    applied multiplicatively to p, so fully-masked rows accumulate exact
+    zeros (l == 0, handled at finalize)."""
     if has_mask:
         mask_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
         mask_ref = None
     j = pl.program_id(1)
-    ki = pl.program_id(2)
+    t = pl.program_id(2)
+    kb = band.kv_lo(j) + t
 
-    @pl.when(ki == 0)
+    @pl.when(t == 0)
     def _init():
         m_ref[:] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
         l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def _compute():
+    def _compute(masked):
         q = q_ref[0]  # (bq, d)
         s = jnp.dot(q, k_ref[0].T, preferred_element_type=jnp.float32) * scale
         vis = _tile_visibility(
-            s.shape, j, ki, causal,
-            mask_ref[0, 0:1, :] if has_mask else None,
-        )
+            s.shape, j, kb, band.causal,
+            mask_ref[0, 0:1, :] if has_mask else None, band.window,
+        ) if masked else None
         if vis is not None:
             s = jnp.where(vis, s, NEG)
         m_prev = m_ref[:, 0:1]  # (bq, 1)
@@ -154,24 +263,23 @@ def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, n_k: int, bq: int,
         p = jnp.exp(s - m_new)
         if has_mask:
             # all-masked-so-far rows have m_new == NEG and p == exp(0) == 1
-            # on masked entries; the multiplicative mask restores exact 0
+            # on masked entries; the multiplicative mask restores exact 0.
+            # (Under a window a row's first tile may hold none of its
+            # columns either, but its diagonal tile always follows, and
+            # alpha == exp(NEG - m) == 0 there wipes what this one left.)
             p = p * vis
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-            p, v_ref[0], preferred_element_type=jnp.float32
+            p.astype(v_ref.dtype), v_ref[0],
+            preferred_element_type=jnp.float32
         )
         m_ref[:, 0:1] = m_new
         l_ref[:, 0:1] = l_new
 
-    if causal:
-        # tiles entirely above the diagonal contribute nothing: skip the
-        # matmuls (roughly half the MXU work at large T)
-        pl.when(ki * bk < (j + 1) * bq)(_compute)
-    else:
-        _compute()
+    _band_dispatch(band, has_mask, j, kb, kb <= band.kv_hi(j), _compute)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(t == width - 1)
     def _finalize():
         l = l_ref[:, 0:1]
         if has_mask:
@@ -273,38 +381,55 @@ def _interpreted_under_shard_map(x, interpret: bool) -> bool:
     return interpret and bool(jax.typeof(x).vma)
 
 
+def _check_heads(q, k, v):
+    H, KV = q.shape[2], k.shape[2]
+    if k.shape != v.shape or H % KV or (
+            q.shape[:2] + q.shape[3:] != k.shape[:2] + k.shape[3:]):
+        raise ValueError(
+            f"flash attention: q {q.shape} against k {k.shape}, v {v.shape}: "
+            "key-value heads must divide the query heads, the rest agree")
+    return H // KV
+
+
 def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
-                   interpret: bool, causal: bool = False):
+                   interpret: bool, causal: bool = False, window: int = 0):
     """Returns (out, lse) — lse is None on the interpreted-under-shard_map
     jnp detour."""
     B, T, H, D = q.shape
+    group = _check_heads(q, k, v)
     scale = 1.0 / np.sqrt(D)
     if _interpreted_under_shard_map(q, interpret):
-        return _reference(q, k, v, causal=causal, kv_mask=kv_mask), None
+        return _reference(q, k, v, causal=causal, kv_mask=kv_mask,
+                          window=window), None
     bq, bk, d_pad, _ = _unpadded_plan(
         q.shape, block_q, block_k, kv_mask is not None)
     qf, kf, vf = _fold(q, d_pad), _fold(k, d_pad), _fold(v, d_pad)
-    n_k = T // bk
-    grid = (B * H, T // bq, n_k)  # kv-block innermost: sequential carry
+    band = _Band(causal or bool(window), window, bq, bk, T // bq, T // bk)
+    width = band.kv_width
+    grid = (B * H, band.n_q, width)  # the band innermost: sequential carry
     has_mask = kv_mask is not None
+
+    def kv_block(j, t):
+        return _least(band.kv_lo(j) + t, band.kv_hi(j))
+
+    kv_spec = pl.BlockSpec(
+        (1, bk, d_pad), lambda i, j, t: (i // group, kv_block(j, t), 0),
+        memory_space=pltpu.VMEM)
     in_specs = [
-        pl.BlockSpec((1, bq, d_pad), lambda i, j, kk: (i, j, 0),
+        pl.BlockSpec((1, bq, d_pad), lambda i, j, t: (i, j, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, d_pad), lambda i, j, kk: (i, kk, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, d_pad), lambda i, j, kk: (i, kk, 0),
-                     memory_space=pltpu.VMEM),
+        kv_spec, kv_spec,
     ]
     args = [qf, kf, vf]
     if has_mask:
-        in_specs.append(pl.BlockSpec((1, _SUBLANES, bk),
-                                     lambda i, j, kk: (i, 0, kk),
-                                     memory_space=pltpu.VMEM))
+        in_specs.append(pl.BlockSpec(
+            (1, _SUBLANES, bk), lambda i, j, t: (i, 0, kv_block(j, t)),
+            memory_space=pltpu.VMEM))
         args.append(_fold_mask(kv_mask, H))
     with jax.named_scope(kernel_scope("flash_fwd")):
         out, lse = pl.pallas_call(
-            functools.partial(_kernel, scale=scale, n_k=n_k, bq=bq, bk=bk,
-                              causal=causal, has_mask=has_mask),
+            functools.partial(_kernel, scale=scale, band=band, width=width,
+                              has_mask=has_mask),
             out_shape=[
                 _sds((B * H, T, d_pad), q.dtype, qf),
                 _sds((B * H, T, LANE), jnp.float32, qf),
@@ -312,9 +437,9 @@ def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, bq, d_pad), lambda i, j, kk: (i, j, 0),
+                pl.BlockSpec((1, bq, d_pad), lambda i, j, t: (i, j, 0),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, bq, LANE), lambda i, j, kk: (i, j, 0),
+                pl.BlockSpec((1, bq, LANE), lambda i, j, t: (i, j, 0),
                              memory_space=pltpu.VMEM),
             ],
             scratch_shapes=[
@@ -322,19 +447,23 @@ def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
                 pltpu.VMEM((bq, LANE), jnp.float32),   # running max
                 pltpu.VMEM((bq, LANE), jnp.float32),   # running denom
             ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(*args)
     return _unfold(out, q.shape), lse
 
 
-def _tile_p(q, kb, lse_col, q_blk, kv_blk, scale, causal, mask_row):
+def _tile_p(q, kb, lse_col, q_blk, kv_blk, scale, band: _Band, mask_row,
+            masked: bool):
     """Recompute one tile's probabilities p = exp(s - lse) under the same
     visibility the forward applied — shared by both backward kernels.
-    Masked entries are exact zeros: causal-only masking underflows
+    Masked entries are exact zeros: causal and window masking underflow
     (lse is finite), kv-masked rows with lse == NEG are restored to 0 by
-    the multiplicative mask. Returns (p, s-visibility applied)."""
+    the multiplicative mask."""
     s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
-    vis = _tile_visibility(s.shape, q_blk, kv_blk, causal, mask_row)
+    vis = _tile_visibility(s.shape, q_blk, kv_blk, band.causal, mask_row,
+                           band.window) if masked else None
     if vis is not None:
         s = jnp.where(vis, s, NEG)
     p = jnp.exp(s - lse_col)
@@ -344,88 +473,89 @@ def _tile_p(q, kb, lse_col, q_blk, kv_blk, scale, causal, mask_row):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *rest,
-               scale: float, n_k: int, bq: int, bk: int, causal: bool,
-               has_mask: bool):
-    """dQ: for a fixed q block, stream kv blocks (innermost grid dim) and
-    accumulate ds @ k in VMEM scratch; p is recomputed from the saved row
-    logsumexp, never materialized beyond one (bq, bk) tile. Causal skips
-    above-diagonal tiles like the forward."""
+               scale: float, band: _Band, width: int, has_mask: bool):
+    """dQ: for a fixed q block, stream the kv blocks of its band (innermost
+    grid dim) and accumulate ds @ k in VMEM scratch; p is recomputed from
+    the saved row logsumexp, never materialized beyond one (bq, bk) tile."""
     if has_mask:
         mask_ref, dq_ref, dq_acc = rest
     else:
         dq_ref, dq_acc = rest
         mask_ref = None
     j = pl.program_id(1)
-    ki = pl.program_id(2)
+    t = pl.program_id(2)
+    kb = band.kv_lo(j) + t
 
-    @pl.when(ki == 0)
+    @pl.when(t == 0)
     def _init():
         dq_acc[:] = jnp.zeros(dq_acc.shape, jnp.float32)
 
-    def _compute():
+    def _compute(masked):
         q = q_ref[0]
-        kb = k_ref[0]
-        p = _tile_p(q, kb, lse_ref[0][:, 0:1], j, ki, scale, causal,
-                    mask_ref[0, 0:1, :] if has_mask else None)
+        k = k_ref[0]
+        p = _tile_p(q, k, lse_ref[0][:, 0:1], j, kb, scale, band,
+                    mask_ref[0, 0:1, :] if has_mask else None, masked)
         dp = jnp.dot(do_ref[0], v_ref[0].T,
                      preferred_element_type=jnp.float32)  # (bq, bk)
         ds = p * (dp - di_ref[0][:, 0:1]) * scale
-        dq_acc[:] += jnp.dot(ds, kb, preferred_element_type=jnp.float32)
+        dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(ki * bk < (j + 1) * bq)(_compute)
-    else:
-        _compute()
+    _band_dispatch(band, has_mask, j, kb, kb <= band.kv_hi(j), _compute)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(t == width - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *rest,
-                scale: float, n_q: int, bq: int, bk: int, causal: bool,
+                scale: float, band: _Band, width: int, group: int,
                 has_mask: bool):
-    """dK/dV: for a fixed kv block, stream q blocks (innermost grid dim),
-    accumulating p^T @ do and ds^T @ q in VMEM scratch. Causal skips tiles
-    whose q rows all precede this kv block."""
+    """dK/dV: for a fixed kv block of one key-value head, stream the q
+    blocks that see it, for each query head of its group in turn (innermost
+    grid dim: step ``t`` is head ``t // width`` of the group and q block
+    ``q_lo + t % width``), accumulating p^T @ do and ds^T @ q in VMEM
+    scratch."""
     if has_mask:
         mask_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = rest
         mask_ref = None
-    j = pl.program_id(1)   # kv-block index
-    qi = pl.program_id(2)  # q-block index (innermost)
+    jk = pl.program_id(1)   # kv-block index
+    t = pl.program_id(2)
+    qb = band.q_lo(jk) + t % width
 
-    @pl.when(qi == 0)
+    @pl.when(t == 0)
     def _init():
         dk_acc[:] = jnp.zeros(dk_acc.shape, jnp.float32)
         dv_acc[:] = jnp.zeros(dv_acc.shape, jnp.float32)
 
-    def _compute():
+    def _compute(masked):
         q = q_ref[0]
-        kb = k_ref[0]
         do = do_ref[0]
-        p = _tile_p(q, kb, lse_ref[0][:, 0:1], qi, j, scale, causal,
-                    mask_ref[0, 0:1, :] if has_mask else None)
-        dv_acc[:] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
+        p = _tile_p(q, k_ref[0], lse_ref[0][:, 0:1], qb, jk, scale, band,
+                    mask_ref[0, 0:1, :] if has_mask else None, masked)
+        dv_acc[:] += jnp.dot(p.astype(do.dtype).T, do,
+                             preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v_ref[0].T, preferred_element_type=jnp.float32)
         ds = p * (dp - di_ref[0][:, 0:1]) * scale
-        dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dk_acc[:] += jnp.dot(ds.astype(q.dtype).T, q,
+                             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(j * bk < (qi + 1) * bq)(_compute)
-    else:
-        _compute()
+    _band_dispatch(band, has_mask, qb, jk, qb <= band.q_hi(jk), _compute)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(t == group * width - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
-                    block_k: int, interpret: bool, causal: bool = False):
+                    block_k: int, interpret: bool, causal: bool = False,
+                    window: int = 0):
     B, T, H, D = q.shape
+    group = _check_heads(q, k, v)
+    KV = H // group
     scale = 1.0 / np.sqrt(D)
     bq, bk, d_pad, _ = _unpadded_plan(
         q.shape, block_q, block_k, kv_mask is not None)
@@ -439,67 +569,82 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
                 axis=-1, keepdims=True),
         (B * H, T, LANE),
     )
-    n_q, n_k = T // bq, T // bk
+    band = _Band(causal or bool(window), window, bq, bk, T // bq, T // bk)
     has_mask = kv_mask is not None
-    mask_f = _fold_mask(kv_mask, H) if has_mask else None
-    kparams = dict(scale=scale, bq=bq, bk=bk, causal=causal,
-                   has_mask=has_mask)
+    kparams = dict(scale=scale, band=band, has_mask=has_mask)
 
-    q_spec = pl.BlockSpec((1, bq, d_pad), lambda i, j, kk: (i, j, 0),
+    def kv_block(j, t):
+        return _least(band.kv_lo(j) + t, band.kv_hi(j))
+
+    q_spec = pl.BlockSpec((1, bq, d_pad), lambda i, j, t: (i, j, 0),
                           memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, bq, LANE), lambda i, j, kk: (i, j, 0),
+    row_spec = pl.BlockSpec((1, bq, LANE), lambda i, j, t: (i, j, 0),
                             memory_space=pltpu.VMEM)
-    kv_inner = pl.BlockSpec((1, bk, d_pad), lambda i, j, kk: (i, kk, 0),
-                            memory_space=pltpu.VMEM)
+    kv_inner = pl.BlockSpec(
+        (1, bk, d_pad), lambda i, j, t: (i // group, kv_block(j, t), 0),
+        memory_space=pltpu.VMEM)
     in_specs = [q_spec, kv_inner, kv_inner, q_spec, row_spec, row_spec]
     args = [qf, kf, vf, gf, lse, di]
     if has_mask:
-        in_specs.append(pl.BlockSpec((1, _SUBLANES, bk),
-                                     lambda i, j, kk: (i, 0, kk),
-                                     memory_space=pltpu.VMEM))
-        args.append(mask_f)
+        in_specs.append(pl.BlockSpec(
+            (1, _SUBLANES, bk), lambda i, j, t: (i, 0, kv_block(j, t)),
+            memory_space=pltpu.VMEM))
+        args.append(_fold_mask(kv_mask, H))
+    semantics = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
     with jax.named_scope(kernel_scope("flash_dq")):
         dq = pl.pallas_call(
-            functools.partial(_dq_kernel, n_k=n_k, **kparams),
+            functools.partial(_dq_kernel, width=band.kv_width, **kparams),
             out_shape=_sds((B * H, T, d_pad), q.dtype, gf),
-            grid=(B * H, n_q, n_k),  # kv innermost: dq carry in scratch
+            grid=(B * H, band.n_q, band.kv_width),  # dq carry in scratch
             in_specs=in_specs,
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((bq, d_pad), jnp.float32)],
+            compiler_params=semantics,
             interpret=interpret,
         )(*args)
 
-    q_inner = pl.BlockSpec((1, bq, d_pad), lambda i, j, qq: (i, qq, 0),
-                           memory_space=pltpu.VMEM)
-    row_inner = pl.BlockSpec((1, bq, LANE), lambda i, j, qq: (i, qq, 0),
-                             memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, bk, d_pad), lambda i, j, qq: (i, j, 0),
+    width = band.q_width
+
+    def q_index(i, jk, t):
+        """(folded query head, q block) of step ``t`` for kv head ``i``."""
+        return (i * group + t // width,
+                _least(band.q_lo(jk) + t % width, band.q_hi(jk)))
+
+    q_inner = pl.BlockSpec(
+        (1, bq, d_pad), lambda i, jk, t: (*q_index(i, jk, t), 0),
+        memory_space=pltpu.VMEM)
+    row_inner = pl.BlockSpec(
+        (1, bq, LANE), lambda i, jk, t: (*q_index(i, jk, t), 0),
+        memory_space=pltpu.VMEM)
+    kv_spec = pl.BlockSpec((1, bk, d_pad), lambda i, jk, t: (i, jk, 0),
                            memory_space=pltpu.VMEM)
     in_specs = [q_inner, kv_spec, kv_spec, q_inner, row_inner, row_inner]
     args = [qf, kf, vf, gf, lse, di]
     if has_mask:
         in_specs.append(pl.BlockSpec((1, _SUBLANES, bk),
-                                     lambda i, j, qq: (i, 0, j),
+                                     lambda i, jk, t: (i, 0, jk),
                                      memory_space=pltpu.VMEM))
-        args.append(mask_f)
+        args.append(_fold_mask(kv_mask, KV))
     with jax.named_scope(kernel_scope("flash_dkv")):
         dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, n_q=n_q, **kparams),
+            functools.partial(_dkv_kernel, width=width, group=group,
+                              **kparams),
             out_shape=[
-                _sds((B * H, T, d_pad), k.dtype, gf),
-                _sds((B * H, T, d_pad), v.dtype, gf),
+                _sds((B * KV, T, d_pad), k.dtype, gf),
+                _sds((B * KV, T, d_pad), v.dtype, gf),
             ],
-            grid=(B * H, n_k, n_q),  # q innermost: dk/dv carry in scratch
+            grid=(B * KV, band.n_k, group * width),  # dk/dv carry in scratch
             in_specs=in_specs,
             out_specs=[kv_spec, kv_spec],
             scratch_shapes=[
                 pltpu.VMEM((bk, d_pad), jnp.float32),
                 pltpu.VMEM((bk, d_pad), jnp.float32),
             ],
+            compiler_params=semantics,
             interpret=interpret,
         )(*args)
-    shape = q.shape
-    return _unfold(dq, shape), _unfold(dk, shape), _unfold(dv, shape)
+    return _unfold(dq, q.shape), _unfold(dk, k.shape), _unfold(dv, v.shape)
 
 
 def _resolve_interpret(interpret):
@@ -512,29 +657,31 @@ def _resolve_interpret(interpret):
     return interpret
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, kv_mask, block_q, block_k, interpret, causal):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, kv_mask, block_q, block_k, interpret, causal, window):
     out, _ = _flash_forward(
         q, k, v, kv_mask, block_q=block_q, block_k=block_k,
         interpret=_resolve_interpret(interpret), causal=causal,
+        window=window,
     )
     return out
 
 
-def _fwd(q, k, v, kv_mask, block_q, block_k, interpret, causal):
+def _fwd(q, k, v, kv_mask, block_q, block_k, interpret, causal, window):
     out, lse = _flash_forward(
         q, k, v, kv_mask, block_q=block_q, block_k=block_k,
         interpret=_resolve_interpret(interpret), causal=causal,
+        window=window,
     )
     return out, (q, k, v, kv_mask, out, lse)
 
 
-def _bwd(block_q, block_k, interpret, causal, res, g):
+def _bwd(block_q, block_k, interpret, causal, window, res, g):
     q, k, v, kv_mask, o, lse = res
     if lse is None:  # forward took the interpreted-under-shard_map detour
         _, vjp = jax.vjp(
             lambda a, b, c: _reference(a, b, c, causal=causal,
-                                       kv_mask=kv_mask),
+                                       kv_mask=kv_mask, window=window),
             q, k, v,
         )
         dq, dk, dv = vjp(g)
@@ -542,6 +689,7 @@ def _bwd(block_q, block_k, interpret, causal, res, g):
         dq, dk, dv = _flash_backward(
             q, k, v, o, lse, g, kv_mask, block_q=block_q, block_k=block_k,
             interpret=_resolve_interpret(interpret), causal=causal,
+            window=window,
         )
     dm = None if kv_mask is None else jnp.zeros_like(kv_mask)
     return dq, dk, dv, dm
@@ -552,11 +700,15 @@ _flash.defvjp(_fwd, _bwd)
 
 def flash_attention(q, k, v, block_q: int = 128, block_k: int = 128,
                     interpret: bool | None = None, *, causal: bool = False,
-                    kv_mask=None):
+                    kv_mask=None, window: int = 0):
     """(B, T, H, D) attention as a Pallas TPU kernel (fwd + bwd).
 
-    ``causal`` masks col > row and skips above-diagonal tiles (the decoder
-    regime — roughly half the MXU work at large T). ``kv_mask`` (B, Tk),
+    ``causal`` masks col > row; ``window`` (which implies it) also masks
+    col <= row - window. Only the kv blocks a q block can see are visited
+    (the decoder regime: half the tiles under ``causal`` at large T, a
+    band of them under a window). ``k`` and ``v`` may carry fewer heads
+    than ``q``: (B, T, KV, D) with ``H % KV == 0``, each shared by a group
+    of ``H // KV`` query heads. ``kv_mask`` (B, Tk),
     nonzero = attend, masks key/value columns (padding); rows with no
     visible key output exactly 0, with clean zero gradients. ``interpret``
     defaults to True off TPU (CPU tests) and False on TPU. A T the blocks
@@ -566,11 +718,14 @@ def flash_attention(q, k, v, block_q: int = 128, block_k: int = 128,
     (attention-free CNN, SURVEY.md §5.7); the causal/masked forms cover
     the decoder workloads the ring-parallel long-context path implies."""
     B, T = q.shape[:2]
+    window = int(window or 0)
+    causal = bool(causal or window)
     if kv_mask is not None:
         kv_mask = kv_mask.astype(jnp.float32)
     plan = _plan(q.shape, block_q, block_k, kv_mask is not None)
     if plan.t_pad == T:
-        return _flash(q, k, v, kv_mask, plan.bq, plan.bk, interpret, causal)
+        return _flash(q, k, v, kv_mask, plan.bq, plan.bk, interpret, causal,
+                      window)
     pad = plan.t_pad - T
     q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
                for x in (q, k, v))
@@ -578,4 +733,4 @@ def flash_attention(q, k, v, block_q: int = 128, block_k: int = 128,
         kv_mask = jnp.ones((B, T), jnp.float32)
     kv_mask = jnp.pad(kv_mask, ((0, 0), (0, pad)))
     return _flash(q, k, v, kv_mask, plan.bq, plan.bk, interpret,
-                  causal)[:, :T]
+                  causal, window)[:, :T]

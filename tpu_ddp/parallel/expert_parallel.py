@@ -18,6 +18,7 @@ handled by that builder's ``aux_weight`` path, mirroring the Switch recipe.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable
 
 import optax
@@ -27,6 +28,40 @@ from tpu_ddp.parallel.mesh import DATA_AXIS, EXPERT_AXIS
 from tpu_ddp.parallel.partitioning import PartitionRule, specs_for_params
 from tpu_ddp.train.losses import cross_entropy_loss
 from tpu_ddp.train.state import TrainState
+
+@dataclasses.dataclass(frozen=True)
+class ExpertShare:
+    """Which of a layer's ``num_experts`` routed experts live here: ``held``
+    of them, ids ``offset`` and up. Data, not code: the layer that is told
+    its share (``tpu_ddp.models.moe.DroplessMoE``) routes over all
+    ``num_experts``, computes the part of the result its own experts give for
+    the tokens routed to them, and hands that partial result on, whether the
+    other shares run on the other positions of an ``expert`` mesh axis (their
+    partial results then meet in the exchange between chips) or nowhere (one
+    chip of an expert-parallel deployment, run alone)."""
+
+    num_experts: int
+    held: int
+    offset: int = 0
+
+    def __post_init__(self):
+        if not (0 < self.held <= self.num_experts
+                and 0 <= self.offset <= self.num_experts - self.held):
+            raise ValueError(
+                f"experts {self.offset}..{self.offset + self.held - 1} are "
+                f"not a share of {self.num_experts}")
+
+    @classmethod
+    def of_position(cls, num_experts: int, position: int,
+                    positions: int) -> "ExpertShare":
+        """The share of one of ``positions`` equal shares (one position of
+        an ``expert`` mesh axis)."""
+        if num_experts % positions:
+            raise ValueError(
+                f"{num_experts} experts do not divide over {positions}")
+        held = num_experts // positions
+        return cls(num_experts, held, position * held)
+
 
 # Layout for tpu_ddp.models.moe.MoEMlp (paths like block_1/moe/w_up).
 # Router weights stay replicated: every device routes its own tokens.

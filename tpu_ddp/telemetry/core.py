@@ -156,6 +156,36 @@ class Telemetry:
         if self.enabled:
             self.registry.counter(name).inc(n)
 
+    def record_model_counters(self, per_step) -> dict:
+        """What the model counted in some steps, reduced per name on the
+        host. ``per_step`` holds one ``{name: array}`` a step: what the
+        layers sowed into ``counters`` and the step handed out with its
+        metrics (``train/steps.py::sum_counters``), already fetched. For
+        every name:
+
+        ``model/<name>_sum``    gauge: the array's sum in a step
+        ``model/<name>_mean``   gauge: its mean element in a step
+        ``model/<name>_max``    gauge: its largest element in a step
+        ``model/<name>_total``  counter: the sum over all these steps
+
+        each gauge the mean over the steps. Returns the gauges (also where
+        telemetry is off: ``Trainer.run``'s result carries them)."""
+        import numpy as np
+
+        out = {}
+        for name in sorted({k for counted in per_step for k in counted}):
+            steps = [np.asarray(c[name]) for c in per_step if name in c]
+            stats = {"sum": [x.sum() for x in steps],
+                     "mean": [x.mean() for x in steps],
+                     "max": [x.max() for x in steps]}
+            for stat, values in stats.items():
+                out[f"model/{name}_{stat}"] = float(np.mean(values))
+            self.count(f"model/{name}_total", float(np.sum(stats["sum"])))
+        if self.enabled:
+            for key, value in out.items():
+                self.registry.gauge(key).set(value)
+        return out
+
     # -- span listeners (capture windows) ---------------------------------
 
     def add_span_listener(self, listener) -> None:

@@ -44,6 +44,13 @@ INPUT_SCOPE = "tpu_ddp.input"
 METRICS_SCOPE = "tpu_ddp.metrics"
 HEALTH_SCOPE = "tpu_ddp.health"
 KERNEL_SCOPE_PREFIX = "tpu_ddp.kernel."
+#: a scope inside the model that names a module of its own: the part of a
+#: layer a per-layer metric reads (``tpu_ddp.module.moe_route``), where the
+#: flax path alone would say ``layer_3`` for router, experts and attention
+#: alike. It names the module of everything inside it, a kernel call too
+#: (the kernel's own scope stays in the ``op_name``, which is how a roofline
+#: reader finds it); it never decides a phase.
+MODULE_SCOPE_PREFIX = "tpu_ddp.module."
 
 #: scope (by prefix: the zero3 scopes end in ``/b<k>``) -> phase. The
 #: forward scopes are decided by the AD marker instead; a kernel scope
@@ -94,6 +101,11 @@ def kernel_scope(name: str) -> str:
     return KERNEL_SCOPE_PREFIX + name
 
 
+def module_scope(name: str) -> str:
+    """The scope a model wraps one named part of a layer in."""
+    return MODULE_SCOPE_PREFIX + name
+
+
 def _unwrap(component: str) -> str:
     """``transpose(jvp(ResNet))`` -> ``ResNet``: AD and batching wrappers
     off, a called function (``jit(log_softmax)``) stays as it is."""
@@ -114,16 +126,20 @@ def _scope_phase(scope: str):
 @functools.lru_cache(maxsize=8192)  # a program repeats its paths
 def _classify_path(path: str, opcode: str) -> Tuple[str, str]:
     names = [_unwrap(c) for c in path.strip().split("/")]
-    phase, module, anchor = OTHER, "", None
+    phase, module, anchor, named = OTHER, "", None, False
     for i, name in enumerate(names):
         if not name.startswith(SCOPE_PREFIX):
             continue
         if name in (FORWARD_BACKWARD_SCOPE, FORWARD_SCOPE):
             anchor = i
             phase = BACKWARD if BACKWARD_MARKER in path else FORWARD
-            module = ""
+            module, named = "", False
+        elif name.startswith(MODULE_SCOPE_PREFIX):
+            if not named:  # the outermost names the module
+                module, named = name[len(MODULE_SCOPE_PREFIX):], True
         elif name.startswith(KERNEL_SCOPE_PREFIX):
-            module = name[len(SCOPE_PREFIX):]
+            if not named:
+                module = name[len(SCOPE_PREFIX):]
         else:
             known = _scope_phase(name)
             if known is not None:

@@ -19,8 +19,10 @@ directory (the sink grammar of ``telemetry.sink_file_name``):
      "instructions": {"fusion.11": {"op_name": "...", "opcode": "fusion",
                                     "phase": "backward", "module": "head"}}}
 
-An instruction the compiler made itself has no ``op_name``; it takes the
-phase of the instruction that uses its result and says ``"inherited": true``.
+An instruction the compiler made itself has no ``op_name`` (a custom call
+it emits for one primitive has only its own name for it, no path); it takes
+the phase of the instruction that uses its result and says
+``"inherited": true``.
 A fusion takes the phase of the ``op_name`` the compiler left on it; where
 the instructions fused into it disagree on the phase (a weight-gradient
 convolution with the optimizer's update as its epilogue), it says
@@ -102,6 +104,11 @@ def _inherit(instructions, table) -> None:
     first named operand, and is marked ``inherited``: where the data goes
     is where the work belongs."""
     def unnamed(ins):
+        # a kernel the compiler emits itself for one primitive (XLA:TPU's
+        # grouped product for ``lax.ragged_dot``) is a custom call that
+        # keeps the compiler's name for it, ``ragged-dot-none``, and no path
+        if ins["opcode"] == "custom-call" and "/" not in ins["op_name"]:
+            return True
         return not ins["op_name"] and ins["opcode"] not in _NEVER_RUN
 
     def take(row, source):

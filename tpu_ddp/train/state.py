@@ -40,17 +40,30 @@ class TrainState(struct.PyTreeNode):
     grad_residual: Any = None
 
 
-def init_model_variables(model, rng, input_shape=(1, 32, 32, 3)) -> tuple:
+def init_model_variables(model, rng, input_shape=(1, 32, 32, 3),
+                         example=None) -> tuple:
     """(params, batch_stats) from a dummy-input init — THE init recipe,
     shared by ``create_train_state`` and the ZeRO-1 path (which must defer
     ``tx.init`` so the optimizer state is born scattered; seed-parity
-    between the two paths depends on this being one function)."""
-    variables = model.init(rng, jnp.zeros(input_shape, jnp.float32), train=False)
+    between the two paths depends on this being one function).
+
+    ``example`` is the dummy input of a model that does not read float32
+    images (``train/tasks.py::Task.example_input``: a few token ids). Its
+    init is one jitted program: a decoder's forward pass holds kernels and
+    sorts, which nobody wants dispatched one by one."""
+    if example is not None:
+        variables = jax.jit(lambda r, x: model.init(r, x, train=False))(
+            rng, example)
+    else:
+        variables = model.init(
+            rng, jnp.zeros(input_shape, jnp.float32), train=False)
     return variables["params"], variables.get("batch_stats", {})
 
 
-def create_train_state(model, tx, rng, input_shape=(1, 32, 32, 3)) -> TrainState:
-    params, batch_stats = init_model_variables(model, rng, input_shape)
+def create_train_state(model, tx, rng, input_shape=(1, 32, 32, 3),
+                       example=None) -> TrainState:
+    params, batch_stats = init_model_variables(model, rng, input_shape,
+                                               example)
     return TrainState(
         step=jnp.zeros((), jnp.int32),
         params=params,

@@ -59,6 +59,7 @@ from tpu_ddp.train.losses import (
 )
 from tpu_ddp.train.optim import apply_optimizer
 from tpu_ddp.train.state import TrainState
+from tpu_ddp.train.tasks import IMAGE_CLASSIFICATION, Task
 
 # Where the DDP gradient sync lives: the builders pmean the per-shard loss
 # BEFORE differentiation — AD's transpose of the replicated-params
@@ -81,6 +82,25 @@ def resolve_remat(model, remat: bool):
     return model, remat
 
 Batch = dict
+
+#: the collections a train step lets the model write: batch statistics,
+#: auxiliary losses, and ``counters`` (what a layer counted this step: the
+#: load of each held expert, ``models/moe.py``), which ride out of the step
+#: with its metrics and are fetched where the losses are
+COUNTERS = "counters"
+MUTABLE = ("batch_stats", "aux_loss", COUNTERS)
+
+
+def sum_counters(counters, data_axis: str):
+    """What the model sowed into ``counters``, one array per name (a name
+    sown by several layers is stacked in layer order), summed over the
+    shards."""
+    by_name = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(counters):
+        name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+        by_name.setdefault(name, []).append(leaf)
+    return {name: lax.psum(jnp.stack(leaves), data_axis)
+            for name, leaves in by_name.items()}
 
 
 def state_specs_for(zero1, compress, data_axis: str = DATA_AXIS):
@@ -130,9 +150,15 @@ def _make_shard_step(
     health: Optional[HealthConfig] = None,
     zero1=None,
     compress=None,
+    task: Task = IMAGE_CLASSIFICATION,
 ):
     """Per-shard train-step body shared by the single-step and scanned
     variants: forward, pmean'd loss (the gradient allreduce), optax update.
+
+    ``task`` (``train/tasks.py``) says which array of the batch the model
+    reads and which loss it takes: an image classifier's ``loss_fn`` over
+    ``label``, or a decoder's masked next-token loss over its own
+    ``tokens``. Everything else in the step is the same step.
 
     ``compress`` (a ``tpu_ddp.parallel.compression.GradCompressor``)
     swaps the gradient sync's wire format: without zero1 the pmean
@@ -174,25 +200,26 @@ def _make_shard_step(
             {"params": params, "batch_stats": batch_stats},
             images,
             train=True,
-            mutable=["batch_stats", "aux_loss"],
+            mutable=list(MUTABLE),
         )
 
     if remat:
         apply_model = jax.checkpoint(apply_model)
 
     def compute_loss(params, batch_stats, batch):
-        logits, mutated = apply_model(params, batch_stats, batch["image"])
+        logits, mutated = apply_model(params, batch_stats,
+                                      batch[task.input_key])
         with jax.named_scope(LOSS_SCOPE):
-            task = loss_fn(logits, batch["label"], batch.get("mask"))
+            task_loss = task.loss(loss_fn, logits, batch)
             if mixup_alpha > 0:
                 # hard-label mixup: blend the two CE terms by the same
                 # lambda the images were blended with
                 # (data/augment.py::mixup)
-                task = (batch["_mix_lam"] * task
-                        + (1.0 - batch["_mix_lam"])
-                        * loss_fn(logits, batch["_mix_label"],
-                                  batch.get("mask")))
-            loss, aux = combine_aux_loss(task, mutated, aux_weight)
+                task_loss = (batch["_mix_lam"] * task_loss
+                             + (1.0 - batch["_mix_lam"])
+                             * loss_fn(logits, batch["_mix_label"],
+                                       batch.get("mask")))
+            loss, aux = combine_aux_loss(task_loss, mutated, aux_weight)
         # Gradient sync lives HERE: pmean-ing the per-shard
         # loss before differentiation makes reverse-mode AD produce the
         # globally *averaged* gradient — the pmean's transpose scatters
@@ -210,7 +237,8 @@ def _make_shard_step(
         # cannot own either — same local-loss convention.
         if zero1 is None and compress is None:
             loss = lax.pmean(loss, data_axis)
-        return loss, (mutated.get("batch_stats", batch_stats), logits, task, aux)
+        return loss, (mutated.get("batch_stats", batch_stats), logits,
+                      task_loss, aux, mutated.get(COUNTERS))
 
     def shard_step(state: TrainState, batch: Batch):
         # Every part of the step sits in a scope of telemetry/phases.py:
@@ -258,9 +286,8 @@ def _make_shard_step(
         else:
             p_in = state.params
         with jax.named_scope(FORWARD_BACKWARD_SCOPE):
-            (_, (new_stats, logits, task, aux)), grads = grad_fn(
-                p_in, state.batch_stats, batch
-            )
+            (_, (new_stats, logits, task_loss, aux, counters)), grads = (
+                grad_fn(p_in, state.batch_stats, batch))
         with jax.named_scope(STATS_SYNC_SCOPE):
             new_stats = jax.tree.map(
                 lambda s: lax.pmean(s, data_axis), new_stats)
@@ -304,13 +331,14 @@ def _make_shard_step(
                           if want_err else None)
                 if zero1 is not None:
                     hstats = zero1.health_stats(
-                        loss=lax.pmean(task, data_axis), grad_shards=gshards,
+                        loss=lax.pmean(task_loss, data_axis),
+                        grad_shards=gshards,
                         params=state.params, update_shards=ushards,
                         per_layer=health.per_layer, compress_error_sq=err_sq,
                     )
                 else:
                     hstats = health_stats(
-                        loss=lax.pmean(task, data_axis), grads=grads,
+                        loss=lax.pmean(task_loss, data_axis), grads=grads,
                         params=state.params, updates=updates,
                         per_layer=health.per_layer, compress_error_sq=err_sq,
                     )
@@ -329,14 +357,16 @@ def _make_shard_step(
             grad_residual=new_residual,
         )
         with jax.named_scope(METRICS_SCOPE):
-            metrics = {"loss": lax.pmean(task, data_axis)}
+            metrics = {"loss": lax.pmean(task_loss, data_axis)}
             if health is not None:
                 metrics["health"] = hstats
             if aux is not None:
                 metrics["aux_loss"] = lax.pmean(aux, data_axis)
-            if compute_accuracy:
+            if counters:
+                metrics[COUNTERS] = sum_counters(counters, data_axis)
+            if compute_accuracy and task.accuracy:
                 correct, count = masked_accuracy(
-                    logits, batch["label"], batch.get("mask")
+                    logits, batch[task.target_key], batch.get("mask")
                 )
                 metrics["accuracy"] = (
                     lax.psum(correct, data_axis)
@@ -363,6 +393,7 @@ def make_train_step(
     health: Optional[HealthConfig] = None,
     zero1=None,
     compress=None,
+    task: Task = IMAGE_CLASSIFICATION,
 ) -> Callable[[TrainState, Batch], tuple]:
     """Build the compiled DDP train step for `mesh`.
 
@@ -393,6 +424,7 @@ def make_train_step(
         health=health,
         zero1=zero1,
         compress=compress,
+        task=task,
     )
     state_specs = state_specs_for(zero1, compress, data_axis)
     sharded = jax.shard_map(
@@ -422,6 +454,7 @@ def make_scan_train_step(
     health: Optional[HealthConfig] = None,
     zero1=None,
     compress=None,
+    task: Task = IMAGE_CLASSIFICATION,
 ) -> Callable[[TrainState, Batch], tuple]:
     """K train steps fused into ONE dispatch via ``lax.scan``.
 
@@ -458,6 +491,7 @@ def make_scan_train_step(
         health=health,
         zero1=zero1,
         compress=compress,
+        task=task,
     )
 
     def shard_multi(state: TrainState, batches: Batch):
@@ -488,6 +522,7 @@ def make_grad_accum_train_step(
     health: Optional[HealthConfig] = None,
     zero1=None,
     compress=None,
+    task: Task = IMAGE_CLASSIFICATION,
 ) -> Callable[[TrainState, Batch], tuple]:
     """ONE optimizer step over a global batch too large to activate at
     once: each shard splits its rows into ``accum_steps`` microbatches,
@@ -520,26 +555,28 @@ def make_grad_accum_train_step(
             {"params": params, "batch_stats": batch_stats},
             images,
             train=True,
-            mutable=["batch_stats", "aux_loss"],
+            mutable=list(MUTABLE),
         )
 
     if remat:
         apply_model = jax.checkpoint(apply_model)
 
     def compute_loss(params, batch_stats, micro):
-        logits, mutated = apply_model(params, batch_stats, micro["image"])
+        logits, mutated = apply_model(params, batch_stats,
+                                      micro[task.input_key])
         with jax.named_scope(LOSS_SCOPE):
-            task = loss_fn(logits, micro["label"], micro.get("mask"))
-            loss, aux = combine_aux_loss(task, mutated, aux_weight)
+            task_loss = task.loss(loss_fn, logits, micro)
+            loss, aux = combine_aux_loss(task_loss, mutated, aux_weight)
         # grad sync, as in _make_shard_step (zero1/compress: the sync is
         # the (ring) reduce-scatter AFTER accumulation — the loss stays
         # local, ONE compressed collective per accumulated batch)
         if zero1 is None and compress is None:
             loss = lax.pmean(loss, data_axis)
-        return loss, (mutated.get("batch_stats", batch_stats), logits, task, aux)
+        return loss, (mutated.get("batch_stats", batch_stats), logits,
+                      task_loss, aux, mutated.get(COUNTERS))
 
     def shard_step(state: TrainState, batch: Batch):
-        b = batch["image"].shape[0]
+        b = batch[task.input_key].shape[0]
         if b % accum_steps:
             raise ValueError(
                 f"per-shard batch {b} not divisible by accum_steps "
@@ -572,19 +609,20 @@ def make_grad_accum_train_step(
         def accum(carry, micro):
             grads_acc, stats, correct, count, loss_sum, aux_sum = carry
             with jax.named_scope(FORWARD_BACKWARD_SCOPE):
-                (_, (new_stats, logits, task, aux)), grads = grad_fn(
-                    p_in, stats, micro
-                )
+                (_, (new_stats, logits, task_loss, aux, counted)), grads = (
+                    grad_fn(p_in, stats, micro))
             with jax.named_scope(GRAD_ACCUM_SCOPE):
                 grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
-            with jax.named_scope(METRICS_SCOPE):
-                c, n = masked_accuracy(
-                    logits, micro["label"], micro.get("mask"))
+            if compute_accuracy and task.accuracy:
+                with jax.named_scope(METRICS_SCOPE):
+                    c, n = masked_accuracy(
+                        logits, micro[task.target_key], micro.get("mask"))
+                correct, count = correct + c, count + n
             aux_term = jnp.zeros(()) if aux is None else aux
             return (
-                grads_acc, new_stats, correct + c, count + n,
-                loss_sum + task, aux_sum + aux_term,
-            ), None
+                grads_acc, new_stats, correct, count,
+                loss_sum + task_loss, aux_sum + aux_term,
+            ), counted  # the model's counters: one row a microbatch
 
         # Values computed from shard-local data (metric scalars, fresh BN
         # stats) are VARYING over the data axis under shard_map; the carry
@@ -595,7 +633,8 @@ def make_grad_accum_train_step(
             lambda s: lax.pcast(s, (data_axis,), to="varying"),
             state.batch_stats,
         )
-        (grads_acc, new_stats, correct, count, loss_sum, aux_sum), _ = lax.scan(
+        ((grads_acc, new_stats, correct, count, loss_sum, aux_sum),
+         counters) = lax.scan(
             accum,
             (zero_grads, stats0, zero, zero, zero, zero),
             micros,
@@ -663,7 +702,11 @@ def make_grad_accum_train_step(
             metrics = {"loss": lax.pmean(loss_sum / accum_steps, data_axis)}
             if health is not None:
                 metrics["health"] = hstats
-            if compute_accuracy:
+            if counters:  # a step's count is the sum over its microbatches
+                metrics[COUNTERS] = sum_counters(
+                    jax.tree.map(lambda c: c.sum(axis=0), counters),
+                    data_axis)
+            if compute_accuracy and task.accuracy:
                 metrics["accuracy"] = lax.psum(correct, data_axis) / jnp.maximum(
                     lax.psum(count, data_axis), 1.0
                 )
@@ -686,26 +729,30 @@ def make_eval_step(
     data_axis: str = DATA_AXIS,
     loss_fn: Callable = cross_entropy_loss,
     compute_accuracy: bool = True,
+    task: Task = IMAGE_CLASSIFICATION,
 ) -> Callable[[TrainState, Batch], dict]:
     """Compiled eval step: running-stats BN, summed correct/count/loss over
     the mesh. The eval loop the reference's runnable path never had
-    (SURVEY.md §6)."""
+    (SURVEY.md §6). ``count`` counts rows (examples), whatever the task's
+    loss averages over."""
 
     def shard_eval(state: TrainState, batch: Batch):
         variables = {"params": state.params, "batch_stats": state.batch_stats}
         with jax.named_scope(FORWARD_SCOPE):
-            logits = model.apply(variables, batch["image"], train=False)
+            logits = model.apply(variables, batch[task.input_key],
+                                 train=False)
             mask = batch.get("mask")
             with jax.named_scope(LOSS_SCOPE):
-                loss = loss_fn(logits, batch["label"], mask)
+                loss = task.loss(loss_fn, logits, batch)
         with jax.named_scope(METRICS_SCOPE):
             shard_count = (
                 mask.astype(jnp.float32).sum()
                 if mask is not None
                 else jnp.asarray(float(logits.shape[0]))
             )
-            if compute_accuracy:
-                correct, _ = masked_accuracy(logits, batch["label"], mask)
+            if compute_accuracy and task.accuracy:
+                correct, _ = masked_accuracy(
+                    logits, batch[task.target_key], mask)
             else:
                 correct = jnp.zeros(())
             return {
@@ -734,6 +781,7 @@ def make_predict_step(
     mesh: Mesh,
     *,
     data_axis: str = DATA_AXIS,
+    task: Task = IMAGE_CLASSIFICATION,
 ):
     """Compiled batch-inference step: sharded forward, logits returned in the
     batch's global order. Covers the reference's batch-inference capability
@@ -743,7 +791,8 @@ def make_predict_step(
     def shard_predict(state: TrainState, batch: Batch):
         variables = {"params": state.params, "batch_stats": state.batch_stats}
         with jax.named_scope(FORWARD_SCOPE):
-            return model.apply(variables, batch["image"], train=False)
+            return model.apply(variables, batch[task.input_key],
+                               train=False)
 
     sharded = jax.shard_map(
         shard_predict,

@@ -168,6 +168,13 @@ class TrainConfig:
     remat: bool = False                   # jax.checkpoint the forward:
                                           # trade FLOPs for HBM on big models
     model: str = "netresdeep"
+    model_overrides: Optional[dict] = None  # keyword arguments of the
+                                          # registry factory beyond the
+                                          # classifier's: one chip's share
+                                          # of a decoder (laguna_xs2:
+                                          # num_layers, experts_held,
+                                          # expert_offset, vocab_rows;
+                                          # models/decoder.py). No width
     n_chans1: int = 32                    # NetResDeep width (the reference's
                                           # ctor arg, model/resnet.py:5)
     n_blocks: int = 10                    # NetResDeep depth (same ctor)
@@ -525,7 +532,7 @@ def build_model(config: TrainConfig):
     import jax.numpy as jnp
 
     from tpu_ddp.models import NetResDeep
-    from tpu_ddp.models.zoo import MODEL_REGISTRY
+    from tpu_ddp.models.zoo import LAZY_MODELS, MODEL_REGISTRY
 
     bn_axis = DATA_AXIS if config.sync_bn else None
     dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[config.compute_dtype]
@@ -539,28 +546,51 @@ def build_model(config: TrainConfig):
             bn_cross_replica_axis=bn_axis,
             dtype=dtype,
         )
+    if name in LAZY_MODELS:  # registers itself when first asked for
+        import importlib
+
+        importlib.import_module(LAZY_MODELS[name])
     if name in MODEL_REGISTRY:
         model = MODEL_REGISTRY[name](
             num_classes=config.num_classes, bn_cross_replica_axis=bn_axis,
-            dtype=dtype,
+            dtype=dtype, **(config.model_overrides or {}),
         )
         if config.attention == "flash":
             if not hasattr(model, "attention_impl"):
                 raise ValueError(
                     f"--attention flash needs an attention model (ViT "
-                    f"family); {config.model!r} has none"
+                    f"family, decoders); {config.model!r} has none"
                 )
             from tpu_ddp.ops.flash_attention import flash_attention
 
+            # a model may say which blocks suit its sequences
+            # (``flash_blocks``); the kernel's defaults otherwise
+            blocks = getattr(model, "flash_blocks", None)
+            if blocks is not None:
+                import functools
+
+                flash_attention = functools.partial(
+                    flash_attention, block_q=blocks[0], block_k=blocks[1])
             model = model.clone(attention_impl=flash_attention)
         return model
     raise ValueError(f"unknown model {config.model!r}")
 
 
-def load_dataset(c: TrainConfig):
+def load_dataset(c: TrainConfig, task=None, model=None):
     """(train, test) (images, labels) tuples for a config — shared by the
     Trainer and the k-fold CV driver (which re-splits the train set itself,
-    the reference's ``cv_mode`` path, ``ppe_main_ddp.py:91-93``)."""
+    the reference's ``cv_mode`` path, ``ppe_main_ddp.py:91-93``). For a
+    ``task`` other than image classification, what the task makes for the
+    built ``model`` (``train/tasks.py``): synthetic data only, a corpus
+    comes as ``train_data``."""
+    if task is not None and task.synthetic is not None:
+        if not c.synthetic_data:
+            raise ValueError(
+                f"model {c.model!r} trains on {task.name}: pass "
+                "--synthetic-data, or train_data=(inputs, targets)")
+        return (task.synthetic(model, c.synthetic_size, c.seed),
+                task.synthetic(model, max(c.synthetic_size // 5, 8),
+                               c.seed + 1))
     if c.synthetic_data:
         from tpu_ddp.data.cifar10 import (
             synthetic_cifar10,
@@ -859,6 +889,17 @@ class Trainer:
                 )
         self._data_prefetcher = None  # staged background prefetcher
         self.model = build_model(config)
+        from tpu_ddp.train.tasks import IMAGE_CLASSIFICATION, task_of
+
+        # what the model reads from a batch and which loss it takes
+        self.task = task_of(self.model)
+        if (self.task is not IMAGE_CLASSIFICATION
+                and self.parallelism != "dp"):
+            raise ValueError(
+                f"model {config.model!r} trains on {self.task.name}; the "
+                f"{self.parallelism} strategy's step and layout rules are "
+                "an image classifier's. Use --parallelism dp (zero1, "
+                "accumulation and recomputation come with it)")
         self._load_data(train_data, test_data)
         total_steps = self.train_loader.steps_per_epoch * config.epochs
         freeze = None
@@ -882,7 +923,8 @@ class Trainer:
 
                 abstract_params, _ = jax.eval_shape(
                     lambda: init_model_variables(
-                        self.model, jax.random.key(0))
+                        self.model, jax.random.key(0),
+                        example=self._init_example())
                 )
                 decay_mask = _decay_mask(abstract_params)
         self.tx = make_optimizer(
@@ -1148,6 +1190,10 @@ class Trainer:
         return base.replace(
             grad_residual=self._compress.residual_shardings(self.mesh))
 
+    def _init_example(self):
+        """The dummy input ``model.init`` is shown (None: float32 images)."""
+        return self.task.example_input(self.train_loader.images)
+
     def _init_dp_steps(self, loss_fn, with_acc):
         """Flagship data-parallel path: shard_map DDP-semantics step, scan
         fusion, on-device augmentation, replicated state (``--zero1``:
@@ -1180,7 +1226,8 @@ class Trainer:
             from tpu_ddp.train.state import TrainState, init_model_variables
 
             params, batch_stats = init_model_variables(
-                self.model, jax.random.key(config.seed))
+                self.model, jax.random.key(config.seed),
+                example=self._init_example())
             # on the mesh before the scatters below read them; the step and
             # the batch statistics are left to _place_state
             params = jax.device_put(params, replicated_sharding(self.mesh))
@@ -1198,7 +1245,8 @@ class Trainer:
             )
         else:
             self.state = create_train_state(
-                self.model, self.tx, jax.random.key(config.seed)
+                self.model, self.tx, jax.random.key(config.seed),
+                example=self._init_example(),
             )
         if config.zero1 or config.zero3:
             if self._zero1 is None:  # finetune path: scatter the restored
@@ -1250,7 +1298,7 @@ class Trainer:
                 loss_fn=loss_fn, compute_accuracy=with_acc,
                 remat=config.remat, aux_weight=config.aux_weight,
                 health=self._health, zero1=self._zero1,
-                compress=self._compress,
+                compress=self._compress, task=self.task,
             )
         else:
             self.train_step = make_train_step(
@@ -1260,7 +1308,7 @@ class Trainer:
                 mixup_alpha=config.mixup_alpha,
                 aux_weight=config.aux_weight,
                 health=self._health, zero1=self._zero1,
-                compress=self._compress,
+                compress=self._compress, task=self.task,
             )
         self.multi_step = None
         # Clamp to the epoch length: a scan longer than the epoch would
@@ -1287,11 +1335,12 @@ class Trainer:
                 mixup_alpha=config.mixup_alpha,
                 aux_weight=config.aux_weight,
                 health=self._health, zero1=self._zero1,
-                compress=self._compress,
+                compress=self._compress, task=self.task,
             )
             self.stacked_sharding = stacked_batch_sharding(self.mesh)
         self.eval_step = make_eval_step(
-            self.model, self.mesh, loss_fn=loss_fn, compute_accuracy=with_acc
+            self.model, self.mesh, loss_fn=loss_fn,
+            compute_accuracy=with_acc, task=self.task,
         )
         self.predict_step = None  # built lazily in predict()
 
@@ -1380,7 +1429,7 @@ class Trainer:
             train = train_data
             test = test_data if test_data is not None else train_data
         else:
-            train, test = load_dataset(c)
+            train, test = load_dataset(c, self.task, self.model)
         self.train_loader = ShardedBatchLoader(
             *train,
             world_size=self.data_size,
@@ -1392,6 +1441,7 @@ class Trainer:
             process_count=self.process_count,
             telemetry=self.telemetry,
             observer=self._datapath,
+            keys=(self.task.input_key, self.task.target_key),
         )
         if c.loss == "bce" and np.asarray(train[1]).ndim != 2:
             raise ValueError(
@@ -1408,6 +1458,7 @@ class Trainer:
             process_index=self.process_index,
             process_count=self.process_count,
             telemetry=self.telemetry,
+            keys=(self.task.input_key, self.task.target_key),
         )
 
     def _put(self, batch):
@@ -1551,6 +1602,7 @@ class Trainer:
         loader = self.train_loader
         img_tail = loader.images.shape[1:]
         lbl_tail = loader.labels.shape[1:]
+        in_key, target_key = self.task.input_key, self.task.target_key
         # Copy UNLESS the backend is known to complete a real H2D copy by
         # block_until_ready (TPU/GPU): any backend that may zero-copy-alias
         # host memory (CPU does, and ignores may_alias=False) would
@@ -1602,7 +1654,7 @@ class Trainer:
                 else:
                     sharding = self.batch_sharding
                 dev = self._put_with(
-                    {"image": img, "label": lbl, "mask": mask}, sharding
+                    {in_key: img, target_key: lbl, "mask": mask}, sharding
                 )
                 # Fence ONLY the H2D transfer, then recycle the slot; the
                 # copy of batch N+depth overlaps the device computing batch N.
@@ -1613,12 +1665,12 @@ class Trainer:
                 if kind == "stacked":
                     for k in range(K):
                         dw.record(step_base + seq + k, {
-                            "image": img[k], "label": lbl[k],
+                            in_key: img[k], target_key: lbl[k],
                             "mask": mask[k],
                         })
                 else:
                     dw.record(step_base + seq, {
-                        "image": img, "label": lbl, "mask": mask,
+                        in_key: img, target_key: lbl, "mask": mask,
                     })
             pf.release(slot)
             return kind, dev, int(mask.sum())
@@ -1748,14 +1800,21 @@ class Trainer:
         label_shape, label_dtype = (
             ((gb, c.num_classes), jnp.float32) if c.loss == "bce"
             else ((gb,), jnp.int32))
+        from tpu_ddp.train.tasks import IMAGE_CLASSIFICATION
+
+        if self.task is IMAGE_CLASSIFICATION:
+            pair = {"image": ((gb, 32, 32, 3), jnp.float32),
+                    "label": (label_shape, label_dtype)}
+        else:  # the training set's own two arrays, a global batch of them
+            loader = self.train_loader
+            pair = {key: ((gb,) + a.shape[1:], a.dtype) for key, a in (
+                (self.task.input_key, loader.images),
+                (self.task.target_key, loader.labels))}
         batch = {
-            "image": jax.ShapeDtypeStruct(
-                (gb, 32, 32, 3), jnp.float32, sharding=shard_of("image")),
-            "label": jax.ShapeDtypeStruct(
-                label_shape, label_dtype, sharding=shard_of("label")),
-            "mask": jax.ShapeDtypeStruct(
-                (gb,), bool, sharding=shard_of("mask")),
-        }
+            key: jax.ShapeDtypeStruct(shape, dtype, sharding=shard_of(key))
+            for key, (shape, dtype) in pair.items()}
+        batch["mask"] = jax.ShapeDtypeStruct(
+            (gb,), bool, sharding=shard_of("mask"))
         return state, batch
 
     def lint_preflight(self, *, raise_on_error: bool = True):
@@ -2083,6 +2142,7 @@ class Trainer:
             # the async dispatch pipeline (SURVEY.md §3.1). One device_get at
             # epoch end materializes them all.
             step_losses = []
+            step_counters = []  # what the model counted, step by step
             epoch_metrics = None
             n_steps = 0
             # host-side global step mirror (one device sync per epoch),
@@ -2122,6 +2182,7 @@ class Trainer:
                             self.state, dev_batch
                         )
                     step_losses.append(epoch_metrics["loss"])  # (K,)
+                    step_counters.append(epoch_metrics.get("counters"))
                     n_steps += self.steps_per_call
                 else:
                     with tel.span("compiled_step"):
@@ -2129,6 +2190,7 @@ class Trainer:
                             self.state, dev_batch
                         )
                     step_losses.append(epoch_metrics["loss"])
+                    step_counters.append(epoch_metrics.get("counters"))
                     n_steps += 1
                 if (self._program_map is not None
                         and not self._program_map.done):
@@ -2235,18 +2297,31 @@ class Trainer:
                             f"Epoch {epoch}, iter {n_steps}, loss {cur:.4f}"
                         )
             with tel.span("epoch_metrics_fetch", epoch=epoch):
+                # the model's counters come over with the losses: one fetch
+                step_losses, step_counters = jax.device_get(
+                    (step_losses, step_counters))
                 mean_loss = (
                     float(
                         np.mean(
                             np.concatenate(
-                                [np.atleast_1d(x)
-                                 for x in jax.device_get(step_losses)]
+                                [np.atleast_1d(x) for x in step_losses]
                             )
                         )
                     )
                     if step_losses
                     else float("nan")
                 )
+            per_step = []  # {name: array} a step; a fused dispatch has K
+            for counted, loss in zip(step_counters, step_losses):
+                if not counted:
+                    continue
+                if np.ndim(loss):
+                    per_step.extend({k: v[i] for k, v in counted.items()}
+                                    for i in range(len(loss)))
+                else:
+                    per_step.append(counted)
+            if per_step:
+                last_metrics.update(tel.record_model_counters(per_step))
             trace_dump_seconds = 0.0
             if epoch == profile_epoch:
                 # the device_get above already fenced the epoch's dispatches;
@@ -2674,7 +2749,8 @@ class Trainer:
         from tpu_ddp.train.steps import make_predict_step
 
         if self.predict_step is None:
-            self.predict_step = make_predict_step(self.model, self.mesh)
+            self.predict_step = make_predict_step(
+                self.model, self.mesh, task=self.task)
         loader = loader if loader is not None else self.test_loader
         pred_state = self._eval_source_state()
         logits_all, labels_all = [], []
@@ -2691,5 +2767,5 @@ class Trainer:
                 logits = np.asarray(out)
             mask = batch["mask"]
             logits_all.append(logits[mask])
-            labels_all.append(np.asarray(batch["label"])[mask])
+            labels_all.append(np.asarray(batch[self.task.target_key])[mask])
         return np.concatenate(logits_all), np.concatenate(labels_all)
