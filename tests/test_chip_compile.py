@@ -114,6 +114,44 @@ def test_grouped_expert_products_compile_at_the_decoder_widths(topo):
     assert _text(bwd, rows, weights, sizes).count("ragged-dot") >= 2
 
 
+def test_the_routed_ladder_compiles_at_the_decoder_widths(topo):
+    """``models/moe.py::_switch`` over the benchmark cell's ladder (32,768
+    and 131,072 rows), forward and backward: two conditionals, each
+    branch with its grouped kernels, and no more temporary memory than the
+    longest rung alone needs (``lax.switch`` left to AD hands out every
+    branch's residuals: 7.09 GB against 1.96 GB here)."""
+    import functools
+
+    from tpu_ddp.models import moe
+
+    one = _one_chip(topo)
+    n, c, f, k, held = 16384, 2048, 512, 8, 32
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    rungs = moe.buffer_rungs(n * k, held, 256)
+    assert rungs == (32768, 131072)
+    walk = tuple(functools.partial(moe._routed, r, k, jnp.bfloat16)
+                 for r in rungs)
+    routing = (shape((n * k,), jnp.int32), shape((n * k,), jnp.int32),
+               shape((held,), jnp.int32), shape((n,), jnp.int32))
+    floats = (shape((n, c), jnp.bfloat16), shape((held, c, f), jnp.float32),
+              shape((held, c, f), jnp.float32),
+              shape((held, f, c), jnp.float32), shape((n, k), jnp.float32))
+
+    def loss(floats, index, routing):
+        return moe._switch(walk, index, routing, floats).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        floats, shape((), jnp.int32), routing).compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 2
+    assert text.count("ragged-dot") >= 2 * len(rungs)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
 # ---- the int8 ring's quantize / dequantize ----------------------------------
 
 def test_fused_quant_compiles_at_real_size(topo):

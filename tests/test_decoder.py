@@ -9,6 +9,7 @@ import dataclasses
 import os
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -162,6 +163,324 @@ def test_a_skewed_router_drops_no_token(ref):
     load = np.asarray(mut["counters"]["expert_load"][0])
     assert load[2] == 2 * tiny.T, load  # every token chose expert 2
     np.testing.assert_allclose(y, want, atol=2e-5)
+
+
+# -- the routed layer's ladder of buffer lengths ------------------------------
+# 32 experts, 8 a token, this share holds experts 8..15: of 2 x 128 tokens'
+# 2,048 (token, choice) pairs a fair router lands 512, so the ladder is
+# 1,024 and 2,048 rows (``moe.buffer_rungs``).
+
+L_EXPERTS, L_HELD, L_OFFSET, L_K, L_T, L_C, L_F = 32, 8, 8, 8, 128, 32, 24
+L_PAIRS = 2 * L_T * L_K
+L_RUNGS = (L_PAIRS // 2, L_PAIRS)
+
+
+class FullBufferMoE(nn.Module):
+    """``DroplessMoE.__call__`` as PR 27 left it, kept here word for word:
+    the sorted buffer has a row for every (token, choice), whatever landed.
+    What every rung is held to, and the program a whole share still is."""
+
+    share: object
+    top_k: int
+    expert_width: int
+    shared_width: int = 0
+    scaling: float = 1.0
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):  # (B, T, C) -> (B, T, C)
+        from tpu_ddp.models.moe import (SwiGLU, _collect, _spread,
+                                        grouped_matmul)
+        from tpu_ddp.telemetry.phases import module_scope
+
+        B, T, C = x.shape
+        E, held, offset = (self.share.num_experts, self.share.held,
+                           self.share.offset)
+        K, F = self.top_k, self.expert_width
+        xf = x.reshape(B * T, C).astype(self.dtype)
+        stacked = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
+        w_gate = self.param("w_gate", stacked, (held, C, F), jnp.float32)
+        w_up = self.param("w_up", stacked, (held, C, F), jnp.float32)
+        w_down = self.param("w_down", stacked, (held, F, C), jnp.float32)
+
+        with jax.named_scope(module_scope("moe_route")):
+            logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST,
+                              name="router")(xf.astype(jnp.float32))
+            scores, ids = jax.lax.top_k(jax.nn.sigmoid(logits), K)  # (N, K)
+            weights = (scores / scores.sum(axis=-1, keepdims=True)
+                       * self.scaling)
+            self.sow("intermediates", "expert_ids", ids)
+
+        with jax.named_scope(module_scope("moe_dispatch")):
+            local = ids.reshape(-1) - offset                    # (N*K,)
+            group = jnp.where((local >= 0) & (local < held), local, held)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            inverse = jnp.argsort(order).astype(jnp.int32)
+            load = jnp.sum(group[:, None] == jnp.arange(held)[None, :],
+                           axis=0, dtype=jnp.int32)             # (held,)
+            self.sow("counters", "expert_load", load)
+            real = (jnp.arange(order.shape[0]) < load.sum())[:, None]
+            keep = lambda a: jnp.where(real, a, 0)  # noqa: E731
+            rows = keep(_spread(xf, order, inverse, K))
+
+        with jax.named_scope(module_scope("moe_experts")):
+            w_in = jnp.concatenate([w_gate, w_up], axis=-1).astype(self.dtype)
+            h = keep(grouped_matmul(rows, w_in, load))
+            h = nn.silu(h[:, :F]) * h[:, F:]
+            out = keep(grouped_matmul(h, w_down.astype(self.dtype), load))
+
+        with jax.named_scope(module_scope("moe_combine")):
+            w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
+            y = _collect(out * w_sorted.astype(out.dtype), order, inverse, K)
+
+        if self.shared_width:
+            with jax.named_scope(module_scope("moe_shared")):
+                y = y + SwiGLU(self.shared_width, dtype=self.dtype,
+                               name="shared")(xf)
+        return y.reshape(B, T, C)
+
+
+def _layers(share=None):
+    from tpu_ddp.models.moe import DroplessMoE
+    from tpu_ddp.parallel.expert_parallel import ExpertShare
+
+    share = share or ExpertShare(L_EXPERTS, L_HELD, L_OFFSET)
+    kwargs = dict(top_k=L_K, expert_width=L_F, shared_width=L_F, scaling=2.5)
+    return DroplessMoE(share, **kwargs), FullBufferMoE(share, **kwargs)
+
+
+#: what each router lands on the held experts, and the rung that holds it
+ROUTERS = {
+    "fair": (None, L_RUNGS[0]),
+    "to_the_brim": (L_RUNGS[0], L_RUNGS[0]),
+    "one_over": (L_RUNGS[0] + 1, L_RUNGS[1]),
+    "skewed": (L_PAIRS, L_RUNGS[1]),
+}
+
+
+def _routed_case(kind, seed=0):
+    """(params, x) whose router lands what ``ROUTERS[kind]`` says. A token's
+    first three channels say which of three rows of the router it reads
+    (all eight choices held here; none; one), the other channels are noise
+    that the router hardly weighs."""
+    layer, _ = _layers()
+    keys = jax.random.split(jax.random.key(seed), 3)
+    x = np.array(jax.random.normal(keys[0], (2, L_T, L_C)))
+    tree = jax.tree.map(np.array, layer.init(keys[1], x)["params"])
+    landed = ROUTERS[kind][0]
+    if landed is None:
+        return jax.tree.map(jnp.asarray, tree), jnp.asarray(x)
+    here = np.arange(L_OFFSET, L_OFFSET + L_HELD)
+    there = np.arange(L_OFFSET + L_HELD, L_OFFSET + L_HELD + L_K)
+    router = 0.02 * np.array(jax.random.normal(keys[2], (L_C, L_EXPERTS)))
+    router[:3] = -3.0
+    router[0, here] = 3.0                      # eight pairs land
+    router[1, there] = 3.0                     # none lands
+    router[2, np.append(there[:-1], here[0])] = 3.0   # one lands
+    kinds = np.ones(2 * L_T, int)
+    kinds[:landed // L_K] = 0
+    kinds[landed // L_K:landed // L_K + landed % L_K] = 2
+    kinds = np.random.default_rng(seed).permutation(kinds)
+    x[..., :3] = np.eye(3)[kinds].reshape(2, L_T, 3)
+    tree["router"]["kernel"] = router
+    return jax.tree.map(jnp.asarray, tree), jnp.asarray(x)
+
+
+def _value_grads_counters(layer, tree, x, wrap=lambda f: f):
+    w = jax.random.normal(jax.random.key(17), x.shape)
+
+    def loss(tree, x):
+        y, sown = wrap(lambda tree, x: layer.apply(
+            {"params": tree}, x, mutable=["counters"]))(tree, x)
+        return jnp.sum(y * w), (y, sown["counters"])
+
+    with jax.default_matmul_precision("highest"):
+        (_, (y, counters)), grads = jax.value_and_grad(
+            loss, (0, 1), has_aux=True)(tree, x)
+    return y, grads, {k: np.asarray(v[0]) for k, v in counters.items()}
+
+
+def _assert_same(got, want, atol=2e-5):
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            g, w, atol=atol * float(jnp.max(jnp.abs(w))) + 1e-7,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_the_ladder_is_read_off_the_shapes_and_ends_at_every_pair():
+    from tpu_ddp.models.moe import buffer_rungs
+
+    # the benchmark cell: 16,384 tokens x 8 choices, 32 of 256 experts held
+    assert buffer_rungs(131072, 32, 256) == (32768, 131072)
+    assert buffer_rungs(L_PAIRS, L_HELD, L_EXPERTS) == L_RUNGS
+    assert buffer_rungs(131072, 256, 256) == (131072,)   # a whole share
+    assert buffer_rungs(131072, 128, 256) == (131072,)   # twice fair is all
+    # whole tiles of the grouped kernel: 188 fair rows are one tile of 512
+    assert buffer_rungs(3000, 1, 16) == (1024, 3000)
+    assert buffer_rungs(144, 4, 16) == (144,)            # the tiny decoder's
+
+
+@pytest.mark.parametrize("kind", list(ROUTERS))
+def test_every_rung_is_the_full_buffers_layer(kind):
+    """Output and gradients (input, router, experts, shared expert) at each
+    rung, with the buffer full to its last row and one row over."""
+    layer, full = _layers()
+    tree, x = _routed_case(kind)
+    y, grads, counters = _value_grads_counters(layer, tree, x)
+    want_y, want_grads, want_counters = _value_grads_counters(full, tree, x)
+    landed, rung = ROUTERS[kind]
+    if landed is not None:
+        assert counters["expert_load"].sum() == landed
+    assert counters["expert_rows_walked"] == rung
+    np.testing.assert_array_equal(counters["expert_load"],
+                                  want_counters["expert_load"])
+    np.testing.assert_allclose(y, want_y, atol=2e-5)
+    _assert_same(grads, want_grads)
+
+
+def test_rows_walked_is_the_rung_and_the_top_rung_drops_nothing(ref):
+    """A fair router walks the shortest buffer; one that sends every pair
+    here walks a row for every pair and is still the reference's layer."""
+    layer, _ = _layers()
+    walked = {}
+    for kind in ("fair", "skewed"):
+        tree, x = _routed_case(kind, seed=1)
+        p = {"moe.router": tree["router"]["kernel"],
+             **{f"moe.{k}": tree[k] for k in ("w_gate", "w_up", "w_down")},
+             **{f"moe.shared.{k}": tree["shared"][k]["kernel"]
+                for k in ("gate", "up", "down")}}
+        with jax.default_matmul_precision("highest"):
+            y, sown = layer.apply({"params": tree}, x, mutable=["counters"])
+            want = ref._moe({"num_experts_per_tok": L_K,
+                             "moe_routed_scaling_factor": 2.5}, p, x,
+                            (L_OFFSET, L_HELD), "float32_highest")
+        np.testing.assert_allclose(y, want, atol=2e-5)
+        walked[kind] = (int(sown["counters"]["expert_rows_walked"][0]),
+                        int(sown["counters"]["expert_load"][0].sum()))
+    assert walked["fair"][0] == L_RUNGS[0] > walked["fair"][1]
+    assert walked["skewed"] == (L_PAIRS, L_PAIRS)
+
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for v in value if isinstance(value, (tuple, list)) else (value,):
+            v = getattr(v, "jaxpr", v)
+            if hasattr(v, "eqns"):
+                yield v
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("passes", ["forward", "backward"])
+def test_the_shortest_rung_holds_no_wide_array_of_every_pair(passes):
+    """Inside the branch of the shortest rung, forward and backward, nothing
+    has a row for every (token, choice) except vectors of indices."""
+    layer, _ = _layers()
+    tree, x = _routed_case("fair")
+
+    def forward(tree, x):
+        return jnp.sum(layer.apply({"params": tree}, x))
+
+    fn = forward if passes == "forward" else jax.grad(forward, (0, 1))
+    switches = [e for e in _equations(jax.make_jaxpr(fn)(tree, x).jaxpr)
+                if e.primitive.name == "cond"]
+    assert len(switches) == (1 if passes == "forward" else 2)
+
+    def wide(branch):
+        return [v.aval.shape for e in _equations(branch.jaxpr)
+                for v in list(e.invars) + list(e.outvars)
+                if getattr(v.aval, "shape", ())[:1] == (L_PAIRS,)
+                and np.prod(v.aval.shape[1:]) > 1]
+
+    for switch in switches:
+        shortest, longest = switch.params["branches"]
+        assert wide(shortest) == []
+        assert (L_PAIRS, L_C) in wide(longest)    # the walk does find them
+
+
+def test_a_whole_share_is_the_parents_program():
+    """One rung: no ``cond`` is traced, and the lowered text, forward and
+    backward, is the full-buffer layer's to the byte."""
+    from tpu_ddp.parallel.expert_parallel import ExpertShare
+
+    layer, full = _layers(ExpertShare(L_EXPERTS, L_EXPERTS, 0))
+    x = jax.random.normal(jax.random.key(2), (2, L_T, L_C))
+    tree = layer.init(jax.random.key(3), x)["params"]
+
+    def forward(layer):
+        return lambda tree, x: jnp.sum(layer.apply({"params": tree}, x))
+
+    for fn in (forward, lambda layer: jax.grad(forward(layer), (0, 1))):
+        assert not [e for e in _equations(
+            jax.make_jaxpr(fn(layer))(tree, x).jaxpr)
+            if e.primitive.name == "cond"]
+        assert (jax.jit(fn(layer)).lower(tree, x).as_text()
+                == jax.jit(fn(full)).lower(tree, x).as_text())
+    _, sown = layer.apply({"params": tree}, x, mutable=["counters"])
+    assert int(sown["counters"]["expert_rows_walked"][0]) == L_PAIRS
+
+
+@pytest.mark.parametrize("kind", list(ROUTERS))
+def test_a_recomputed_rung_is_the_full_buffers_layer(kind):
+    layer, full = _layers()
+    tree, x = _routed_case(kind)
+    y, grads, counters = _value_grads_counters(layer, tree, x,
+                                               wrap=jax.checkpoint)
+    want_y, want_grads, _ = _value_grads_counters(full, tree, x)
+    assert counters["expert_rows_walked"] == ROUTERS[kind][1]
+    np.testing.assert_allclose(y, want_y, atol=2e-5)
+    _assert_same(grads, want_grads)
+
+
+@pytest.mark.parametrize("kinds", [("fair", "one_over"),
+                                   ("to_the_brim", "skewed")])
+def test_shards_take_their_own_rungs_under_shard_map(kinds):
+    """``data=2`` on the CPU mesh, the parameters replicated: each shard
+    switches on what landed on it, and the gradient of the replicated
+    parameters is the sum of the shards'."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    layer, full = _layers()
+    cases = [_routed_case(kind) for kind in kinds]
+    tree = cases[1][0]   # one set of weights; the router of the second case
+    x = jnp.concatenate([x for _, x in cases])
+    w = jax.random.normal(jax.random.key(17), x.shape)
+
+    def local(layer, tree, x, w):
+        y, sown = layer.apply({"params": tree}, x, mutable=["counters"])
+        return jnp.sum(y * w), sown["counters"].get(
+            "expert_rows_walked", (None,))[0]
+
+    def shard(tree, x, w):
+        def loss(tree, x):
+            value, walked = local(layer, tree, x, w)
+            return jax.lax.psum(value, "data"), walked
+        (_, walked), grads = jax.value_and_grad(
+            loss, (0, 1), has_aux=True)(tree, x)
+        return grads, walked[None]
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    with jax.default_matmul_precision("highest"):
+        (g_tree, g_x), walked = jax.jit(jax.shard_map(
+            shard, mesh=mesh, in_specs=(P(), P("data"), P("data")),
+            out_specs=((P(), P("data")), P("data"))))(tree, x, w)
+        want = [jax.grad(lambda tree, x, w=w: local(full, tree, x, w)[0],
+                         (0, 1))(tree, x, w)
+                for x, w in zip(jnp.split(x, 2), jnp.split(w, 2))]
+    # the first case's tokens read the second case's router: a fair router
+    # may become another, so its rung is read off what landed, not named
+    assert int(walked[1]) == ROUTERS[kinds[1]][1]
+    assert {int(walked[0])} <= set(L_RUNGS)
+    _assert_same(g_tree, jax.tree.map(jnp.add, want[0][0], want[1][0]))
+    _assert_same(g_x, jnp.concatenate([want[0][1], want[1][1]]))
 
 
 def test_expert_share_is_checked():

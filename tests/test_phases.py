@@ -64,6 +64,10 @@ STEP = "jit(shard_step)/shard_map/"
     ("jit(shard_multi)/shard_map/while/body/tpu_ddp.forward_backward/"
      "jvp(checkpoint)/NetResDeep/resblock/conv_general_dilated", "fusion",
      ("forward", "resblock")),
+    # inside the branch of a switch that a custom_vjp's backward rule made
+    (STEP + "tpu_ddp.forward_backward/transpose(jvp(SparseDecoder))/layer_1/"
+     "moe/cond/branch_0_fun/jvp(tpu_ddp.module.moe_combine)/jit(_take)/"
+     "gather", "fusion", ("backward", "moe_combine")),
     (STEP + "tpu_ddp.input/jit(_uniform)/mul", "fusion", ("input", "input")),
     (STEP + "tpu_ddp.bn_stats_sync/div", "fusion",
      ("grad_sync", "bn_stats_sync")),
@@ -78,6 +82,54 @@ STEP = "jit(shard_step)/shard_map/"
 def test_classify(op_name, opcode, expected):
     assert phases.classify(op_name, opcode) == expected
     assert expected[0] in phases.PHASES
+
+
+@pytest.mark.parametrize("opcode", ["conditional", "while", "call"])
+def test_an_instruction_that_only_runs_others_has_no_phase(opcode):
+    """Whatever scope it sits in: a trace shows it as long as what it runs,
+    and what it runs beside it."""
+    path = (STEP + "tpu_ddp.forward_backward/transpose(jvp(SparseDecoder))/"
+            "layer_1/moe/cond")
+    assert phases.classify(path, opcode) == (phases.CONTROL, "")
+    assert phases.CONTROL not in phases.PHASES
+
+
+def test_a_switch_is_mapped_as_control_and_its_branches_by_their_scopes():
+    moe = ("jit(shard_step)/tpu_ddp.forward_backward/jvp(SparseDecoder)/"
+           "layer_1/moe/cond")
+    inside = moe + "/branch_{}_fun/tpu_ddp.module.moe_experts/mul"
+    text = f'''HloModule jit_shard_step, is_scheduled=true
+
+%short (p.2: f32[8]) -> f32[8] {{
+  %p.2 = f32[8]{{0}} parameter(0)
+  ROOT %fusion.3 = f32[8]{{0}} fusion(%p.2), kind=kLoop, calls=%fused.1, metadata={{op_name="{inside.format(0)}"}}
+}}
+
+%long (p.4: f32[8]) -> f32[8] {{
+  %p.4 = f32[8]{{0}} parameter(0)
+  ROOT %fusion.5 = f32[8]{{0}} fusion(%p.4), kind=kLoop, calls=%fused.2, metadata={{op_name="{inside.format(1)}"}}
+}}
+
+ENTRY %main.9 (p.1: f32[8], i.1: s32[]) -> f32[8] {{
+  %p.1 = f32[8]{{0}} parameter(0), metadata={{op_name="batch"}}
+  %i.1 = s32[] parameter(1), metadata={{op_name="index"}}
+  %copy.6 = f32[8]{{0}} copy(%p.1)
+  ROOT %conditional.7 = f32[8]{{0}} conditional(%i.1, %copy.6, %copy.6), branch_computations={{%short, %long}}, metadata={{op_name="{moe}"}}
+}}
+'''
+    record = build_record(text, program="train_step")
+    rows = record["instructions"]
+    assert rows["conditional.7"]["phase"] == phases.CONTROL
+    assert rows["conditional.7"]["module"] == ""
+    # the copy the compiler made for the switch inherits no ``control``
+    assert rows["copy.6"]["phase"] == "other"
+    for name in ("fusion.3", "fusion.5"):
+        assert (rows[name]["phase"], rows[name]["module"]) == (
+            "forward", "moe_experts")
+    assert record["phases"][phases.CONTROL] == 1
+    assert sum(record["phases"].values()) == len(rows)
+    # a sum over the phases of the step never meets the switch's own time
+    assert sum(record["phases"][p] for p in phases.PHASES) == len(rows) - 1
 
 
 def test_a_fusion_that_holds_work_of_two_scopes_is_mixed():
@@ -182,6 +234,16 @@ ENTRY %main.9 (p.1: f32[8]) -> f32[8] {{
     rows = build_record(text, program="train_step")["instructions"]
     assert rows["ragged-dot-none.1"] == {
         "op_name": "ragged-dot-none", "opcode": "custom-call",
+        "phase": "backward", "module": "moe_experts", "inherited": True}
+    # inside a jitted function that the compiler inlined, the call's path
+    # stands before the compiler's name: where it was called, not its scope
+    called = moe.rsplit("/tpu_ddp.module", 1)[0] + "/jit(_routed)/"
+    rows = build_record(
+        text.replace('op_name="ragged-dot-none"',
+                     f'op_name="{called}ragged-dot-none"'),
+        program="train_step")["instructions"]
+    assert rows["ragged-dot-none.1"] == {
+        "op_name": called + "ragged-dot-none", "opcode": "custom-call",
         "phase": "backward", "module": "moe_experts", "inherited": True}
     assert rows["compare.4"]["phase"] == "other"
     assert "inherited" not in rows["compare.4"]

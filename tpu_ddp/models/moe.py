@@ -22,7 +22,7 @@ Expert-parallel layout rules live in ``tpu_ddp.parallel.expert_parallel``.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -267,38 +267,118 @@ def vit_moe_s4_top2(num_classes: int = 10, bn_cross_replica_axis=None,
 # a held expert is computed, whatever the imbalance. Beside ``MoEMlp`` (dense
 # one-hot dispatch, capacity, drops), which the ViT family keeps.
 
+#: rows of the grouped kernel's tile: a rung of the ladder is whole tiles
+_ROW_TILE = 512
+
+
+def buffer_rungs(pairs: int, held: int, num_experts: int):
+    """The lengths the sorted buffer may take, ascending, the last one
+    ``pairs`` (a row for every (token, choice): no capacity, no drop). A
+    fair router lands ``pairs * held / num_experts`` rows on the held
+    experts; the short rung is twice that, in whole tiles, where that is
+    shorter than ``pairs``. A whole share has the one rung. Two rungs and
+    not more: each is one more copy of the routed path to trace, lower,
+    compile and load (PERF.md section 6, PR 28)."""
+    fair = -(-pairs * held // num_experts)
+    short = 2 * -(-fair // _ROW_TILE) * _ROW_TILE
+    return (short, pairs) if short < pairs else (pairs,)
+
+
+class _ByToken(NamedTuple):
+    """Where a short buffer's landed rows sit once put in token order: a
+    token's rows side by side, in the order of its choices. ``R + k - 1``
+    places, so that the ``k`` from any place on are there to read."""
+
+    rows: jax.Array    # the buffer's row at each place of token order
+    token: jax.Array   # that row's token; past landed, no token's number
+    count: jax.Array   # (N,) rows each token landed
+
+
+def _by_token(order, landed, count, k):
+    """``order`` is the first ``R`` of the sorted buffer's (token, choice)
+    pairs, ``landed`` of them real. One sort of ``R`` keys."""
+    tokens = count.shape[0]
+    pair = jnp.where(jnp.arange(order.shape[0]) < landed, order, tokens * k)
+    rows = jnp.argsort(pair).astype(jnp.int32)
+    return _ByToken(
+        jnp.concatenate([rows, jnp.zeros((k - 1,), jnp.int32)]),
+        jnp.concatenate([jnp.take(pair, rows) // k,
+                         jnp.full((k - 1,), tokens + 1, jnp.int32)]),
+        count)
+
+
 def _spread_rows(x, order, k):
     return jnp.take(x, order // k, axis=0)
 
 
-def _collect_rows(y, inverse, k):
-    rows = jnp.take(y, inverse, axis=0).astype(jnp.float32)
-    return rows.reshape(-1, k, y.shape[-1]).sum(axis=1).astype(y.dtype)
+def _collect_rows(y, back, k):
+    if not isinstance(back, _ByToken):  # a row for every pair: by ``inverse``
+        rows = jnp.take(y, back, axis=0).astype(jnp.float32)
+        return rows.reshape(-1, k, y.shape[-1]).sum(axis=1).astype(y.dtype)
+    # a short buffer: its rows in token order, at each place the float32 sum
+    # of the rows of that place's token from there on (at most ``k``, in the
+    # order of the choices, as the full buffer sums them), and of these each
+    # token's first
+    rows = jnp.take(y, back.rows, axis=0, mode="clip")
+    rung = y.shape[0]
+    total = rows[:rung].astype(jnp.float32)
+    for j in range(1, k):
+        same = (back.token[j:j + rung] == back.token[:rung])[:, None]
+        total = total + jnp.where(same, rows[j:j + rung], 0).astype(
+            jnp.float32)
+    first = jnp.cumsum(back.count) - back.count
+    sums = jnp.take(total.astype(y.dtype), first, axis=0, mode="clip")
+    return jnp.where((back.count > 0)[:, None], sums, 0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _spread(x, order, inverse, k):
-    """Row ``a`` of the result is ``x[order[a] // k]``: each of ``x``'s rows
-    repeated ``k`` times (one per choice), in the order ``order`` gives. The
+def _spread(x, order, back, k):
+    """Row ``a`` of the result is ``x[order[a] // k]``: ``x``'s rows, one per
+    (token, choice) that ``order`` names, in the order it gives. The
     transpose of ``_collect``; both ways are gathers, never a scatter."""
     return _spread_rows(x, order, k)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _collect(y, order, inverse, k):
+def _collect(y, order, back, k):
     """Rows of ``y`` put back where ``order`` took them from and summed over
-    each token's ``k`` choices, in float32: ``(R, C) -> (R // k, C)``."""
-    return _collect_rows(y, inverse, k)
+    each token's choices, in float32: ``(R, C) -> (N, C)``. ``back`` says
+    where: the inverse of ``order`` when ``y`` has a row for every (token,
+    choice), a ``_ByToken`` when it has the first ``R`` of them."""
+    return _collect_rows(y, back, k)
 
 
 _spread.defvjp(
-    lambda x, order, inverse, k: (_spread_rows(x, order, k),
-                                  (order, inverse)),
+    lambda x, order, back, k: (_spread_rows(x, order, k), (order, back)),
     lambda k, res, g: (_collect_rows(g, res[1], k), None, None))
 _collect.defvjp(
-    lambda y, order, inverse, k: (_collect_rows(y, inverse, k),
-                                  (order, inverse)),
+    lambda y, order, back, k: (_collect_rows(y, back, k), (order, back)),
     lambda k, res, g: (_spread_rows(g, res[0], k), None, None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _switch(branches, index, routing, floats):
+    """``branches[index](routing, *floats)``: ``routing`` integers, the
+    result differentiated in ``floats``.
+    Its residuals are its operands, and the backward pass switches again:
+    ``lax.switch`` left to AD hands out every branch's residuals, zeros for
+    the ones not taken."""
+    return jax.lax.switch(index, branches, routing, *floats)
+
+
+def _switch_bwd(branches, res, g):
+    index, routing, floats = res
+    pulls = [lambda routing, floats, g, branch=branch: jax.vjp(
+        functools.partial(branch, routing), *floats)[1](g)
+        for branch in branches]
+    return None, None, jax.lax.switch(index, pulls, routing, floats, g)
+
+
+_switch.defvjp(
+    lambda branches, index, routing, floats: (
+        jax.lax.switch(index, branches, routing, *floats),
+        (index, routing, floats)),
+    _switch_bwd)
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -313,6 +393,45 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
     with jax.named_scope(kernel_scope("grouped_matmul")):
         return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+def _routed(rung, k, dtype, routing, xf, w_gate, w_up, w_down, weights):
+    """The held experts' part of the layer over a sorted buffer of ``rung``
+    rows: ``xf`` (N, C) tokens, ``weights`` (N, k) of their choices,
+    ``routing`` = ``order``, ``inverse`` (N * k,), ``load`` (held,) and, for
+    a buffer shorter than ``order``, the rows each token landed (N,).
+    ``rung`` holds what landed (``load.sum()``): the caller's to see to."""
+    from tpu_ddp.telemetry.phases import module_scope
+
+    order, back, load = routing[:3]
+    width = w_gate.shape[-1]
+    with jax.named_scope(module_scope("moe_dispatch")):
+        if rung < order.shape[0]:
+            order = order[:rung]
+            back = _by_token(order, load.sum(), routing[3], k)
+        # the buffer's first ``landed`` rows are real. What a grouped
+        # product leaves in the others is unspecified (on the chip:
+        # whatever the memory held, NaN included), so each of its results
+        # is selected to zero there before anything reads it, forward and
+        # (the select's transpose) backward
+        real = (jnp.arange(rung) < load.sum())[:, None]
+        keep = lambda a: jnp.where(real, a, 0)  # noqa: E731
+        rows = keep(_spread(xf, order, back, k))
+
+    with jax.named_scope(module_scope("moe_experts")):
+        w_in = jnp.concatenate([w_gate, w_up], axis=-1).astype(dtype)
+        h = keep(grouped_matmul(rows, w_in, load))
+        h = nn.silu(h[:, :width]) * h[:, width:]
+        out = keep(grouped_matmul(h, w_down.astype(dtype), load))
+
+    with jax.named_scope(module_scope("moe_combine")):
+        w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
+        return _collect(out * w_sorted.astype(out.dtype), order, back, k)
+
+
+#: traced once per rung and shapes: the layers of a stack, the forward and
+#: the backward rule of ``_switch`` and a recomputed layer share the trace
+_routed_once = jax.jit(_routed, static_argnums=(0, 1, 2))
 
 
 class SwiGLU(nn.Module):
@@ -344,12 +463,20 @@ class DroplessMoE(nn.Module):
     would add is theirs to add. ``sum over shares of (y - shared) + shared``
     is the whole layer.
 
-    Static shapes without drops: the sorted buffer has a row for every
-    (token, choice), the held ones first; the grouped products compute only
-    the rows of held experts, however many those are.
+    Static shapes without drops: the (token, choice) pairs are sorted by
+    expert, the held ones first, and the routed path (``_routed``) walks the
+    first ``R`` rows of that order, ``R`` a rung of a short static ladder
+    read off the shapes and the share (``buffer_rungs``: twice what a fair
+    router lands here, and a row for every pair).
+    Each call counts what landed and takes, on the device, the shortest
+    rung that holds it (``_switch``; per shard under ``shard_map``), so a
+    router however skewed drops nothing, and a whole share has the one rung
+    and traces no switch. The grouped products compute only the rows of
+    held experts, however many those are.
 
     Sows ``counters/expert_load``: (held,) int32, the (token, choice) pairs
-    each held expert got this call. Stacked expert weights: ``w_gate``,
+    each held expert got this call; and ``counters/expert_rows_walked``:
+    int32, the rung this call took. Stacked expert weights: ``w_gate``,
     ``w_up`` (held, C, F), ``w_down`` (held, F, C), so that expert
     parallelism is a ``PartitionSpec`` on the leading axis.
     """
@@ -393,24 +520,32 @@ class DroplessMoE(nn.Module):
             load = jnp.sum(group[:, None] == jnp.arange(held)[None, :],
                            axis=0, dtype=jnp.int32)             # (held,)
             self.sow("counters", "expert_load", load)
-            # the sorted buffer's first ``landed`` rows are real. What a
-            # grouped product leaves in the others is unspecified (on the
-            # chip: whatever the memory held, NaN included), so each of its
-            # results is selected to zero there before anything reads it,
-            # forward and (the select's transpose) backward
-            real = (jnp.arange(order.shape[0]) < load.sum())[:, None]
-            keep = lambda a: jnp.where(real, a, 0)  # noqa: E731
-            rows = keep(_spread(xf, order, inverse, K))
 
-        with jax.named_scope(module_scope("moe_experts")):
-            w_in = jnp.concatenate([w_gate, w_up], axis=-1).astype(self.dtype)
-            h = keep(grouped_matmul(rows, w_in, load))
-            h = nn.silu(h[:, :F]) * h[:, F:]
-            out = keep(grouped_matmul(h, w_down.astype(self.dtype), load))
-
-        with jax.named_scope(module_scope("moe_combine")):
-            w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
-            y = _collect(out * w_sorted.astype(out.dtype), order, inverse, K)
+        rungs = buffer_rungs(order.shape[0], held, E)
+        walk = [functools.partial(_routed_once, rung, K, self.dtype)
+                for rung in rungs]
+        routing = (order, inverse, load)
+        if len(rungs) == 1:
+            y = _routed(rungs[0], K, self.dtype, routing, xf, w_gate, w_up,
+                        w_down, weights)
+            walked = rungs[0]
+        else:  # the shortest buffer that holds what landed, each call
+            # the weights cross the switch as the matrix unit takes them,
+            # and their gradients come back so: in float32 the gradients
+            # of a layer's experts are 0.4 GB that nothing else would hold
+            with jax.named_scope(module_scope("moe_experts")):
+                floats = (xf, w_gate.astype(self.dtype),
+                          w_up.astype(self.dtype), w_down.astype(self.dtype),
+                          weights)
+            with jax.named_scope(module_scope("moe_dispatch")):
+                landed = load.sum()
+                index = sum((landed > rung).astype(jnp.int32)
+                            for rung in rungs[:-1])
+                count = jnp.sum(group.reshape(-1, K) < held, axis=1,
+                                dtype=jnp.int32)                # (N,)
+                walked = jnp.asarray(rungs, jnp.int32)[index]
+            y = _switch(tuple(walk), index, routing + (count,), floats)
+        self.sow("counters", "expert_rows_walked", jnp.int32(walked))
 
         if self.shared_width:
             with jax.named_scope(module_scope("moe_shared")):

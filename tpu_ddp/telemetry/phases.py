@@ -28,6 +28,12 @@ from typing import Tuple
 FORWARD, BACKWARD, OPTIMIZER = "forward", "backward", "optimizer"
 GRAD_SYNC, INPUT, OTHER = "grad_sync", "input", "other"
 PHASES = (FORWARD, BACKWARD, OPTIMIZER, GRAD_SYNC, INPUT, OTHER)
+#: not a phase: an instruction that only runs other instructions of the map
+#: (a ``conditional``'s branch, a ``while``'s body). A device trace shows it
+#: as one operation as long as everything it runs, and those beside it, so
+#: its time is theirs: whoever sums operations by phase leaves it out
+CONTROL = "control"
+CONTROL_OPCODES = frozenset(("conditional", "while", "call"))
 
 #: scopes a step builder uses (a new builder or kernel takes these, or adds
 #: its own ``tpu_ddp.`` scope to ``SCOPE_PHASES`` below)
@@ -178,11 +184,14 @@ def _model_module(names, anchor: int) -> str:
 def classify(op_name: str, opcode: str = "") -> Tuple[str, str]:
     """``(phase, module)`` of one instruction from its ``op_name`` (and,
     where the caller has it, its HLO opcode: a collective is ``grad_sync``
-    whatever scope it sits in).
+    whatever scope it sits in; a ``conditional`` or a ``while`` is
+    ``CONTROL``, no phase, whatever it runs).
 
     An ``op_name`` that joins several paths with ``;`` takes the phase
     they agree on, else the first path's; :func:`is_mixed` says which it
     was."""
+    if opcode in CONTROL_OPCODES:
+        return CONTROL, ""
     paths = [p for p in (op_name or "").split(";") if p.strip()]
     if not paths:
         if opcode.startswith(COLLECTIVE_OPCODES):

@@ -20,9 +20,13 @@ directory (the sink grammar of ``telemetry.sink_file_name``):
                                     "phase": "backward", "module": "head"}}}
 
 An instruction the compiler made itself has no ``op_name`` (a custom call
-it emits for one primitive has only its own name for it, no path); it takes
-the phase of the instruction that uses its result and says
-``"inherited": true``.
+it emits for one primitive has only its own name for it, after the path of
+the jitted function's call where it sits in one); it takes the phase of the
+instruction that uses its result and says ``"inherited": true``.
+A ``conditional`` or a ``while`` has the phase ``"control"``, which is none:
+a trace shows it for as long as the instructions of the branch or body it
+runs, and shows those too, so a sum by phase that counted it would count
+their time twice (``phases.CONTROL``).
 A fusion takes the phase of the ``op_name`` the compiler left on it; where
 the instructions fused into it disagree on the phase (a weight-gradient
 convolution with the optimizer's update as its epilogue), it says
@@ -78,8 +82,8 @@ def build_record(hlo_text: str, *, program: str, dispatch: int = 0) -> dict:
         table[ins["name"]] = row
     _inherit(instructions, table)
     by_phase = {phase: 0 for phase in phases.PHASES}
-    for row in table.values():
-        by_phase[row["phase"]] += 1
+    for row in table.values():  # ``control`` is counted where there is any
+        by_phase[row["phase"]] = by_phase.get(row["phase"], 0) + 1
     return {
         "type": "program_map",
         "schema_version": PROGRAM_MAP_SCHEMA_VERSION,
@@ -103,16 +107,27 @@ def _inherit(instructions, table) -> None:
     and module of the first instruction that uses its result, else of its
     first named operand, and is marked ``inherited``: where the data goes
     is where the work belongs."""
-    def unnamed(ins):
+    def compilers_own(ins):
         # a kernel the compiler emits itself for one primitive (XLA:TPU's
         # grouped product for ``lax.ragged_dot``) is a custom call that
         # keeps the compiler's name for it, ``ragged-dot-none``, and no path
-        if ins["opcode"] == "custom-call" and "/" not in ins["op_name"]:
+        # of the program's; inside a jitted function that the compiler
+        # inlined it has the path of the call before that name, which says
+        # where the function was called and not which scope the work is in
+        if ins["opcode"] != "custom-call":
+            return False
+        last = ins["op_name"].rsplit("/", 1)[-1]
+        return "/" not in ins["op_name"] or (
+            bool(last) and ins["name"].startswith(last))
+
+    def unnamed(ins):
+        if compilers_own(ins):
             return True
         return not ins["op_name"] and ins["opcode"] not in _NEVER_RUN
 
     def take(row, source):
-        if source is not None and source["phase"] != phases.OTHER:
+        if source is not None and source["phase"] not in (
+                phases.OTHER, phases.CONTROL):  # a switch hands on no phase
             row.update(phase=source["phase"], module=source["module"],
                        inherited=True)
             return True
@@ -120,6 +135,8 @@ def _inherit(instructions, table) -> None:
 
     first_user = {}
     for ins in instructions:
+        if compilers_own(ins):  # whatever its call's path made of it
+            table[ins["name"]].update(phase=phases.OTHER, module="")
         for operand in ins["operands"]:
             first_user.setdefault(operand, ins["name"])
     # users follow their operands in a scheduled computation: backwards,
