@@ -133,13 +133,13 @@ def _scan_point(
     from tpu_ddp.data import synthetic_cifar10
     from tpu_ddp.metrics.mfu import compiled_flops, mfu
     from tpu_ddp.parallel import MeshSpec, create_mesh, stacked_batch_sharding
-    from tpu_ddp.train import create_train_state, make_scan_train_step
+    from tpu_ddp.train import create_train_state, make_train_step
 
     devices = jax.devices()
     n_chips = len(devices)
     mesh = create_mesh(MeshSpec(data=-1), devices)
     state = create_train_state(model, tx, jax.random.key(0))
-    step = make_scan_train_step(model, tx, mesh, steps_per_call=steps_per_call)
+    step = make_train_step(model, tx, mesh, steps_per_call=steps_per_call)
 
     global_batch = per_shard * n_chips
     imgs, labels = synthetic_cifar10(steps_per_call * global_batch, seed=seed)

@@ -52,7 +52,6 @@ def main() -> None:
     from tpu_ddp.train import (
         create_train_state,
         make_optimizer,
-        make_scan_train_step,
         make_train_step,
     )
 
@@ -175,8 +174,7 @@ def main() -> None:
         for per_shard in (32, 256):
             def sweep(k=k, per_shard=per_shard):
                 model, tx = NetResDeep(), make_optimizer(lr=1e-2)
-                step = make_scan_train_step(model, tx, mesh,
-                                            steps_per_call=k)
+                step = make_train_step(model, tx, mesh, steps_per_call=k)
                 return step.trace(astate(model, tx),
                                   stacked_batch(k, per_shard))
             jobs.append((f"sweep_scan{k}_b{per_shard}", sweep))
@@ -196,7 +194,7 @@ def main() -> None:
         model = MODEL_REGISTRY["resnet50"](num_classes=10,
                                            dtype=jnp.bfloat16)
         tx = make_optimizer(lr=1e-1, momentum=0.9)
-        step = make_scan_train_step(model, tx, mesh, steps_per_call=8)
+        step = make_train_step(model, tx, mesh, steps_per_call=8)
         return step.trace(astate(model, tx), stacked_batch(8, 256))
 
     jobs.append(("compute_fused_scan8_b256", fused))

@@ -345,7 +345,7 @@ def test_watchdog_abort_escalates_after_dump(monkeypatch):
         while not exits and time.monotonic() < deadline:
             time.sleep(0.01)
     finally:
-        dog.stop()
+        dog.close()
     assert exits and exits[0] == wd.HANG_EXIT_CODE
     assert dumps and "thread stacks follow" in dumps[0]
 
@@ -362,7 +362,7 @@ def test_watchdog_without_abort_only_dumps():
         while not dumps and time.monotonic() < deadline:
             time.sleep(0.01)
     finally:
-        dog.stop()
+        dog.close()
     assert dog.fired and dumps  # and the process is, visibly, alive
 
 

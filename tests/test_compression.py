@@ -37,7 +37,6 @@ from tpu_ddp.parallel.compression import (
 from tpu_ddp.parallel.mesh import replicated_sharding
 from tpu_ddp.parallel.zero import Zero1Partition
 from tpu_ddp.train import create_train_state, make_optimizer, make_train_step
-from tpu_ddp.train.steps import make_scan_train_step
 
 _ATOL = 1e-5  # float32 reduction-order drift (same pin as test_zero1)
 
@@ -355,7 +354,7 @@ def test_scan_step_carries_residual(devices):
     s0 = jax.device_put(state, replicated_sharding(mesh)).replace(
         grad_residual=comp.init_residual(mesh))
     single = make_train_step(model, tx, mesh, donate=False, compress=comp)
-    fused = make_scan_train_step(
+    fused = make_train_step(
         model, tx, mesh, steps_per_call=K, donate=False, compress=comp)
     s_seq = s0
     seq_losses = []
@@ -374,7 +373,7 @@ def test_scan_step_carries_residual(devices):
     comp8 = GradCompressor(
         GradCompression(mode="int8", block=64, error_feedback=True),
         state.params, 4)
-    fused8 = make_scan_train_step(
+    fused8 = make_train_step(
         model, tx, mesh, steps_per_call=K, donate=False, compress=comp8)
     s8, m8 = fused8(
         s0.replace(grad_residual=comp8.init_residual(mesh)), stacked)

@@ -280,7 +280,7 @@ def test_stage_monitor_health_and_stall_hook_order(tmp_path):
 
     mon = StageMonitor(str(tmp_path), stall_hook=hook,
                        min_write_interval_s=0.0)
-    mon.set_step(7)
+    mon.on_step(7)
     mon.stage_enter("gather")
     mon.stage_exit("gather", 0.01, 1024)
     mon.stage_enter("augment")  # never exits: left wedged
@@ -650,7 +650,7 @@ def test_hang_bundle_names_suspect_stage(tmp_path):
     from tpu_ddp.comms.forensics import write_hang_bundle
 
     mon = StageMonitor(str(tmp_path), min_write_interval_s=0.0)
-    mon.set_step(5)
+    mon.on_step(5)
     mon.stage_enter("collate")  # wedged
     rec = write_hang_bundle(str(tmp_path))
     assert rec["suspect_stage"]["stage"] == "collate"

@@ -87,8 +87,8 @@ def test_ema_state_inherits_param_shardings():
 
 def test_ema_updates_inside_scan_fused_step():
     """The flagship config fuses K optimizer steps into one dispatch
-    (make_scan_train_step); the EMA shadow must advance once per INNER
-    step, not once per dispatch — K fused steps and K unfused steps from
+    (``make_train_step(steps_per_call=K)``); the EMA shadow must advance once
+    per INNER step, not once per dispatch — K fused steps and K unfused steps from
     the same start must produce the same shadow."""
     from tpu_ddp.data import synthetic_cifar10
     from tpu_ddp.models import NetResDeep
@@ -100,7 +100,6 @@ def test_ema_updates_inside_scan_fused_step():
     )
     from tpu_ddp.train import (
         create_train_state,
-        make_scan_train_step,
         make_train_step,
     )
 
@@ -114,8 +113,8 @@ def test_ema_updates_inside_scan_fused_step():
     imgs = imgs.astype(np.float32)
 
     fused_state = create_train_state(model, tx, jax.random.key(0))
-    fused = make_scan_train_step(model, tx, mesh, steps_per_call=K,
-                                 donate=False)
+    fused = make_train_step(model, tx, mesh, steps_per_call=K,
+                            donate=False)
     batch_k = jax.device_put(
         {"image": imgs.reshape(K, gb, 32, 32, 3),
          "label": labels.reshape(K, gb),

@@ -22,12 +22,7 @@ from tpu_ddp.health.monitor import HealthMonitor, SpikeDetector
 from tpu_ddp.health.summarize import summarize_health
 from tpu_ddp.models import NetResDeep
 from tpu_ddp.parallel import MeshSpec, create_mesh
-from tpu_ddp.train import create_train_state, make_optimizer
-from tpu_ddp.train.steps import (
-    make_grad_accum_train_step,
-    make_scan_train_step,
-    make_train_step,
-)
+from tpu_ddp.train import create_train_state, make_optimizer, make_train_step
 from tpu_ddp.telemetry import reset_default_registry
 from tpu_ddp.train.trainer import TrainConfig, Trainer
 
@@ -153,10 +148,9 @@ def test_dp_parity_bitwise(devices):
 def test_grad_accum_parity_bitwise(devices):
     mesh = create_mesh(MeshSpec(data=-1))
     model, tx = _model(), make_optimizer(lr=0.01)
-    off = make_grad_accum_train_step(model, tx, mesh, accum_steps=2,
-                                     donate=False)
-    on = make_grad_accum_train_step(model, tx, mesh, accum_steps=2,
-                                    donate=False, health=HC)
+    off = make_train_step(model, tx, mesh, accum_steps=2, donate=False)
+    on = make_train_step(model, tx, mesh, accum_steps=2, donate=False,
+                         health=HC)
     s_off = create_train_state(model, tx, jax.random.key(1))
     s_on = create_train_state(model, tx, jax.random.key(1))
     for i in range(2):
@@ -191,8 +185,8 @@ def test_sp_parity_bitwise(devices):
 def test_scan_fused_health_carries_step_axis(devices):
     mesh = create_mesh(MeshSpec(data=-1))
     model, tx = _model(), make_optimizer(lr=0.01)
-    step = make_scan_train_step(model, tx, mesh, steps_per_call=3,
-                                donate=False, health=HC)
+    step = make_train_step(model, tx, mesh, steps_per_call=3,
+                           donate=False, health=HC)
     stacked = {
         k: np.stack([_batch(i)[k] for i in range(3)]) for k in _batch(0)
     }

@@ -153,14 +153,13 @@ def test_scan_multi_step_matches_sequential(devices):
     identical params, identical per-step losses (dispatch amortization must
     not change semantics)."""
     from tpu_ddp.parallel import stacked_batch_sharding
-    from tpu_ddp.train import make_scan_train_step
 
     K, n_dev, per_shard = 4, 8, 4
     mesh = create_mesh(MeshSpec(data=-1))
     model = NetResDeep(n_blocks=2)
     tx = make_optimizer(lr=0.05)
     step = make_train_step(model, tx, mesh, donate=False)
-    multi = make_scan_train_step(
+    multi = make_train_step(
         model, tx, mesh, steps_per_call=K, donate=False
     )
 

@@ -159,10 +159,10 @@ def test_watchdog_fires_on_stalled_step(tmp_path):
         poll_interval=0.02,
     ).start()
     try:
-        wd.beat(step=12)
+        wd.on_step(step=12)
         time.sleep(0.5)  # the "stalled step"
     finally:
-        wd.stop()
+        wd.close()
     assert wd.fired and wd.fire_count == 1  # one dump per stall episode
     assert "thread" in dumps[0] and "tpu_ddp watchdog" in dumps[0]
     # heartbeat file records the last completed step
@@ -178,10 +178,10 @@ def test_watchdog_silent_on_healthy_run(tmp_path):
     wd = HangWatchdog(0.3, poll_interval=0.02).start()
     try:
         for step in range(10):
-            wd.beat(step)
+            wd.on_step(step)
             time.sleep(0.03)  # healthy cadence well inside the deadline
     finally:
-        wd.stop()
+        wd.close()
     assert not wd.fired
 
 
@@ -392,44 +392,44 @@ def test_watchdog_heartbeat_freshness_contract(tmp_path):
     wd = HangWatchdog(0.3, heartbeat_dir=str(tmp_path), poll_interval=10.0)
     path = tmp_path / "heartbeat-p0.json"
 
-    wd.beat(step=1)
+    wd.on_step(step=1)
     rec1 = read_heartbeat(str(path))
     assert rec1["step"] == 1 and rec1["pid"] > 0
     assert heartbeat_age_seconds(rec1) < 5.0
 
     # within the rate limit the file does NOT advance (atomic writes are
     # throttled to 1/sec so a hot step loop can't thrash the filesystem)
-    wd.beat(step=2)
+    wd.on_step(step=2)
     assert read_heartbeat(str(path))["step"] == 1
     # past the limiter it must advance (simulate >1s elapsing)
     wd._last_file_write -= 2.0
-    wd.beat(step=3)
+    wd.on_step(step=3)
     assert read_heartbeat(str(path))["step"] == 3
 
     # freshness predicates: fresh now, stale exactly past the deadline
     assert wd.seconds_since_beat() < 0.3 and not wd.is_stale()
     wd._last_beat -= 0.5  # no beat for 0.5s > 0.3s deadline
     assert wd.is_stale()
-    wd.beat(step=4)  # a beat re-arms freshness
+    wd.on_step(step=4)  # a beat re-arms freshness
     assert not wd.is_stale()
 
     # stop() force-flushes the FINAL step past the rate limiter
-    wd.beat(step=5)
-    wd.stop()
+    wd.on_step(step=5)
+    wd.close()
     assert read_heartbeat(str(path))["step"] == 5
 
 
 def test_watchdog_staleness_fires_at_deadline_not_before(tmp_path):
     wd = HangWatchdog(0.25, poll_interval=0.02).start()
     try:
-        wd.beat(0)
+        wd.on_step(0)
         time.sleep(0.15)  # inside the deadline: silent and fresh
         assert not wd.fired and not wd.is_stale()
         time.sleep(0.25)  # now past it: predicate and dump agree
         assert wd.is_stale()
         assert wd.fired
     finally:
-        wd.stop()
+        wd.close()
 
 
 def _write_multihost_traces(tmp_path, p50s_ms):

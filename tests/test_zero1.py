@@ -24,10 +24,6 @@ from tpu_ddp.parallel.mesh import replicated_sharding
 from tpu_ddp.parallel.zero import Zero1Partition, clip_by_global_norm_sharded
 from tpu_ddp.train import create_train_state, make_optimizer, make_train_step
 from tpu_ddp.train.optim import _decay_mask
-from tpu_ddp.train.steps import (
-    make_grad_accum_train_step,
-    make_scan_train_step,
-)
 
 _STEPS = 4
 _ATOL = 1e-5  # float32 reduction-order drift over _STEPS tiny-model steps
@@ -133,7 +129,7 @@ def test_zero1_scan_parity(devices):
     model = _model()
 
     def build(tx, part):
-        return make_scan_train_step(
+        return make_train_step(
             model, tx, mesh, steps_per_call=K, donate=False, zero1=part)
 
     tx_rep = make_optimizer(lr=1e-2, momentum=0.9)
@@ -165,7 +161,7 @@ def test_zero1_grad_accum_parity(devices):
     model = _model()
 
     def build(tx, part):
-        return make_grad_accum_train_step(
+        return make_train_step(
             model, tx, mesh, accum_steps=2, donate=False, zero1=part)
 
     s_rep, s_z, part, losses, _ = _run_pair(

@@ -272,6 +272,10 @@ class ChaosInjector:
                 continue
             getattr(self, f"_fire_{fault['kind']}")(fault_id, fault, step)
 
+    def close(self) -> None:
+        """Nothing is held open: the fire-once state is written as each
+        fault fires."""
+
     def _fire_data_stall(self, fault_id: int, fault: dict,
                          step: int) -> None:
         self._mark_fired(fault_id)

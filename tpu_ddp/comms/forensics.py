@@ -106,7 +106,7 @@ class HopMonitor:
         self._last_write = 0.0
         self._hops = 0
 
-    def set_step(self, step: int) -> None:
+    def on_step(self, step: int) -> None:
         self._step = int(step)
 
     # -- the hook itself (installed via set_ring_hop_hook) ---------------

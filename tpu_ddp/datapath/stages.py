@@ -69,7 +69,7 @@ class StageMonitor:
     an in-flight marker, and a chaos stall seam.
 
     Implements the loader's observer protocol (``stage_enter`` /
-    ``stage_exit``) plus the Trainer-facing ``set_step``/``close``.
+    ``stage_exit``) plus the Trainer-facing ``on_step``/``close``.
     The health file is rewritten atomically and throttled to
     ``min_write_interval_s``, except that entering a *different* stage
     than last written forces a write — a stall anywhere leaves the
@@ -101,7 +101,7 @@ class StageMonitor:
         self._last_write = 0.0
         self._write({}, time.monotonic(), force=True)
 
-    def set_step(self, step: int) -> None:
+    def on_step(self, step: int) -> None:
         with self._lock:
             self._step = int(step)
 

@@ -128,7 +128,7 @@ def batch_sharding(mesh: Mesh, axis: str = DATA_AXIS) -> NamedSharding:
 def stacked_batch_sharding(mesh: Mesh, axis: str = DATA_AXIS) -> NamedSharding:
     """Sharding for a K-stacked batch (K, global_batch, ...): the scan axis
     is replicated, the batch axis sharded — the input layout of
-    ``make_scan_train_step``."""
+    ``make_train_step(steps_per_call=K)``."""
     return NamedSharding(mesh, P(None, axis))
 
 

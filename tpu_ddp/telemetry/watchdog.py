@@ -11,7 +11,7 @@ that burns chips while emitting nothing. Two mechanisms:
   with ``cat`` — can see per-host liveness and the last completed step
   without attaching to the process.
 - **In-process deadline**: a daemon thread checks monotonic time since the
-  last ``beat()``. When the deadline passes it logs a stack dump of every
+  last ``on_step()``. When the deadline passes it logs a stack dump of every
   thread (so the wedge site is in the log even if the process is later
   SIGKILLed), emits a ``watchdog_hang`` telemetry instant, and bumps the
   ``watchdog/hangs`` counter. One dump per stall episode — a new beat
@@ -75,7 +75,7 @@ def all_stack_dump() -> str:
 
 
 class HangWatchdog:
-    """Deadline monitor over a ``beat()`` heartbeat.
+    """Deadline monitor over an ``on_step()`` heartbeat.
 
     Parameters
     ----------
@@ -145,7 +145,7 @@ class HangWatchdog:
         return self._last_step
 
     def seconds_since_beat(self) -> float:
-        """Age of the newest ``beat()`` — the freshness the ``/healthz``
+        """Age of the newest ``on_step()`` beat — the freshness the ``/healthz``
         endpoint and the staleness verdicts are computed from."""
         return time.monotonic() - self._last_beat
 
@@ -163,7 +163,7 @@ class HangWatchdog:
         self._thread.start()
         return self
 
-    def beat(self, step: Optional[int] = None) -> None:
+    def on_step(self, step: Optional[int] = None) -> None:
         """Mark progress: training completed a step (or another liveness
         boundary). Re-arms the stall dump and refreshes the heartbeat
         file (rate-limited to 1 write/sec, atomic)."""
@@ -195,7 +195,7 @@ class HangWatchdog:
         except OSError:  # heartbeat IO must never take down training
             pass
 
-    def stop(self) -> None:
+    def close(self) -> None:
         self._stop.set()
         if self._thread is not None:
             self._thread.join(timeout=5.0)

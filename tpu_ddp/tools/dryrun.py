@@ -20,7 +20,6 @@ def dryrun(n_devices: int) -> None:
     from tpu_ddp.parallel import MeshSpec, batch_sharding, create_mesh
     from tpu_ddp.train import (
         create_train_state,
-        make_grad_accum_train_step,
         make_optimizer,
         make_train_step,
     )
@@ -127,7 +126,7 @@ def dryrun(n_devices: int) -> None:
     # Gradient accumulation over the same mesh (one optimizer step, K
     # sequential microbatches per shard — the big-global-batch memory knob).
     t0 = time.perf_counter()
-    astep = make_grad_accum_train_step(model, tx, mesh, accum_steps=2)
+    astep = make_train_step(model, tx, mesh, accum_steps=2)
     astate = create_train_state(model, tx, jax.random.key(1))
     astate, ametrics = astep(astate, batch)
     jax.block_until_ready(astate.params)

@@ -10,8 +10,6 @@ from tpu_ddp.train.state import TrainState, create_train_state
 from tpu_ddp.train.losses import cross_entropy_loss, masked_accuracy
 from tpu_ddp.train.steps import (
     make_train_step,
-    make_scan_train_step,
-    make_grad_accum_train_step,
     make_eval_step,
 )
 from tpu_ddp.train.optim import make_optimizer
@@ -23,8 +21,6 @@ __all__ = [
     "cross_entropy_loss",
     "masked_accuracy",
     "make_train_step",
-    "make_scan_train_step",
-    "make_grad_accum_train_step",
     "make_eval_step",
     "make_optimizer",
     "Trainer",

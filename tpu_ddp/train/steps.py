@@ -14,9 +14,9 @@ becomes ONE compiled function per mesh:
 run under ``jax.shard_map`` so per-device semantics match DDP exactly:
 each device computes loss/grads on ITS shard with ITS batch-norm statistics
 (the reference has no SyncBatchNorm — BN normalizes per replica), and only
-gradients (and running stats, see note) cross the interconnect. XLA lowers
-the pmean to ICI all-reduce and overlaps it with the backward pass — the
-replacement for DDP's C++ bucketing Reducer (SURVEY.md §2.6).
+gradients (and running stats, see note) cross the interconnect. The pmean
+before AD is the all-reduce: it stands where DDP's C++ bucketing Reducer
+did (SURVEY.md §2.6); what it costs on the chip is PERF.md's to say.
 
 BN running stats: per-replica stats physically diverge across DDP ranks in
 the reference and rank 0's are the ones checkpointed (``main.py:45``). With a
@@ -61,10 +61,9 @@ from tpu_ddp.train.optim import apply_optimizer
 from tpu_ddp.train.state import TrainState
 from tpu_ddp.train.tasks import IMAGE_CLASSIFICATION, Task
 
-# Where the DDP gradient sync lives: the builders pmean the per-shard loss
-# BEFORE differentiation — AD's transpose of the replicated-params
-# pbroadcast IS the cross-shard psum (shard_map's check_vma rewrite), and
-# XLA overlaps it with the backward pass.
+# Where the DDP gradient sync lives: make_train_step pmeans the per-shard
+# loss BEFORE differentiation — AD's transpose of the replicated-params
+# pbroadcast IS the cross-shard psum (shard_map's check_vma rewrite).
 
 
 def resolve_remat(model, remat: bool):
@@ -135,12 +134,16 @@ def _bind_compressor(zero1, compress):
             )
 
 
-def _make_shard_step(
+def make_train_step(
     model,
     tx: optax.GradientTransformation,
+    mesh: Mesh,
     *,
+    accum_steps: int = 1,
+    steps_per_call: int = 1,
     data_axis: str = DATA_AXIS,
     loss_fn: Callable = cross_entropy_loss,
+    donate: bool = True,
     compute_accuracy: bool = True,
     remat: bool = False,
     augment: bool = False,
@@ -151,14 +154,54 @@ def _make_shard_step(
     zero1=None,
     compress=None,
     task: Task = IMAGE_CLASSIFICATION,
-):
-    """Per-shard train-step body shared by the single-step and scanned
-    variants: forward, pmean'd loss (the gradient allreduce), optax update.
+) -> Callable[[TrainState, Batch], tuple]:
+    """Build the compiled DDP train step for `mesh`: forward, the loss
+    whose pmean is the gradient all-reduce, one optax update.
+
+    Returns step(state, batch) -> (state, metrics) where batch is a global
+    {image, label, mask} dict sharded on its leading axis over `data_axis`.
+    ``compute_accuracy=False`` for losses whose labels aren't class indices
+    (e.g. multi-hot BCE targets). ``remat=True`` rematerializes the forward
+    during backward (jax.checkpoint) — trades FLOPs for HBM on deep models.
+    ``augment=True`` applies on-device random crop+flip to the shard's images
+    (keyed by step and shard index — reproducible across resume, distinct
+    per device; the recipe extension the reference lacks, SURVEY.md §7.3).
 
     ``task`` (``train/tasks.py``) says which array of the batch the model
     reads and which loss it takes: an image classifier's ``loss_fn`` over
     ``label``, or a decoder's masked next-token loss over its own
     ``tokens``. Everything else in the step is the same step.
+
+    ``accum_steps`` > 1 makes the ONE optimizer step over a global batch
+    too large to activate at once: each shard splits its rows into
+    ``accum_steps`` microbatches and accumulates their gradients with
+    ``lax.scan`` (activations for only one microbatch live at a time — the
+    trade the reference cannot express; its global batch is rigidly
+    per-process-batch × world size, ``main.py:61``), then everything after
+    the gradients runs once, on their average: one statistics sync, one
+    reduce-scatter or compressed ring, one update. With equal real counts
+    per microbatch the accumulated gradient equals the full-batch gradient
+    exactly (each microbatch's pmean-before-AD sync is preserved; the mean
+    over microbatches commutes with AD). With masked/unequal microbatches
+    the average weights microbatches equally — same approximation class as
+    every accumulation implementation. BatchNorm stats chain through the
+    scan (each microbatch normalizes by its own statistics, as the
+    reference's per-replica BN does per step). Per-shard rows must divide
+    by ``accum_steps``; what the model counted is summed over the
+    microbatches.
+
+    ``steps_per_call`` = K > 1 fuses K optimizer steps into ONE dispatch
+    via ``lax.scan``. The reference pays Python-interpreter + launcher
+    overhead every batch (the ``main.py:32-41`` hot loop crosses the host
+    boundary per step). Here the host stacks K global batches on a new
+    leading axis: every array in ``batch`` has shape (K, global_batch, ...)
+    sharded over ``data_axis`` on axis 1, and every metric leaf gains a
+    leading (K,) axis (per-step losses, in order — the trainer logs them
+    exactly as if stepped one by one). Under ``zero1`` the scattered
+    optimizer state rides the scan carry UNGATHERED: the K inner steps each
+    reduce-scatter fresh grads, update their shard, and all-gather only the
+    params — the shard state never re-replicates inside the fused dispatch.
+    Under ``compress`` the error-feedback residual likewise rides the carry.
 
     ``compress`` (a ``tpu_ddp.parallel.compression.GradCompressor``)
     swaps the gradient sync's wire format: without zero1 the pmean
@@ -180,10 +223,11 @@ def _make_shard_step(
     ``health`` compiles the numerics flight recorder into the step (see
     ``tpu_ddp.health.stats``): a ``metrics["health"]`` dict of global
     norms + finite-ness sentinels computed on the already-synchronized
-    gradients/updates, and (``skip_nonfinite``) the in-graph guard that
-    keeps the old params/batch_stats/opt_state when the update is
-    poisoned. ``health=None`` (default) leaves the traced step byte-
-    identical to a build without the feature.
+    gradients/updates (under accumulation: the average the optimizer
+    consumed), and (``skip_nonfinite``) the in-graph guard that keeps the
+    old params/batch_stats/opt_state when the update is poisoned.
+    ``health=None`` (default) leaves the traced step byte-identical to a
+    build without the feature.
 
     Models that sow auxiliary losses into the ``aux_loss`` collection (the
     MoE router's load-balance term, ``models.moe.MoEMlp``) get them added to
@@ -191,9 +235,16 @@ def _make_shard_step(
     model picked from the zoo trains correctly through this generic step,
     not only through ``make_ep_train_step``. Reported ``loss`` stays the
     task loss; the aux term appears as its own metric when present."""
-
+    if accum_steps < 1:
+        raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
+    if accum_steps > 1 and (augment or mixup_alpha > 0):
+        raise ValueError(
+            "--augment/--mixup-alpha are not yet supported with "
+            "--grad-accum-steps"
+        )
     model, remat = resolve_remat(model, remat)
     _bind_compressor(zero1, compress)
+    want_accuracy = compute_accuracy and task.accuracy
 
     def apply_model(params, batch_stats, images):
         return model.apply(
@@ -227,18 +278,66 @@ def _make_shard_step(
         # replicated (unvarying) params inserts the cross-shard psum
         # automatically under shard_map. Net effect: grads == grad of the
         # global mean loss, the exact semantics of DDP's NCCL allreduce-mean
-        # (main.py:63), with the collective visible to XLA for backward/comm
-        # overlap. (An explicit post-hoc pmean on grads would then DOUBLE-
-        # count: AD has already summed.)
+        # (main.py:63). (An explicit post-hoc pmean on grads would then
+        # DOUBLE-count: AD has already summed.)
         # Under zero1 the sync is the reduce-scatter in sharded_update, so
         # the loss must stay LOCAL (AD differentiates w.r.t. pcast-varying
         # params instead — zero1.varying below).
         # Under --grad-compress the sync is the quantized ring, which AD
-        # cannot own either — same local-loss convention.
+        # cannot own either — same local-loss convention. Both run AFTER
+        # any accumulation: one collective per optimizer step.
         if zero1 is None and compress is None:
             loss = lax.pmean(loss, data_axis)
         return loss, (mutated.get("batch_stats", batch_stats), logits,
                       task_loss, aux, mutated.get(COUNTERS))
+
+    def accumulate(grad_fn, p_in, batch_stats, batch):
+        """The gradient stage over ``accum_steps`` microbatches: what one
+        ``grad_fn`` call gives, averaged (the counters and the accuracy's
+        ``(correct, count)`` summed), with the statistics chained."""
+        b = batch[task.input_key].shape[0]
+        if b % accum_steps:
+            raise ValueError(
+                f"per-shard batch {b} not divisible by accum_steps "
+                f"{accum_steps}"
+            )
+        micros = jax.tree.map(
+            lambda x: x.reshape((accum_steps, b // accum_steps) + x.shape[1:]),
+            batch,
+        )
+
+        def accum(carry, micro):
+            grads_acc, stats = carry
+            with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+                (_, (stats, logits, task_loss, aux, counted)), grads = (
+                    grad_fn(p_in, stats, micro))
+            with jax.named_scope(GRAD_ACCUM_SCOPE):
+                grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
+            hits = None
+            if want_accuracy:
+                with jax.named_scope(METRICS_SCOPE):
+                    hits = masked_accuracy(
+                        logits, micro[task.target_key], micro.get("mask"))
+            # one row a microbatch of what is not carried
+            return (grads_acc, stats), (task_loss, aux, counted, hits)
+
+        # The accumulator takes the differentiation input's shapes (under
+        # zero3 state.params are flat shards) AND its varying type: under
+        # zero1/compress the grads are LOCAL, and zeros_like keeps p_in's
+        # varying-over-data marking so the scan carry types match. The
+        # fresh BN stats are computed from shard-local data, so the
+        # replicated incoming stats are cast to match.
+        zero_grads = jax.tree.map(jnp.zeros_like, p_in)
+        stats0 = jax.tree.map(
+            lambda s: lax.pcast(s, (data_axis,), to="varying"), batch_stats)
+        (grads_acc, new_stats), rows = lax.scan(
+            accum, (zero_grads, stats0), micros)
+        with jax.named_scope(GRAD_ACCUM_SCOPE):
+            grads = jax.tree.map(lambda g: g / accum_steps, grads_acc)
+            loss_sum, aux_sum, counters, hits = jax.tree.map(
+                lambda x: x.sum(axis=0), rows)
+            aux = None if aux_sum is None else aux_sum / accum_steps
+        return grads, new_stats, loss_sum / accum_steps, aux, counters, hits
 
     def shard_step(state: TrainState, batch: Batch):
         # Every part of the step sits in a scope of telemetry/phases.py:
@@ -274,7 +373,8 @@ def _make_shard_step(
                 # the double-buffered prefetch schedule (block k+1's
                 # all-gather rides under block k's compute —
                 # parallel/zero.py::Zero3Partition.stream_params). The
-                # gather sits OUTSIDE the grad closure, so the backward
+                # gather sits OUTSIDE the grad closure (and outside the
+                # accumulation scan: once a step), so the backward
                 # is re-gather-free: grads come out full-shaped and LOCAL
                 # (the gathered values are varying), exactly what the
                 # reduce-scatter below consumes.
@@ -285,9 +385,14 @@ def _make_shard_step(
             p_in = compress.varying(state.params)
         else:
             p_in = state.params
-        with jax.named_scope(FORWARD_BACKWARD_SCOPE):
-            (_, (new_stats, logits, task_loss, aux, counters)), grads = (
-                grad_fn(p_in, state.batch_stats, batch))
+        if accum_steps == 1:
+            with jax.named_scope(FORWARD_BACKWARD_SCOPE):
+                (_, (new_stats, logits, task_loss, aux, counters)), grads = (
+                    grad_fn(p_in, state.batch_stats, batch))
+            hits = None  # read from the logits where the metrics are made
+        else:
+            grads, new_stats, task_loss, aux, counters, hits = accumulate(
+                grad_fn, p_in, state.batch_stats, batch)
         with jax.named_scope(STATS_SYNC_SCOPE):
             new_stats = jax.tree.map(
                 lambda s: lax.pmean(s, data_axis), new_stats)
@@ -364,359 +469,26 @@ def _make_shard_step(
                 metrics["aux_loss"] = lax.pmean(aux, data_axis)
             if counters:
                 metrics[COUNTERS] = sum_counters(counters, data_axis)
-            if compute_accuracy and task.accuracy:
-                correct, count = masked_accuracy(
-                    logits, batch[task.target_key], batch.get("mask")
-                )
+            if want_accuracy:
+                if hits is None:
+                    hits = masked_accuracy(
+                        logits, batch[task.target_key], batch.get("mask"))
+                correct, count = hits
                 metrics["accuracy"] = (
                     lax.psum(correct, data_axis)
                     / jnp.maximum(lax.psum(count, data_axis), 1.0))
         return new_state, metrics
 
-    return shard_step
-
-
-def make_train_step(
-    model,
-    tx: optax.GradientTransformation,
-    mesh: Mesh,
-    *,
-    data_axis: str = DATA_AXIS,
-    loss_fn: Callable = cross_entropy_loss,
-    donate: bool = True,
-    compute_accuracy: bool = True,
-    remat: bool = False,
-    augment: bool = False,
-    augment_seed: int = 0,
-    mixup_alpha: float = 0.0,
-    aux_weight: float = 0.01,
-    health: Optional[HealthConfig] = None,
-    zero1=None,
-    compress=None,
-    task: Task = IMAGE_CLASSIFICATION,
-) -> Callable[[TrainState, Batch], tuple]:
-    """Build the compiled DDP train step for `mesh`.
-
-    Returns step(state, batch) -> (state, metrics) where batch is a global
-    {image, label, mask} dict sharded on its leading axis over `data_axis`.
-    ``compute_accuracy=False`` for losses whose labels aren't class indices
-    (e.g. multi-hot BCE targets). ``remat=True`` rematerializes the forward
-    during backward (jax.checkpoint) — trades FLOPs for HBM on deep models.
-    ``augment=True`` applies on-device random crop+flip to the shard's images
-    (keyed by step and shard index — reproducible across resume, distinct
-    per device; the recipe extension the reference lacks, SURVEY.md §7.3).
-    ``zero1`` (Zero1Partition) runs the ZeRO-1 sharded weight update; the
-    state's opt leaves then enter/leave scattered over ``data_axis``.
-    ``compress`` (GradCompressor) quantizes the gradient sync's wire
-    payloads (--grad-compress; parallel/compression.py).
-    """
-    shard_step = _make_shard_step(
-        model,
-        tx,
-        data_axis=data_axis,
-        loss_fn=loss_fn,
-        compute_accuracy=compute_accuracy,
-        remat=remat,
-        augment=augment,
-        augment_seed=augment_seed,
-        mixup_alpha=mixup_alpha,
-        aux_weight=aux_weight,
-        health=health,
-        zero1=zero1,
-        compress=compress,
-        task=task,
-    )
-    state_specs = state_specs_for(zero1, compress, data_axis)
-    sharded = jax.shard_map(
-        shard_step,
-        mesh=mesh,
-        in_specs=(state_specs, P(data_axis)),
-        out_specs=(state_specs, P()),
-    )
-    return jax.jit(sharded, donate_argnums=(0,) if donate else ())
-
-
-def make_scan_train_step(
-    model,
-    tx: optax.GradientTransformation,
-    mesh: Mesh,
-    *,
-    steps_per_call: int,
-    data_axis: str = DATA_AXIS,
-    loss_fn: Callable = cross_entropy_loss,
-    donate: bool = True,
-    compute_accuracy: bool = True,
-    remat: bool = False,
-    augment: bool = False,
-    augment_seed: int = 0,
-    mixup_alpha: float = 0.0,
-    aux_weight: float = 0.01,
-    health: Optional[HealthConfig] = None,
-    zero1=None,
-    compress=None,
-    task: Task = IMAGE_CLASSIFICATION,
-) -> Callable[[TrainState, Batch], tuple]:
-    """K train steps fused into ONE dispatch via ``lax.scan``.
-
-    The reference pays Python-interpreter + launcher overhead every batch
-    (the ``main.py:32-41`` hot loop crosses the host boundary per step); for
-    a 76K-param model on TPU that overhead dominates the step itself. Here
-    ``steps_per_call`` optimizer steps run inside a single jitted call: the
-    host stacks K global batches on a new leading axis and XLA executes the
-    whole scan on-device with zero intervening dispatches.
-
-    step(state, batches) -> (state, metrics) where every array in ``batches``
-    has shape (K, global_batch, ...) sharded over ``data_axis`` on axis 1,
-    and every metric leaf gains a leading (K,) axis (per-step losses, in
-    order — the trainer logs them exactly as if stepped one by one).
-
-    Under ``zero1`` the scattered optimizer state rides the scan carry
-    UNGATHERED: the K inner steps each reduce-scatter fresh grads, update
-    their shard, and all-gather only the params (once per inner step, for
-    the next forward/backward) — the shard state never re-replicates
-    inside the fused dispatch. Under ``compress`` the error-feedback
-    residual likewise rides the carry, updated every inner step.
-    """
-    shard_step = _make_shard_step(
-        model,
-        tx,
-        data_axis=data_axis,
-        loss_fn=loss_fn,
-        compute_accuracy=compute_accuracy,
-        remat=remat,
-        augment=augment,
-        augment_seed=augment_seed,
-        mixup_alpha=mixup_alpha,
-        aux_weight=aux_weight,
-        health=health,
-        zero1=zero1,
-        compress=compress,
-        task=task,
-    )
-
     def shard_multi(state: TrainState, batches: Batch):
         return lax.scan(shard_step, state, batches, length=steps_per_call)
 
+    fused = steps_per_call > 1
     state_specs = state_specs_for(zero1, compress, data_axis)
     sharded = jax.shard_map(
-        shard_multi,
+        shard_multi if fused else shard_step,
         mesh=mesh,
-        in_specs=(state_specs, P(None, data_axis)),
-        out_specs=(state_specs, P()),
-    )
-    return jax.jit(sharded, donate_argnums=(0,) if donate else ())
-
-
-def make_grad_accum_train_step(
-    model,
-    tx: optax.GradientTransformation,
-    mesh: Mesh,
-    *,
-    accum_steps: int,
-    data_axis: str = DATA_AXIS,
-    loss_fn: Callable = cross_entropy_loss,
-    donate: bool = True,
-    compute_accuracy: bool = True,
-    remat: bool = False,
-    aux_weight: float = 0.01,
-    health: Optional[HealthConfig] = None,
-    zero1=None,
-    compress=None,
-    task: Task = IMAGE_CLASSIFICATION,
-) -> Callable[[TrainState, Batch], tuple]:
-    """ONE optimizer step over a global batch too large to activate at
-    once: each shard splits its rows into ``accum_steps`` microbatches,
-    accumulates gradients over them with ``lax.scan`` (activations for only
-    one microbatch live at a time — the classic memory/throughput trade the
-    reference cannot express; its global batch is rigidly
-    per-process-batch × world size, ``main.py:61``), then applies a single
-    optax update with the average gradient.
-
-    Semantics: with equal real counts per microbatch the accumulated
-    gradient equals the full-batch gradient exactly (each microbatch's
-    cross-shard pmean-before-AD sync is preserved; the outer mean over
-    microbatches commutes with AD). With masked/unequal microbatches the
-    average weights microbatches equally — same approximation class as
-    every accumulation implementation. BatchNorm stats chain through the
-    scan (each microbatch normalizes by its own statistics, as the
-    reference's per-replica BN does per step).
-
-    step(state, batch) -> (state, metrics): batch is the usual global
-    {image, label, mask}; per-shard rows must divide by ``accum_steps``.
-    """
-    if accum_steps < 1:
-        raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
-
-    model, remat = resolve_remat(model, remat)
-    _bind_compressor(zero1, compress)
-
-    def apply_model(params, batch_stats, images):
-        return model.apply(
-            {"params": params, "batch_stats": batch_stats},
-            images,
-            train=True,
-            mutable=list(MUTABLE),
-        )
-
-    if remat:
-        apply_model = jax.checkpoint(apply_model)
-
-    def compute_loss(params, batch_stats, micro):
-        logits, mutated = apply_model(params, batch_stats,
-                                      micro[task.input_key])
-        with jax.named_scope(LOSS_SCOPE):
-            task_loss = task.loss(loss_fn, logits, micro)
-            loss, aux = combine_aux_loss(task_loss, mutated, aux_weight)
-        # grad sync, as in _make_shard_step (zero1/compress: the sync is
-        # the (ring) reduce-scatter AFTER accumulation — the loss stays
-        # local, ONE compressed collective per accumulated batch)
-        if zero1 is None and compress is None:
-            loss = lax.pmean(loss, data_axis)
-        return loss, (mutated.get("batch_stats", batch_stats), logits,
-                      task_loss, aux, mutated.get(COUNTERS))
-
-    def shard_step(state: TrainState, batch: Batch):
-        b = batch[task.input_key].shape[0]
-        if b % accum_steps:
-            raise ValueError(
-                f"per-shard batch {b} not divisible by accum_steps "
-                f"{accum_steps}"
-            )
-        micros = jax.tree.map(
-            lambda x: x.reshape((accum_steps, b // accum_steps) + x.shape[1:]),
-            batch,
-        )
-        grad_fn = jax.value_and_grad(compute_loss, has_aux=True)
-        if getattr(zero1, "scattered_params", False):
-            # ZeRO-3: gather ONCE, outside the scan — every microbatch
-            # reuses the same streamed params (they only change at the
-            # update), and grads accumulate in the gathered (original)
-            # shapes, which is what the single post-scan reduce-scatter
-            # consumes.
-            p_in = zero1.stream_params(state.params)
-        elif zero1 is not None:
-            p_in = zero1.varying(state.params)
-        elif compress is not None:
-            p_in = compress.varying(state.params)
-        else:
-            p_in = state.params
-        # the accumulator takes the differentiation input's shapes (under
-        # zero3 state.params are flat shards) AND its varying type: under
-        # zero1/compress the grads are LOCAL, and zeros_like keeps p_in's
-        # varying-over-data marking so the scan carry types match
-        zero_grads = jax.tree.map(jnp.zeros_like, p_in)
-
-        def accum(carry, micro):
-            grads_acc, stats, correct, count, loss_sum, aux_sum = carry
-            with jax.named_scope(FORWARD_BACKWARD_SCOPE):
-                (_, (new_stats, logits, task_loss, aux, counted)), grads = (
-                    grad_fn(p_in, stats, micro))
-            with jax.named_scope(GRAD_ACCUM_SCOPE):
-                grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
-            if compute_accuracy and task.accuracy:
-                with jax.named_scope(METRICS_SCOPE):
-                    c, n = masked_accuracy(
-                        logits, micro[task.target_key], micro.get("mask"))
-                correct, count = correct + c, count + n
-            aux_term = jnp.zeros(()) if aux is None else aux
-            return (
-                grads_acc, new_stats, correct, count,
-                loss_sum + task_loss, aux_sum + aux_term,
-            ), counted  # the model's counters: one row a microbatch
-
-        # Values computed from shard-local data (metric scalars, fresh BN
-        # stats) are VARYING over the data axis under shard_map; the carry
-        # inits (zeros / the replicated incoming stats) must match that
-        # type. (The grad accumulator got its type from p_in above.)
-        zero = lax.pcast(jnp.zeros(()), (data_axis,), to="varying")
-        stats0 = jax.tree.map(
-            lambda s: lax.pcast(s, (data_axis,), to="varying"),
-            state.batch_stats,
-        )
-        ((grads_acc, new_stats, correct, count, loss_sum, aux_sum),
-         counters) = lax.scan(
-            accum,
-            (zero_grads, stats0, zero, zero, zero, zero),
-            micros,
-        )
-        with jax.named_scope(GRAD_ACCUM_SCOPE):
-            grads = jax.tree.map(lambda g: g / accum_steps, grads_acc)
-        with jax.named_scope(STATS_SYNC_SCOPE):
-            new_stats = jax.tree.map(
-                lambda s: lax.pmean(s, data_axis), new_stats)
-        ef = compress is not None and compress.config.error_feedback
-        want_err = compress is not None and (ef or health is not None)
-        residual = state.grad_residual if ef else None
-        err_state = None
-        if zero1 is not None:
-            # ONE reduce-scatter for the whole accumulated batch: the
-            # microbatch mean above commutes with the cross-shard average.
-            with jax.named_scope(OPTIMIZER_SCOPE):
-                new_params, new_opt_state, gshards, ushards, err_state = (
-                    zero1.sharded_update(
-                        grads, state.params, state.opt_state,
-                        residual=residual, with_error=want_err)
-                )
-        else:
-            if compress is not None:  # one compressed ring per step
-                with jax.named_scope(GRAD_COMPRESS_SCOPE):
-                    grads, err_state = compress.all_reduce_mean(
-                        grads, residual, with_error=want_err)
-            with jax.named_scope(OPTIMIZER_SCOPE):
-                new_params, updates, new_opt_state = apply_optimizer(
-                    tx, grads, state.opt_state, state.params)
-        new_residual = err_state if ef else state.grad_residual
-        if health is not None:
-            with jax.named_scope(HEALTH_SCOPE):
-                # same guarantees as _make_shard_step: grads/updates are the
-                # synchronized values the optimizer consumed (the accumulated
-                # average), so the stats are the true full-batch numbers
-                err_sq = compress.error_sq(err_state) if want_err else None
-                if zero1 is not None:
-                    hstats = zero1.health_stats(
-                        loss=lax.pmean(loss_sum / accum_steps, data_axis),
-                        grad_shards=gshards, params=state.params,
-                        update_shards=ushards, per_layer=health.per_layer,
-                        compress_error_sq=err_sq,
-                    )
-                else:
-                    hstats = health_stats(
-                        loss=lax.pmean(loss_sum / accum_steps, data_axis),
-                        grads=grads, params=state.params, updates=updates,
-                        per_layer=health.per_layer, compress_error_sq=err_sq,
-                    )
-                (new_params, new_stats, new_opt_state, new_residual) = guard_step(
-                    health, hstats,
-                    (new_params, new_stats, new_opt_state, new_residual),
-                    (state.params, state.batch_stats, state.opt_state,
-                     state.grad_residual),
-                )
-        new_state = state.replace(
-            step=state.step + 1,
-            params=new_params,
-            batch_stats=new_stats,
-            opt_state=new_opt_state,
-            grad_residual=new_residual,
-        )
-        with jax.named_scope(METRICS_SCOPE):
-            metrics = {"loss": lax.pmean(loss_sum / accum_steps, data_axis)}
-            if health is not None:
-                metrics["health"] = hstats
-            if counters:  # a step's count is the sum over its microbatches
-                metrics[COUNTERS] = sum_counters(
-                    jax.tree.map(lambda c: c.sum(axis=0), counters),
-                    data_axis)
-            if compute_accuracy and task.accuracy:
-                metrics["accuracy"] = lax.psum(correct, data_axis) / jnp.maximum(
-                    lax.psum(count, data_axis), 1.0
-                )
-        return new_state, metrics
-
-    state_specs = state_specs_for(zero1, compress, data_axis)
-    sharded = jax.shard_map(
-        shard_step,
-        mesh=mesh,
-        in_specs=(state_specs, P(data_axis)),
+        in_specs=(state_specs,
+                  P(None, data_axis) if fused else P(data_axis)),
         out_specs=(state_specs, P()),
     )
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())

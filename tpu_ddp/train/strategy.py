@@ -639,10 +639,7 @@ def build_abstract_step(
         )
 
     if parallelism == "dp":
-        from tpu_ddp.train.steps import (
-            make_grad_accum_train_step,
-            make_train_step,
-        )
+        from tpu_ddp.train.steps import make_train_step
 
         state = jax.eval_shape(
             lambda: create_train_state(
@@ -683,15 +680,10 @@ def build_abstract_step(
                     lambda _: rep, state.replace(grad_residual=None))
             shardings = shardings.replace(
                 grad_residual=comp.residual_shardings(mesh))
-        if grad_accum_steps > 1:
-            step = make_grad_accum_train_step(
-                model, tx, mesh, accum_steps=grad_accum_steps,
-                loss_fn=loss_fn, remat=remat, zero1=part, compress=comp,
-                health=health, donate=donate)
-        else:
-            step = make_train_step(model, tx, mesh, loss_fn=loss_fn,
-                                   remat=remat, zero1=part, compress=comp,
-                                   health=health, donate=donate)
+        step = make_train_step(
+            model, tx, mesh, accum_steps=grad_accum_steps, loss_fn=loss_fn,
+            remat=remat, zero1=part, compress=comp, health=health,
+            donate=donate)
         return step, abstract_train_state(state, shardings)
 
     has_bs_state = jax.eval_shape(

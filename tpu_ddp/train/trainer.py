@@ -730,7 +730,18 @@ class Trainer:
             run_meta=self.run_meta,
             incarnation=self.incarnation,
         )
-        self._watchdog = None
+        # What is told of every step (``on_step(host_step)`` after each
+        # dispatch, in this list's order; ``close()`` in its reverse when
+        # the run releases its workers). Each watcher below appends itself
+        # where it is built, and the order they are built in is the order
+        # they hear of a step: watchdog beat (put first by the start of
+        # ``run``), then chaos (an injected hang blocks the loop inside
+        # its on_step, so the beat before it is the last one — exactly the
+        # silhouette of a wedged collective), hop monitor, stage monitor,
+        # capture, memory sampler. With telemetry off and nothing
+        # configured the list is empty and the loop keeps no host step.
+        self._watchers = []
+        self._watchdog = None   # HangWatchdog (started in run())
         self._exporter = None   # monitor HTTP endpoint (started in run())
         # Numerics flight recorder (docs/health.md): the in-graph half is
         # compiled into the step builders below (health=self._health);
@@ -771,30 +782,6 @@ class Trainer:
             # satellite fix: create the profiler dir up front — a typo'd
             # path fails NOW, not after an epoch of training
             os.makedirs(config.profile_dir, exist_ok=True)
-        # Anomaly profiler (docs/profiling.md): the capture manager sits
-        # dormant until a window is armed — by --profile-steps here, by
-        # POST /profile on the exporter, or by the capture_profile alert
-        # action. Needs the run dir for its bundles, so it exists exactly
-        # when telemetry does.
-        self._capture = None
-        if config.telemetry_dir:
-            from tpu_ddp.profiler.capture import (
-                CaptureManager,
-                parse_profile_steps,
-            )
-
-            self._capture = CaptureManager(
-                config.telemetry_dir,
-                process_index=self.process_index,
-                window_steps=config.profile_window_steps,
-                host_hz=config.profile_host_hz,
-                telemetry=self.telemetry,
-                run_meta=self.run_meta,
-            )
-            window = parse_profile_steps(config.profile_steps)
-            if window:
-                self._capture.arm_window(*window)
-
         # Chaos injector (docs/resilience.md): deterministic step-
         # triggered fault injection — exists exactly when --chaos is
         # given; its save_fault_hook threads into the Checkpointer below
@@ -809,10 +796,13 @@ class Trainer:
                 checkpoint_dir=config.checkpoint_dir,
                 telemetry=self.telemetry,
             )
+            self._watchers.append(self._chaos)
 
         # Comms observatory (docs/comms.md): per-hop host callback on the
         # quantized ring collectives -> live per-axis bandwidth + the
-        # in-flight collective, the hang forensics' suspect evidence.
+        # in-flight collective, the hang forensics' suspect evidence; its
+        # on_step stamps the host step onto subsequent hop records so the
+        # forensics can say WHEN the ring wedged.
         # Installed BEFORE the strategy builds its jitted step so the
         # hook is baked into the traced ring; the chaos comm_stall fault
         # rides the same seam (fault_hook), which is why the injector
@@ -833,30 +823,12 @@ class Trainer:
                 telemetry=self.telemetry,
             )
             set_ring_hop_hook(self._comms_monitor.on_hop)
-
-        # Live memory sampler (docs/memory.md): per-step device
-        # memory_stats -> memory/* gauges + the incarnation-stamped
-        # mem-p<i>.jsonl sink. Exists exactly when telemetry does
-        # (dormant otherwise, like the capture manager); its ring of
-        # recent samples is the OOM postmortem's evidence.
-        self._memtrack = None
-        if config.telemetry_dir and config.mem_sample_steps > 0:
-            from tpu_ddp.memtrack.sampler import MemorySampler
-
-            local = set(jax.local_devices())
-            self._memtrack = MemorySampler(
-                config.telemetry_dir,
-                process_index=self.process_index,
-                incarnation=self.incarnation,
-                telemetry=self.telemetry,
-                every=config.mem_sample_steps,
-                run_meta=self.run_meta,
-                devices=[d for d in devices if d in local],
-            )
+            self._watchers.append(self._comms_monitor)
 
         # Data-path observatory (docs/data.md): the per-stage loader
         # observer keeps data-health-p<i>.json fresh for the fleet
-        # aggregator / DAT001 and carries the chaos per-stage stall seam;
+        # aggregator / DAT001 (its in-flight stage marker names the step
+        # a stall wedged on) and carries the chaos per-stage stall seam;
         # the digest writer records each step's batch-content digest into
         # the incarnation-stamped data-p<i>.i<k>.jsonl sink for the
         # determinism audit. Both exist exactly when telemetry does, and
@@ -876,6 +848,7 @@ class Trainer:
                 ),
                 telemetry=self.telemetry,
             )
+            self._watchers.append(self._datapath)
             if config.data_digests:
                 from tpu_ddp.datapath.audit import DataDigestWriter
 
@@ -887,6 +860,55 @@ class Trainer:
                     run_id=self.run_meta.get("run_id"),
                     global_batch=config.per_shard_batch * self.data_size,
                 )
+        # Anomaly profiler (docs/profiling.md): the capture manager sits
+        # dormant until a window is armed — by --profile-steps here, by
+        # POST /profile on the exporter, or by the capture_profile alert
+        # action; its on_step opens an armed window when its start step
+        # arrives and closes + writes the bundle when it ends (boundaries
+        # snap to dispatch boundaries under scan fusion). Needs the run
+        # dir for its bundles, so it exists exactly when telemetry does.
+        self._capture = None
+        if config.telemetry_dir:
+            from tpu_ddp.profiler.capture import (
+                CaptureManager,
+                parse_profile_steps,
+            )
+
+            self._capture = CaptureManager(
+                config.telemetry_dir,
+                process_index=self.process_index,
+                window_steps=config.profile_window_steps,
+                host_hz=config.profile_host_hz,
+                telemetry=self.telemetry,
+                run_meta=self.run_meta,
+            )
+            window = parse_profile_steps(config.profile_steps)
+            if window:
+                self._capture.arm_window(*window)
+            self._watchers.append(self._capture)
+
+        # Live memory sampler (docs/memory.md): per-step device
+        # memory_stats (host-side runtime reads, no device sync) ->
+        # memory/* gauges + the incarnation-stamped mem-p<i>.jsonl sink.
+        # Exists exactly when telemetry does (dormant otherwise, like the
+        # capture manager); its ring of recent samples is the OOM
+        # postmortem's evidence.
+        self._memtrack = None
+        if config.telemetry_dir and config.mem_sample_steps > 0:
+            from tpu_ddp.memtrack.sampler import MemorySampler
+
+            local = set(jax.local_devices())
+            self._memtrack = MemorySampler(
+                config.telemetry_dir,
+                process_index=self.process_index,
+                incarnation=self.incarnation,
+                telemetry=self.telemetry,
+                every=config.mem_sample_steps,
+                run_meta=self.run_meta,
+                devices=[d for d in devices if d in local],
+            )
+            self._watchers.append(self._memtrack)
+
         self._data_prefetcher = None  # staged background prefetcher
         self.model = build_model(config)
         from tpu_ddp.train.tasks import IMAGE_CLASSIFICATION, task_of
@@ -1284,32 +1306,18 @@ class Trainer:
                     grad_residual=self._compress.init_residual(self.mesh))
                 self.state_shardings = self._residual_shardings(
                     self.state_shardings)
-        if config.grad_accum_steps > 1:
-            from tpu_ddp.train.steps import make_grad_accum_train_step
-
-            if config.augment or config.mixup_alpha > 0:
-                raise ValueError(
-                    "--augment/--mixup-alpha are not yet supported with "
-                    "--grad-accum-steps"
-                )
-            self.train_step = make_grad_accum_train_step(
-                self.model, self.tx, self.mesh,
-                accum_steps=config.grad_accum_steps,
-                loss_fn=loss_fn, compute_accuracy=with_acc,
-                remat=config.remat, aux_weight=config.aux_weight,
-                health=self._health, zero1=self._zero1,
-                compress=self._compress, task=self.task,
-            )
-        else:
-            self.train_step = make_train_step(
-                self.model, self.tx, self.mesh,
-                loss_fn=loss_fn, compute_accuracy=with_acc, remat=config.remat,
-                augment=config.augment, augment_seed=config.seed,
-                mixup_alpha=config.mixup_alpha,
-                aux_weight=config.aux_weight,
-                health=self._health, zero1=self._zero1,
-                compress=self._compress, task=self.task,
-            )
+        # one builder for every DP step; it refuses --augment/--mixup-alpha
+        # under --grad-accum-steps
+        step_options = dict(
+            accum_steps=config.grad_accum_steps,
+            loss_fn=loss_fn, compute_accuracy=with_acc, remat=config.remat,
+            augment=config.augment, augment_seed=config.seed,
+            mixup_alpha=config.mixup_alpha, aux_weight=config.aux_weight,
+            health=self._health, zero1=self._zero1,
+            compress=self._compress, task=self.task,
+        )
+        self.train_step = make_train_step(
+            self.model, self.tx, self.mesh, **step_options)
         self.multi_step = None
         # Clamp to the epoch length: a scan longer than the epoch would
         # compile but never fill, silently running every step un-fused.
@@ -1324,19 +1332,10 @@ class Trainer:
             )
         if self.steps_per_call > 1:
             from tpu_ddp.parallel.mesh import stacked_batch_sharding
-            from tpu_ddp.train.steps import make_scan_train_step
 
-            self.multi_step = make_scan_train_step(
+            self.multi_step = make_train_step(
                 self.model, self.tx, self.mesh,
-                steps_per_call=self.steps_per_call,
-                loss_fn=loss_fn, compute_accuracy=with_acc,
-                remat=config.remat,
-                augment=config.augment, augment_seed=config.seed,
-                mixup_alpha=config.mixup_alpha,
-                aux_weight=config.aux_weight,
-                health=self._health, zero1=self._zero1,
-                compress=self._compress, task=self.task,
-            )
+                steps_per_call=self.steps_per_call, **step_options)
             self.stacked_sharding = stacked_batch_sharding(self.mesh)
         self.eval_step = make_eval_step(
             self.model, self.mesh, loss_fn=loss_fn,
@@ -1685,41 +1684,34 @@ class Trainer:
 
     def _release_workers(self) -> None:
         """Stop the host-side helpers: prefetcher (worker thread + slot
-        buffers), monitor exporter, profiler capture manager (writes any
-        open window as a truncated bundle), watchdog, and the health
-        monitor (flushes its JSONL footer). Idempotent; does NOT close
-        the telemetry sinks."""
+        buffers), monitor exporter, everything in ``_watchers`` (the
+        watchdog among them), and the health monitor (flushes its JSONL
+        footer). Idempotent; does NOT close the telemetry sinks."""
         if self._prefetcher is not None:
             self._prefetcher.close()
             self._prefetcher = None
         if self._data_prefetcher is not None:
             self._data_prefetcher.close()
             self._data_prefetcher = None
-        if self._datapath is not None:
-            self._datapath.close()
         if self._data_digests is not None:
             self._data_digests.close()
         if self._exporter is not None:
             self._exporter.close()
             self._exporter = None
-        if self._capture is not None:
-            # a window still open when the run drains is written as a
-            # truncated bundle — a preempted run's capture is evidence
-            # too. The manager stays (idempotent close) for a second call
-            self._capture.close()
-        if self._watchdog is not None:
-            self._watchdog.stop()
-            self._watchdog = None
-        if self._comms_monitor is not None:
-            # uninstall the hop hook BEFORE closing: a straggling
-            # dispatch must not write through a closed monitor
-            from tpu_ddp.parallel.collectives import set_ring_hop_hook
+        # each watcher once, the last told first, so that the watchdog
+        # outlives the others' last writes (a capture window still open
+        # when the run drains is written as a truncated bundle — a
+        # preempted run's capture is evidence too)
+        watchers, self._watchers = self._watchers, []
+        self._watchdog = None
+        for watcher in reversed(watchers):
+            if watcher is self._comms_monitor:
+                # uninstall the hop hook BEFORE its monitor closes: a
+                # straggling dispatch must not write through a closed one
+                from tpu_ddp.parallel.collectives import set_ring_hop_hook
 
-            set_ring_hop_hook(None)
-            self._comms_monitor.close()
-            self._comms_monitor = None
-        if self._memtrack is not None:
-            self._memtrack.close()
+                set_ring_hop_hook(None)
+            watcher.close()
         if self._health_monitor is not None:
             self._health_monitor.close()
 
@@ -1925,6 +1917,7 @@ class Trainer:
         except ValueError:  # not the main thread (e.g. driven from a test)
             old_handlers = {}
         try:
+            self._start_run_watchers()
             return self._run_loop(c, start)
         except Exception as e:
             # OOM forensics (docs/memory.md): an XLA allocation failure
@@ -2017,6 +2010,84 @@ class Trainer:
         )
         return bool(np.asarray(flags).max())
 
+    def _dispatch_target(self, kind) -> tuple:
+        """The callable one dispatch of ``_epoch_stream``'s ``kind`` goes to
+        and the optimizer steps it makes. Read at each dispatch, as
+        attributes: a caller may stand a probe in ``train_step`` after
+        ``__init__``."""
+        if kind == "stacked":
+            return self.multi_step, self.steps_per_call
+        return self.train_step, 1
+
+    def _start_run_watchers(self) -> None:
+        """What lives for one ``run``, not for the ``Trainer``: the hang
+        watchdog (its first deadline window starts here) and the monitor
+        exporter. ``_release_workers`` stops both."""
+        c = self.config
+        if c.watchdog_deadline_seconds > 0:
+            from tpu_ddp.telemetry import HangWatchdog
+
+            on_hang = None
+            if c.telemetry_dir:
+                # hang forensics (docs/comms.md, docs/data.md): join the
+                # stack dump with the last comms-health and data-health
+                # records so the hang bundle NAMES the suspect collective
+                # and/or the suspect loader stage — written before the
+                # abort escalation, because after it there is no process
+                # left to ask
+                from tpu_ddp.comms.forensics import write_hang_bundle
+
+                def on_hang(dump: str) -> None:
+                    write_hang_bundle(c.telemetry_dir, dump_text=dump,
+                                      process_index=self.process_index)
+
+            self._watchdog = HangWatchdog(
+                c.watchdog_deadline_seconds,
+                heartbeat_dir=c.telemetry_dir,
+                process_index=self.process_index,
+                telemetry=self.telemetry,
+                on_hang=on_hang,
+                abort_on_hang=c.watchdog_abort,
+            ).start()
+            # first in the list: its beat comes before every other
+            # watcher hears of the step. Without tracing the dispatch is
+            # async: the beat then means "the host is still submitting
+            # work", which still catches wedged collectives (the host
+            # blocks inside the NEXT dispatch when the device queue jams)
+            self._watchers.insert(0, self._watchdog)
+        if c.monitor_port:
+            # Per-host live scrape endpoint (docs/monitoring.md). A bind
+            # failure (port taken) degrades to a warning: observability
+            # must never take down the training it observes.
+            from tpu_ddp.monitor.exporter import MonitorExporter
+
+            try:
+                self._exporter = MonitorExporter(
+                    registry=self.telemetry.registry,
+                    run_meta=self.run_meta,
+                    port=c.monitor_port if c.monitor_port > 0 else 0,
+                    host=c.monitor_bind,
+                    process_index=self.process_index,
+                    watchdog_provider=lambda: self._watchdog,
+                    run_dir=c.telemetry_dir,
+                    profile_trigger=(
+                        self._capture.request
+                        if self._capture is not None else None
+                    ),
+                    allow_remote_trigger=c.monitor_allow_remote_trigger,
+                ).start()
+                log.info(
+                    "monitor exporter on port %d "
+                    "(/metrics /snapshot.json /healthz)",
+                    self._exporter.port,
+                )
+            except OSError as e:
+                log.warning(
+                    "monitor exporter failed to bind port %s: %s "
+                    "(continuing without the live endpoint)",
+                    c.monitor_port, e,
+                )
+
     def _run_loop(self, c, start) -> dict:
         # Multi-host: this process only counts its LOCAL rows (the loader
         # yields the local slice), so rate against local chips; the per-chip
@@ -2042,66 +2113,6 @@ class Trainer:
         }
         if tel.enabled:
             tel.emit_counters(name="counters_baseline")
-        if c.watchdog_deadline_seconds > 0:
-            from tpu_ddp.telemetry import HangWatchdog
-
-            on_hang = None
-            if c.telemetry_dir:
-                # hang forensics (docs/comms.md, docs/data.md): join the
-                # stack dump with the last comms-health and data-health
-                # records so the hang bundle NAMES the suspect collective
-                # and/or the suspect loader stage — written before the
-                # abort escalation, because after it there is no process
-                # left to ask
-                from tpu_ddp.comms.forensics import write_hang_bundle
-
-                run_dir = c.telemetry_dir
-                pidx = self.process_index
-
-                def on_hang(dump: str, _dir=run_dir, _p=pidx) -> None:
-                    write_hang_bundle(_dir, process_index=_p,
-                                      dump_text=dump)
-
-            self._watchdog = HangWatchdog(
-                c.watchdog_deadline_seconds,
-                heartbeat_dir=c.telemetry_dir,
-                process_index=self.process_index,
-                telemetry=tel,
-                on_hang=on_hang,
-                abort_on_hang=c.watchdog_abort,
-            ).start()
-        if c.monitor_port:
-            # Per-host live scrape endpoint (docs/monitoring.md). A bind
-            # failure (port taken) degrades to a warning: observability
-            # must never take down the training it observes.
-            from tpu_ddp.monitor.exporter import MonitorExporter
-
-            try:
-                self._exporter = MonitorExporter(
-                    registry=tel.registry,
-                    run_meta=self.run_meta,
-                    port=c.monitor_port if c.monitor_port > 0 else 0,
-                    host=c.monitor_bind,
-                    process_index=self.process_index,
-                    watchdog_provider=lambda: self._watchdog,
-                    run_dir=c.telemetry_dir,
-                    profile_trigger=(
-                        self._capture.request
-                        if self._capture is not None else None
-                    ),
-                    allow_remote_trigger=c.monitor_allow_remote_trigger,
-                ).start()
-                log.info(
-                    "monitor exporter on port %d "
-                    "(/metrics /snapshot.json /healthz)",
-                    self._exporter.port,
-                )
-            except OSError as e:
-                log.warning(
-                    "monitor exporter failed to bind port %s: %s "
-                    "(continuing without the live endpoint)",
-                    c.monitor_port, e,
-                )
         last_metrics = {}
         # Steady-state step time: measured per epoch between REAL sync points
         # (the device_get below), excluding the first epoch (XLA compile).
@@ -2148,14 +2159,10 @@ class Trainer:
             # host-side global step mirror (one device sync per epoch),
             # kept for ALL consumers so watchdog heartbeats/hang logs and
             # health records carry the global step even with telemetry off
-            track_step = (
-                tel.enabled
-                or self._watchdog is not None
+            track_step = bool(
+                self._watchers
+                or tel.enabled
                 or self._health_monitor is not None
-                or self._memtrack is not None
-                or self._chaos is not None
-                or self._comms_monitor is not None
-                or self._datapath is not None
                 or (self.checkpointer is not None
                     and c.checkpoint_steps > 0)
             )
@@ -2168,30 +2175,18 @@ class Trainer:
                 # others would block in the next step's collectives.
                 if self.process_count == 1 and self._preempted:
                     break
+                step_fn, dn = self._dispatch_target(kind)
                 if skip:
-                    item_steps = (
-                        self.steps_per_call if kind == "stacked" else 1
-                    )
-                    if skip >= item_steps:
-                        skip -= item_steps
+                    if skip >= dn:
+                        skip -= dn
                         continue
                     skip = 0  # straddling fused group: replay its tail
-                if kind == "stacked":
-                    with tel.span("compiled_step", steps=self.steps_per_call):
-                        self.state, epoch_metrics = self.multi_step(
-                            self.state, dev_batch
-                        )
-                    step_losses.append(epoch_metrics["loss"])  # (K,)
-                    step_counters.append(epoch_metrics.get("counters"))
-                    n_steps += self.steps_per_call
-                else:
-                    with tel.span("compiled_step"):
-                        self.state, epoch_metrics = self.train_step(
-                            self.state, dev_batch
-                        )
-                    step_losses.append(epoch_metrics["loss"])
-                    step_counters.append(epoch_metrics.get("counters"))
-                    n_steps += 1
+                with tel.span("compiled_step",
+                              **({"steps": dn} if dn > 1 else {})):
+                    self.state, epoch_metrics = step_fn(self.state, dev_batch)
+                step_losses.append(epoch_metrics["loss"])  # (K,) if fused
+                step_counters.append(epoch_metrics.get("counters"))
+                n_steps += dn
                 if (self._program_map is not None
                         and not self._program_map.done):
                     # while the device runs the step just dispatched; over
@@ -2199,9 +2194,7 @@ class Trainer:
                     self._program_map.after_dispatch(
                         kind, self.state, dev_batch)
                 if track_step:
-                    host_step += (
-                        self.steps_per_call if kind == "stacked" else 1
-                    )
+                    host_step += dn
                     # the step the OOM forensics stamp on a postmortem
                     # bundle if this very dispatch exhausts HBM
                     self._last_host_step = host_step
@@ -2214,7 +2207,6 @@ class Trainer:
                     with tel.span("device_sync"):
                         jax.block_until_ready(epoch_metrics["loss"])
                     tel.current_step = host_step
-                    dn = self.steps_per_call if kind == "stacked" else 1
                     tel.count("train/steps", dn)
                     tel.count("train/images", n_real)
                     # Periodic counters snapshot: a killed/preempted run
@@ -2228,40 +2220,11 @@ class Trainer:
                     ):
                         self._update_goodput_gauges(tel)
                         tel.emit_counters(name="counters_snapshot")
-                if self._watchdog is not None:
-                    # without tracing the dispatch is async: the beat then
-                    # means "the host is still submitting work", which
-                    # still catches wedged collectives (the host blocks
-                    # inside the NEXT dispatch when the device queue jams)
-                    self._watchdog.beat(host_step)
-                if self._chaos is not None:
-                    # AFTER the beat: an injected hang blocks the loop
-                    # here, so the beat above is the last one — exactly
-                    # the silhouette of a wedged collective
-                    self._chaos.on_step(host_step)
-                if self._comms_monitor is not None:
-                    # stamp the host step onto subsequent hop records so
-                    # the hang forensics can say WHEN the ring wedged
-                    self._comms_monitor.set_step(host_step)
-                if self._datapath is not None:
-                    # same stamp for data-health records: the in-flight
-                    # stage marker names the step a stall wedged on
-                    self._datapath.set_step(host_step)
-                if self._capture is not None:
-                    # capture-window lifecycle: opens an armed window when
-                    # its start step arrives, closes + writes the bundle
-                    # when it ends (boundaries snap to dispatch
-                    # boundaries under scan fusion)
-                    self._capture.on_step(host_step)
-                if self._memtrack is not None:
-                    # live memory sample (host-side runtime reads, no
-                    # device sync): memory/* gauges + mem-p<i>.jsonl
-                    self._memtrack.on_step(host_step)
+                for watcher in self._watchers:
+                    watcher.on_step(host_step)
                 if (self.checkpointer is not None and c.checkpoint_steps
                         and (host_step // c.checkpoint_steps)
-                        > ((host_step
-                            - (self.steps_per_call if kind == "stacked"
-                               else 1)) // c.checkpoint_steps)):
+                        > ((host_step - dn) // c.checkpoint_steps)):
                     # step-cadence save (--checkpoint-steps): the knob
                     # the goodput ledger's Young–Daly advisor recommends
                     # a value for. Async initiation, same as the epoch-
@@ -2269,10 +2232,9 @@ class Trainer:
                     # the boundary it crosses.
                     self.checkpointer.save(host_step, self._ckpt_state())
                 if self._health_monitor is not None:
-                    dn = self.steps_per_call if kind == "stacked" else 1
                     verdict = self._on_health(
                         host_step - dn, epoch_metrics.pop("health"),
-                        kind, dev_batch,
+                        dn, dev_batch,
                     )
                     if verdict == "halt":
                         # stats are replicated globals — every host reaches
@@ -2284,7 +2246,6 @@ class Trainer:
                     mfu_probe = (kind, dev_batch)
                 throughput.add(n_real)
                 if c.log_every_steps:
-                    dn = self.steps_per_call if kind == "stacked" else 1
                     if (n_steps // c.log_every_steps) > (
                         (n_steps - dn) // c.log_every_steps
                     ):
@@ -2588,13 +2549,12 @@ class Trainer:
         tel.gauge("goodput/productive_seconds").set(productive)
         tel.gauge("goodput/elapsed_seconds").set(elapsed)
 
-    def _on_health(self, step_base, health_out, kind, dev_batch) -> str:
+    def _on_health(self, step_base, health_out, K, dev_batch) -> str:
         """Feed one dispatch's in-graph health stats to the monitor: ONE
-        device_get for the scalar subtree (a fused K-step group carries
-        (K,) leaves, unstacked here into K per-step records), the batch
-        fetched lazily only if an anomaly dump fires. Returns the
-        strongest policy verdict across the group's steps."""
-        K = self.steps_per_call if kind == "stacked" else 1
+        device_get for the scalar subtree (a fused group of ``K`` > 1
+        steps carries (K,) leaves, unstacked here into K per-step
+        records), the batch fetched lazily only if an anomaly dump fires.
+        Returns the strongest policy verdict across the group's steps."""
         per_layer = health_out.pop("per_layer", None)
         host = jax.device_get(health_out)
         if per_layer is not None:
@@ -2622,7 +2582,7 @@ class Trainer:
                     # be reassembled from the loaders if ever needed)
                     return None
                 b = jax.device_get(dev_batch)
-                if kind == "stacked":
+                if K > 1:
                     b = {k: v[j] for k, v in b.items()}
                 return b
 
@@ -2652,8 +2612,7 @@ class Trainer:
         ):
             return None
         kind, dev_batch = mfu_probe
-        step_fn = self.multi_step if kind == "stacked" else self.train_step
-        steps_per_exec = self.steps_per_call if kind == "stacked" else 1
+        step_fn, steps_per_exec = self._dispatch_target(kind)
         flops = compiled_flops(step_fn, self.state, dev_batch)
         if flops is None:
             return None
