@@ -539,6 +539,10 @@ def test_published_sizes_count_the_published_parameters():
     (64, 4, 4, 40, 32, 16),    # no grouping, a window wider than a block
     (64, 4, 1, 8, 16, 16),     # one key-value head, a window inside a block
     (128, 2, 1, 100, 32, 64),
+    # the kernels keep their row statistics (rows, 128): against scores a
+    # lane width of columns wide, and two (``_lanes``)
+    (256, 4, 2, 70, 32, 128),
+    (512, 2, 1, 200, 64, 256),
 ])
 def test_windowed_grouped_flash_matches_the_blocked_reference(
         ref, t, heads, kv, window, bq, bk):
@@ -567,6 +571,78 @@ def test_windowed_grouped_flash_matches_the_blocked_reference(
                         (0, 1, 2))(q, k, v)
     for g, x in zip(got, want):
         np.testing.assert_allclose(g, x, atol=5e-6)
+
+
+@pytest.mark.parametrize("t,heads,kv,window,bq,bk", [
+    (256, 4, 2, 48, 64, 128),   # a window, groups of two
+    (512, 2, 1, 0, 64, 256),    # keys two lane widths wide
+])
+def test_a_dead_row_under_a_band_is_exact_zeros(t, heads, kv, window, bq,
+                                                bk):
+    """``kv_mask`` with the decoder's bands and shared heads: forward and
+    the three gradients against the fused reference; rows that see no key
+    (batch 1 before ``t // 4``) come out exact zeros, gradients too."""
+    from tpu_ddp.ops.flash_attention import _reference, flash_attention
+
+    key = jax.random.key(t + window)
+    q, k, v, w = (jax.random.normal(jax.random.fold_in(key, i), shape)
+                  for i, shape in enumerate([
+                      (2, t, heads, 16), (2, t, kv, 16), (2, t, kv, 16),
+                      (2, t, heads, 16)]))
+    how = dict(causal=True, window=window,
+               kv_mask=jnp.ones((2, t)).at[1, :t // 4].set(0.0))
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, bq, bk, True, **how)
+
+    def fused(q, k, v):
+        return _reference(q, k, v, **how)
+
+    with jax.default_matmul_precision("highest"):
+        out = kernel(q, k, v)
+        np.testing.assert_allclose(out, fused(q, k, v), atol=2e-6)
+        got = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), (0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(fused(*a) * w), (0, 1, 2))(q, k, v)
+    for g, x in zip(got, want):
+        np.testing.assert_allclose(g, x, atol=5e-6)
+    assert np.all(np.asarray(out)[1, :t // 4] == 0.0)
+    assert np.all(np.asarray(got[0])[1, :t // 4] == 0.0)
+
+
+def _kernel_primitives(fn, *args) -> dict:
+    """How often each primitive stands in the Pallas kernels ``fn`` calls,
+    branches included."""
+    counts = {}
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if inside:
+                name = eqn.primitive.name
+                counts[name] = counts.get(name, 0) + 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, inside or eqn.primitive.name == "pallas_call")
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return counts
+
+
+def test_the_forward_kernel_computes_a_tile_whole():
+    """ViT-B/16's 196 tokens are one whole-axis block and no band: one
+    product of scores, one of values, one row maximum and one row sum behind
+    three branches (first step, the tile, last step). A decoder's tile has
+    two branches, with the mask arithmetic and without, each the same once."""
+    from tpu_ddp.ops.flash_attention import flash_attention
+
+    names = ("dot_general", "reduce_max", "reduce_sum", "exp", "cond")
+    x = jnp.zeros((2, 196, 4, 64), jnp.bfloat16)
+    vit = _kernel_primitives(
+        lambda q: flash_attention(q, q, q, 128, 128, False), x)
+    assert [vit[p] for p in names] == [2, 1, 1, 2, 3]
+    y = jnp.zeros((1, 1024, 4, 128), jnp.bfloat16)
+    decoder = _kernel_primitives(
+        lambda q: flash_attention(q, q, q, 512, 512, False, causal=True,
+                                  window=512), y)
+    assert [decoder[p] for p in names] == [4, 2, 2, 4, 4]
 
 
 def test_the_band_visits_only_tiles_that_hold_a_visible_pair():

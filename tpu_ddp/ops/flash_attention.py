@@ -23,13 +23,12 @@ short sequences, else T zero-padded to a block multiple with the padding
 masked out through ``kv_mask`` — the kernel asked for is the kernel run.
 
 Masking (round-4 verdict item 3, the decoder regime): ``causal=True``
-skips tiles entirely above the diagonal via ``pl.when`` (~half the MXU
-work at large T) and masks diagonal-straddling tiles in-register;
-``kv_mask`` (B, Tk) handles key padding via a sublane-broadcast
-(B*H, 8, Tk) slab applied multiplicatively to p, so rows with no visible
-key output exactly 0 with zero gradients (the ``NEG`` finite -inf + safe
-l/lse discipline below). Both compose, both differentiate through the
-Pallas backward kernels.
+leaves out the tiles entirely above the diagonal and masks the tiles the
+diagonal crosses; ``kv_mask`` (B, Tk) handles key padding via a
+sublane-broadcast (B*H, 8, Tk) slab applied multiplicatively to p, so rows
+with no visible key output exactly 0 with zero gradients (the ``NEG`` finite
+-inf + safe l/lse discipline below). Both compose, both differentiate
+through the Pallas backward kernels.
 
 The decoder regime proper (a window, and key-value heads shared by groups
 of query heads): ``window=W`` lets row ``i`` see columns ``i - W < j <= i``
@@ -39,9 +38,15 @@ head ``g``). The grid's inner dimension is the *band*: for a q block only
 the kv blocks it can see are visited (``_Band``), in the forward and in
 both backward kernels, so a tile wholly outside the band costs neither a
 product nor a copy, and under ``causal`` the blocks above the diagonal are
-no longer streamed. The dK/dV kernel sums over the query heads of its
-group in its inner dimension. Tiles that no mask edge crosses skip the
-mask arithmetic.
+not streamed. The dK/dV kernel sums over the query heads of its group in
+its inner dimension. Tiles that no mask edge crosses take no mask
+arithmetic.
+
+The row statistics of the online softmax (``m``, ``l`` and the rescale
+``alpha``; ``lse`` and ``di`` in the backward kernels) are kept
+lane-replicated, (rows, 128), in the kernels as in their buffers
+(``_lanes``). What that is worth on the chip, and what walking a tile in
+smaller pieces was not, is PERF.md's to say (section 6, PR 30).
 """
 
 from __future__ import annotations
@@ -217,6 +222,15 @@ def _band_dispatch(band: _Band, has_mask: bool, q_blk, kv_blk, visible,
         functools.partial(compute, False))
 
 
+def _lanes(x, n: int):
+    """(rows, LANE) lane-replicated row statistics against (rows, n) values:
+    a (rows, 1) column costs a register for every eight rows all the same,
+    and every use of it a broadcast across the lanes."""
+    if n % LANE:
+        return x[:, 0:1]
+    return x if n == LANE else jnp.tile(x, (1, n // LANE))
+
+
 def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, band: _Band,
             width: int, has_mask: bool):
     """One (q-block, kv-block) tile. The position in the band is the
@@ -224,8 +238,9 @@ def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, band: _Band,
     times back-to-back with VMEM scratch (acc/m/l) carrying the
     online-softmax state — only one (bq, d) + (bk, d) tile pair is resident
     per step; K/V stream from HBM block-by-block via the BlockSpec pipeline.
-    The final step also writes the row logsumexp (lane-broadcast) — the
-    backward's residual.
+    The running maximum and denominator are lane-replicated, (bq, LANE) as
+    their scratch is (``_lanes``). The final step also writes the row
+    logsumexp (lane-broadcast) — the backward's residual.
 
     Step ``t`` of q block ``j`` is kv block ``kv_lo(j) + t``; steps past
     ``kv_hi(j)`` (a q block whose band is narrower than the widest) do
@@ -257,10 +272,10 @@ def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, band: _Band,
         ) if masked else None
         if vis is not None:
             s = jnp.where(vis, s, NEG)
-        m_prev = m_ref[:, 0:1]  # (bq, 1)
-        l_prev = l_ref[:, 0:1]
+        m_prev = m_ref[:]  # (bq, LANE), every lane the row's
+        l_prev = l_ref[:]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
         if has_mask:
             # all-masked-so-far rows have m_new == NEG and p == exp(0) == 1
             # on masked entries; the multiplicative mask restores exact 0.
@@ -270,26 +285,27 @@ def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, band: _Band,
             p = p * vis
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+        acc_ref[:] = acc_ref[:] * _lanes(alpha, acc_ref.shape[1]) + jnp.dot(
             p.astype(v_ref.dtype), v_ref[0],
             preferred_element_type=jnp.float32
         )
-        m_ref[:, 0:1] = m_new
-        l_ref[:, 0:1] = l_new
+        m_ref[:] = m_new
+        l_ref[:] = l_new
 
     _band_dispatch(band, has_mask, j, kb, kb <= band.kv_hi(j), _compute)
 
     @pl.when(t == width - 1)
     def _finalize():
-        l = l_ref[:, 0:1]
+        l = l_ref[:]  # lane-replicated, like m and like the lse written
         if has_mask:
             safe_l = jnp.where(l > 0, l, 1.0)
-            o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-            lse = jnp.where(l > 0, m_ref[:, 0:1] + jnp.log(safe_l), NEG)
+            o_ref[0] = (acc_ref[:] / _lanes(safe_l, acc_ref.shape[1])
+                        ).astype(o_ref.dtype)
+            lse_ref[0] = jnp.where(l > 0, m_ref[:] + jnp.log(safe_l), NEG)
         else:
-            o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-            lse = m_ref[:, 0:1] + jnp.log(l)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+            o_ref[0] = (acc_ref[:] / _lanes(l, acc_ref.shape[1])
+                        ).astype(o_ref.dtype)
+            lse_ref[0] = m_ref[:] + jnp.log(l)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -454,10 +470,11 @@ def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
     return _unfold(out, q.shape), lse
 
 
-def _tile_p(q, kb, lse_col, q_blk, kv_blk, scale, band: _Band, mask_row,
+def _tile_p(q, kb, lse, q_blk, kv_blk, scale, band: _Band, mask_row,
             masked: bool):
     """Recompute one tile's probabilities p = exp(s - lse) under the same
     visibility the forward applied — shared by both backward kernels.
+    ``lse`` is the rows' logsumexp as it is stored, lane-replicated.
     Masked entries are exact zeros: causal and window masking underflow
     (lse is finite), kv-masked rows with lse == NEG are restored to 0 by
     the multiplicative mask."""
@@ -466,7 +483,7 @@ def _tile_p(q, kb, lse_col, q_blk, kv_blk, scale, band: _Band, mask_row,
                            band.window) if masked else None
     if vis is not None:
         s = jnp.where(vis, s, NEG)
-    p = jnp.exp(s - lse_col)
+    p = jnp.exp(s - _lanes(lse, s.shape[1]))
     if mask_row is not None:
         p = p * vis
     return p
@@ -493,11 +510,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *rest,
     def _compute(masked):
         q = q_ref[0]
         k = k_ref[0]
-        p = _tile_p(q, k, lse_ref[0][:, 0:1], j, kb, scale, band,
+        p = _tile_p(q, k, lse_ref[0], j, kb, scale, band,
                     mask_ref[0, 0:1, :] if has_mask else None, masked)
         dp = jnp.dot(do_ref[0], v_ref[0].T,
                      preferred_element_type=jnp.float32)  # (bq, bk)
-        ds = p * (dp - di_ref[0][:, 0:1]) * scale
+        ds = p * (dp - _lanes(di_ref[0], dp.shape[1])) * scale
         dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
                              preferred_element_type=jnp.float32)
 
@@ -533,12 +550,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *rest,
     def _compute(masked):
         q = q_ref[0]
         do = do_ref[0]
-        p = _tile_p(q, k_ref[0], lse_ref[0][:, 0:1], qb, jk, scale, band,
+        p = _tile_p(q, k_ref[0], lse_ref[0], qb, jk, scale, band,
                     mask_ref[0, 0:1, :] if has_mask else None, masked)
         dv_acc[:] += jnp.dot(p.astype(do.dtype).T, do,
                              preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v_ref[0].T, preferred_element_type=jnp.float32)
-        ds = p * (dp - di_ref[0][:, 0:1]) * scale
+        ds = p * (dp - _lanes(di_ref[0], dp.shape[1])) * scale
         dk_acc[:] += jnp.dot(ds.astype(q.dtype).T, q,
                              preferred_element_type=jnp.float32)
 
@@ -562,7 +579,7 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
     qf, kf, vf = _fold(q, d_pad), _fold(k, d_pad), _fold(v, d_pad)
     gf = _fold(g, d_pad)
     # di = rowsum(dO * O): cheap elementwise+reduce, XLA fuses it; stored
-    # lane-broadcast like lse so the kernels slice column 0.
+    # lane-broadcast like lse, as the kernels use both (``_lanes``).
     di = jnp.broadcast_to(
         jnp.sum(_fold(g.astype(jnp.float32), d_pad)
                 * _fold(o.astype(jnp.float32), d_pad),
@@ -705,8 +722,8 @@ def flash_attention(q, k, v, block_q: int = 128, block_k: int = 128,
 
     ``causal`` masks col > row; ``window`` (which implies it) also masks
     col <= row - window. Only the kv blocks a q block can see are visited
-    (the decoder regime: half the tiles under ``causal`` at large T, a
-    band of them under a window). ``k`` and ``v`` may carry fewer heads
+    (the decoder regime: the tiles on and under the diagonal, a band of
+    them under a window). ``k`` and ``v`` may carry fewer heads
     than ``q``: (B, T, KV, D) with ``H % KV == 0``, each shared by a group
     of ``H // KV`` query heads. ``kv_mask`` (B, Tk),
     nonzero = attend, masks key/value columns (padding); rows with no
