@@ -330,6 +330,29 @@ def per_layer(bench, workload, roots, record, reduced) -> dict:
     return out
 
 
+#: HLO's own names for the instructions that run a branch or a body
+#: (``conditional.2``, ``while``, ``call.7``): a trace shows one as long as
+#: what it runs and shows those operations beside it, so its time is theirs.
+#: They are what the program's map calls ``control`` (``scopes.CONTROL``)
+CONTROL_STEMS = ("conditional", "while", "call")
+
+
+def breakdown(reduced) -> dict:
+    """What the ledger keeps of a traced run, ten entries of each at most.
+    ``device_ops``: the largest operations of chip 0, each as its share of
+    the busy time that ``device_step_ms`` is made of (the union of the ``XLA
+    Ops`` intervals over the slice's steps), every instruction once: one
+    named as control flow (``CONTROL_STEMS``) is left out, because the
+    branch's or body's operations are in the list. ``idle_gaps``: seconds of
+    the traced slice (``device.window_s``) in which chip 0 ran nothing, by
+    what the host was doing."""
+    busy_s = reduced["steps"] * reduced["device_step_ms"] / 1e3
+    shares = [[name, seconds / busy_s]
+              for name, seconds in reduced["device_ops"]
+              if name.split(".")[0] not in CONTROL_STEMS]
+    return {"device_ops": shares[:10], "idle_gaps": reduced["idle_gaps"][:10]}
+
+
 # -- one run -----------------------------------------------------------------
 
 def run_cell(workload, seed, seconds, trace, *, bench_path=None, roots=None,
@@ -391,8 +414,7 @@ def run_cell(workload, seed, seconds, trace, *, bench_path=None, roots=None,
             "examples/s/chip")
         if not -5.0 <= idle <= 100.0:
             raise Refused(f"idle share {idle!r}% is not a share")
-        result["breakdown"] = {"device_ops": reduced["device_ops"][:10],
-                               "idle_gaps": reduced["idle_gaps"][:10]}
+        result["breakdown"] = breakdown(reduced)
     else:
         values = end_to_end(record)
         # an example that is a sequence: its mix states what it holds, and

@@ -27,6 +27,10 @@ import os
 import re
 
 PHASES = ("forward", "backward", "optimizer", "grad_sync", "input", "other")
+#: the map's word for a ``conditional``, a ``while`` or a ``call``: no phase.
+#: A trace shows such an instruction as long as the branch or body it runs
+#: and shows that branch's operations beside it, so its time is theirs
+CONTROL = "control"
 #: a map that lacks the names of more than this share of busy time is the
 #: map of another program
 UNMAPPED_CEILING = 0.01
@@ -121,13 +125,19 @@ def join(device_ops, instructions: dict, steps: int) -> dict:
     ``instructions``: the map's rows by short name. Milliseconds per
     optimizer step by phase and by (module, phase), and the shares of busy
     time that the map lacks, that sit in mixed fusions, and that took their
-    phase from a neighbour."""
-    busy = sum(seconds for _, seconds in device_ops)
+    phase from a neighbour. Busy time is time counted once: an instruction
+    the map calls ``control`` is in none of the sums (``control_ms`` alone
+    says how long the trace showed it)."""
+    busy = control = 0.0
     by_phase = {phase: 0.0 for phase in PHASES}
     by_row = {}
     unmapped = mixed = inherited = 0.0
     for name, seconds in device_ops:
         row = instructions.get(name)
+        if row is not None and row["phase"] == CONTROL:
+            control += seconds
+            continue
+        busy += seconds
         if row is None:
             unmapped += seconds
             continue
@@ -147,6 +157,7 @@ def join(device_ops, instructions: dict, steps: int) -> dict:
         "busy_s": busy,
         "phase_ms": {p: s * to_ms for p, s in by_phase.items()},
         "unmapped_ms": unmapped * to_ms,
+        "control_ms": control * to_ms,
         "unmapped_share": share(unmapped),
         "mixed_share": share(mixed),
         "inherited_share": share(inherited),
@@ -221,7 +232,8 @@ def report_split(split: dict, device_step_ms, out=say):
     total = sum(split["phase_ms"].values()) + split["unmapped_ms"]
     out(f"scopes: ms per step by phase {split['phase_ms']} unmapped "
         f"{split['unmapped_ms']!r} sum {total!r} device_step_ms "
-        f"{device_step_ms!r}")
+        f"{device_step_ms!r} (control flow, counted in what it runs: "
+        f"{split['control_ms']!r})")
     out(f"scopes: share of busy time unmapped "
         f"{100 * split['unmapped_share']!r}% in mixed fusions "
         f"{100 * split['mixed_share']!r}% phase inherited from a neighbour "

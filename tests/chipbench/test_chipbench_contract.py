@@ -1,15 +1,29 @@
-"""BENCHMARK.json against the contract, and every name in it against a file."""
+"""BENCHMARK.json against the contract, and every name in it against a file:
+the shipped one, and a copy to which a configuration, a cell and two
+per-layer metrics were appended the way a PR that changes the program may
+append them (``chipbench_tiny.append``). To try an addition of your own,
+give ``case`` a third parameter that writes it."""
 
-import importlib.util
+import copy
 import json
 import os
 import re
+import sys
+import types
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
+sys.path.insert(0, REPO)
+sys.path.insert(0, HERE)
+
+import chipbench_tiny  # noqa: E402
+from chipbench import run as harness  # noqa: E402
+
 BENCH = os.path.join(REPO, "chipbench")
+SHIPPED = os.path.join(REPO, "BENCHMARK.json")
+LISTS = ("configs", "workloads", "end_to_end", "per_layer")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -17,27 +31,47 @@ WIDTH_WORDS = ("hidden", "intermediate", "latent", "state_size", "proj",
                "width", "filters", "chans", "expansion", "head_dim")
 
 
+@pytest.fixture(scope="module", params=["shipped", "appended"])
+def case(request, tmp_path_factory):
+    """``path`` of a benchmark file and the ``roots`` its names are found
+    under (``harness.find``): the shipped ones, or a copy with new entries
+    at the end of its lists and their files in a directory in front."""
+    if request.param == "shipped":
+        return types.SimpleNamespace(path=SHIPPED, roots=[BENCH],
+                                     appended=False)
+    root = tmp_path_factory.mktemp("appended")
+    path, roots = chipbench_tiny.append(str(root), SHIPPED)
+    return types.SimpleNamespace(path=path, roots=roots + [BENCH],
+                                 appended=True)
+
+
 @pytest.fixture(scope="module")
-def bench():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        return json.load(f)
+def bench(case):
+    return harness.load_json(case.path)
 
 
-def load(path):
-    spec = importlib.util.spec_from_file_location("m", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def appended_only(shipped: dict, proposed: dict) -> bool:
+    """Is ``proposed`` what a PR that changes the program may make of the
+    ``shipped`` BENCHMARK.json: every shipped entry where it was and as it
+    was (its ``workloads`` list too), new entries after the last shipped one
+    of a list, nothing else changed? The driver asks the same question and
+    calls anything else an edit of the benchmark, which only a PR of kind
+    ``benchmark`` may make."""
+    if set(proposed) != set(shipped):
+        return False
+    return all(
+        proposed[key][:len(shipped[key])] == shipped[key] if key in LISTS
+        else proposed[key] == shipped[key] for key in shipped)
 
 
-def test_top_level_keys_and_sizes(bench):
+def test_top_level_keys_and_sizes(case, bench):
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     assert bench["command"] == ["python3", "chipbench/run.py"]
     assert bench["paths"] == ["chipbench", "tests/chipbench"]
     assert isinstance(bench["run_seconds"], int)
     assert 10 <= bench["run_seconds"] <= 51
-    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
+    assert os.path.getsize(case.path) < 64 * 1024
     # a full check at the full 24 cells must fit the driver's 43200 s
     runs = 2 + 14 * 24
     assert (runs * (bench["run_seconds"] + 60) + 24 * 2 * 90 + 1200) <= 43200
@@ -78,7 +112,7 @@ def test_every_name_and_unit_is_legal(bench):
     assert len(pairs) == len(set(pairs))
 
 
-def test_every_configuration_states_its_cut(bench):
+def test_every_configuration_states_its_cut(case, bench):
     used = {w["config"] for w in bench["workloads"]}
     files = [c["file"] for c in bench["configs"]]
     assert len(files) == len(set(files))
@@ -87,8 +121,12 @@ def test_every_configuration_states_its_cut(bench):
         assert entry["name"] in used
         assert entry["file"].startswith("chipbench/")
         assert 1 <= len(entry["source"]) <= 200
-        with open(os.path.join(REPO, entry["file"])) as f:
-            config = json.load(f)
+        path = os.path.join(REPO, entry["file"])
+        if case.appended and not os.path.exists(path):
+            # the copy's own configuration, found as ``load_cell`` finds it
+            path = harness.find(
+                case.roots, "configs", os.path.basename(entry["file"]))
+        config = harness.load_json(path)
         assert config["reduced"] == entry["reduced"]
         assert config["source"] == entry["source"]
         for key in entry["reduced"]:
@@ -103,13 +141,9 @@ def test_every_configuration_states_its_cut(bench):
         assert config["train_config"]["compute_dtype"] == config["precision"]
 
 
-def test_every_cell_is_found_by_name(bench):
-    import sys
-    sys.path.insert(0, REPO)
-    from chipbench import run as harness
-
+def test_every_cell_is_found_by_name(case, bench):
     for cell in bench["workloads"]:
-        loaded = harness.load_cell(bench, cell["name"], [BENCH])
+        loaded = harness.load_cell(bench, cell["name"], case.roots)
         assert loaded["traffic"]["chips"] == cell["chips"]
         assert loaded["traffic"]["mesh"]["data"] == cell["chips"]
         assert set(loaded["limits"]["limits"]) == {
@@ -124,16 +158,81 @@ def test_every_cell_is_found_by_name(bench):
         assert ONE_NOTCH_LOWER[loaded["config"]["precision"]] in PRECISIONS
 
 
-def test_every_per_layer_metric_has_its_reader(bench):
+def test_every_per_layer_metric_has_its_reader(case, bench):
     for metric in bench["per_layer"]:
-        reader = load(os.path.join(
-            BENCH, "layer_metrics", metric["name"] + ".py"))
+        reader = harness.load_module(harness.find(
+            case.roots, "layer_metrics", metric["name"] + ".py"), "reader")
         assert reader.NAME == metric["name"]
         assert reader.UNIT == metric["unit"]
         assert reader.LAYER == metric["layer"]
         assert reader.MOVES == metric["moves"]
         assert reader.SOURCE == metric["source"]
         assert callable(reader.read)
+
+
+def test_a_cell_reports_the_metrics_that_list_it_or_list_nobody(case, bench):
+    """The harness reads each cell's per-layer metrics from whichever file
+    it is given, each in the cells its ``workloads`` list names, or in every
+    cell that reports what it ``moves`` where it has no list. The record
+    here is an untraced run's, a count of steps and of examples: the copy's
+    two readers read those, and what else a later PR's reader finds in it is
+    not this test's to judge."""
+    read = {cell["name"]: harness.per_layer(
+        bench, cell["name"], case.roots, {"steps": 7, "examples": 56}, None)
+        for cell in bench["workloads"]}
+    for metric in bench["per_layer"]:
+        for cell, out in read.items():
+            if not harness.metric_reports_in(metric, cell, bench):
+                assert metric["name"] not in out
+    steps = {"value": 7, "unit": "steps"}
+    examples = {"value": 56, "unit": "examples"}
+    for cell, out in read.items():
+        assert (out.get("window_steps") == steps) == case.appended
+        assert (out.get("window_examples") == examples) == (
+            case.appended and cell == chipbench_tiny.CELL)
+
+
+def test_what_is_not_appended_at_the_end_is_an_edit(tmp_path):
+    """``appended_only`` says yes to the copy the other tests read, and no
+    to the same additions made any other way."""
+    shipped = harness.load_json(SHIPPED)
+    path, _ = chipbench_tiny.append(str(tmp_path), SHIPPED)
+    appended = harness.load_json(path)
+    assert appended_only(shipped, shipped)
+    assert appended_only(shipped, appended)
+    assert [len(appended[key]) - len(shipped[key]) for key in LISTS] == [
+        1, 1, 0, 2]
+
+    def edited(change):
+        proposed = copy.deepcopy(appended)
+        change(proposed)
+        return proposed
+
+    def named(bench, group, name):
+        return next(m for m in bench[group] if m["name"] == name)
+
+    last = len(shipped["per_layer"]) - 1
+    cell = chipbench_tiny.CELL
+    edits = {
+        # what PR 31 did: the new entry before the last shipped one
+        "placed_before_the_last": lambda b: b["per_layer"].insert(
+            last, b["per_layer"].pop()),
+        # a shipped metric made to report in the new cell
+        "workloads_lengthened": lambda b: named(
+            b, "per_layer", "device_moe_ms")["workloads"].append(cell),
+        "workloads_given": lambda b: named(
+            b, "per_layer", "device_step_ms").update(workloads=[cell]),
+        "cell_placed_first": lambda b: b["workloads"].insert(
+            0, b["workloads"].pop()),
+        "bound_loosened": lambda b: named(
+            b, "end_to_end", "images_per_s_per_chip").update(bound=0.02),
+        "entry_taken_away": lambda b: b["per_layer"].remove(
+            named(b, "per_layer", "compile_s")),
+        "run_seconds_changed": lambda b: b.update(run_seconds=10),
+        "key_added": lambda b: b.update(notes="x"),
+    }
+    for name, change in edits.items():
+        assert not appended_only(shipped, edited(change)), name
 
 
 def test_peaks_table_has_the_chip_and_its_source():

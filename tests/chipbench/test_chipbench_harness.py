@@ -23,7 +23,7 @@ import chipbench_tiny  # noqa: E402
 from chipbench import control, datagen  # noqa: E402
 from chipbench import run as harness  # noqa: E402
 
-CELL = "tiny-netresdeep.t8"
+CELL = chipbench_tiny.CELL
 _CONFIG = ("jax_compilation_cache_dir",
            "jax_persistent_cache_min_compile_time_secs",
            "jax_persistent_cache_min_entry_size_bytes")
@@ -40,7 +40,10 @@ def keep_jax_config():
 
 def run_tiny(tmp_path, seed=5, **kwargs):
     bench, roots = chipbench_tiny.write(str(tmp_path), **kwargs)
-    return harness.run_cell(CELL, seed, 0.5, False, bench_path=bench,
+    # 1.0 s: the harness wants 20 dispatch intervals, so three of these
+    # 8-step epochs, so 16 steps inside ``--seconds``: a CPU step of up to
+    # 62.5 ms (0.5 s held up to 31.25 ms; test_chipbench_laguna.py::SECONDS)
+    return harness.run_cell(CELL, seed, 1.0, False, bench_path=bench,
                             roots=roots, device_check=False)
 
 
@@ -55,6 +58,19 @@ def test_a_cell_made_of_new_files_only_runs_and_is_correct(tmp_path):
     assert set(result["device"]) >= {"platform", "kind", "count",
                                      "memory_peak_bytes"}
     json.dumps(result)
+
+
+def test_the_cell_runs_from_a_copy_of_the_shipped_benchmark(tmp_path):
+    """What a later PR does: its entries at the end of the shipped lists,
+    its files beside the shipped ones, and the shipped metrics' own
+    ``workloads`` lists decide what the new cell reports."""
+    bench, roots = chipbench_tiny.append(
+        str(tmp_path), os.path.join(REPO, "BENCHMARK.json"))
+    result = harness.run_cell(CELL, 2**31 + 12, 1.0, False, bench_path=bench,
+                              roots=roots, device_check=False)
+    assert result["correct"] is True and result["attempted"] > 20
+    # ``step_ms_p95`` lists the one shipped cell that reports it
+    assert set(result["metrics"]) == {"images_per_s_per_chip", "setup_s"}
 
 
 def test_four_shards_agree_with_the_per_shard_reference(tmp_path):

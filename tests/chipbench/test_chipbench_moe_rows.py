@@ -13,7 +13,9 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, REPO)
+sys.path.insert(0, HERE)
 
+import chipbench_tiny  # noqa: E402
 from chipbench import run as harness  # noqa: E402
 
 NAME = "moe_rows_walked_over_landed"
@@ -37,9 +39,18 @@ def _traced(tmp_path, gauges):
                "device_step_ms": 100.0})
 
 
-def test_the_entry_names_the_cell_that_holds_a_share():
-    bench = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
-    entry = bench["per_layer"][-1]   # new entries go last
+@pytest.mark.parametrize("appended", [False, True],
+                         ids=["shipped", "appended"])
+def test_the_entry_names_the_cell_that_holds_a_share(tmp_path, appended):
+    """Found by its name: a later PR's entries go after it, at the end of
+    the list (``chipbench_tiny.append`` makes such a copy)."""
+    path = os.path.join(REPO, "BENCHMARK.json")
+    if appended:
+        path, _ = chipbench_tiny.append(str(tmp_path), path)
+    bench = harness.load_json(path)
+    names = [m["name"] for m in bench["per_layer"]]
+    assert not appended or names.index(NAME) < len(names) - 1
+    entry = next(m for m in bench["per_layer"] if m["name"] == NAME)
     assert entry == {
         "name": NAME, "unit": "ratio", "better": "lower",
         "source": "program_counter", "layer": "models",
@@ -126,6 +137,10 @@ def test_a_switch_is_not_summed_with_the_branch_it_runs():
     assert sum(split["phase_ms"][p] for p in scopes.PHASES) == pytest.approx(
         15.0)
     assert split["unmapped_share"] == 0.0
+    # nor is it busy time: the shares are of time counted once
+    assert split["busy_s"] == pytest.approx(0.030)
+    assert split["inherited_share"] == pytest.approx(1 / 3)
+    assert split["control_ms"] == pytest.approx(15.0)
     by_module = {module: ms for module, phase, ms in split["rows"]
                  if phase in scopes.PHASES}
     assert by_module == {"moe_combine": pytest.approx(10.0),

@@ -22,7 +22,7 @@ import chipbench_tiny  # noqa: E402
 from chipbench import run as harness  # noqa: E402
 from chipbench import scopes  # noqa: E402
 
-CELL = "tiny-netresdeep.t8"
+CELL = chipbench_tiny.CELL
 METRICS = ("device_forward_ms", "device_backward_ms", "device_optimizer_ms",
            "device_grad_sync_ms", "device_other_ms")
 _CONFIG = ("jax_compilation_cache_dir",
@@ -203,3 +203,191 @@ def test_the_tiny_cell_writes_what_the_readers_read(tmp_path, capsys,
     assert out["trace_lower_s"]["value"] > 0
     printed = capsys.readouterr().out
     assert "scopes: shard_step" in printed  # the per-function table
+
+
+# -- the recorded v5e slice under a map, against the parent's readings -------
+
+TESTDATA = os.path.join(harness.HERE, "testdata")
+#: real operations of the slice, renamed to what the decoder cell's and the
+#: four-chip cell's readers look for by name
+RENAMED = {"fusion.4": "ragged-dot-none.3", "fusion.13": "ragged-dot-metadata.5",
+           "fusion.17": "all-reduce.7"}
+#: the two longest, which a window layer's kernels could be (2.8 and 2.6 ms
+#: a step against 1.35 and 2.03 at the least); no operation is long enough
+#: for any other call of a flash kernel
+KERNELS = {"select_and_scatter.19": ("attention_window", "flash_fwd"),
+           "broadcast_maximum_fusion": ("attention_window", "flash_dq")}
+MODULES = ("layer_0", "moe_route", "moe_dispatch", "moe_experts",
+           "moe_combine", "moe_shared", "attention_window", "attention_full",
+           "optimizer_update", "")
+
+
+def recorded_run(tmp_path):
+    """``(record, reduced trace)`` of the recorded v5e slice, dressed as a
+    traced run of ``laguna-xs2.seq8k`` leaves one. In each of its 39 program
+    executions a ``conditional.1`` lies over operations 100-199 and a
+    ``while.2`` over operations 250-259, as a trace shows a switch: as long
+    as what it runs, and that beside it. The map calls the two ``control``
+    and gives every other name a phase and a module by its checksum; two
+    operations are calls of the flash kernels, three are renamed."""
+    import zlib
+
+    from chipbench import xplane
+
+    with open(os.path.join(TESTDATA, "recorded.json")) as f:
+        recorded = json.load(f)
+    planes = xplane.load(os.path.join(TESTDATA, recorded["file"]))
+    lines = planes["devices"][0]
+    ops = sorted(((RENAMED.get(n, n), s, e)
+                  for n, s, e in lines[xplane.OPS_LINE]), key=lambda o: o[1])
+    for _, start, end in lines[xplane.MODULES_LINE]:
+        inside = [o for o in ops if start <= o[1] < end]
+        ops.append(("conditional.1", inside[100][1], inside[199][2]))
+        ops.append(("while.2", inside[250][1], inside[259][2]))
+    lines[xplane.OPS_LINE] = ops
+    spans = [(name, 0.07 * i + a, 0.07 * i + b) for i in range(39)
+             for name, a, b in (("data_wait", 0.0, 0.004), ("h2d", 0.004, 0.009),
+                                ("compiled_step", 0.009, 0.013))]
+    reduced = xplane.reduce(planes, window_s=recorded["window_s"],
+                            dispatches=recorded["programs"])
+
+    instructions = {}
+    for name, _ in reduced["device_ops"]:
+        n = zlib.crc32(name.encode())
+        module, kernel = KERNELS.get(name, (MODULES[n % len(MODULES)], None))
+        instructions[name] = {
+            "opcode": "custom-call" if name.startswith("ragged") else "fusion",
+            "op_name": "jit(shard_step)/layer_1/tpu_ddp.module." + module + (
+                f"/tpu_ddp.kernel.{kernel}/pallas_call" if kernel else "/op"),
+            "phase": scopes.PHASES[n // 16 % len(scopes.PHASES)],
+            "module": module, "mixed": n % 7 == 0, "inherited": n % 11 == 0}
+    for name in ("conditional.1", "while.2"):
+        instructions[name] = {"opcode": name.split(".")[0], "op_name": "",
+                              "phase": "control", "module": ""}
+    tel = tmp_path / "laguna-xs2.seq8k" / "telemetry"
+    tel.mkdir(parents=True)
+    (tel / "programs-p0.jsonl").write_text(json.dumps({
+        "type": "program_map", "program": "train_step",
+        "instructions": instructions}) + "\n")
+    (tel / "trace-p0.jsonl").write_text(json.dumps({
+        "type": "counters", "attrs": {
+            "tables": {"jax/functions": {}},
+            "histograms": {"jax/trace_seconds": {"sum": 12.5},
+                           "jax/lower_seconds": {"sum": 7.25}},
+            "gauges": {"model/expert_load_max": 2260.0,
+                       "model/expert_load_mean": 565.5,
+                       "model/expert_load_sum": 72388.0,
+                       "model/expert_rows_walked_sum": 131072.0}}}) + "\n")
+    record = {"trace_dir": str(tel.parent / "profile"), "steps": 39,
+              "chips": 1, "examples": 78, "host_spans": spans,
+              "peak_flops_per_s": 197e12, "trainer_init_s": 6.25,
+              "compile_s": 5.5, "train_flops_per_example": 1.97e11}
+    return record, reduced
+
+
+def read_recorded(record, reduced, bench_path, roots) -> dict:
+    """cell -> {metric: value}: every per-layer metric of the benchmark file
+    at ``bench_path`` that finds something to read in ``recorded_run``, in
+    the decoder's cell and in the four-chip cell."""
+    bench = harness.load_json(bench_path)
+    return {cell: {name: m["value"] for name, m in harness.per_layer(
+        bench, cell, roots, record, reduced).items()}
+        for cell in ("laguna-xs2.seq8k", "resnet50-cifar.dp4")}
+
+
+@pytest.mark.parametrize("appended", [False, True],
+                         ids=["shipped", "appended"])
+def test_no_metric_reads_the_busy_sum_or_the_breakdown(tmp_path, capsys,
+                                                       appended):
+    """``testdata/recorded_metrics.json`` holds what the parent of the PR
+    that took ``control`` out of ``busy_s`` and put the ``breakdown`` in
+    shares read here, metric by metric: each of those is that, to the last
+    digit. What a later PR appends to ``per_layer`` is read too (the copy's
+    ``window_steps`` is) and is not this test's to judge."""
+    with open(os.path.join(TESTDATA, "recorded_metrics.json")) as f:
+        parent = json.load(f)["metrics"]
+    record, reduced = recorded_run(tmp_path)
+    bench_path, roots = os.path.join(REPO, "BENCHMARK.json"), []
+    if appended:
+        bench_path, roots = chipbench_tiny.append(
+            str(tmp_path / "appended"), bench_path)
+    got = read_recorded(record, reduced, bench_path, roots + [harness.HERE])
+    for cell, metrics in parent.items():
+        assert {name: got[cell][name] for name in metrics} == metrics
+        assert ("window_steps" in got[cell]) == appended
+    with open(os.path.join(TESTDATA, "recorded.json")) as f:
+        recorded = json.load(f)  # the slice as recorded, without a switch
+    by_phase = sum(got["resnet50-cifar.dp4"][name] for name in METRICS)
+    assert by_phase == pytest.approx(recorded["device_step_ms"], rel=1e-12)
+    # the union is a little longer: a switch covers the gaps between the
+    # operations it runs
+    step = got["laguna-xs2.seq8k"]["device_step_ms"]
+    assert by_phase < step < 1.0001 * by_phase
+    # what did move: the shares are of time counted once ...
+    seconds = dict(map(tuple, reduced["device_ops"]))
+    instructions = scopes.load_map(scopes.newest(scopes.telemetry_dir(
+        record))["programs"])["instructions"]
+    split = scopes.join(reduced["device_ops"], instructions, reduced["steps"])
+    assert split["busy_s"] == pytest.approx(recorded["busy_s"], rel=1e-12)
+    assert split["control_ms"] * 39 / 1e3 == pytest.approx(
+        seconds["conditional.1"] + seconds["while.2"])
+    assert f"sum {by_phase!r} device_step_ms {step!r}" in (
+        capsys.readouterr().out)
+    # ... and so are the breakdown's: every instruction once, the switch
+    # (the longest "operation" of the slice) not among them
+    assert reduced["device_ops"][0][0] == "conditional.1"
+    ops = harness.breakdown(reduced)["device_ops"]
+    assert len(ops) == 10 and ops[0] == [
+        "select_and_scatter.19",
+        pytest.approx(seconds["select_and_scatter.19"] / (39 * step / 1e3))]
+    assert ops[0][1] * step == pytest.approx(
+        1e3 * seconds["select_and_scatter.19"] / 39)  # its ms a step
+    assert sum(share for _, share in ops) < 1
+    # the names it leaves out are the rows the map leaves out of ``busy_s``
+    assert {name for name in seconds
+            if name.split(".")[0] in harness.CONTROL_STEMS} == {
+        name for name, row in instructions.items()
+        if row["phase"] == scopes.CONTROL} == {"conditional.1", "while.2"}
+
+
+def test_the_breakdown_leaves_control_flow_out_by_its_name():
+    """An instruction's name is its opcode and a number unless someone named
+    it: ``run.CONTROL_STEMS`` are the opcodes to which the program's map
+    gives the phase ``control`` (a ``conditional``, a ``while``, a ``call``),
+    and a fusion that only starts like one stays in the list."""
+    reduced = {"steps": 4, "device_step_ms": 250.0, "idle_gaps": [],
+               "device_ops": [["call.3", 0.9], ["while", 0.5],
+                              ["fusion.1", 0.4], ["conditional.12", 0.3],
+                              ["while_fusion.2", 0.2], ["custom-call.5", 0.1]]}
+    assert harness.breakdown(reduced) == {"idle_gaps": [], "device_ops": [
+        ["fusion.1", 0.4], ["while_fusion.2", 0.2], ["custom-call.5", 0.1]]}
+
+
+def test_the_unmapped_ceiling_is_of_time_counted_once(tmp_path, capsys):
+    """``busy_s`` feeds one decision: a map that lacks the names of more
+    than ``UNMAPPED_CEILING`` of it is another program's, and no phase
+    metric is reported. Without the ``control`` rows the sum is smaller and
+    the check stricter by as much: unmapped time just under 1% of the sum
+    that counted a switch twice is refused where the switch is long."""
+    mapped = dict(INSTRUCTIONS, **{"fusion.99": row("other")})
+    mapped["conditional.1"] = row(scopes.CONTROL)
+    mapped["conditional.1"]["opcode"] = "conditional"
+    once = sum(s for _, s in DEVICE_OPS)                      # 0.64 s
+    switch = ["conditional.1", 0.60]  # over fusion.2, fusion.1 and fusion.3
+
+    def split_with(unmapped_s, name):
+        (tmp_path / name).mkdir()
+        run = write_run(tmp_path / name, instructions=mapped)
+        run.trace = dict(run.trace, device_ops=DEVICE_OPS + [
+            switch, ["fusion.100", unmapped_s]])
+        return scopes.of_run(run)["split"]
+
+    under = 0.0099 * once / (1 - 0.0099)    # 0.99% of time counted once
+    split = split_with(under, "under")
+    assert split["busy_s"] == pytest.approx(once + under)
+    assert split["unmapped_share"] == pytest.approx(0.0099)
+    # 0.99% of the doubled sum is 1.9% of the time: not this program's map
+    doubled = 0.0099 * (once + switch[1]) / (1 - 0.0099)
+    assert doubled / (once + switch[1] + doubled) < scopes.UNMAPPED_CEILING
+    assert split_with(doubled, "doubled") is None
+    assert "map of another program" in capsys.readouterr().out

@@ -42,7 +42,10 @@ def keep_jax_config():
 
 def run_lm(tmp_path, cell=tiny_lm.CELL, seed=5, **kwargs):
     bench, roots = tiny_lm.write(str(tmp_path), **kwargs)
-    return harness.run_cell(cell, seed, 0.3, False, bench_path=bench,
+    # 1.0 s: the harness wants 20 dispatch intervals, so three of these
+    # 8-step epochs, so 16 steps inside ``--seconds``: a CPU step of up to
+    # 62.5 ms (0.3 s held up to 18.75 ms; test_chipbench_laguna.py::SECONDS)
+    return harness.run_cell(cell, seed, 1.0, False, bench_path=bench,
                             roots=roots, device_check=False)
 
 
