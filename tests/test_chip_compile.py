@@ -152,6 +152,96 @@ def test_the_routed_ladder_compiles_at_the_decoder_widths(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
+# ---- the hybrid decoder's own shapes (nemotron3-super.seq8k-v16384) ---------
+
+def test_the_state_space_scan_compiles_at_the_hybrid_decoder_widths(topo):
+    """``ops/ssd_scan.py`` at the benchmark cell's shapes: 2 sequences of
+    8,192 positions, 16 heads of 64 on one B/C group of state 128, chunks of
+    128, bfloat16 operands; forward and the hand-written backward pass. The
+    (128, 128) blocks of 64 chunks a head are float32 temporaries, 134 MB
+    each, of which the two passes hold a few at a time."""
+    from tpu_ddp.ops.ssd_scan import ssd_scan
+
+    one = _one_chip(topo)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    operands = (shape((2, 8192, 16, 64)), shape((2, 8192, 16), jnp.float32),
+                shape((16,), jnp.float32), shape((2, 8192, 1, 128)),
+                shape((2, 8192, 1, 128)))
+    forward = jax.jit(ssd_scan).lower(*operands).compile()
+    assert forward.memory_analysis().temp_size_in_bytes < 1.5e9
+    backward = jax.jit(jax.grad(
+        lambda *a: ssd_scan(*a).astype(jnp.float32).sum(),
+        range(5))).lower(*operands).compile()
+    assert backward.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def test_the_routed_ladder_compiles_at_the_latent_experts_widths(topo):
+    """``_switch`` over the hybrid cell's ladder: 16,384 tokens x 22 choices
+    with 8 of 512 plain experts held, so 11,264 rows and, a token's choices
+    being distinct, 131,072 at most (not 360,448); latent width 1,024,
+    expert width 2,688, no gate matrix."""
+    import functools
+
+    from tpu_ddp.models import moe
+
+    one = _one_chip(topo)
+    n, c, f, k, held = 16384, 1024, 2688, 22, 8
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    rungs = moe.buffer_rungs(n * k, held, 512, k)
+    assert rungs == (11264, 131072)
+    walk = tuple(functools.partial(moe._routed, r, k, jnp.bfloat16)
+                 for r in rungs)
+    routing = (shape((n * k,), jnp.int32), None, shape((held,), jnp.int32),
+               shape((n,), jnp.int32))
+    floats = (shape((n, c), jnp.bfloat16), None,
+              shape((held, c, f), jnp.bfloat16),
+              shape((held, f, c), jnp.bfloat16), shape((n, k), jnp.float32))
+
+    def loss(floats, index, routing):
+        return moe._switch(walk, index, routing, floats).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        floats, shape((), jnp.int32), routing).compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 2
+    assert text.count("ragged-dot") >= 2 * len(rungs)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def test_a_recomputed_expert_block_walks_its_routed_path_twice(topo):
+    """A recomputed block of the hybrid decoder keeps the routed result
+    (``moe.ROUTED_NAME``, 33.5 MB a block at the cell's sizes), so its
+    backward pass makes the router, the sorts and the projections again but
+    not the ladder's branch with its grouped products: one switch forward
+    and one backward for the one expert block, where recomputing everything
+    has three."""
+    from tpu_ddp.models.hybrid import HybridDecoder, nemotron3_super_spec
+
+    one = _one_chip(topo)
+    spec = nemotron3_super_spec(num_layers=2, experts_held=8, vocab_rows=512,
+                                head_positions=8)
+    model = HybridDecoder(spec, dtype=jnp.bfloat16, remat=True)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 256), jnp.int32)))["params"]
+    params = jax.tree.map(lambda l: jax.ShapeDtypeStruct(
+        l.shape, l.dtype, sharding=one), shapes)
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one)
+
+    def loss(p, tokens):
+        logits, _ = model.apply({"params": p}, tokens, mutable=["counters"])
+        return logits.sum()
+
+    text = jax.jit(jax.grad(loss)).lower(params, tokens).compile().as_text()
+    assert text.count(" conditional(") == 2
+
+
 # ---- the int8 ring's quantize / dequantize ----------------------------------
 
 def test_fused_quant_compiles_at_real_size(topo):

@@ -8,6 +8,13 @@ published sizes.
 
     y = x + Attn(RMSNorm(x));  z = y + FFN(RMSNorm(y));  head(RMSNorm(z_last))
 
+``GroupedQueryAttention`` is also the attention mixer of the hybrid stack
+(``models/hybrid.py``), whose blocks have one mixer each: a ``LayerSpec``
+with ``rotary`` None rotates nothing, one with ``gate`` False has no output
+gate, and its ``heads`` and the ``kv_heads`` beside it are the heads held
+here (``parallel.expert_parallel.HeadShare``), whichever of the published
+ones those are.
+
 Input ``tokens`` (B, T) int32, output float32 logits (B, T, vocabulary rows
 held). The sequence length is the data's. What the model trains on is its
 ``task`` (``train/tasks.py``): tokens in, masked next-token loss out.
@@ -93,8 +100,9 @@ def rotate(x, cos, sin):
 class LayerSpec:
     heads: int           # query heads
     window: int          # 0 = full causal attention
-    rotary: Rotary
+    rotary: Optional[Rotary]  # None = no position encoding in attention
     sparse: bool         # routed experts + shared expert, else dense SwiGLU
+    gate: bool = True    # the head-wise output gate
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,9 +133,11 @@ def reference_attention(q, k, v, *, causal=True, window=0):
 
 class GroupedQueryAttention(nn.Module):
     """Causal attention of ``spec.heads`` query heads over ``kv_heads``
-    shared key-value heads, rotary positions, an optional window, and a
+    shared key-value heads, an optional window, and, as ``spec`` says,
+    rotary positions (``cos``, ``sin`` of ``spec.rotary``'s tables) and a
     head-wise output gate ``o_h = sigmoid(x w_h) * attn_h`` before the output
-    projection."""
+    projection. A share of the heads is the same module with fewer of them:
+    its output is then partial, the other shares' to add to."""
 
     spec: LayerSpec
     kv_heads: int
@@ -136,19 +146,22 @@ class GroupedQueryAttention(nn.Module):
     attention_impl: Optional[Callable] = None
 
     @nn.compact
-    def __call__(self, x, cos, sin):
+    def __call__(self, x, cos=None, sin=None):
         B, T, C = x.shape
         H, KV, D = self.spec.heads, self.kv_heads, self.head_dim
         dense = lambda n, name: nn.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name)
-        q = rotate(dense(H * D, "q")(x).reshape(B, T, H, D), cos, sin)
-        k = rotate(dense(KV * D, "k")(x).reshape(B, T, KV, D), cos, sin)
+        turn = (lambda a: a) if self.spec.rotary is None else (
+            lambda a: rotate(a, cos, sin))
+        q = turn(dense(H * D, "q")(x).reshape(B, T, H, D))
+        k = turn(dense(KV * D, "k")(x).reshape(B, T, KV, D))
         v = dense(KV * D, "v")(x).reshape(B, T, KV, D)
         attend = self.attention_impl or reference_attention
         kind = "attention_window" if self.spec.window else "attention_full"
         with jax.named_scope(module_scope(kind)):
             o = attend(q, k, v, causal=True, window=self.spec.window)
-        o = o * nn.sigmoid(dense(H, "gate")(x))[..., None]
+        if self.spec.gate:
+            o = o * nn.sigmoid(dense(H, "gate")(x))[..., None]
         return dense(C, "o")(o.reshape(B, T, H * D))
 
 

@@ -28,6 +28,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from tpu_ddp.models.vit import MultiHeadSelfAttention, TransformerBlock
 from tpu_ddp.models.zoo import register
@@ -269,41 +270,49 @@ def vit_moe_s4_top2(num_classes: int = 10, bn_cross_replica_axis=None,
 
 #: rows of the grouped kernel's tile: a rung of the ladder is whole tiles
 _ROW_TILE = 512
+#: the routed result's name to a recomputation policy (``checkpoint_name``)
+ROUTED_NAME = "moe_routed"
 
 
-def buffer_rungs(pairs: int, held: int, num_experts: int):
-    """The lengths the sorted buffer may take, ascending, the last one
-    ``pairs`` (a row for every (token, choice): no capacity, no drop). A
+def buffer_rungs(pairs: int, held: int, num_experts: int, top_k: int = 0):
+    """The lengths the sorted buffer may take, ascending, the last one the
+    most rows that can land here (no capacity, no drop): ``pairs``, a row
+    for every (token, choice), or, told ``top_k`` larger than ``held``,
+    ``held`` rows a token, since a token's choices are distinct experts. A
     fair router lands ``pairs * held / num_experts`` rows on the held
     experts; the short rung is twice that, in whole tiles, where that is
-    shorter than ``pairs``. A whole share has the one rung. Two rungs and
+    shorter than the last. A whole share has the one rung. Two rungs and
     not more: each is one more copy of the routed path to trace, lower,
     compile and load (PERF.md section 6, PR 28)."""
+    most = pairs // top_k * held if top_k > held else pairs
     fair = -(-pairs * held // num_experts)
     short = 2 * -(-fair // _ROW_TILE) * _ROW_TILE
-    return (short, pairs) if short < pairs else (pairs,)
+    return (short, most) if short < most else (most,)
 
 
 class _ByToken(NamedTuple):
     """Where a short buffer's landed rows sit once put in token order: a
-    token's rows side by side, in the order of its choices. ``R + k - 1``
-    places, so that the ``k`` from any place on are there to read."""
+    token's rows side by side, in the order of its choices. ``R + reach -
+    1`` places, ``reach`` the most rows one token can land, so that the
+    ``reach`` from any place on are there to read."""
 
     rows: jax.Array    # the buffer's row at each place of token order
     token: jax.Array   # that row's token; past landed, no token's number
     count: jax.Array   # (N,) rows each token landed
 
 
-def _by_token(order, landed, count, k):
+def _by_token(order, landed, count, k, reach):
     """``order`` is the first ``R`` of the sorted buffer's (token, choice)
-    pairs, ``landed`` of them real. One sort of ``R`` keys."""
+    pairs, ``landed`` of them real; a token lands ``reach`` rows at most
+    (``k``, or the experts held where those are fewer). One sort of ``R``
+    keys."""
     tokens = count.shape[0]
     pair = jnp.where(jnp.arange(order.shape[0]) < landed, order, tokens * k)
     rows = jnp.argsort(pair).astype(jnp.int32)
     return _ByToken(
-        jnp.concatenate([rows, jnp.zeros((k - 1,), jnp.int32)]),
+        jnp.concatenate([rows, jnp.zeros((reach - 1,), jnp.int32)]),
         jnp.concatenate([jnp.take(pair, rows) // k,
-                         jnp.full((k - 1,), tokens + 1, jnp.int32)]),
+                         jnp.full((reach - 1,), tokens + 1, jnp.int32)]),
         count)
 
 
@@ -311,18 +320,18 @@ def _spread_rows(x, order, k):
     return jnp.take(x, order // k, axis=0)
 
 
-def _collect_rows(y, back, k):
+def _collect_rows(y, back, k, reach=None):
     if not isinstance(back, _ByToken):  # a row for every pair: by ``inverse``
         rows = jnp.take(y, back, axis=0).astype(jnp.float32)
         return rows.reshape(-1, k, y.shape[-1]).sum(axis=1).astype(y.dtype)
     # a short buffer: its rows in token order, at each place the float32 sum
-    # of the rows of that place's token from there on (at most ``k``, in the
-    # order of the choices, as the full buffer sums them), and of these each
-    # token's first
+    # of the rows of that place's token from there on (``reach`` at most, as
+    # ``_by_token`` was told; in the order of the choices, as the full buffer
+    # sums them), and of these each token's first
     rows = jnp.take(y, back.rows, axis=0, mode="clip")
     rung = y.shape[0]
     total = rows[:rung].astype(jnp.float32)
-    for j in range(1, k):
+    for j in range(1, reach):
         same = (back.token[j:j + rung] == back.token[:rung])[:, None]
         total = total + jnp.where(same, rows[j:j + rung], 0).astype(
             jnp.float32)
@@ -331,29 +340,33 @@ def _collect_rows(y, back, k):
     return jnp.where((back.count > 0)[:, None], sums, 0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _spread(x, order, back, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _spread(x, order, back, k, reach=None):
     """Row ``a`` of the result is ``x[order[a] // k]``: ``x``'s rows, one per
     (token, choice) that ``order`` names, in the order it gives. The
     transpose of ``_collect``; both ways are gathers, never a scatter."""
     return _spread_rows(x, order, k)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _collect(y, order, back, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _collect(y, order, back, k, reach=None):
     """Rows of ``y`` put back where ``order`` took them from and summed over
     each token's choices, in float32: ``(R, C) -> (N, C)``. ``back`` says
     where: the inverse of ``order`` when ``y`` has a row for every (token,
-    choice), a ``_ByToken`` when it has the first ``R`` of them."""
-    return _collect_rows(y, back, k)
+    choice), a ``_ByToken`` when it has the first ``R`` of them, with the
+    ``reach`` it was made for."""
+    return _collect_rows(y, back, k, reach)
 
 
 _spread.defvjp(
-    lambda x, order, back, k: (_spread_rows(x, order, k), (order, back)),
-    lambda k, res, g: (_collect_rows(g, res[1], k), None, None))
+    lambda x, order, back, k, reach: (
+        _spread_rows(x, order, k), (order, back)),
+    lambda k, reach, res, g: (
+        _collect_rows(g, res[1], k, reach), None, None))
 _collect.defvjp(
-    lambda y, order, back, k: (_collect_rows(y, back, k), (order, back)),
-    lambda k, res, g: (_spread_rows(g, res[0], k), None, None))
+    lambda y, order, back, k, reach: (
+        _collect_rows(y, back, k, reach), (order, back)),
+    lambda k, reach, res, g: (_spread_rows(g, res[0], k), None, None))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -400,15 +413,19 @@ def _routed(rung, k, dtype, routing, xf, w_gate, w_up, w_down, weights):
     rows: ``xf`` (N, C) tokens, ``weights`` (N, k) of their choices,
     ``routing`` = ``order``, ``inverse`` (N * k,), ``load`` (held,) and, for
     a buffer shorter than ``order``, the rows each token landed (N,).
-    ``rung`` holds what landed (``load.sum()``): the caller's to see to."""
+    ``rung`` holds what landed (``load.sum()``): the caller's to see to.
+    An expert is ``down(silu(gate(x)) * up(x))``, or, with ``w_gate`` None,
+    the plain ``down(relu(up(x)) ** 2)``."""
     from tpu_ddp.telemetry.phases import module_scope
 
     order, back, load = routing[:3]
-    width = w_gate.shape[-1]
+    reach = None
     with jax.named_scope(module_scope("moe_dispatch")):
         if rung < order.shape[0]:
-            order = order[:rung]
-            back = _by_token(order, load.sum(), routing[3], k)
+            # a token's choices are distinct experts, so it lands ``k`` rows
+            # at most, or as many as there are experts held
+            order, reach = order[:rung], min(k, load.shape[0])
+            back = _by_token(order, load.sum(), routing[3], k, reach)
         # the buffer's first ``landed`` rows are real. What a grouped
         # product leaves in the others is unspecified (on the chip:
         # whatever the memory held, NaN included), so each of its results
@@ -416,17 +433,23 @@ def _routed(rung, k, dtype, routing, xf, w_gate, w_up, w_down, weights):
         # (the select's transpose) backward
         real = (jnp.arange(rung) < load.sum())[:, None]
         keep = lambda a: jnp.where(real, a, 0)  # noqa: E731
-        rows = keep(_spread(xf, order, back, k))
+        rows = keep(_spread(xf, order, back, k, reach))
 
     with jax.named_scope(module_scope("moe_experts")):
-        w_in = jnp.concatenate([w_gate, w_up], axis=-1).astype(dtype)
-        h = keep(grouped_matmul(rows, w_in, load))
-        h = nn.silu(h[:, :width]) * h[:, width:]
+        if w_gate is None:
+            h = keep(grouped_matmul(rows, w_up.astype(dtype), load))
+            h = jnp.square(nn.relu(h))
+        else:
+            width = w_gate.shape[-1]
+            w_in = jnp.concatenate([w_gate, w_up], axis=-1).astype(dtype)
+            h = keep(grouped_matmul(rows, w_in, load))
+            h = nn.silu(h[:, :width]) * h[:, width:]
         out = keep(grouped_matmul(h, w_down.astype(dtype), load))
 
     with jax.named_scope(module_scope("moe_combine")):
         w_sorted = jnp.take(weights.reshape(-1), order)[:, None]
-        return _collect(out * w_sorted.astype(out.dtype), order, back, k)
+        return _collect(out * w_sorted.astype(out.dtype), order, back, k,
+                        reach)
 
 
 #: traced once per rung and shapes: the layers of a stack, the forward and
@@ -448,9 +471,24 @@ class SwiGLU(nn.Module):
         return dense(x.shape[-1], "down")(h)
 
 
+class Relu2MLP(nn.Module):
+    """``down(relu(up(x)) ** 2)``: ``SwiGLU``'s sibling without a gate, no
+    biases, float32 weights."""
+
+    width: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
+        return dense(x.shape[-1], "down")(
+            jnp.square(nn.relu(dense(self.width, "up")(x))))
+
+
 class DroplessMoE(nn.Module):
-    """Top-k routed SwiGLU experts without a capacity, the share of them
-    that lives here, and a shared expert.
+    """Top-k routed experts without a capacity, the share of them that lives
+    here, and a shared expert.
 
     ``share`` (``parallel.expert_parallel.ExpertShare``: ``num_experts``,
     ``held``, ``offset``) says which experts this layer holds. The router
@@ -463,22 +501,40 @@ class DroplessMoE(nn.Module):
     would add is theirs to add. ``sum over shares of (y - shared) + shared``
     is the whole layer.
 
+    The kinds of layer it is, by three options. ``gated`` (the default): an
+    expert, routed or shared, is a SwiGLU, ``down(silu(gate(x)) * up(x))``;
+    without it the plain ``down(relu(up(x)) ** 2)``, which has no ``w_gate``
+    and no ``shared/gate``. ``latent`` > 0: the routed experts work in a
+    space of that width, between ``latent_down`` (hidden to latent, on every
+    token) and ``latent_up`` (latent to hidden, on the summed routed
+    result); router and shared expert read the hidden state. Both
+    projections are linear, so partial results still add.
+    ``selection_bias``: a float32 leaf ``router_bias`` (num_experts,) is
+    added to the scores for the choice only; the weights are the chosen
+    scores without it, so no gradient reaches it and no decay applies to
+    its one axis: it stays what it was set to.
+
     Static shapes without drops: the (token, choice) pairs are sorted by
     expert, the held ones first, and the routed path (``_routed``) walks the
     first ``R`` rows of that order, ``R`` a rung of a short static ladder
     read off the shapes and the share (``buffer_rungs``: twice what a fair
-    router lands here, and a row for every pair).
+    router lands here, and the most that can land: a row for every pair, or
+    ``held`` rows a token where a token makes more choices than that).
     Each call counts what landed and takes, on the device, the shortest
     rung that holds it (``_switch``; per shard under ``shard_map``), so a
     router however skewed drops nothing, and a whole share has the one rung
     and traces no switch. The grouped products compute only the rows of
-    held experts, however many those are.
+    held experts, however many those are. The routed result carries the
+    name ``ROUTED_NAME``: a stack that recomputes its layers may keep it
+    (``save_only_these_names``; (tokens, ``C``) in ``dtype``), and the
+    backward pass then makes the routing again but not the ladder's branch.
 
     Sows ``counters/expert_load``: (held,) int32, the (token, choice) pairs
     each held expert got this call; and ``counters/expert_rows_walked``:
     int32, the rung this call took. Stacked expert weights: ``w_gate``,
-    ``w_up`` (held, C, F), ``w_down`` (held, F, C), so that expert
-    parallelism is a ``PartitionSpec`` on the leading axis.
+    ``w_up`` (held, C, F), ``w_down`` (held, F, C), ``C`` the latent width
+    where there is one, so that expert parallelism is a ``PartitionSpec`` on
+    the leading axis.
     """
 
     share: object
@@ -487,6 +543,9 @@ class DroplessMoE(nn.Module):
     shared_width: int = 0
     scaling: float = 1.0
     dtype: jnp.dtype = jnp.float32
+    gated: bool = True
+    latent: int = 0
+    selection_bias: bool = False
 
     @nn.compact
     def __call__(self, x):  # (B, T, C) -> (B, T, C)
@@ -496,59 +555,88 @@ class DroplessMoE(nn.Module):
         E, held, offset = (self.share.num_experts, self.share.held,
                            self.share.offset)
         K, F = self.top_k, self.expert_width
+        W = self.latent or C  # the width the routed experts work in
         xf = x.reshape(B * T, C).astype(self.dtype)
         stacked = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
-        w_gate = self.param("w_gate", stacked, (held, C, F), jnp.float32)
-        w_up = self.param("w_up", stacked, (held, C, F), jnp.float32)
-        w_down = self.param("w_down", stacked, (held, F, C), jnp.float32)
+        w_gate = self.param("w_gate", stacked, (held, W, F),
+                            jnp.float32) if self.gated else None
+        w_up = self.param("w_up", stacked, (held, W, F), jnp.float32)
+        w_down = self.param("w_down", stacked, (held, F, W), jnp.float32)
+        cast = lambda w: None if w is None else w.astype(  # noqa: E731
+            self.dtype)
 
         with jax.named_scope(module_scope("moe_route")):
             logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
                               precision=jax.lax.Precision.HIGHEST,
                               name="router")(xf.astype(jnp.float32))
-            scores, ids = jax.lax.top_k(jax.nn.sigmoid(logits), K)  # (N, K)
+            if self.selection_bias:
+                bias = self.param("router_bias", nn.initializers.zeros, (E,),
+                                  jnp.float32)
+                scored = jax.nn.sigmoid(logits)
+                _, ids = jax.lax.top_k(scored + bias, K)        # (N, K)
+                scores = jnp.take_along_axis(scored, ids, axis=-1)
+            else:
+                scores, ids = jax.lax.top_k(jax.nn.sigmoid(logits), K)
             weights = (scores / scores.sum(axis=-1, keepdims=True)
                        * self.scaling)
             self.sow("intermediates", "expert_ids", ids)
 
+        tokens = xf
+        if self.latent:
+            with jax.named_scope(module_scope("moe_latent")):
+                tokens = nn.Dense(W, use_bias=False, dtype=self.dtype,
+                                  name="latent_down")(xf)
+
+        rungs = buffer_rungs(B * T * K, held, E, K)
         with jax.named_scope(module_scope("moe_dispatch")):
             local = ids.reshape(-1) - offset                    # (N*K,)
             group = jnp.where((local >= 0) & (local < held), local, held)
             order = jnp.argsort(group, stable=True).astype(jnp.int32)
-            inverse = jnp.argsort(order).astype(jnp.int32)
+            # a buffer with a row for every pair finds its way back by this
+            inverse = jnp.argsort(order).astype(
+                jnp.int32) if rungs[-1] == order.shape[0] else None
             load = jnp.sum(group[:, None] == jnp.arange(held)[None, :],
                            axis=0, dtype=jnp.int32)             # (held,)
             self.sow("counters", "expert_load", load)
 
-        rungs = buffer_rungs(order.shape[0], held, E)
+        def landed_by_token():
+            return jnp.sum(group.reshape(-1, K) < held, axis=1,
+                           dtype=jnp.int32)                     # (N,)
+
         walk = [functools.partial(_routed_once, rung, K, self.dtype)
                 for rung in rungs]
         routing = (order, inverse, load)
         if len(rungs) == 1:
-            y = _routed(rungs[0], K, self.dtype, routing, xf, w_gate, w_up,
-                        w_down, weights)
+            if inverse is None:
+                with jax.named_scope(module_scope("moe_dispatch")):
+                    routing += (landed_by_token(),)
+            y = _routed(rungs[0], K, self.dtype, routing, tokens, w_gate,
+                        w_up, w_down, weights)
             walked = rungs[0]
         else:  # the shortest buffer that holds what landed, each call
             # the weights cross the switch as the matrix unit takes them,
             # and their gradients come back so: in float32 the gradients
             # of a layer's experts are 0.4 GB that nothing else would hold
             with jax.named_scope(module_scope("moe_experts")):
-                floats = (xf, w_gate.astype(self.dtype),
-                          w_up.astype(self.dtype), w_down.astype(self.dtype),
+                floats = (tokens, cast(w_gate), cast(w_up), cast(w_down),
                           weights)
             with jax.named_scope(module_scope("moe_dispatch")):
                 landed = load.sum()
                 index = sum((landed > rung).astype(jnp.int32)
                             for rung in rungs[:-1])
-                count = jnp.sum(group.reshape(-1, K) < held, axis=1,
-                                dtype=jnp.int32)                # (N,)
+                count = landed_by_token()
                 walked = jnp.asarray(rungs, jnp.int32)[index]
             y = _switch(tuple(walk), index, routing + (count,), floats)
         self.sow("counters", "expert_rows_walked", jnp.int32(walked))
+        y = checkpoint_name(y, ROUTED_NAME)
 
+        if self.latent:
+            with jax.named_scope(module_scope("moe_latent")):
+                y = nn.Dense(C, use_bias=False, dtype=self.dtype,
+                             name="latent_up")(y)
         if self.shared_width:
             with jax.named_scope(module_scope("moe_shared")):
-                y = y + SwiGLU(self.shared_width, dtype=self.dtype,
-                               name="shared")(xf)
+                y = y + (SwiGLU if self.gated else Relu2MLP)(
+                    self.shared_width, dtype=self.dtype, name="shared")(xf)
         return y.reshape(B, T, C)

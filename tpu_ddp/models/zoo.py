@@ -10,7 +10,8 @@ MODEL_REGISTRY: dict = {}
 #: models whose module is imported when the model is first asked for
 #: (``train/trainer.py::build_model``), not with the package: a run that
 #: trains another model never pays for their imports
-LAZY_MODELS = {"laguna_xs2": "tpu_ddp.models.decoder"}
+LAZY_MODELS = {"laguna_xs2": "tpu_ddp.models.decoder",
+               "nemotron3_super": "tpu_ddp.models.hybrid"}
 
 
 def register(name: str):

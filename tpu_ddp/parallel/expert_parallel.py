@@ -14,6 +14,12 @@ EP composes with DP on a 2-D ``data x expert`` mesh (batch sharded over
 rule-agnostic GSPMD builder TP and FSDP use; only the layout rules differ.
 The MoE load-balance aux loss (sown into the ``aux_loss`` collection) is
 handled by that builder's ``aux_weight`` path, mirroring the Switch recipe.
+
+Beside the rules, two pieces of data that say what one chip of a deployment
+holds of a layer: ``ExpertShare`` (which routed experts;
+``models/moe.py::DroplessMoE`` routes over all and computes its own) and
+``HeadShare`` (which heads of an attention or state-space mixer). Neither
+adds an exchange: a share run alone hands on its partial result.
 """
 
 from __future__ import annotations
@@ -61,6 +67,36 @@ class ExpertShare:
                 f"{num_experts} experts do not divide over {positions}")
         held = num_experts // positions
         return cls(num_experts, held, position * held)
+
+
+@dataclasses.dataclass(frozen=True)
+class HeadShare:
+    """Which of a layer's heads live here: position ``position`` of
+    ``positions`` equal shares (one position of a tensor-parallel group).
+    ``of(n)`` is ``(held, first)`` of ``n`` published heads or groups: ``n /
+    positions`` of them from ``position * held`` up, or, of fewer heads than
+    positions (two key-value heads over eight chips), the one head this
+    position's query heads read, which its neighbours hold too. Data, not
+    code, like ``ExpertShare``: a mixer built with the heads held here
+    (``models/hybrid.py``, ``models/decoder.py::GroupedQueryAttention``)
+    computes their part of the result and hands it on; the parts of all
+    positions add up to the whole mixer's output, in the exchange between
+    chips or, on one chip of the deployment run alone, nowhere."""
+
+    positions: int = 1
+    position: int = 0
+
+    def __post_init__(self):
+        if not 0 <= self.position < self.positions:
+            raise ValueError(f"position {self.position} is not one of "
+                             f"{self.positions}")
+
+    def of(self, heads: int):
+        if heads % self.positions and self.positions % heads:
+            raise ValueError(
+                f"{heads} heads do not divide over {self.positions}")
+        held = max(heads // self.positions, 1)
+        return held, self.position * heads // self.positions
 
 
 # Layout for tpu_ddp.models.moe.MoEMlp (paths like block_1/moe/w_up).
