@@ -215,13 +215,11 @@ def test_the_routed_ladder_compiles_at_the_latent_experts_widths(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
 
 
-def test_a_recomputed_expert_block_walks_its_routed_path_twice(topo):
-    """A recomputed block of the hybrid decoder keeps the routed result
-    (``moe.ROUTED_NAME``, 33.5 MB a block at the cell's sizes), so its
-    backward pass makes the router, the sorts and the projections again but
-    not the ladder's branch with its grouped products: one switch forward
-    and one backward for the one expert block, where recomputing everything
-    has three."""
+@pytest.fixture(scope="module")
+def expert_block_step(topo):
+    """The gradient of blocks ``ME`` of the hybrid decoder at the cell's
+    widths (16,384 tokens, 22 of 512 experts, 8 held), recomputed, compiled
+    for one described chip."""
     from tpu_ddp.models.hybrid import HybridDecoder, nemotron3_super_spec
 
     one = _one_chip(topo)
@@ -238,8 +236,40 @@ def test_a_recomputed_expert_block_walks_its_routed_path_twice(topo):
         logits, _ = model.apply({"params": p}, tokens, mutable=["counters"])
         return logits.sum()
 
-    text = jax.jit(jax.grad(loss)).lower(params, tokens).compile().as_text()
-    assert text.count(" conditional(") == 2
+    return jax.jit(jax.grad(loss)).lower(params, tokens).compile()
+
+
+def test_a_recomputed_expert_block_walks_its_routed_path_twice(
+        expert_block_step):
+    """A recomputed block of the hybrid decoder keeps the routed result
+    (``moe.ROUTED_NAME``, 33.5 MB a block at the cell's sizes), so its
+    backward pass makes the sorts and the projections again but not the
+    ladder's branch with its grouped products: one switch forward and one
+    backward for the one expert block, where recomputing everything has
+    three."""
+    assert expert_block_step.as_text().count(" conditional(") == 2
+
+
+def test_a_recomputed_expert_block_makes_its_routers_choice_once(
+        expert_block_step):
+    """It keeps the router's float32 logits, the chosen ids and their scores
+    too (``moe.LOGITS_NAME``, ``IDS_NAME``, ``SCORES_NAME``: 33.5 MB and
+    twice 1.4 MB a block), so the backward pass makes none of the six-pass
+    product, the ``top_k`` and the gather of the chosen scores again. The
+    TPU compiler writes ``lax.top_k`` of 22 over 512 as a whole ``sort`` of
+    the (16,384, 512) scores with their places and a slice, and leaves
+    ``top_k`` in its ``op_name``: one, the forward pass's; of the router's
+    product and of ``take_along_axis``'s gather (3.7 ms a block on the
+    chip, more than product and sort together) nothing under
+    ``rematted_computation``. The temporaries are 2.849 GB, 0.7 MB more
+    than with nothing of the router kept: what is kept is not paid for
+    again in room made elsewhere (PERF.md section 6, PR 34)."""
+    text = expert_block_step.as_text()
+    assert len(re.findall(r" sort\(.*moe_route/top_k", text)) == 1
+    again = r"rematted_computation[^\"]*moe_route/"
+    assert not re.search(again + "router", text)
+    assert not re.search(again + r"jit\(take_along_axis\)/gather", text)
+    assert expert_block_step.memory_analysis().temp_size_in_bytes < 2.95e9
 
 
 # ---- the int8 ring's quantize / dequantize ----------------------------------

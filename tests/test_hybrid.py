@@ -9,6 +9,7 @@ decoder's traced step, which the options all this added must not move.
 
 import hashlib
 import os
+import re
 import sys
 
 import jax
@@ -76,6 +77,72 @@ def test_logits_loss_and_gradients_match_the_reference(ref, seeded, remat):
     # the selection bias chooses and does not weigh: no gradient reaches it
     assert not np.any(want_grads["block_1.mixer.router_bias"])
     assert not np.any(grads["block_1"]["mixer"]["router_bias"])
+
+
+def _bfloat16_loss(remat, **spec_changes):
+    """``(loss, tree, tokens)`` of the tiny decoder as the cell runs it:
+    bfloat16, blocks recomputed or not."""
+    from tpu_ddp.models.hybrid import HybridDecoder
+
+    tokens = jnp.asarray(tiny.tokens(2, seed=3)[0])
+    model = HybridDecoder(tiny.spec(**spec_changes), dtype=jnp.bfloat16,
+                          remat=remat)
+    tree = model.init(jax.random.key(0), tokens[:, :8])["params"]
+
+    def loss(tree):
+        logits, _ = model.apply({"params": tree}, tokens,
+                                mutable=["counters"])
+        return jnp.mean(logits ** 2)
+
+    return loss, tree, tokens
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_an_expert_block_makes_its_routers_choice_once(remat):
+    """A recomputed expert block keeps its router's logits, chosen ids and
+    their scores (``moe.LOGITS_NAME``, ``IDS_NAME``, ``SCORES_NAME``) beside
+    the routed result, so its backward pass makes neither the float32
+    product nor the ``top_k`` nor the gather of the chosen scores again: one
+    ``top_k`` and one call of ``take_along_axis`` that hands out (tokens,
+    choices) scores an ``E`` block, and the router's three ``HIGHEST``
+    products (forward, and the gradients of operand and weight), with
+    recomputation as without."""
+    loss, tree, _ = _bfloat16_loss(remat)
+    text = jax.jit(jax.value_and_grad(loss)).lower(tree).as_text()
+    blocks = tiny.PATTERN.count("E")
+    n, c, e = 2 * tiny.T, tiny.HIDDEN, tiny.WHOLE["n_routed_experts"]
+    k = tiny.SIZES["num_experts_per_tok"]
+    assert text.count("chlo.top_k") == blocks
+    assert len(re.findall(
+        rf"call @take_along_axis\w*\(.*\) -> \(tensor<{n}x{k}xf32>",
+        text)) == blocks
+    router_shaped = [f"-> tensor<{a}x{b}xf32>"
+                     for a, b in ((n, e), (e, c), (n, c))]
+    products = [line for line in text.splitlines()
+                if "precision = [HIGHEST, HIGHEST]" in line
+                and line.rstrip().endswith(tuple(router_shaped))]
+    assert len(products) == 3 * blocks, products
+
+
+def test_a_recomputed_expert_block_keeps_its_routers_choice(capsys):
+    """What an expert block hands its backward pass from inside the layer,
+    beside the block's input and the weights: the three values
+    ``HybridDecoder``'s policy names of the router, (tokens, experts)
+    float32 logits, (tokens, choices) ids and float32 scores, the (tokens,
+    latent) routed result, and the ids as ``take_along_axis`` made them
+    indices. Nothing (tokens, hidden) wide, nothing of the sorts."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    spec = tiny.spec(pattern="E")
+    loss, tree, tokens = _bfloat16_loss(True, pattern="E")
+    print_saved_residuals(loss, tree)  # "f32[56,16] output of ... (where)"
+    kept = sorted(line.split()[0]
+                  for line in capsys.readouterr().out.splitlines()
+                  if line.endswith("(DroplessMoE.__call__)"))
+    n, k = tokens.size, spec.top_k
+    assert kept == sorted([f"f32[{n},{spec.num_experts}]", f"i32[{n},{k}]",
+                           f"i32[{n},{k}]", f"f32[{n},{k}]",
+                           f"bf16[{n},{spec.latent}]"])
 
 
 def test_the_references_blocked_loss_is_its_loss_of_the_logits(ref, seeded,

@@ -272,6 +272,10 @@ def vit_moe_s4_top2(num_classes: int = 10, bn_cross_replica_axis=None,
 _ROW_TILE = 512
 #: the routed result's name to a recomputation policy (``checkpoint_name``)
 ROUTED_NAME = "moe_routed"
+#: likewise the router's float32 logits, the ids of the experts it chose and
+#: their scores
+LOGITS_NAME, IDS_NAME, SCORES_NAME = (
+    "moe_router_logits", "moe_router_ids", "moe_router_scores")
 
 
 def buffer_rungs(pairs: int, held: int, num_experts: int, top_k: int = 0):
@@ -524,10 +528,24 @@ class DroplessMoE(nn.Module):
     rung that holds it (``_switch``; per shard under ``shard_map``), so a
     router however skewed drops nothing, and a whole share has the one rung
     and traces no switch. The grouped products compute only the rows of
-    held experts, however many those are. The routed result carries the
-    name ``ROUTED_NAME``: a stack that recomputes its layers may keep it
-    (``save_only_these_names``; (tokens, ``C``) in ``dtype``), and the
-    backward pass then makes the routing again but not the ladder's branch.
+    held experts, however many those are.
+
+    Four values carry names a stack that recomputes its layers may keep
+    (``save_only_these_names``; ``HybridDecoder`` keeps all four). The
+    routed result, ``ROUTED_NAME`` ((tokens, ``C``) in ``dtype``): the
+    backward pass then walks the ladder's branch once and not twice.
+    Under ``selection_bias`` the router's logits, ``LOGITS_NAME`` ((tokens,
+    ``num_experts``) float32), the chosen ids, ``IDS_NAME``, and their
+    scores, ``SCORES_NAME`` ((tokens, ``top_k``) int32 and float32): the
+    backward pass then makes none of the float32 product, the ``top_k``
+    and the gather of the chosen scores again (on a v5e the gather is the
+    dearest of the three), only the sigmoid and the normalisation,
+    elementwise, and the sorts of the dispatch. At 16,384 tokens, 512
+    experts, 22 choices and a latent width of 1,024 that is 33.5 MB, 33.5
+    MB and twice 1.4 MB a block (PERF.md section 6, PR 33 and PR 34).
+    Without ``selection_bias`` scores and ids are the ``top_k``'s own
+    results and nothing carries a name: that layer's program is what it
+    was.
 
     Sows ``counters/expert_load``: (held,) int32, the (token, choice) pairs
     each held expert got this call; and ``counters/expert_rows_walked``:
@@ -573,9 +591,11 @@ class DroplessMoE(nn.Module):
             if self.selection_bias:
                 bias = self.param("router_bias", nn.initializers.zeros, (E,),
                                   jnp.float32)
-                scored = jax.nn.sigmoid(logits)
+                scored = jax.nn.sigmoid(checkpoint_name(logits, LOGITS_NAME))
                 _, ids = jax.lax.top_k(scored + bias, K)        # (N, K)
-                scores = jnp.take_along_axis(scored, ids, axis=-1)
+                ids = checkpoint_name(ids, IDS_NAME)
+                scores = checkpoint_name(
+                    jnp.take_along_axis(scored, ids, axis=-1), SCORES_NAME)
             else:
                 scores, ids = jax.lax.top_k(jax.nn.sigmoid(logits), K)
             weights = (scores / scores.sum(axis=-1, keepdims=True)
