@@ -95,6 +95,28 @@ def test_windowed_grouped_flash_compiles_at_the_decoder_widths(topo, heads,
     assert _text(bwd, q, kv, kv).count(CUSTOM_CALL) == 3
 
 
+def test_latent_flash_compiles_at_the_decoder_widths(topo):
+    """A JoyAI-LLM-Flash layer's attention at the benchmark cell's shapes:
+    8,192 positions, 32 heads, queries and keys of 192 (padded to 256 lanes
+    on their own) over values of 128 (not padded), blocks of 512."""
+    from tpu_ddp.ops.flash_attention import flash_attention
+
+    one = _one_chip(topo)
+    qk = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, 512, 512, False, causal=True)
+
+    bwd = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                   (0, 1, 2))
+    text = _text(fwd, qk, qk, v)
+    assert text.count(CUSTOM_CALL) == 1
+    # the output is of the values' width: nothing of 256 comes back
+    assert "bf16[64,8192,128]" in text and "bf16[64,8192,256]{" in text
+    assert _text(bwd, qk, qk, v).count(CUSTOM_CALL) == 3
+
+
 def test_grouped_expert_products_compile_at_the_decoder_widths(topo):
     """``models/moe.py::grouped_matmul`` over 32 held experts of width 512
     on a hidden size of 2,048, a row for each of 16,384 tokens' 8 choices:

@@ -87,8 +87,9 @@ def test_the_program_task_loss_is_the_reference_loss(ref, seeded):
              "mask": jnp.asarray(rows)}
     want = ref.next_token_loss(logits, tokens, ref.target_mask(
         {k: np.asarray(v) for k, v in batch.items()}))
-    np.testing.assert_allclose(NEXT_TOKEN.loss(None, logits, batch), want,
-                               rtol=1e-6)
+    loss, terms = NEXT_TOKEN.loss(None, logits, batch)
+    np.testing.assert_allclose(loss, want, rtol=1e-6)
+    assert terms == {}  # a loss of one term names none
 
 
 def test_the_shares_add_up_to_the_uncut_layer(ref):

@@ -255,8 +255,12 @@ def test_metric_logger_jsonl_schema_version(tmp_path, capsys):
 def telemetry_run(tmp_path_factory):
     """One 5-step CPU training run with JSONL+Chrome sinks + watchdog on
     (shared across the end-to-end assertions below)."""
+    from tpu_ddp.telemetry.registry import reset_default_registry
     from tpu_ddp.train.trainer import TrainConfig, Trainer
 
+    # the counters registry is process-wide: whatever an earlier file of
+    # this worker left in it is not this run's
+    reset_default_registry()
     run_dir = tmp_path_factory.mktemp("telemetry_run")
     cfg = TrainConfig(
         synthetic_data=True,

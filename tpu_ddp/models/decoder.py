@@ -4,9 +4,19 @@ gate) and a feed-forward that is a dense SwiGLU or routed experts with a
 shared expert (``models/moe.py::DroplessMoE``); RMSNorm, no biases, untied
 embedding and head. One flax module, ``SparseDecoder``, described by a
 ``DecoderSpec``; ``laguna_xs2`` registers poolside's Laguna-XS.2 at its
-published sizes.
+published sizes and ``joyai_llm_flash`` JD's JoyAI-LLM-Flash at its own.
 
     y = x + Attn(RMSNorm(x));  z = y + FFN(RMSNorm(y));  head(RMSNorm(z_last))
+
+A layer whose ``LayerSpec`` carries ``latent`` widths has latent attention
+in place of the grouped-query kind (``LatentAttention``: queries and
+key-values through low-rank projections, keys of 192 over values of 128,
+one rotary key shared by all heads). A ``DecoderSpec`` with ``mtp`` grows
+DeepSeek-V3's multi-token-prediction module after the stack (one more layer
+over ``W_eh [RMSNorm(h_i); RMSNorm(Emb(t_{i+1}))]``, predicting ``t_{i+2}``
+through the stack's own final norm and head); the model then returns
+``(logits, mtp_logits)`` and trains on ``next_token_mtp``: ``L_next +
+mtp_weight * L_mtp``.
 
 ``GroupedQueryAttention`` is also the attention mixer of the hybrid stack
 (``models/hybrid.py``), whose blocks have one mixer each: a ``LayerSpec``
@@ -97,12 +107,28 @@ def rotate(x, cos, sin):
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """Widths of latent attention (DeepSeek-V2's MLA, arXiv:2405.04434): the
+    ranks of the query and of the joint key-value compression, a head's
+    part without positions and its rotary part (a query and a key are the
+    two side by side), and a value head."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     heads: int           # query heads
     window: int          # 0 = full causal attention
     rotary: Optional[Rotary]  # None = no position encoding in attention
     sparse: bool         # routed experts + shared expert, else dense SwiGLU
     gate: bool = True    # the head-wise output gate
+    #: latent attention of these widths, and not grouped-query attention
+    latent: Optional[LatentSpec] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,6 +147,12 @@ class DecoderSpec:
     shared_width: int
     routed_scaling: float
     norm_eps: float = 1e-6
+    #: the router chooses by its scores plus a bias that no gradient reaches
+    selection_bias: bool = False
+    #: the multi-token-prediction module's one layer, or None for no module
+    mtp: Optional[LayerSpec] = None
+    #: weight of the module's term in the loss
+    mtp_weight: float = 0.0
 
 
 def reference_attention(q, k, v, *, causal=True, window=0):
@@ -165,6 +197,57 @@ class GroupedQueryAttention(nn.Module):
         return dense(C, "o")(o.reshape(B, T, H * D))
 
 
+class LatentAttention(nn.Module):
+    """Causal latent attention of ``spec.heads`` heads by ``spec.latent``'s
+    widths:
+
+        c_q = RMSNorm(x W_qa);  q_h = c_q W_qb = [q_nope_h, q_rope_h]
+        [c_kv, r] = x W_kva;  [k_nope_h, v_h] = RMSNorm(c_kv) W_kvb
+        k_h = [k_nope_h, rot(r)];  o_h = softmax(rot(q_h) k_h^T / sqrt(d)) v_h
+
+    ``rot`` turns the rotary part only; ``r`` is one key head that all heads
+    share, broadcast over them before the kernel is called (a kernel that
+    reads it once is not written yet). Keys are ``nope_dim + rope_dim`` wide
+    and values ``v_dim``: ``attention_impl`` takes the two widths."""
+
+    spec: LayerSpec
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+    attention_impl: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        B, T, C = x.shape
+        H, w = self.spec.heads, self.spec.latent
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
+        norm = lambda name: nn.RMSNorm(  # noqa: E731
+            epsilon=self.norm_eps, dtype=self.dtype, name=name)
+        with jax.named_scope(module_scope("mla_q")):
+            q = dense(H * (w.nope_dim + w.rope_dim), "q_b")(
+                norm("q_norm")(dense(w.q_rank, "q_a")(x)))
+            q = q.reshape(B, T, H, w.nope_dim + w.rope_dim)
+        with jax.named_scope(module_scope("mla_kv")):
+            c_kv, r = jnp.split(dense(w.kv_rank + w.rope_dim, "kv_a")(x),
+                                [w.kv_rank], axis=-1)
+            k_nope, v = jnp.split(
+                dense(H * (w.nope_dim + w.v_dim), "kv_b")(
+                    norm("kv_norm")(c_kv)).reshape(
+                        B, T, H, w.nope_dim + w.v_dim),
+                [w.nope_dim], axis=-1)
+            q = jnp.concatenate(
+                [q[..., :w.nope_dim],
+                 rotate(q[..., w.nope_dim:], cos, sin)], axis=-1)
+            r = rotate(r[:, :, None, :], cos, sin)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(r, (B, T, H, w.rope_dim))], axis=-1)
+        attend = self.attention_impl or reference_attention
+        with jax.named_scope(module_scope("attention_latent")):
+            o = attend(q, k, v, causal=True, window=0)
+        with jax.named_scope(module_scope("mla_out")):
+            return dense(C, "o")(o.reshape(B, T, H * w.v_dim))
+
+
 class DecoderLayer(nn.Module):
     spec: LayerSpec
     model: DecoderSpec
@@ -176,10 +259,15 @@ class DecoderLayer(nn.Module):
         m = self.model
         norm = lambda name: nn.RMSNorm(  # noqa: E731
             epsilon=m.norm_eps, dtype=self.dtype, name=name)
-        x = x + GroupedQueryAttention(
-            self.spec, m.kv_heads, m.head_dim, dtype=self.dtype,
-            attention_impl=self.attention_impl, name="attn",
-        )(norm("attn_norm")(x), cos, sin)
+        if self.spec.latent is None:
+            attn = GroupedQueryAttention(
+                self.spec, m.kv_heads, m.head_dim, dtype=self.dtype,
+                attention_impl=self.attention_impl, name="attn")
+        else:
+            attn = LatentAttention(
+                self.spec, m.norm_eps, dtype=self.dtype,
+                attention_impl=self.attention_impl, name="attn")
+        x = x + attn(norm("attn_norm")(x), cos, sin)
         h = norm("mlp_norm")(x)
         if not self.spec.sparse:
             return x + SwiGLU(m.dense_width, dtype=self.dtype, name="mlp")(h)
@@ -189,7 +277,7 @@ class DecoderLayer(nn.Module):
             ExpertShare(m.num_experts, m.experts_held, m.expert_offset),
             top_k=m.top_k, expert_width=m.expert_width,
             shared_width=m.shared_width, scaling=m.routed_scaling,
-            dtype=self.dtype, name="moe",
+            dtype=self.dtype, selection_bias=m.selection_bias, name="moe",
         )(h)
 
 
@@ -201,34 +289,61 @@ class SparseDecoder(nn.Module):
     attention_impl: Optional[Callable] = None
     #: recompute each layer in the backward pass (``resolve_remat``)
     remat: bool = False
-    #: what the model reads from a batch and which loss it takes
-    task = "next_token"
     #: (block_q, block_k) of ``--attention flash``: a decoder's sequences
     #: are long, and a grid step of 128 x 128 is mostly its own overhead
     flash_blocks = (512, 512)
+
+    @property
+    def task(self) -> str:
+        """What the model reads from a batch and which loss it takes."""
+        return "next_token" if self.spec.mtp is None else "next_token_mtp"
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         del train  # no dropout, no batch statistics
         s = self.spec
-        x = nn.Embed(s.vocab_rows, s.hidden, dtype=self.dtype,
-                     name="embed")(tokens)
+        embed = nn.Embed(s.vocab_rows, s.hidden, dtype=self.dtype,
+                         name="embed")
+        x = embed(tokens)
         tables = {}
         layer_cls = nn.remat(DecoderLayer) if self.remat else DecoderLayer
-        for i, layer in enumerate(s.layers):
+
+        def layer_of(layer, name):
             if layer.rotary not in tables:
                 tables[layer.rotary] = layer.rotary.tables(tokens.shape[1])
-            x = layer_cls(layer, s, dtype=self.dtype,
-                          attention_impl=self.attention_impl,
-                          name=f"layer_{i}")(x, *tables[layer.rotary])
-        x = nn.RMSNorm(epsilon=s.norm_eps, dtype=self.dtype,
-                       name="final_norm")(x)
+            return functools.partial(
+                layer_cls(layer, s, dtype=self.dtype,
+                          attention_impl=self.attention_impl, name=name),
+                cos=tables[layer.rotary][0], sin=tables[layer.rotary][1])
+
+        for i, layer in enumerate(s.layers):
+            x = layer_of(layer, f"layer_{i}")(x)
+        final_norm = nn.RMSNorm(epsilon=s.norm_eps, dtype=self.dtype,
+                                name="final_norm")
         # operands in ``dtype``, logits accumulated and kept in float32
-        return nn.Dense(
+        head = nn.Dense(
             s.vocab_rows, use_bias=False, dtype=self.dtype, name="head",
             dot_general=functools.partial(
-                jax.lax.dot_general, preferred_element_type=jnp.float32),
-        )(x).astype(jnp.float32)
+                jax.lax.dot_general, preferred_element_type=jnp.float32))
+        logits = head(final_norm(x)).astype(jnp.float32)
+        if s.mtp is None:
+            return logits
+        # The prediction module, on every position: position i reads the
+        # stack's output h_i (before the final norm: the module has a norm
+        # of its own for it) and the embedding of token i + 1, the last
+        # position a pad that no target follows, so that both stacks give
+        # the kernels one shape. Embedding, final norm and head are the
+        # stack's own.
+        with jax.named_scope(module_scope("mtp")):
+            norm = lambda name: nn.RMSNorm(  # noqa: E731
+                epsilon=s.norm_eps, dtype=self.dtype, name=name)
+            ahead = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
+            h = nn.Dense(s.hidden, use_bias=False, dtype=self.dtype,
+                         name="mtp_proj")(jnp.concatenate(
+                             [norm("mtp_hidden_norm")(x),
+                              norm("mtp_embed_norm")(embed(ahead))], axis=-1))
+            h = layer_of(s.mtp, "mtp_layer")(h)
+            return logits, head(final_norm(h)).astype(jnp.float32)
 
 
 # -- poolside/Laguna-XS.2 ----------------------------------------------------
@@ -264,3 +379,41 @@ def laguna_xs2(num_classes: int = 10, bn_cross_replica_axis=None,
                dtype=jnp.float32, **share):
     del num_classes, bn_cross_replica_axis  # a classifier's
     return SparseDecoder(laguna_xs2_spec(**share), dtype=dtype)
+
+
+# -- jdopensource/JoyAI-LLM-Flash ---------------------------------------------
+# https://huggingface.co/jdopensource/JoyAI-LLM-Flash/blob/main/config.json
+
+def joyai_llm_flash_spec(*, num_layers: int = 40, experts_held: int = 256,
+                         expert_offset: int = 0,
+                         vocab_rows: int = 129280) -> DecoderSpec:
+    """The published model (``model_type`` ``joyai_llm_flash``, DeepSeek-V3's
+    layout): 40 layers of latent attention (32 heads; queries through rank
+    1,536, keys and values through rank 512; 128 + 64 rotary a query and
+    key, 128 a value; theta 32e6, no scaling), layer 0 dense (width 7,168),
+    the others 256 routed experts of width 768 with 8 a token chosen by
+    sigmoid scores plus a selection bias (weights without it, normalised,
+    times 2.5) and one shared expert of width 768; one multi-token
+    prediction module, its term weighted 0.3 (the config has no key for the
+    weight: DeepSeek-V3's for most of its pre-training). The arguments are
+    one chip's share; no width changes."""
+    layer = LayerSpec(
+        heads=32, window=0, rotary=Rotary(dims=64, theta=32000000.0),
+        sparse=True, gate=False,
+        latent=LatentSpec(q_rank=1536, kv_rank=512, nope_dim=128,
+                          rope_dim=64, v_dim=128))
+    layers = tuple(dataclasses.replace(layer, sparse=i > 0)
+                   for i in range(num_layers))
+    return DecoderSpec(
+        vocab_rows=vocab_rows, hidden=2048, head_dim=192, kv_heads=32,
+        layers=layers, dense_width=7168, num_experts=256,
+        experts_held=experts_held, expert_offset=expert_offset, top_k=8,
+        expert_width=768, shared_width=768, routed_scaling=2.5,
+        selection_bias=True, mtp=layer, mtp_weight=0.3)
+
+
+@register("joyai_llm_flash")
+def joyai_llm_flash(num_classes: int = 10, bn_cross_replica_axis=None,
+                    dtype=jnp.float32, **share):
+    del num_classes, bn_cross_replica_axis  # a classifier's
+    return SparseDecoder(joyai_llm_flash_spec(**share), dtype=dtype)

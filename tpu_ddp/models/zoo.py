@@ -11,6 +11,7 @@ MODEL_REGISTRY: dict = {}
 #: (``train/trainer.py::build_model``), not with the package: a run that
 #: trains another model never pays for their imports
 LAZY_MODELS = {"laguna_xs2": "tpu_ddp.models.decoder",
+               "joyai_llm_flash": "tpu_ddp.models.decoder",
                "nemotron3_super": "tpu_ddp.models.hybrid"}
 
 

@@ -7,9 +7,13 @@ O(T_q_block * T) scores per step instead of materializing the full (T, T)
 matrix in HBM, and the QK^T / PV matmuls hit the MXU tile-by-tile.
 
 Layout: (B, T, H, D) like the rest of the framework; internally heads fold
-into the grid. Head dim is zero-padded to the 128 lane width (padding k
+into the grid. Head dims are zero-padded to the 128 lane width (padding k
 contributes 0 to scores; padding v yields padded output columns that are
-sliced away).
+sliced away). Queries and keys may be of another width than values
+(latent attention: 192 over 128): ``q`` and ``k`` (and ``dq``, ``dk``) are
+padded to the lane width on their own, ``v``, ``o``, ``dO`` and ``dv`` on
+theirs, and the scale is ``1 / sqrt(q.shape[-1])``. With one width the
+kernels' programs are the ones a single ``D`` made.
 
 Differentiation: forward AND backward are Pallas kernels (``jax.custom_vjp``).
 The forward additionally emits the per-row logsumexp (broadcast along a
@@ -399,11 +403,12 @@ def _interpreted_under_shard_map(x, interpret: bool) -> bool:
 
 def _check_heads(q, k, v):
     H, KV = q.shape[2], k.shape[2]
-    if k.shape != v.shape or H % KV or (
+    if k.shape[:3] != v.shape[:3] or H % KV or (
             q.shape[:2] + q.shape[3:] != k.shape[:2] + k.shape[3:]):
         raise ValueError(
             f"flash attention: q {q.shape} against k {k.shape}, v {v.shape}: "
-            "key-value heads must divide the query heads, the rest agree")
+            "key-value heads must divide the query heads, queries and keys "
+            "share a width, the rest agree")
     return H // KV
 
 
@@ -419,7 +424,8 @@ def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
                           window=window), None
     bq, bk, d_pad, _ = _unpadded_plan(
         q.shape, block_q, block_k, kv_mask is not None)
-    qf, kf, vf = _fold(q, d_pad), _fold(k, d_pad), _fold(v, d_pad)
+    dv_pad = _round_up(v.shape[-1], LANE)  # values on their own width
+    qf, kf, vf = _fold(q, d_pad), _fold(k, d_pad), _fold(v, dv_pad)
     band = _Band(causal or bool(window), window, bq, bk, T // bq, T // bk)
     width = band.kv_width
     grid = (B * H, band.n_q, width)  # the band innermost: sequential carry
@@ -428,13 +434,15 @@ def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
     def kv_block(j, t):
         return _least(band.kv_lo(j) + t, band.kv_hi(j))
 
-    kv_spec = pl.BlockSpec(
-        (1, bk, d_pad), lambda i, j, t: (i // group, kv_block(j, t), 0),
-        memory_space=pltpu.VMEM)
+    def kv_spec(d):
+        return pl.BlockSpec(
+            (1, bk, d), lambda i, j, t: (i // group, kv_block(j, t), 0),
+            memory_space=pltpu.VMEM)
+
     in_specs = [
         pl.BlockSpec((1, bq, d_pad), lambda i, j, t: (i, j, 0),
                      memory_space=pltpu.VMEM),
-        kv_spec, kv_spec,
+        kv_spec(d_pad), kv_spec(dv_pad),
     ]
     args = [qf, kf, vf]
     if has_mask:
@@ -447,19 +455,19 @@ def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
             functools.partial(_kernel, scale=scale, band=band, width=width,
                               has_mask=has_mask),
             out_shape=[
-                _sds((B * H, T, d_pad), q.dtype, qf),
+                _sds((B * H, T, dv_pad), q.dtype, qf),
                 _sds((B * H, T, LANE), jnp.float32, qf),
             ],
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, bq, d_pad), lambda i, j, t: (i, j, 0),
+                pl.BlockSpec((1, bq, dv_pad), lambda i, j, t: (i, j, 0),
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, bq, LANE), lambda i, j, t: (i, j, 0),
                              memory_space=pltpu.VMEM),
             ],
             scratch_shapes=[
-                pltpu.VMEM((bq, d_pad), jnp.float32),  # acc
+                pltpu.VMEM((bq, dv_pad), jnp.float32),  # acc
                 pltpu.VMEM((bq, LANE), jnp.float32),   # running max
                 pltpu.VMEM((bq, LANE), jnp.float32),   # running denom
             ],
@@ -467,7 +475,7 @@ def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(*args)
-    return _unfold(out, q.shape), lse
+    return _unfold(out, q.shape[:3] + v.shape[3:]), lse
 
 
 def _tile_p(q, kb, lse, q_blk, kv_blk, scale, band: _Band, mask_row,
@@ -576,13 +584,14 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
     scale = 1.0 / np.sqrt(D)
     bq, bk, d_pad, _ = _unpadded_plan(
         q.shape, block_q, block_k, kv_mask is not None)
-    qf, kf, vf = _fold(q, d_pad), _fold(k, d_pad), _fold(v, d_pad)
-    gf = _fold(g, d_pad)
+    dv_pad = _round_up(v.shape[-1], LANE)  # v, o, dO and dv on their own
+    qf, kf, vf = _fold(q, d_pad), _fold(k, d_pad), _fold(v, dv_pad)
+    gf = _fold(g, dv_pad)
     # di = rowsum(dO * O): cheap elementwise+reduce, XLA fuses it; stored
     # lane-broadcast like lse, as the kernels use both (``_lanes``).
     di = jnp.broadcast_to(
-        jnp.sum(_fold(g.astype(jnp.float32), d_pad)
-                * _fold(o.astype(jnp.float32), d_pad),
+        jnp.sum(_fold(g.astype(jnp.float32), dv_pad)
+                * _fold(o.astype(jnp.float32), dv_pad),
                 axis=-1, keepdims=True),
         (B * H, T, LANE),
     )
@@ -593,14 +602,18 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
     def kv_block(j, t):
         return _least(band.kv_lo(j) + t, band.kv_hi(j))
 
-    q_spec = pl.BlockSpec((1, bq, d_pad), lambda i, j, t: (i, j, 0),
-                          memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, bq, LANE), lambda i, j, t: (i, j, 0),
+    def q_spec(d):
+        return pl.BlockSpec((1, bq, d), lambda i, j, t: (i, j, 0),
                             memory_space=pltpu.VMEM)
-    kv_inner = pl.BlockSpec(
-        (1, bk, d_pad), lambda i, j, t: (i // group, kv_block(j, t), 0),
-        memory_space=pltpu.VMEM)
-    in_specs = [q_spec, kv_inner, kv_inner, q_spec, row_spec, row_spec]
+
+    def kv_inner(d):
+        return pl.BlockSpec(
+            (1, bk, d), lambda i, j, t: (i // group, kv_block(j, t), 0),
+            memory_space=pltpu.VMEM)
+
+    row_spec = q_spec(LANE)
+    in_specs = [q_spec(d_pad), kv_inner(d_pad), kv_inner(dv_pad),
+                q_spec(dv_pad), row_spec, row_spec]
     args = [qf, kf, vf, gf, lse, di]
     if has_mask:
         in_specs.append(pl.BlockSpec(
@@ -615,7 +628,7 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
             out_shape=_sds((B * H, T, d_pad), q.dtype, gf),
             grid=(B * H, band.n_q, band.kv_width),  # dq carry in scratch
             in_specs=in_specs,
-            out_specs=q_spec,
+            out_specs=q_spec(d_pad),
             scratch_shapes=[pltpu.VMEM((bq, d_pad), jnp.float32)],
             compiler_params=semantics,
             interpret=interpret,
@@ -628,15 +641,18 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
         return (i * group + t // width,
                 _least(band.q_lo(jk) + t % width, band.q_hi(jk)))
 
-    q_inner = pl.BlockSpec(
-        (1, bq, d_pad), lambda i, jk, t: (*q_index(i, jk, t), 0),
-        memory_space=pltpu.VMEM)
-    row_inner = pl.BlockSpec(
-        (1, bq, LANE), lambda i, jk, t: (*q_index(i, jk, t), 0),
-        memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, bk, d_pad), lambda i, jk, t: (i, jk, 0),
-                           memory_space=pltpu.VMEM)
-    in_specs = [q_inner, kv_spec, kv_spec, q_inner, row_inner, row_inner]
+    def q_inner(d):
+        return pl.BlockSpec(
+            (1, bq, d), lambda i, jk, t: (*q_index(i, jk, t), 0),
+            memory_space=pltpu.VMEM)
+
+    def kv_spec(d):
+        return pl.BlockSpec((1, bk, d), lambda i, jk, t: (i, jk, 0),
+                            memory_space=pltpu.VMEM)
+
+    row_inner = q_inner(LANE)
+    in_specs = [q_inner(d_pad), kv_spec(d_pad), kv_spec(dv_pad),
+                q_inner(dv_pad), row_inner, row_inner]
     args = [qf, kf, vf, gf, lse, di]
     if has_mask:
         in_specs.append(pl.BlockSpec((1, _SUBLANES, bk),
@@ -649,14 +665,14 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
                               **kparams),
             out_shape=[
                 _sds((B * KV, T, d_pad), k.dtype, gf),
-                _sds((B * KV, T, d_pad), v.dtype, gf),
+                _sds((B * KV, T, dv_pad), v.dtype, gf),
             ],
             grid=(B * KV, band.n_k, group * width),  # dk/dv carry in scratch
             in_specs=in_specs,
-            out_specs=[kv_spec, kv_spec],
+            out_specs=[kv_spec(d_pad), kv_spec(dv_pad)],
             scratch_shapes=[
                 pltpu.VMEM((bk, d_pad), jnp.float32),
-                pltpu.VMEM((bk, d_pad), jnp.float32),
+                pltpu.VMEM((bk, dv_pad), jnp.float32),
             ],
             compiler_params=semantics,
             interpret=interpret,
@@ -718,7 +734,9 @@ _flash.defvjp(_fwd, _bwd)
 def flash_attention(q, k, v, block_q: int = 128, block_k: int = 128,
                     interpret: bool | None = None, *, causal: bool = False,
                     kv_mask=None, window: int = 0):
-    """(B, T, H, D) attention as a Pallas TPU kernel (fwd + bwd).
+    """(B, T, H, D) attention as a Pallas TPU kernel (fwd + bwd). ``v`` may
+    be of another width than ``q`` and ``k`` (``o`` is of ``v``'s); the
+    scale is ``1 / sqrt(q.shape[-1])``.
 
     ``causal`` masks col > row; ``window`` (which implies it) also masks
     col <= row - window. Only the kv blocks a q block can see are visited

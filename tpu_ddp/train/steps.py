@@ -261,7 +261,7 @@ def make_train_step(
         logits, mutated = apply_model(params, batch_stats,
                                       batch[task.input_key])
         with jax.named_scope(LOSS_SCOPE):
-            task_loss = task.loss(loss_fn, logits, batch)
+            task_loss, terms = task.loss(loss_fn, logits, batch)
             if mixup_alpha > 0:
                 # hard-label mixup: blend the two CE terms by the same
                 # lambda the images were blended with
@@ -289,7 +289,7 @@ def make_train_step(
         if zero1 is None and compress is None:
             loss = lax.pmean(loss, data_axis)
         return loss, (mutated.get("batch_stats", batch_stats), logits,
-                      task_loss, aux, mutated.get(COUNTERS))
+                      task_loss, aux, mutated.get(COUNTERS), terms)
 
     def accumulate(grad_fn, p_in, batch_stats, batch):
         """The gradient stage over ``accum_steps`` microbatches: what one
@@ -309,7 +309,7 @@ def make_train_step(
         def accum(carry, micro):
             grads_acc, stats = carry
             with jax.named_scope(FORWARD_BACKWARD_SCOPE):
-                (_, (stats, logits, task_loss, aux, counted)), grads = (
+                (_, (stats, logits, task_loss, aux, counted, terms)), grads = (
                     grad_fn(p_in, stats, micro))
             with jax.named_scope(GRAD_ACCUM_SCOPE):
                 grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
@@ -319,7 +319,7 @@ def make_train_step(
                     hits = masked_accuracy(
                         logits, micro[task.target_key], micro.get("mask"))
             # one row a microbatch of what is not carried
-            return (grads_acc, stats), (task_loss, aux, counted, hits)
+            return (grads_acc, stats), (task_loss, aux, counted, hits, terms)
 
         # The accumulator takes the differentiation input's shapes (under
         # zero3 state.params are flat shards) AND its varying type: under
@@ -334,10 +334,12 @@ def make_train_step(
             accum, (zero_grads, stats0), micros)
         with jax.named_scope(GRAD_ACCUM_SCOPE):
             grads = jax.tree.map(lambda g: g / accum_steps, grads_acc)
-            loss_sum, aux_sum, counters, hits = jax.tree.map(
+            loss_sum, aux_sum, counters, hits, term_sums = jax.tree.map(
                 lambda x: x.sum(axis=0), rows)
             aux = None if aux_sum is None else aux_sum / accum_steps
-        return grads, new_stats, loss_sum / accum_steps, aux, counters, hits
+            terms = {k: v / accum_steps for k, v in term_sums.items()}
+        return (grads, new_stats, loss_sum / accum_steps, aux, counters, hits,
+                terms)
 
     def shard_step(state: TrainState, batch: Batch):
         # Every part of the step sits in a scope of telemetry/phases.py:
@@ -387,12 +389,12 @@ def make_train_step(
             p_in = state.params
         if accum_steps == 1:
             with jax.named_scope(FORWARD_BACKWARD_SCOPE):
-                (_, (new_stats, logits, task_loss, aux, counters)), grads = (
-                    grad_fn(p_in, state.batch_stats, batch))
+                (_, (new_stats, logits, task_loss, aux, counters,
+                     terms)), grads = grad_fn(p_in, state.batch_stats, batch)
             hits = None  # read from the logits where the metrics are made
         else:
-            grads, new_stats, task_loss, aux, counters, hits = accumulate(
-                grad_fn, p_in, state.batch_stats, batch)
+            (grads, new_stats, task_loss, aux, counters, hits,
+             terms) = accumulate(grad_fn, p_in, state.batch_stats, batch)
         with jax.named_scope(STATS_SYNC_SCOPE):
             new_stats = jax.tree.map(
                 lambda s: lax.pmean(s, data_axis), new_stats)
@@ -463,6 +465,8 @@ def make_train_step(
         )
         with jax.named_scope(METRICS_SCOPE):
             metrics = {"loss": lax.pmean(task_loss, data_axis)}
+            for name, term in terms.items():  # a loss of several terms
+                metrics[name] = lax.pmean(term, data_axis)
             if health is not None:
                 metrics["health"] = hstats
             if aux is not None:
@@ -515,7 +519,7 @@ def make_eval_step(
                                  train=False)
             mask = batch.get("mask")
             with jax.named_scope(LOSS_SCOPE):
-                loss = task.loss(loss_fn, logits, batch)
+                loss, _ = task.loss(loss_fn, logits, batch)
         with jax.named_scope(METRICS_SCOPE):
             shard_count = (
                 mask.astype(jnp.float32).sum()

@@ -9,6 +9,10 @@ batch into the scalar the step differentiates. A model names its task by a
 
     image classification   {"image", "label", "mask"}        ``loss_fn(logits, label, mask)``
     next-token prediction  {"tokens", "loss_mask", "mask"}   masked next-token NLL
+    ... with a prediction module   (the same keys)           ``L_next + weight * L_mtp``
+
+A loss that is a sum of several terms hands each to the step beside the
+sum, by name, and the step reports each in its metrics.
 
 The step builders (``train/steps.py``) take a ``Task`` and know no other
 difference between the two: zero1, accumulation, recomputation, health and
@@ -18,6 +22,7 @@ the run loop come with the one builder.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -31,8 +36,10 @@ class Task:
     #: what the loss holds it against
     input_key: str
     target_key: str
-    #: ``(loss_fn, outputs, batch) -> scalar``: the shard's loss, a mean over
-    #: its real targets, in float32
+    #: ``(loss_fn, outputs, batch) -> (scalar, {name: scalar})``: the shard's
+    #: loss, a mean over its real targets, in float32, and each term of a
+    #: loss of several by the name the step's metrics carry it under ({} for
+    #: a loss of one term)
     loss: Callable
     #: does ``masked_accuracy(outputs, batch[target_key], mask)`` mean
     #: anything (a class per row)?
@@ -56,16 +63,16 @@ class Task:
 
 
 def _classification_loss(loss_fn, logits, batch):
-    return loss_fn(logits, batch["label"], batch.get("mask"))
+    return loss_fn(logits, batch["label"], batch.get("mask")), {}
 
 
-def next_token_loss(logits, tokens, loss_mask, row_mask=None):
-    """Mean negative log-likelihood of token t+1 at position t over the real
-    targets: positions whose target ``loss_mask`` marks, in rows ``row_mask``
-    keeps. Float32 whatever the logits' type."""
-    logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
-    nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0]
-    w = loss_mask[:, 1:].astype(jnp.float32)
+def next_token_loss(logits, tokens, loss_mask, row_mask=None, ahead=1):
+    """Mean negative log-likelihood of token ``t + ahead`` at position ``t``
+    over the real targets: positions whose target ``loss_mask`` marks, in
+    rows ``row_mask`` keeps. Float32 whatever the logits' type."""
+    logp = jax.nn.log_softmax(logits[:, :-ahead].astype(jnp.float32))
+    nll = -jnp.take_along_axis(logp, tokens[:, ahead:, None], axis=-1)[..., 0]
+    w = loss_mask[:, ahead:].astype(jnp.float32)
     if row_mask is not None:
         w = w * row_mask[:, None].astype(jnp.float32)
     return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1.0)
@@ -74,7 +81,25 @@ def next_token_loss(logits, tokens, loss_mask, row_mask=None):
 def _next_token_loss(loss_fn, logits, batch):
     del loss_fn  # a classifier's
     return next_token_loss(logits, batch["tokens"], batch["loss_mask"],
-                           batch.get("mask"))
+                           batch.get("mask")), {}
+
+
+def _next_token_mtp_loss(weight, loss_fn, outputs, batch):
+    """``L_next + weight * L_mtp`` of a model with a multi-token-prediction
+    module (arXiv:2412.19437, section 2.2): ``outputs`` is ``(logits,
+    mtp_logits)``, and the module's set at position ``i`` predicts token
+    ``i + 2``. ``L_mtp`` is the mean NLL over the real targets among those,
+    masked as ``L_next`` is; the module runs on every position, and the last
+    two have no target."""
+    del loss_fn  # a classifier's
+    logits, mtp_logits = outputs
+    tokens, loss_mask, row_mask = (batch["tokens"], batch["loss_mask"],
+                                   batch.get("mask"))
+    loss_next = next_token_loss(logits, tokens, loss_mask, row_mask)
+    loss_mtp = next_token_loss(mtp_logits, tokens, loss_mask, row_mask,
+                               ahead=2)
+    return (loss_next + weight * loss_mtp,
+            {"loss_next": loss_next, "loss_mtp": loss_mtp})
 
 
 def _synthetic_tokens(model, size, seed):
@@ -90,5 +115,17 @@ NEXT_TOKEN = Task("next_token", "tokens", "loss_mask", _next_token_loss,
 TASKS = {t.name: t for t in (IMAGE_CLASSIFICATION, NEXT_TOKEN)}
 
 
+def next_token_mtp(weight: float) -> Task:
+    """Next-token prediction by a model with a prediction module:
+    ``NEXT_TOKEN``'s batch, ``(logits, mtp_logits)`` out, ``L_next + weight
+    * L_mtp`` with both terms named."""
+    return dataclasses.replace(
+        NEXT_TOKEN, name="next_token_mtp",
+        loss=functools.partial(_next_token_mtp_loss, weight))
+
+
 def task_of(model) -> Task:
-    return TASKS[getattr(model, "task", IMAGE_CLASSIFICATION.name)]
+    name = getattr(model, "task", IMAGE_CLASSIFICATION.name)
+    if name == "next_token_mtp":  # the second term's weight is the model's
+        return next_token_mtp(model.spec.mtp_weight)
+    return TASKS[name]
