@@ -272,26 +272,81 @@ def test_a_recomputed_expert_block_walks_its_routed_path_twice(
     assert expert_block_step.as_text().count(" conditional(") == 2
 
 
+@pytest.fixture(scope="module")
+def sparse_layer_step(topo):
+    """Value and gradient of one sparse layer of ``joyai_llm_flash`` at the
+    cell's widths (16,384 tokens, latent attention through the flash
+    kernels, 8 of 256 experts with a selection bias, 16 held), recomputed
+    without a policy as ``SparseDecoder`` recomputes it, compiled for one
+    described chip."""
+    import functools
+
+    import flax.linen as nn
+
+    from tpu_ddp.models.decoder import DecoderLayer, joyai_llm_flash_spec
+    from tpu_ddp.ops.flash_attention import flash_attention
+
+    one = _one_chip(topo)
+    spec = joyai_llm_flash_spec(num_layers=2, experts_held=16, vocab_rows=512)
+    layer = nn.remat(DecoderLayer)(
+        spec.mtp, spec, dtype=jnp.bfloat16,
+        attention_impl=functools.partial(
+            flash_attention, block_q=512, block_k=512, interpret=False))
+    cos, sin = spec.mtp.rotary.tables(8192)
+    shapes = jax.eval_shape(lambda: layer.init(
+        jax.random.key(0), jnp.zeros((1, 256, spec.hidden), jnp.bfloat16),
+        cos[:256], sin[:256]))["params"]
+    params = jax.tree.map(lambda l: jax.ShapeDtypeStruct(
+        l.shape, l.dtype, sharding=one), shapes)
+    x = jax.ShapeDtypeStruct((2, 8192, spec.hidden), jnp.bfloat16,
+                             sharding=one)
+
+    def loss(p, x):
+        y, _ = layer.apply({"params": p}, x, cos, sin, mutable=["counters"])
+        return y.astype(jnp.float32).sum()
+
+    # with the value, or the forward pass itself is dead code here
+    return jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        params, x).compile()
+
+
+@pytest.mark.parametrize("step,sorts,temporaries", [
+    ("expert_block_step", 1, 2.95e9), ("sparse_layer_step", 2, 4.0e9),
+], ids=["nemotron3_super", "joyai_llm_flash"])
 def test_a_recomputed_expert_block_makes_its_routers_choice_once(
-        expert_block_step):
-    """It keeps the router's float32 logits, the chosen ids and their scores
-    too (``moe.LOGITS_NAME``, ``IDS_NAME``, ``SCORES_NAME``: 33.5 MB and
-    twice 1.4 MB a block), so the backward pass makes none of the six-pass
-    product, the ``top_k`` and the gather of the chosen scores again. The
-    TPU compiler writes ``lax.top_k`` of 22 over 512 as a whole ``sort`` of
-    the (16,384, 512) scores with their places and a slice, and leaves
-    ``top_k`` in its ``op_name``: one, the forward pass's; of the router's
-    product and of ``take_along_axis``'s gather (3.7 ms a block on the
-    chip, more than product and sort together) nothing under
-    ``rematted_computation``. The temporaries are 2.849 GB, 0.7 MB more
-    than with nothing of the router kept: what is kept is not paid for
-    again in room made elsewhere (PERF.md section 6, PR 34)."""
-    text = expert_block_step.as_text()
-    assert len(re.findall(r" sort\(.*moe_route/top_k", text)) == 1
-    again = r"rematted_computation[^\"]*moe_route/"
-    assert not re.search(again + "router", text)
-    assert not re.search(again + r"jit\(take_along_axis\)/gather", text)
-    assert expert_block_step.memory_analysis().temp_size_in_bytes < 2.95e9
+        step, sorts, temporaries, request):
+    """``HybridDecoder`` keeps the router's float32 logits, the chosen ids
+    and their scores too (``moe.LOGITS_NAME``, ``IDS_NAME``,
+    ``SCORES_NAME``: 33.5 MB and twice 1.4 MB a block), so the backward pass
+    makes none of the six-pass product and the ``top_k`` again. The TPU
+    compiler writes ``lax.top_k`` of 22 over 512 as a whole ``sort`` of the
+    (16,384, 512) scores with their places and a slice, and leaves ``top_k``
+    in its ``op_name``: one, the forward pass's, and nothing of the router's
+    product under ``rematted_computation``. ``SparseDecoder`` recomputes a
+    layer without a policy, so its router sorts twice. In both, the chosen
+    scores are read and differentiated as compares against the expert axis
+    (``moe._chosen``): no gather, no scatter and no further sort under
+    ``moe_route`` anywhere in the step, and nothing (tokens, choices,
+    experts) wide is written (184 M and 34 M places): the hybrid pair of
+    blocks' temporaries are 2.848 GB, 0.8 MB under what they were with
+    ``take_along_axis`` (PERF.md section 6, PR 34 and PR 37)."""
+    compiled = request.getfixturevalue(step)
+    text = compiled.as_text()
+    routed = [line for line in text.splitlines()
+              if re.search(r'op_name="[^"]*moe_route/', line)]
+    assert routed
+    sorted_ = [line for line in routed if " sort(" in line]
+    assert len(sorted_) == sorts
+    assert all("moe_route/top_k" in line for line in sorted_)
+    # a stack that sorts again makes the product again, and only that one
+    assert bool(re.search(r"rematted_computation[^\"]*moe_route/router",
+                          text)) == (sorts > 1)
+    # neither the instructions nor what the compiler made of their parts
+    assert not [line for line in routed if re.search(
+        r' (gather|scatter)\(|op_name="[^"]*(gather|scatter)', line)]
+    wide = re.compile(r"\[16384,(8|22),(256|512)\]\{[^}]*\} fusion\(")
+    assert not wide.search(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries
 
 
 # ---- the int8 ring's quantize / dequantize ----------------------------------

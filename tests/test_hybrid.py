@@ -102,20 +102,22 @@ def test_an_expert_block_makes_its_routers_choice_once(remat):
     """A recomputed expert block keeps its router's logits, chosen ids and
     their scores (``moe.LOGITS_NAME``, ``IDS_NAME``, ``SCORES_NAME``) beside
     the routed result, so its backward pass makes neither the float32
-    product nor the ``top_k`` nor the gather of the chosen scores again: one
-    ``top_k`` and one call of ``take_along_axis`` that hands out (tokens,
-    choices) scores an ``E`` block, and the router's three ``HIGHEST``
-    products (forward, and the gradients of operand and weight), with
-    recomputation as without."""
+    product nor the ``top_k`` again: one ``top_k`` an ``E`` block and the
+    router's three ``HIGHEST`` products (forward, and the gradients of
+    operand and weight), with recomputation as without. The chosen scores
+    are read by compares against the expert axis (``moe._chosen``): no call
+    of ``take_along_axis``, and no gather or scatter that makes or consumes
+    a (tokens, choices) float32, in either pass."""
     loss, tree, _ = _bfloat16_loss(remat)
     text = jax.jit(jax.value_and_grad(loss)).lower(tree).as_text()
     blocks = tiny.PATTERN.count("E")
     n, c, e = 2 * tiny.T, tiny.HIDDEN, tiny.WHOLE["n_routed_experts"]
     k = tiny.SIZES["num_experts_per_tok"]
     assert text.count("chlo.top_k") == blocks
-    assert len(re.findall(
-        rf"call @take_along_axis\w*\(.*\) -> \(tensor<{n}x{k}xf32>",
-        text)) == blocks
+    assert "take_along_axis" not in text
+    assert not [line for line in text.splitlines()
+                if re.search(r"stablehlo\.(gather|scatter)", line)
+                and f"tensor<{n}x{k}xf32>" in line]
     router_shaped = [f"-> tensor<{a}x{b}xf32>"
                      for a, b in ((n, e), (e, c), (n, c))]
     products = [line for line in text.splitlines()
@@ -128,9 +130,10 @@ def test_a_recomputed_expert_block_keeps_its_routers_choice(capsys):
     """What an expert block hands its backward pass from inside the layer,
     beside the block's input and the weights: the three values
     ``HybridDecoder``'s policy names of the router, (tokens, experts)
-    float32 logits, (tokens, choices) ids and float32 scores, the (tokens,
-    latent) routed result, and the ids as ``take_along_axis`` made them
-    indices. Nothing (tokens, hidden) wide, nothing of the sorts."""
+    float32 logits, (tokens, choices) ids (which are ``moe._chosen``'s one
+    residual too) and float32 scores, and the (tokens, latent) routed
+    result: nothing (tokens, hidden) wide, nothing of the sorts, nothing
+    (tokens, choices, experts) wide."""
     from jax.ad_checkpoint import print_saved_residuals
 
     spec = tiny.spec(pattern="E")
@@ -141,8 +144,43 @@ def test_a_recomputed_expert_block_keeps_its_routers_choice(capsys):
                   if line.endswith("(DroplessMoE.__call__)"))
     n, k = tokens.size, spec.top_k
     assert kept == sorted([f"f32[{n},{spec.num_experts}]", f"i32[{n},{k}]",
-                           f"i32[{n},{k}]", f"f32[{n},{k}]",
-                           f"bf16[{n},{spec.latent}]"])
+                           f"f32[{n},{k}]", f"bf16[{n},{spec.latent}]"])
+
+
+@pytest.mark.parametrize("wrap", [jax.jit, jax.checkpoint],
+                         ids=["jit", "checkpoint"])
+@pytest.mark.parametrize("n,k,e", [(56, 4, 16), (64, 8, 256), (32, 22, 512)])
+def test_the_chosen_scores_are_take_along_axis_to_the_bit(n, k, e, wrap):
+    """``moe._chosen`` against ``jnp.take_along_axis`` in float32 on the
+    ids of a real ``top_k`` of ``scored + bias``: the same values, and of
+    their weighed sum the same gradient with respect to ``scored`` (the
+    scatter-add's values at its places, zeros elsewhere); and no gradient
+    reaches the bias, which chooses and does not weigh."""
+    from tpu_ddp.models import moe
+
+    keys = jax.random.split(jax.random.key(n + k + e), 3)
+    scored = jax.nn.sigmoid(jax.random.normal(keys[0], (n, e)))
+    bias = 0.1 * jax.random.normal(keys[1], (e,))
+    weigh = jax.random.normal(keys[2], (n, k))
+
+    def weighed(read, scored, bias):
+        _, ids = jax.lax.top_k(scored + bias, k)
+        scores = read(scored, ids)
+        return jnp.sum(scores * weigh), scores
+
+    def both(read):
+        run = wrap(lambda scored, bias: weighed(read, scored, bias))
+        (_, scores), grads = jax.value_and_grad(
+            run, (0, 1), has_aux=True)(scored, bias)
+        return scores, grads
+
+    got, (d_scored, d_bias) = both(lambda s, ids: moe._chosen(s, ids, e))
+    want, (want_scored, _) = both(
+        lambda s, ids: jnp.take_along_axis(s, ids, axis=-1))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(d_scored, want_scored)
+    assert np.count_nonzero(d_scored) == n * k
+    assert not np.any(d_bias)
 
 
 def test_the_references_blocked_loss_is_its_loss_of_the_logits(ref, seeded,

@@ -373,6 +373,31 @@ _collect.defvjp(
     lambda k, reach, res, g: (_spread_rows(g, res[0], k), None, None))
 
 
+def _where_chosen(ids, experts, values, axis):
+    """``values``, broadcast against (N, K, E), summed over ``axis`` where
+    token ``n``'s choice ``k`` is expert ``e``, zero elsewhere."""
+    chosen = ids[:, :, None] == jnp.arange(experts, dtype=ids.dtype)
+    return jnp.where(chosen, values, 0).sum(axis)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _chosen(scored, ids, experts):
+    """``scored[n, ids[n, k]]``, (N, E) and (N, K) -> (N, K), a token's ids
+    distinct: ``jnp.take_along_axis``'s values and gradient to the bit, as
+    compares against the expert axis (``experts`` wide), selects and sums,
+    so that no gather, scatter or sort is made for them. Each sum has one
+    term that is not zero. Its residual is ``ids``: the backward pass
+    compares again, and neither pass writes anything (N, K, E) wide."""
+    return _where_chosen(ids, experts, scored[:, None, :], 2)
+
+
+_chosen.defvjp(
+    lambda scored, ids, experts: (
+        _where_chosen(ids, experts, scored[:, None, :], 2), ids),
+    lambda experts, ids, g: (
+        _where_chosen(ids, experts, g[:, :, None], 1), None))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _switch(branches, index, routing, floats):
     """``branches[index](routing, *floats)``: ``routing`` integers, the
@@ -537,12 +562,15 @@ class DroplessMoE(nn.Module):
     Under ``selection_bias`` the router's logits, ``LOGITS_NAME`` ((tokens,
     ``num_experts``) float32), the chosen ids, ``IDS_NAME``, and their
     scores, ``SCORES_NAME`` ((tokens, ``top_k``) int32 and float32): the
-    backward pass then makes none of the float32 product, the ``top_k``
-    and the gather of the chosen scores again (on a v5e the gather is the
-    dearest of the three), only the sigmoid and the normalisation,
-    elementwise, and the sorts of the dispatch. At 16,384 tokens, 512
-    experts, 22 choices and a latent width of 1,024 that is 33.5 MB, 33.5
-    MB and twice 1.4 MB a block (PERF.md section 6, PR 33 and PR 34).
+    backward pass then makes neither the float32 product nor the ``top_k``
+    again, only the sigmoid and the normalisation, elementwise, and the
+    sorts of the dispatch. The scores are read off the sigmoid's result by
+    ``_chosen``: compares of the ids against the expert axis, selects and
+    sums in both passes, where ``take_along_axis`` was a gather and its
+    transpose a scatter with a sort (on a v5e dearer than product and
+    ``top_k`` together). At 16,384 tokens, 512 experts, 22 choices and a
+    latent width of 1,024 what is kept is 33.5 MB, 33.5 MB and twice 1.4
+    MB a block (PERF.md section 6, PR 33, PR 34 and PR 37).
     Without ``selection_bias`` scores and ids are the ``top_k``'s own
     results and nothing carries a name: that layer's program is what it
     was.
@@ -594,8 +622,7 @@ class DroplessMoE(nn.Module):
                 scored = jax.nn.sigmoid(checkpoint_name(logits, LOGITS_NAME))
                 _, ids = jax.lax.top_k(scored + bias, K)        # (N, K)
                 ids = checkpoint_name(ids, IDS_NAME)
-                scores = checkpoint_name(
-                    jnp.take_along_axis(scored, ids, axis=-1), SCORES_NAME)
+                scores = checkpoint_name(_chosen(scored, ids, E), SCORES_NAME)
             else:
                 scores, ids = jax.lax.top_k(jax.nn.sigmoid(logits), K)
             weights = (scores / scores.sum(axis=-1, keepdims=True)
