@@ -1,7 +1,8 @@
-"""What a Pallas kernel of the ``laguna-xs2`` cells has to do, from shapes:
-the operations and bytes of one call, for its share of its roofline, and the
-join of a traced run's device operations with the program's map that says
-how long the kernel took.
+"""What a kernel of the decoder cells has to do, from shapes: the operations
+and bytes of one call, for its share of its roofline, the join of a traced
+run's device operations with the program's map that says how long the kernel
+took, and the one place that finds a cell's own files and reads what a
+configuration calls its layers, heads and experts.
 
 A roofline share is the least time the chip could take for the calls of one
 kernel in a step, the larger of operations over the chip's bf16 peak and
@@ -19,6 +20,24 @@ program whose ``op_name`` holds the kernel's scope
 such name and is found by the compiler's (``compiler_kernel_calls``). A
 layer recomputed in the backward pass calls its forward kernel twice and has
 two such instructions.
+
+The flash kernels (``tpu_ddp/ops/flash_attention.py``) run at a key width
+and a value width, which latent attention makes differ (queries and keys of
+``qk_nope_head_dim + qk_rope_head_dim``, values of ``v_head_dim``). Products
+of each width are counted apart:
+
+    flash_fwd   S = Q K^T (qk)            O = P V (v)
+    flash_dq    S (qk)   dP = dO V^T (v)  dQ = dS K (qk)
+    flash_dkv   S (qk)   dV = P^T dO (v)  dP (v)   dK = dS^T Q (qk)
+
+and so are the arrays moved, as the program hands them over: ``q`` and
+``dq`` of the query heads and ``k`` and ``dk`` of the key-value heads at the
+key width (latent attention's ``k`` with the shared rotary key already
+broadcast over the heads), ``o`` and ``dO`` of the query heads and ``v`` and
+``dv`` of the key-value heads at the value width, a float32 a row of a query
+head for the logsumexp and for ``rowsum(dO * O)``. Lanes a kernel pads a
+width to (192 to 256) are its waste and not counted as work, so they show as
+a lower share.
 """
 
 from __future__ import annotations
@@ -32,19 +51,28 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 KERNEL_SCOPE = "tpu_ddp.kernel."
 MODULE_SCOPE = "tpu_ddp.module."
 BYTES = 2  # bfloat16 operands and results
-#: products of (pairs x head_dim) size in a call of each flash kernel: the
-#: forward computes scores and output; dQ recomputes scores and computes dP
-#: and dQ; dK/dV recomputes scores and computes dV, dP and dK
-FLASH_PRODUCTS = {"flash_fwd": 2, "flash_dq": 3, "flash_dkv": 4}
+#: (products of the key width, products of the value width) on the visible
+#: pairs in a call of each flash kernel
+FLASH_PRODUCTS = {"flash_fwd": (1, 1), "flash_dq": (2, 1),
+                  "flash_dkv": (2, 2)}
+#: arrays moved in a call: (of the query heads at the key width, at the value
+#: width, of the key-value heads at the key width, at the value width,
+#: float32 rows of the query heads)
+FLASH_MOVED = {"flash_fwd": (1, 1, 1, 1, 1),   # q k v -> o lse
+               "flash_dq": (2, 1, 1, 1, 2),    # q k v dO lse di -> dq
+               "flash_dkv": (1, 1, 2, 2, 2)}   # q k v dO lse di -> dk dv
 
 
-def cell_shapes(record) -> dict:
-    """Sizes of one step on one chip of the cell the run was of, from that
-    cell's two data files. ``run.py`` keeps a cell's runs under
-    ``.chipbench_runs/<cell>/``, which is where the record's ``trace_dir``
-    lies, and ``BENCHMARK.json`` names the cell's configuration and mix.
-    None for a run of no cell of the benchmark, or of a configuration that
-    is not a decoder of this family."""
+# -- a cell's own files, and what its configuration calls its parts ------------
+
+def cell_files(record) -> dict:
+    """The configuration, as it is, and the sizes of one step on one chip of
+    the cell the run was of, from that cell's two data files. ``run.py``
+    keeps a cell's runs under ``.chipbench_runs/<cell>/``, which is where
+    the record's ``trace_dir`` lies, and ``BENCHMARK.json`` names the cell's
+    configuration and mix. None for a run of no cell of the benchmark; what
+    a configuration has of layers, heads and experts is for the functions
+    below to say, each by the configuration's own keys."""
     trace_dir = record.get("trace_dir")
     if not trace_dir:
         return None
@@ -59,18 +87,90 @@ def cell_shapes(record) -> dict:
         arch = json.load(f)
     with open(os.path.join(HERE, "traffic", cell["traffic"] + ".json")) as f:
         traffic = json.load(f)
-    if "layers_here" not in arch or "layer_types" not in arch:
-        return None
-    n = arch["layers_here"]
     return {
         "arch": arch,
         "batch": int(traffic["per_shard_batch"]),
-        "tokens": int(traffic["dataset"]["seq_len"]),
-        "layers": list(zip(arch["layer_types"][:n],
-                           arch["num_attention_heads_per_layer"][:n],
-                           arch["mlp_layer_types"][:n])),
+        "tokens": traffic["dataset"].get("seq_len"),
     }
 
+
+def layer_bodies(arch) -> list:
+    """``[(module scope of its attention or None, query heads, has routed
+    experts)]``, one for each layer body of the share held here, read by the
+    keys the configuration lays its layers out with: per-layer lists
+    (``layer_types``, ``num_attention_heads_per_layer``, ``mlp_layer_types``:
+    window and full layers), a pattern string (``hybrid_override_pattern``:
+    ``*`` attention, ``E`` experts, anything else neither), or latent
+    attention (``kv_lora_rank``) over ``first_k_dense_replace`` dense layers
+    with ``num_nextn_predict_layers`` prediction modules after the stack,
+    each one more body whose outermost scope is ``mtp``. Empty for a
+    configuration laid out in none of these ways."""
+    here = arch.get("layers_here")
+    if here is None:
+        return []
+    if "layer_types" in arch:
+        return [("attention_window" if kind == "sliding_attention"
+                 else "attention_full", heads, ffn == "sparse")
+                for kind, heads, ffn in zip(
+                    arch["layer_types"][:here],
+                    arch["num_attention_heads_per_layer"][:here],
+                    arch["mlp_layer_types"][:here])]
+    if "hybrid_override_pattern" in arch:
+        return [("attention_full" if kind == "*" else None,
+                 arch["num_attention_heads"] if kind == "*" else 0,
+                 kind == "E")
+                for kind in arch["hybrid_override_pattern"][:here]]
+    if "kv_lora_rank" in arch:
+        heads = arch["num_attention_heads"]
+        dense = arch.get("first_k_dense_replace", 0)
+        return ([("attention_latent", heads, i >= dense)
+                 for i in range(here)]
+                + [("mtp", heads, True)] * arch.get(
+                    "num_nextn_predict_layers", 0))
+    return []
+
+
+def attention_shapes(arch) -> dict:
+    """{module scope a flash kernel's call is found under: the ``heads``,
+    ``kv_heads``, ``qk_dim``, ``v_dim`` and ``window`` of that call}."""
+    latent = "kv_lora_rank" in arch
+    found = {}
+    for scope, heads, _ in layer_bodies(arch):
+        if scope is None:
+            continue
+        found[scope] = dict(
+            heads=heads, kv_heads=arch["num_key_value_heads"],
+            qk_dim=(arch["qk_nope_head_dim"] + arch["qk_rope_head_dim"]
+                    if latent else arch["head_dim"]),
+            v_dim=arch["v_head_dim"] if latent else arch["head_dim"],
+            window=(arch["sliding_window"] if scope == "attention_window"
+                    else 0))
+    return found
+
+
+def routed_experts(arch) -> dict:
+    """The routed experts of a layer body as the grouped products see them:
+    ``bodies`` that have them, experts ``held`` here, and the (contraction,
+    columns) of an expert's two products: gated, ``hidden -> 2 x
+    moe_intermediate -> hidden``, or, where the configuration names a plain
+    activation (``mlp_hidden_act`` ``relu2``), ``-> moe_intermediate ->``;
+    from and to ``moe_latent_size`` where the experts work in a latent
+    space. None for a configuration without routed experts."""
+    bodies = sum(1 for _, _, routed in layer_bodies(arch) if routed)
+    if not bodies:
+        return None
+    outer = arch.get("moe_latent_size", arch["hidden_size"])
+    inner = arch["moe_intermediate_size"]
+    gate = 1 if arch.get("mlp_hidden_act") == "relu2" else 2
+    return {
+        "bodies": bodies,
+        "held": arch.get("num_experts", arch.get("n_routed_experts")),
+        "products": [dict(contraction=outer, columns=gate * inner),
+                     dict(contraction=inner, columns=outer)],
+    }
+
+
+# -- operations and bytes of one call ----------------------------------------
 
 def visible_pairs(t: int, window: int) -> int:
     if not window or window >= t:
@@ -78,19 +178,18 @@ def visible_pairs(t: int, window: int) -> int:
     return window * (window + 1) // 2 + (t - window) * window
 
 
-def flash_call(kernel: str, *, batch, tokens, heads, kv_heads, head_dim,
+def flash_call(kernel: str, *, batch, tokens, heads, kv_heads, qk_dim, v_dim,
                window) -> tuple:
-    """(operations, bytes) of one call of a flash kernel."""
+    """(operations, bytes) of one call of a flash kernel (module
+    docstring)."""
     pairs = batch * heads * visible_pairs(tokens, window)
-    flops = 2.0 * FLASH_PRODUCTS[kernel] * pairs * head_dim
-    q_like = batch * tokens * heads * head_dim * BYTES
-    kv_like = batch * tokens * kv_heads * head_dim * BYTES
-    stats = batch * tokens * heads * 4  # one float32 a row (logsumexp, delta)
-    moved = {
-        "flash_fwd": 2 * q_like + 2 * kv_like + stats,       # q k v -> o lse
-        "flash_dq": 3 * q_like + 2 * kv_like + 2 * stats,    # q k v do -> dq
-        "flash_dkv": 2 * q_like + 4 * kv_like + 2 * stats,   # ... -> dk dv
-    }[kernel]
+    of_qk, of_v = FLASH_PRODUCTS[kernel]
+    flops = 2.0 * pairs * (of_qk * qk_dim + of_v * v_dim)
+    q_rows, kv_rows = batch * tokens * heads, batch * tokens * kv_heads
+    q_qk, q_v, kv_qk, kv_v, stats = FLASH_MOVED[kernel]
+    moved = (BYTES * (q_rows * (q_qk * qk_dim + q_v * v_dim)
+                      + kv_rows * (kv_qk * qk_dim + kv_v * v_dim))
+             + 4 * stats * q_rows)
     return flops, float(moved)
 
 
@@ -179,28 +278,21 @@ def modules_ms(run, modules):
 
 def flash_roofline(run, kernel: str):
     """Percent: least seconds of a step's calls of ``kernel`` over their
-    device seconds."""
+    device seconds, every module the calls sit in together (each on an
+    earlier line). None where a call sits in a module the cell's files do
+    not describe: never a guess."""
     found = kernel_calls(run, kernel)
     peaks = peaks_of(run.record)
-    shapes = cell_shapes(run.record)
-    if found is None or peaks is None or shapes is None:
+    cell = cell_files(run.record)
+    if found is None or peaks is None or cell is None:
         return None
-    arch = shapes["arch"]
-    # module scope -> (query heads, window) of that kind of layer
-    kinds = {("attention_window" if kind == "sliding_attention"
-              else "attention_full"): (
-                  heads, arch["sliding_window"]
-                  if kind == "sliding_attention" else 0)
-             for kind, heads, _ in shapes["layers"]}
+    kinds = attention_shapes(cell["arch"])
     least = spent = 0.0
     for module, (calls, seconds) in found.items():
         if module not in kinds:
             return None  # a kernel call the cell's files do not describe
-        heads, window = kinds[module]
-        flops, moved = flash_call(
-            kernel, batch=shapes["batch"], tokens=shapes["tokens"],
-            heads=heads, kv_heads=arch["num_key_value_heads"],
-            head_dim=arch["head_dim"], window=window)
+        flops, moved = flash_call(kernel, batch=cell["batch"],
+                                  tokens=cell["tokens"], **kinds[module])
         scopes.say(f"kernel {kernel} in {module}: {calls} calls a step, "
                    f"{seconds * 1e3!r} ms a step, a call {flops!r} FLOP "
                    f"{moved!r} bytes, least "
@@ -210,12 +302,38 @@ def flash_roofline(run, kernel: str):
     return 100.0 * least / spent if spent else None
 
 
-def landed_rows_per_layer(run, shapes):
-    """Mean (token, choice) pairs a sparse layer's held experts got in a
-    step, from the program's counters; None without them."""
-    gauges = (scopes.of_run(run)["counters"] or {}).get("gauges", {})
-    landed = gauges.get("model/expert_load_sum")
-    if landed is None:
+def grouped_roofline(run, compiler_name: str, layout_calls: str):
+    """Percent: least seconds of a step's calls of the compiler's grouped
+    product (custom calls named ``compiler_name...``; those named
+    ``layout_calls...`` lay the groups out for them and add their time and
+    no work) over their device seconds, at the rows the program's counters
+    say really landed on the held experts of a layer body: the mean of an
+    expert's two products, since all four calls of a product (forward,
+    forward again, backward by rows and by weights) move the same operands
+    and do the same operations. The calls are counted from the trace, never
+    assumed from the layer count. None where the traced program calls no
+    such kernel, keeps no counters, or the cell's files name no routed
+    experts."""
+    found = compiler_kernel_calls(run, compiler_name)
+    cell = cell_files(run.record)
+    peaks = peaks_of(run.record)
+    if found is None or cell is None or peaks is None:
         return None
-    sparse = sum(1 for _, _, ffn in shapes["layers"] if ffn == "sparse")
-    return landed / sparse
+    experts = routed_experts(cell["arch"])
+    landed = (scopes.of_run(run)["counters"] or {}).get("gauges", {}).get(
+        "model/expert_load_sum")
+    if experts is None or landed is None:
+        return None
+    rows = landed / experts["bodies"]
+    layout = compiler_kernel_calls(run, layout_calls) or {}
+    per_call = sum(least_seconds(*grouped_call(
+        rows=rows, held=experts["held"], **product), peaks)
+        for product in experts["products"]) / len(experts["products"])
+    calls = (sum(n for n, _ in found.values())
+             - sum(n for n, _ in layout.values()))
+    spent = sum(s for _, s in found.values())
+    scopes.say(f"kernel grouped_matmul: {calls} calls a step in "
+               f"{sorted(found)}, {spent * 1e3!r} ms a step, {rows!r} real "
+               f"rows a layer body of {experts['bodies']}, least "
+               f"{per_call * 1e3!r} ms a call")
+    return 100.0 * calls * per_call / spent if spent and calls else None

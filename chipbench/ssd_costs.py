@@ -2,9 +2,9 @@
 (``tpu_ddp/ops/ssd_scan.py``) has to do, from shapes: the operations and
 bytes of one call, forward and backward, for its share of its roofline, and
 the join of a traced run's device operations with the program's map that
-says how long its calls took. The shipped ``kernel_costs.py`` is the
-``laguna-xs2`` family's (its ``cell_shapes`` knows no pattern string); what
-the two share (peaks, the least time, the map's files) is taken from there.
+says how long its calls took. What every kernel's costs share (the lookup
+of a cell's own files, peaks, the least time, the map's files) is
+``kernel_costs.py``'s and taken from there.
 
 The scan is ``jax.numpy``, so a call is many instructions of the step
 program, each with ``tpu_ddp.kernel.ssd_scan_fwd`` (or ``_bwd``) in its
@@ -22,43 +22,12 @@ imports the program.
 
 from __future__ import annotations
 
-import json
-import os
 import re
 
 from chipbench import kernel_costs, scopes
 
-HERE = os.path.dirname(os.path.abspath(__file__))
 BLOCK = re.compile(r"/block_(\d+)/")
 SCAN_KERNELS = ("ssd_scan_fwd", "ssd_scan_bwd")
-
-
-def cell_shapes(record) -> dict:
-    """Sizes of one step on one chip of the cell the run was of, for a
-    configuration laid out by ``hybrid_override_pattern``; None for a run of
-    no cell of the benchmark or of another family."""
-    trace_dir = record.get("trace_dir")
-    if not trace_dir:
-        return None
-    name = os.path.basename(os.path.dirname(os.path.abspath(trace_dir)))
-    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    cell = next((w for w in bench["workloads"] if w["name"] == name), None)
-    if cell is None:
-        return None
-    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    with open(os.path.join(os.path.dirname(HERE), entry["file"])) as f:
-        arch = json.load(f)
-    with open(os.path.join(HERE, "traffic", cell["traffic"] + ".json")) as f:
-        traffic = json.load(f)
-    if "hybrid_override_pattern" not in arch or "layers_here" not in arch:
-        return None
-    return {
-        "arch": arch,
-        "batch": int(traffic["per_shard_batch"]),
-        "tokens": int(traffic["dataset"]["seq_len"]),
-        "pattern": arch["hybrid_override_pattern"][:arch["layers_here"]],
-    }
 
 
 def _chunks(tokens: int, chunk: int):
@@ -135,15 +104,18 @@ def scan_calls(run, kernel: str):
 
 def scan_roofline(run, kernel: str):
     """Percent: least seconds of a step's calls of ``kernel`` over their
-    device seconds."""
+    device seconds. None where the cell's configuration names no Mamba heads
+    (``mamba_num_heads``): never a guess."""
     found = scan_calls(run, kernel)
     peaks = kernel_costs.peaks_of(run.record)
-    shapes = cell_shapes(run.record)
-    if found is None or peaks is None or shapes is None:
+    cell = kernel_costs.cell_files(run.record)
+    if found is None or peaks is None or cell is None:
         return None
-    arch = shapes["arch"]
+    arch = cell["arch"]
+    if "mamba_num_heads" not in arch:
+        return None
     flops, moved = scan_call(
-        kernel, batch=shapes["batch"], tokens=shapes["tokens"],
+        kernel, batch=cell["batch"], tokens=cell["tokens"],
         heads=arch["mamba_num_heads"], head_dim=arch["mamba_head_dim"],
         groups=arch["n_groups"], state=arch["ssm_state_size"],
         chunk=arch["chunk_size"])

@@ -15,7 +15,10 @@ so the trace has no host spans. The device's clock is tied to the host's by
 the traced run's own fences: the telemetry ends its ``device_sync`` span of a
 step when the device has finished it, and the step's ``XLA Modules`` event
 ends at that moment on the device's clock. The median difference is the
-offset; idle gaps are then labelled by the host span that covers them.
+offset; idle gaps are then labelled by the host span that covers them. A
+loop that fences no step still has the fence that closes the window
+(``adapters/trainer.py::StepProbe._close``): the last program's end is that
+moment, and one pair gives the offset (``clock_offset_ns``).
 
 ``busy`` is the union of the ``XLA Ops`` intervals of a chip: time in which an
 operation ran on its core. A collective is an event on either ops line whose
@@ -166,14 +169,21 @@ def label_gaps(gaps_ns, host_spans_ns, other="host_other"):
                   key=lambda kv: -kv[1])
 
 
-def clock_offset_ns(program_ends_ns, host_spans):
-    """Device clock minus host clock, from the fences (module docstring);
-    None where the two do not pair up one to one."""
+def clock_offset_ns(program_ends_ns, host_spans, t_close=None):
+    """Device clock minus host clock, from the fences (module docstring).
+    Where the ``device_sync`` spans pair one to one with the program
+    executions (a fence a step), the median over the pairs; where they do
+    not (a loop that fences no step), the one fence every traced slice has:
+    ``t_close``, the host's clock straight after the ``block_until_ready``
+    that closed the window, is when the last program ended on the device's.
+    None without either."""
     sync_ends = sorted(e for name, _, e in host_spans if name == SYNC_SPAN)
-    if not sync_ends or len(sync_ends) != len(program_ends_ns):
+    if sync_ends and len(sync_ends) == len(program_ends_ns):
+        return statistics.median(
+            d - h * 1e9 for d, h in zip(program_ends_ns, sync_ends))
+    if t_close is None or not program_ends_ns:
         return None
-    return statistics.median(
-        d - h * 1e9 for d, h in zip(program_ends_ns, sync_ends))
+    return program_ends_ns[-1] - t_close * 1e9
 
 
 def reduce(planes: dict, *, window_s: float, dispatches: int,
@@ -198,7 +208,9 @@ def reduce(planes: dict, *, window_s: float, dispatches: int,
             f"executions, the harness made {dispatches} dispatches")
     busy_s = sum(c["busy_ns"] for c in chips.values()) / n / 1e9
 
-    offset_ns = clock_offset_ns(first["program_ends"], host_spans)
+    offset_ns = clock_offset_ns(
+        first["program_ends"], host_spans,
+        None if t_open is None else t_open + window_s)
     lo, hi = first["busy"][0][0], first["busy"][-1][1]
     if offset_ns is not None and t_open is not None:
         lo = min(lo, t_open * 1e9 + offset_ns)

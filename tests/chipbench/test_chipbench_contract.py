@@ -1,17 +1,15 @@
 """BENCHMARK.json against the contract, and every name in it against a file:
 the shipped one, and a copy to which a configuration, a cell and two
 per-layer metrics were appended the way a PR that changes the program may
-append them (``chipbench_tiny.append``). To try an addition of your own,
-give ``case`` a third parameter that writes it."""
+append them (``chipbench_tiny.append``): the ``case`` / ``bench`` fixtures of
+``conftest.py``, which every test that reads the shipped file's lists
+takes."""
 
 import copy
 import json
 import os
 import re
 import sys
-import types
-
-import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
@@ -29,25 +27,6 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 WIDTH_WORDS = ("hidden", "intermediate", "latent", "state_size", "proj",
                "width", "filters", "chans", "expansion", "head_dim")
-
-
-@pytest.fixture(scope="module", params=["shipped", "appended"])
-def case(request, tmp_path_factory):
-    """``path`` of a benchmark file and the ``roots`` its names are found
-    under (``harness.find``): the shipped ones, or a copy with new entries
-    at the end of its lists and their files in a directory in front."""
-    if request.param == "shipped":
-        return types.SimpleNamespace(path=SHIPPED, roots=[BENCH],
-                                     appended=False)
-    root = tmp_path_factory.mktemp("appended")
-    path, roots = chipbench_tiny.append(str(root), SHIPPED)
-    return types.SimpleNamespace(path=path, roots=roots + [BENCH],
-                                 appended=True)
-
-
-@pytest.fixture(scope="module")
-def bench(case):
-    return harness.load_json(case.path)
 
 
 def appended_only(shipped: dict, proposed: dict) -> bool:
@@ -168,6 +147,54 @@ def test_every_per_layer_metric_has_its_reader(case, bench):
         assert reader.MOVES == metric["moves"]
         assert reader.SOURCE == metric["source"]
         assert callable(reader.read)
+
+
+def test_every_reader_is_listed_and_every_entry_has_its_file(case, bench):
+    """No reader waits unlisted under ``layer_metrics/`` (PR 36 shipped five
+    that nothing listed), and no entry lacks its file: the ``*.py`` of the
+    roots' ``layer_metrics`` directories are the names of ``per_layer``,
+    each once."""
+    files = []
+    for root in case.roots:
+        folder = os.path.join(root, "layer_metrics")
+        files += [name[:-3] for name in os.listdir(folder)
+                  if name.endswith(".py")]
+    names = [m["name"] for m in bench["per_layer"]]
+    assert sorted(files) == sorted(names)
+    assert len(names) == len(set(names))
+
+
+def test_one_mechanism_has_one_name_in_every_cell_that_runs_it(bench):
+    """A reader is named for a mechanism, not for a configuration: the
+    decoder cells share the readers of attention, the flash kernels, the
+    routed experts and their grouped products."""
+    decoders = ["laguna-xs2.seq8k", "nemotron3-super.seq8k-v16384",
+                "joyai-llm-flash.seq8k-v16160"]
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in ("device_moe_ms", "device_attention_ms",
+                 "expert_load_max_over_mean", "moe_rows_walked_over_landed",
+                 "flash_fwd_roofline", "flash_dq_roofline",
+                 "flash_dkv_roofline", "grouped_matmul_roofline"):
+        assert by_name[name]["workloads"] == decoders, name
+    for name in by_name:
+        assert not name.startswith(("latent_moe", "mla_flash",
+                                    "device_latent")), name
+
+
+def test_an_entry_appended_after_device_mtp_ms_is_appended_only(bench):
+    """``device_mtp_ms`` is found by its name, and nothing is held about
+    what follows it: what follows was appended, and so is one entry more."""
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("device_mtp_ms")
+    cut = dict(bench, per_layer=bench["per_layer"][:at + 1])
+    assert appended_only(cut, bench)
+    more = copy.deepcopy(bench)
+    more["per_layer"].append(dict(
+        bench["per_layer"][at], name="device_step_done_ms"))
+    assert appended_only(bench, more) and appended_only(cut, more)
+    moved = copy.deepcopy(more)
+    moved["per_layer"].insert(at, moved["per_layer"].pop())
+    assert not appended_only(bench, moved)
 
 
 def test_a_cell_reports_the_metrics_that_list_it_or_list_nobody(case, bench):

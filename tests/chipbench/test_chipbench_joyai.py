@@ -2,11 +2,11 @@
 the shipped ``zipf_tokens`` generator and ``trainer`` adapter, so the
 product's ``Trainer.run``) through the shipped harness at a size a CPU
 holds, on a copy of the shipped BENCHMARK.json with the tiny cell appended
-(``chipbench_tiny_joyai.py``); the entries this configuration appended to
-the shipped file; the five per-layer readers of what only it runs, which
-wait for a ``benchmark`` PR to list them (``READERS``), and the flash
-kernels' costs at two widths (``chipbench/mla_costs.py``). The left-out
-tests are in ``test_chipbench_joyai_left_out.py``."""
+(``chipbench_tiny_joyai.py``); the entries this configuration has in the
+shipped file; the two per-layer readers of what only it runs (``READERS``),
+the eight it shares with the other decoder cells (one name a mechanism),
+and the flash kernels' costs at two widths (``chipbench/kernel_costs.py``).
+The left-out tests are in ``test_chipbench_joyai_left_out.py``."""
 
 import json
 import os
@@ -24,17 +24,18 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 import chipbench_tiny_joyai as tiny_cell  # noqa: E402
 import joyai_tiny as tiny  # noqa: E402
-from chipbench import kernel_costs, mla_costs  # noqa: E402
+from chipbench import kernel_costs  # noqa: E402
 from chipbench import run as harness  # noqa: E402
 from test_chipbench_contract import appended_only  # noqa: E402
 
 CELL = "joyai-llm-flash.seq8k-v16160"
-#: readers under ``chipbench/layer_metrics`` that ``per_layer`` does not list
-#: yet: ``test_chipbench_nemotron.py`` holds its six to the end of that list
-#: (PERF.md section 7, for a ``benchmark`` PR), so the cell reports the
-#: shipped metrics that carry no ``workloads`` list and these are read here
-READERS = ("device_mla_ms", "device_mtp_ms", "mla_flash_fwd_roofline",
-           "mla_flash_dq_roofline", "mla_flash_dkv_roofline")
+#: the readers of what only this cell runs
+READERS = ("device_mla_ms", "device_mtp_ms")
+#: the readers it shares with the other decoder cells: one name a mechanism
+SHARED_METRICS = ("device_moe_ms", "device_attention_ms",
+                  "expert_load_max_over_mean", "moe_rows_walked_over_landed",
+                  "flash_fwd_roofline", "flash_dq_roofline",
+                  "flash_dkv_roofline", "grouped_matmul_roofline")
 _CONFIG = ("jax_compilation_cache_dir",
            "jax_persistent_cache_min_compile_time_secs",
            "jax_persistent_cache_min_entry_size_bytes")
@@ -67,19 +68,26 @@ def test_the_new_configuration_is_correct_through_trainer_run(tmp_path,
 
 # -- what was appended to the shipped file --------------------------------------
 
-def test_the_shipped_file_got_one_configuration_and_one_cell():
-    """BENCHMARK.json is the parent's with one configuration and one cell
-    appended and nothing else: cut where this configuration's entries
-    start, it is a file of which the shipped one is ``appended_only``. The
-    cell reports the metrics that list no cells (a later PR may append
-    metrics that list it: nothing here holds the end of a list)."""
-    bench = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
+def test_the_shipped_file_has_its_configuration_cell_and_readers(bench):
+    """Found by name, on the shipped file and on a copy with entries after
+    the end of every list: cut where this configuration's entries start, the
+    file is one of which the whole is ``appended_only``; its own readers
+    list its cell alone, the shared ones list it among the decoder cells.
+    Nothing is held about what follows an entry."""
     names = lambda group: [e["name"] for e in bench[group]]  # noqa: E731
     at = {"configs": names("configs").index("joyai-llm-flash"),
           "workloads": names("workloads").index(CELL)}
     before = dict(bench, **{group: bench[group][:i]
                             for group, i in at.items()})
     assert appended_only(before, bench)
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in READERS:
+        assert by_name[name] == {
+            "name": name, "unit": "ms", "better": "lower",
+            "source": "device_trace", "layer": "models",
+            "moves": "images_per_s_per_chip", "workloads": [CELL]}
+    for name in SHARED_METRICS:
+        assert CELL in by_name[name]["workloads"]
     assert {"step_mfu", "device_step_ms"} <= {
         m["name"] for m in bench["per_layer"] if "workloads" not in m}
     cell = bench["workloads"][at["workloads"]]
@@ -136,7 +144,8 @@ def test_the_mix_draws_from_the_slice():
 
 # -- the kernels' costs at two widths ----------------------------------------------
 
-CALL = dict(batch=2, tokens=8192, heads=32, qk_dim=192, v_dim=128)
+CALL = dict(batch=2, tokens=8192, heads=32, kv_heads=32, qk_dim=192,
+            v_dim=128, window=0)
 
 
 def test_products_of_each_width_are_counted_apart():
@@ -148,19 +157,19 @@ def test_products_of_each_width_are_counted_apart():
         "flash_dkv": (2 * 192 + 2 * 128, 2 * (3 * 192 + 3 * 128) + 8),
     }
     for kernel, (width, row_bytes) in want.items():
-        flops, moved = mla_costs.flash_call(kernel, **CALL)
+        flops, moved = kernel_costs.flash_call(kernel, **CALL)
         assert flops == 2.0 * pairs * width
         assert moved == rows * row_bytes
         # the matrix unit bounds all three at these shapes, not the memory
         assert flops / 197e12 > moved / 819e9
-    # with one width they are the shipped costs of as many heads on each side
-    for kernel in want:
-        assert mla_costs.flash_call(
-            kernel, **dict(CALL, qk_dim=128)) == kernel_costs.flash_call(
-                kernel, batch=2, tokens=8192, heads=32, kv_heads=32,
-                head_dim=128, window=0)
+    # what ``chipbench/mla_costs.py`` counted for these calls (PR 36), to
+    # the last digit: its tables went into the one ``flash_call``
+    assert {k: kernel_costs.flash_call(k, **CALL) for k in want} == {
+        "flash_fwd": (1374557306880.0, 673185792.0),
+        "flash_dq": (2199291691008.0, 876609536.0),
+        "flash_dkv": (2749114613760.0, 1010827264.0)}
     # the padding of 192 to 256 lanes is not work: least 6.98 ms a forward
-    flops, moved = mla_costs.flash_call("flash_fwd", **CALL)
+    flops, moved = kernel_costs.flash_call("flash_fwd", **CALL)
     assert 6.9e-3 < flops / 197e12 < 7.0e-3
 
 
@@ -170,8 +179,27 @@ def test_the_references_count_of_a_step_uses_the_same_pairs():
                            "joyai-llm-flash.json")) as f:
         arch = json.load(f)
     parts = ref.forward_macs_by_part(arch, 8192)
-    flops, _ = mla_costs.flash_call("flash_fwd", **dict(CALL, batch=1))
+    flops, _ = kernel_costs.flash_call("flash_fwd", **dict(CALL, batch=1))
     assert 2.0 * parts["attention"] == 6 * flops  # six layer bodies
+
+
+def test_the_layers_and_the_experts_are_read_by_the_files_keys():
+    """What ``kernel_costs`` makes of ``joyai-llm-flash.json``: five layers
+    of latent attention, the first dense, and the prediction module's one
+    body more under its own scope; 16 gated experts of 768 held."""
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "joyai-llm-flash.json")) as f:
+        arch = json.load(f)
+    assert kernel_costs.layer_bodies(arch) == [
+        ("attention_latent", 32, False)] + [
+        ("attention_latent", 32, True)] * 4 + [("mtp", 32, True)]
+    call = {k: v for k, v in CALL.items() if k not in ("batch", "tokens")}
+    assert kernel_costs.attention_shapes(arch) == {
+        "attention_latent": call, "mtp": call}
+    assert kernel_costs.routed_experts(arch) == {
+        "bodies": 5, "held": 16, "products": [
+            dict(contraction=2048, columns=2 * 768),
+            dict(contraction=768, columns=2048)]}
 
 
 # -- the per-layer readers ----------------------------------------------------------
@@ -182,21 +210,23 @@ def _reader(name):
         "chipbench_metric_" + name)
 
 
-@pytest.mark.parametrize("name", READERS)
+@pytest.mark.parametrize("name", READERS + SHARED_METRICS)
 def test_a_reader_finds_nothing_in_a_program_without_its_scopes(name,
                                                                  tmp_path):
-    """An untraced run, and a traced run of a program that writes no map
-    (the parent): None, nothing raised."""
+    """An untraced run, and a traced run of this cell of a program that
+    writes no map and keeps no counters (the parent): None, nothing
+    raised."""
     reader = _reader(name)
     assert reader.read(types.SimpleNamespace(
         record={"trace_dir": None}, trace=None)) is None
     assert reader.read(types.SimpleNamespace(
         record={"steps": 7, "examples": 56}, trace=None)) is None
-    os.makedirs(tmp_path / "telemetry")
-    (tmp_path / "telemetry" / "trace-p0.jsonl").write_text(json.dumps(
+    root = tmp_path / CELL
+    os.makedirs(root / "telemetry")
+    (root / "telemetry" / "trace-p0.jsonl").write_text(json.dumps(
         {"type": "counters", "attrs": {"tables": {}, "gauges": {}}}) + "\n")
     traced = types.SimpleNamespace(
-        record={"trace_dir": str(tmp_path / "profile"),
+        record={"trace_dir": str(root / "profile"),
                 "peak_flops_per_s": 197e12},
         trace={"device_ops": [["fusion.1", 0.5]], "steps": 5,
                "device_step_ms": 100.0})
@@ -205,8 +235,8 @@ def test_a_reader_finds_nothing_in_a_program_without_its_scopes(name,
 
 def _traced_run(root, cell=CELL):
     """A traced run of a program with the scopes, kept where ``run.py`` keeps
-    a cell's runs: a map of nine instructions and the trace's seconds over
-    a slice of five steps."""
+    a cell's runs: a map of twelve instructions, the trace's seconds over a
+    slice of five steps, the counters."""
     root = root / cell
     step = "jit(shard_step)/tpu_ddp.forward_backward/"
     fwd = step + "jvp(SparseDecoder)/checkpoint/"
@@ -235,18 +265,29 @@ def _traced_run(root, cell=CELL):
                      "dot_general", "forward", "moe_route"),
         "fusion.4": (fwd + mtp + "moe/tpu_ddp.module.moe_route/dot_general",
                      "forward", "mtp"),
+        # the compiler's own kernel: its name, the module its user has, a
+        # layer of the stack's and the module's
+        "ragged-dot-none.3": ("ragged-dot-none", "forward", "moe_experts"),
+        "ragged-dot-none.4": ("ragged-dot-none", "forward", "mtp"),
+        "ragged-dot-metadata.5": ("ragged-dot-metadata", "forward",
+                                  "moe_dispatch"),
     }
     os.makedirs(root / "telemetry")
     (root / "telemetry" / "programs-p0.jsonl").write_text(json.dumps({
         "type": "program_map", "program": "train_step",
-        "instructions": {name: {"op_name": op, "opcode": "fusion",
-                                "phase": phase, "module": module}
-                         for name, (op, phase, module) in rows.items()}})
+        "instructions": {name: {
+            "op_name": op, "phase": phase, "module": module,
+            "opcode": "custom-call" if name.startswith("ragged")
+            else "fusion"} for name, (op, phase, module) in rows.items()}})
         + "\n")
-    (root / "telemetry" / "trace-p0.jsonl").write_text(json.dumps(
-        {"type": "counters", "attrs": {"tables": {}, "gauges": {}}}) + "\n")
+    (root / "telemetry" / "trace-p0.jsonl").write_text(json.dumps({
+        "type": "counters", "attrs": {"tables": {}, "gauges": {
+            "model/expert_load_max": 1871.0, "model/expert_load_mean": 403.0,
+            "model/expert_load_sum": 5 * 6450.0,
+            "model/expert_rows_walked_sum": 5 * 16384.0,
+            "model/expert_rows_walked_max": 16384.0}}}) + "\n")
     seconds = [0.080, 0.085, 0.075, 0.125, 0.150, 0.020, 0.010, 0.004,
-               0.006]
+               0.006, 0.003, 0.0035, 0.0005]
     return types.SimpleNamespace(
         record={"trace_dir": str(root / "profile"),
                 "peak_flops_per_s": 197e12},
@@ -254,23 +295,65 @@ def _traced_run(root, cell=CELL):
                "steps": 5, "device_step_ms": 120.0})
 
 
-def test_the_readers_join_the_map_and_the_trace(tmp_path):
+def test_the_cells_own_readers_join_the_map_and_the_trace(tmp_path):
     run = _traced_run(tmp_path)
     # module milliseconds a step, every phase together; the module's layer
     # is the module's, whatever scopes nest inside
     assert _reader("device_mla_ms").read(run) == pytest.approx(
         (0.080 + 0.085 + 0.125 + 0.150 + 0.020 + 0.010) / 5 * 1e3)
     assert _reader("device_mtp_ms").read(run) == pytest.approx(
-        (0.075 + 0.006) / 5 * 1e3)
+        (0.075 + 0.006 + 0.0035) / 5 * 1e3)
+
+
+def _flash_share(kernel, calls, spent):
+    flops, moved = kernel_costs.flash_call(kernel, **CALL)
+    return 100 * calls * max(flops / 197e12, moved / 819e9) / spent
+
+
+def _grouped_share(rows, held, products, calls, spent):
+    per_call = sum(max(f / 197e12, b / 819e9) for f, b in (
+        kernel_costs.grouped_call(rows=rows, held=held, **product)
+        for product in products)) / 2
+    return 100 * calls * per_call / spent
+
+
+#: what each shared reader reads of ``_traced_run``: the recorded-run tests
+#: of the three ``mla_flash_*_roofline`` went on under the names that took
+#: their place
+SHARED_READINGS = {
     # three forward calls, the module's among them, in 48 ms a step
-    for kernel, calls, spent in (("flash_fwd", 3, 0.048),
-                                 ("flash_dq", 1, 0.025),
-                                 ("flash_dkv", 1, 0.030)):
-        flops, moved = mla_costs.flash_call(kernel, **CALL)
-        least = max(flops / 197e12, moved / 819e9)
-        assert _reader("mla_" + kernel + "_roofline").read(
-            run) == pytest.approx(100 * calls * least / spent)
-    # the same run kept under a cell of another family: not its shapes
-    elsewhere = _traced_run(tmp_path / "elsewhere", "laguna-xs2.seq8k")
-    assert mla_costs.cell_shapes(elsewhere.record) is None
-    assert _reader("mla_flash_fwd_roofline").read(elsewhere) is None
+    "flash_fwd_roofline": _flash_share("flash_fwd", 3, 0.048),
+    "flash_dq_roofline": _flash_share("flash_dq", 1, 0.025),
+    "flash_dkv_roofline": _flash_share("flash_dkv", 1, 0.030),
+    # the stack's layers: the module's layer is the module's
+    "device_attention_ms": (0.080 + 0.085 + 0.125 + 0.150) / 5 * 1e3,
+    "device_moe_ms": (0.004 + 0.003 + 0.0005) / 5 * 1e3,
+    "expert_load_max_over_mean": 1871.0 / 403.0,
+    "moe_rows_walked_over_landed": 16384 / 6450,
+    # two calls, one of them the module's, at 6,450 real rows a body of
+    # five: 16 held gated experts, hidden 2048, width 768
+    "grouped_matmul_roofline": _grouped_share(
+        6450.0, 16, (dict(contraction=2048, columns=1536),
+                     dict(contraction=768, columns=2048)),
+        2, (0.003 + 0.0035 + 0.0005) / 5),
+}
+
+
+@pytest.mark.parametrize("name", SHARED_METRICS)
+def test_a_shared_reader_reads_this_cell_by_its_own_files(name, tmp_path):
+    assert _reader(name).read(_traced_run(tmp_path)) == pytest.approx(
+        SHARED_READINGS[name], rel=1e-12)
+
+
+def test_a_call_under_a_scope_the_cells_files_do_not_describe_is_no_guess(
+        tmp_path):
+    """The same run kept under a cell of another family: the cell's files
+    are found, they describe no ``attention_latent`` and no ``mtp``, and the
+    share is left out, not guessed at that family's widths."""
+    elsewhere = _traced_run(tmp_path, "laguna-xs2.seq8k")
+    found = kernel_costs.cell_files(elsewhere.record)
+    assert found["arch"]["name"] == "laguna-xs2"
+    assert set(kernel_costs.attention_shapes(found["arch"])) == {
+        "attention_window", "attention_full"}
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert _reader(kernel + "_roofline").read(elsewhere) is None

@@ -258,7 +258,7 @@ def test_the_readers_join_the_map_the_trace_and_the_counters(tmp_path):
     # one forward call of a sliding layer's attention in 10 ms a step
     flops, moved = kernel_costs.flash_call(
         "flash_fwd", batch=2, tokens=8192, heads=64, kv_heads=8,
-        head_dim=128, window=512)
+        qk_dim=128, v_dim=128, window=512)
     pairs = 512 * 513 // 2 + (8192 - 512) * 512
     assert flops == 2 * 2 * 2 * 64 * pairs * 128
     least = max(flops / 197e12, moved / 819e9)
@@ -276,9 +276,62 @@ def test_the_readers_join_the_map_the_trace_and_the_counters(tmp_path):
                                   columns=2048))) / 2
     assert _reader("grouped_matmul_roofline").read(run) == pytest.approx(
         100 * per_call / 0.0005)
-    # the same run kept under another cell's name: these shapes are not its
-    elsewhere = types.SimpleNamespace(
-        record=dict(run.record, trace_dir=str(
-            tmp_path.parent / "resnet50-cifar.b512" / "profile")),
-        trace=run.trace)
-    assert kernel_costs.cell_shapes(elsewhere.record) is None
+    # the same record kept under another cell's name: that cell's files are
+    # found as they are, and describe no layer, head or expert of a decoder
+    elsewhere = dict(run.record, trace_dir=str(
+        tmp_path.parent / "resnet50-cifar.b512" / "profile"))
+    found = kernel_costs.cell_files(elsewhere)
+    assert found["arch"]["name"] == "resnet50-cifar"
+    assert (found["batch"], found["tokens"]) == (512, None)
+    assert kernel_costs.layer_bodies(found["arch"]) == []
+    assert kernel_costs.attention_shapes(found["arch"]) == {}
+    assert kernel_costs.routed_experts(found["arch"]) is None
+    # and a run of no cell of the benchmark has no files
+    assert kernel_costs.cell_files(dict(run.record, trace_dir=str(
+        tmp_path.parent / "no-such.cell" / "profile"))) is None
+    assert kernel_costs.cell_files({"trace_dir": None}) is None
+
+
+#: (operations, bytes) of ``laguna-xs2.seq8k``'s six calls as the function of
+#: one head width counted them before it took a key width and a value width
+#: (commit 6e2e4eb, ``flash_call(..., head_dim=128, ...)``)
+RECORDED_CALLS = {
+    ("flash_fwd", 64, 512): (266304749568.0, 608174080.0),
+    ("flash_fwd", 48, 0): (1649468768256.0, 472907776.0),
+    ("flash_dq", 64, 512): (399457124352.0, 880803840.0),
+    ("flash_dq", 48, 0): (2474203152384.0, 677380096.0),
+    ("flash_dkv", 64, 512): (532609499136.0, 679477248.0),
+    ("flash_dkv", 48, 0): (3298937536512.0, 543162368.0),
+}
+
+
+@pytest.mark.parametrize("kernel,heads,window", list(RECORDED_CALLS))
+def test_at_equal_widths_a_flash_call_costs_what_it_did(kernel, heads,
+                                                        window):
+    from chipbench import kernel_costs
+
+    assert kernel_costs.flash_call(
+        kernel, batch=2, tokens=8192, heads=heads, kv_heads=8, qk_dim=128,
+        v_dim=128, window=window) == RECORDED_CALLS[kernel, heads, window]
+
+
+def test_the_window_and_full_layers_are_read_by_the_files_keys():
+    """What ``kernel_costs`` makes of ``laguna-xs2.json``: layers 0-4 by the
+    per-layer lists, a scope for each kind of attention with that kind's
+    heads, 32 gated experts of 512 held in the four sparse layers."""
+    from chipbench import kernel_costs
+
+    arch = harness.load_json(os.path.join(
+        harness.HERE, "configs", "laguna-xs2.json"))
+    assert kernel_costs.layer_bodies(arch) == [
+        ("attention_full", 48, False), ("attention_window", 64, True),
+        ("attention_window", 64, True), ("attention_window", 64, True),
+        ("attention_full", 48, True)]
+    shared = dict(kv_heads=8, qk_dim=128, v_dim=128)
+    assert kernel_costs.attention_shapes(arch) == {
+        "attention_full": dict(shared, heads=48, window=0),
+        "attention_window": dict(shared, heads=64, window=512)}
+    assert kernel_costs.routed_experts(arch) == {
+        "bodies": 4, "held": 32, "products": [
+            dict(contraction=2048, columns=1024),
+            dict(contraction=512, columns=2048)]}

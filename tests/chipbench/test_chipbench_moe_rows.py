@@ -41,7 +41,7 @@ def _traced(tmp_path, gauges):
 
 @pytest.mark.parametrize("appended", [False, True],
                          ids=["shipped", "appended"])
-def test_the_entry_names_the_cell_that_holds_a_share(tmp_path, appended):
+def test_the_entry_names_the_cells_that_hold_a_share(tmp_path, appended):
     """Found by its name: a later PR's entries go after it, at the end of
     the list (``chipbench_tiny.append`` makes such a copy)."""
     path = os.path.join(REPO, "BENCHMARK.json")
@@ -54,7 +54,9 @@ def test_the_entry_names_the_cell_that_holds_a_share(tmp_path, appended):
     assert entry == {
         "name": NAME, "unit": "ratio", "better": "lower",
         "source": "program_counter", "layer": "models",
-        "moves": "images_per_s_per_chip", "workloads": ["laguna-xs2.seq8k"]}
+        "moves": "images_per_s_per_chip", "workloads": [
+            "laguna-xs2.seq8k", "nemotron3-super.seq8k-v16384",
+            "joyai-llm-flash.seq8k-v16160"]}
 
 
 @pytest.mark.parametrize("gauges,want", [

@@ -2,10 +2,11 @@
 the shipped ``zipf_tokens`` generator and ``trainer`` adapter, so the
 product's ``Trainer.run``) through the shipped harness at a size a CPU
 holds, on a copy of the shipped BENCHMARK.json with the tiny cell appended
-(``chipbench_tiny_hybrid.py``); the entries this configuration appended to
-the shipped file; the six per-layer readers it brings and the scan's costs
-from shapes (``chipbench/ssd_costs.py``). The left-out tests are in
-``test_chipbench_nemotron_left_out.py``."""
+(``chipbench_tiny_hybrid.py``); the entries this configuration has in the
+shipped file; the three per-layer readers that are its cell's own, the eight
+it shares with the other decoder cells (one name a mechanism), and the
+scan's costs from shapes (``chipbench/ssd_costs.py``). The left-out tests
+are in ``test_chipbench_nemotron_left_out.py``."""
 
 import json
 import os
@@ -23,14 +24,18 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 import chipbench_tiny_hybrid as tiny_cell  # noqa: E402
 import hybrid_tiny as tiny  # noqa: E402
+from chipbench import kernel_costs, ssd_costs  # noqa: E402
 from chipbench import run as harness  # noqa: E402
-from chipbench import ssd_costs  # noqa: E402
 from test_chipbench_contract import appended_only  # noqa: E402
 
 CELL = "nemotron3-super.seq8k-v16384"
-NEW_METRICS = ("device_mamba_ms", "device_latent_moe_ms", "scan_fwd_roofline",
-               "scan_bwd_roofline", "latent_moe_load_max_over_mean",
-               "latent_moe_rows_walked_over_landed")
+#: the readers of what only this cell runs
+NEW_METRICS = ("device_mamba_ms", "scan_fwd_roofline", "scan_bwd_roofline")
+#: the readers it shares with the other decoder cells: one name a mechanism
+SHARED_METRICS = ("device_moe_ms", "device_attention_ms",
+                  "expert_load_max_over_mean", "moe_rows_walked_over_landed",
+                  "flash_fwd_roofline", "flash_dq_roofline",
+                  "flash_dkv_roofline", "grouped_matmul_roofline")
 _CONFIG = ("jax_compilation_cache_dir",
            "jax_persistent_cache_min_compile_time_secs",
            "jax_persistent_cache_min_entry_size_bytes")
@@ -62,23 +67,25 @@ def test_the_new_configuration_is_correct_through_trainer_run(tmp_path,
 
 # -- what was appended to the shipped file --------------------------------------
 
-def test_the_shipped_file_got_one_configuration_one_cell_six_readers():
-    """BENCHMARK.json is the parent's with entries appended and nothing
-    else: taking the new ones off the ends leaves a file of which the
-    shipped one is ``appended_only``."""
-    bench = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
+def test_the_shipped_file_has_its_configuration_cell_and_readers(bench):
+    """Found by name, on the shipped file and on a copy with entries after
+    the end of every list: cut where this configuration's entries start, the
+    file is one of which the whole is ``appended_only``; its own readers
+    list its cell alone, the shared ones list it among the decoder cells.
+    Nothing is held about what follows an entry."""
     names = lambda group: [e["name"] for e in bench[group]]  # noqa: E731
     at = {"configs": names("configs").index("nemotron3-super"),
-          "workloads": names("workloads").index(CELL),
-          "per_layer": names("per_layer").index(NEW_METRICS[0])}
-    assert names("per_layer")[at["per_layer"]:] == list(NEW_METRICS)
+          "workloads": names("workloads").index(CELL)}
     before = dict(bench, **{group: bench[group][:i]
                             for group, i in at.items()})
     assert appended_only(before, bench)
-    assert not [m for m in before["per_layer"] + before["end_to_end"]
-                if CELL in m.get("workloads", ())]
-    for metric in bench["per_layer"][at["per_layer"]:]:
-        assert metric["workloads"] == [CELL]
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in NEW_METRICS:
+        assert by_name[name]["workloads"] == [CELL]
+    for name in SHARED_METRICS:
+        assert CELL in by_name[name]["workloads"]
+    assert {"step_mfu", "device_step_ms"} <= {
+        m["name"] for m in bench["per_layer"] if "workloads" not in m}
     cell = bench["workloads"][at["workloads"]]
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
     assert "1.6% of its deployed load" in cell["why"]
@@ -163,21 +170,23 @@ def _reader(name):
         "chipbench_metric_" + name)
 
 
-@pytest.mark.parametrize("name", NEW_METRICS)
+@pytest.mark.parametrize("name", NEW_METRICS + SHARED_METRICS)
 def test_a_reader_finds_nothing_in_a_program_without_its_scopes(name,
                                                                  tmp_path):
-    """An untraced run, and a traced run of a program that writes no map and
-    keeps no such counters (the parent): None, nothing raised."""
+    """An untraced run, and a traced run of this cell of a program that
+    writes no map and keeps no such counters (the parent): None, nothing
+    raised."""
     reader = _reader(name)
     assert reader.read(types.SimpleNamespace(
         record={"trace_dir": None}, trace=None)) is None
     assert reader.read(types.SimpleNamespace(
         record={"steps": 7, "examples": 56}, trace=None)) is None
-    os.makedirs(tmp_path / "telemetry")
-    (tmp_path / "telemetry" / "trace-p0.jsonl").write_text(json.dumps(
+    root = tmp_path / CELL
+    os.makedirs(root / "telemetry")
+    (root / "telemetry" / "trace-p0.jsonl").write_text(json.dumps(
         {"type": "counters", "attrs": {"tables": {}, "gauges": {}}}) + "\n")
     traced = types.SimpleNamespace(
-        record={"trace_dir": str(tmp_path / "profile"),
+        record={"trace_dir": str(root / "profile"),
                 "peak_flops_per_s": 197e12},
         trace={"device_ops": [["fusion.1", 0.5]], "steps": 5,
                "device_step_ms": 100.0})
@@ -186,13 +195,14 @@ def test_a_reader_finds_nothing_in_a_program_without_its_scopes(name,
 
 def _traced_run(root, cell=CELL):
     """A traced run of a program with the scopes, kept where ``run.py`` keeps
-    a cell's runs: a map of six instructions, the trace's seconds over a
-    slice of five steps, the counters."""
+    a cell's runs: a map of fourteen instructions, the trace's seconds over
+    a slice of five steps, the counters."""
     root = root / cell
     step = "jit(shard_step)/tpu_ddp.forward_backward/"
     fwd = step + "jvp(HybridDecoder)/checkpoint/"
     bwd = step + "transpose(jvp(HybridDecoder))/checkpoint/"
     scan = "mixer/tpu_ddp.module.ssm_scan/tpu_ddp.kernel."
+    attn = "block_7/mixer/tpu_ddp.module.attention_full/"
     rows = {
         "fusion.1": (fwd + "block_0/" + scan + "ssd_scan_fwd/dot_general",
                      "forward", "ssm_scan"),
@@ -209,13 +219,27 @@ def _traced_run(root, cell=CELL):
                      "dot_general", "forward", "moe_latent"),
         "fusion.7": (fwd + "block_1/mixer/tpu_ddp.module.moe_route/"
                      "dot_general", "forward", "moe_route"),
+        # the one attention block: the three flash kernels and what feeds them
+        "flash_fwd.1": (fwd + attn + "tpu_ddp.kernel.flash_fwd/pallas_call",
+                        "forward", "attention_full"),
+        "flash_dq.1": (bwd + attn + "tpu_ddp.kernel.flash_dq/pallas_call",
+                       "backward", "attention_full"),
+        "flash_dkv.1": (bwd + attn + "tpu_ddp.kernel.flash_dkv/pallas_call",
+                        "backward", "attention_full"),
+        "fusion.8": (bwd + attn + "mul", "backward", "attention_full"),
+        # the compiler's own kernel: its name, the module its user has
+        "ragged-dot-none.3": ("ragged-dot-none", "forward", "moe_experts"),
+        "ragged-dot-none.4": ("ragged-dot-none", "backward", "moe_experts"),
+        "ragged-dot-metadata.5": ("ragged-dot-metadata", "forward",
+                                  "moe_dispatch"),
     }
     os.makedirs(root / "telemetry")
     (root / "telemetry" / "programs-p0.jsonl").write_text(json.dumps({
         "type": "program_map", "program": "train_step",
-        "instructions": {name: {"op_name": op, "opcode": "fusion",
-                                "phase": phase, "module": module}
-                         for name, (op, phase, module) in rows.items()}})
+        "instructions": {name: {
+            "op_name": op, "phase": phase, "module": module,
+            "opcode": "custom-call" if name.startswith("ragged")
+            else "fusion"} for name, (op, phase, module) in rows.items()}})
         + "\n")
     (root / "telemetry" / "trace-p0.jsonl").write_text(json.dumps({
         "type": "counters", "attrs": {"tables": {}, "gauges": {
@@ -223,23 +247,20 @@ def _traced_run(root, cell=CELL):
             "model/expert_load_sum": 5 * 5760.0,
             "model/expert_rows_walked_sum": 5 * 11264.0,
             "model/expert_rows_walked_max": 11264.0}}}) + "\n")
-    seconds = [0.010, 0.005, 0.015, 0.040, 0.020, 0.008, 0.002]
+    seconds = [0.010, 0.005, 0.015, 0.040, 0.020, 0.008, 0.002,
+               0.0075, 0.011, 0.014, 0.0025, 0.003, 0.0035, 0.0005]
     return types.SimpleNamespace(
         record={"trace_dir": str(root / "profile"),
                 "peak_flops_per_s": 197e12},
         trace={"device_ops": [[name, s] for name, s in zip(rows, seconds)],
-               "steps": 5, "device_step_ms": 20.0})
+               "steps": 5, "device_step_ms": 30.0})
 
 
-def test_the_readers_join_the_map_the_trace_and_the_counters(tmp_path):
+def test_the_cells_own_readers_join_the_map_and_the_trace(tmp_path):
     run = _traced_run(tmp_path)
     # module milliseconds a step, every phase together
     assert _reader("device_mamba_ms").read(run) == pytest.approx(
         (0.010 + 0.005 + 0.015 + 0.040 + 0.020) / 5 * 1e3)
-    assert _reader("device_latent_moe_ms").read(run) == pytest.approx(2.0)
-    assert _reader("latent_moe_load_max_over_mean").read(run) == 2.5
-    assert _reader("latent_moe_rows_walked_over_landed").read(
-        run) == pytest.approx(11264 / 5760)
     # two forward calls (block 0, and block 0 again in the backward phase)
     # in 6 ms a step; one backward call in 8 ms
     assert ssd_costs.scan_calls(run, "ssd_scan_fwd") == (
@@ -250,15 +271,85 @@ def test_the_readers_join_the_map_the_trace_and_the_counters(tmp_path):
         least = max(flops / 197e12, moved / 819e9)
         assert _reader(kernel.replace("ssd_", "") + "_roofline").read(
             run) == pytest.approx(100 * calls * least / spent)
-    # the same run kept under a cell of another family: not its shapes
+    # the same run kept under a cell whose configuration names no Mamba
+    # heads: the cell's files are found, and describe no scan
     elsewhere = _traced_run(tmp_path / "elsewhere", "laguna-xs2.seq8k")
-    assert ssd_costs.cell_shapes(elsewhere.record) is None
+    found = kernel_costs.cell_files(elsewhere.record)
+    assert found["arch"]["name"] == "laguna-xs2"
+    assert "mamba_num_heads" not in found["arch"]
     assert _reader("scan_fwd_roofline").read(elsewhere) is None
 
 
-def test_a_program_without_a_latent_space_reports_no_latent_moe_ms(tmp_path):
-    """``laguna-xs2``'s scopes are five of the six: the metric is this
-    configuration's and stays silent there."""
+#: a call of each flash kernel in the one attention block: 4 query heads over
+#: 1 key-value head of 128, no window, no positions
+ATTENTION = dict(batch=2, tokens=8192, heads=4, kv_heads=1, qk_dim=128,
+                 v_dim=128, window=0)
+#: an expert's two products in the latent space: plain relu2, no gate
+PRODUCTS = (dict(contraction=1024, columns=2688),
+            dict(contraction=2688, columns=1024))
+
+
+def _flash_share(kernel, spent):
+    flops, moved = kernel_costs.flash_call(kernel, **ATTENTION)
+    return 100 * max(flops / 197e12, moved / 819e9) / spent
+
+
+def _grouped_share(rows, held, products, calls, spent):
+    per_call = sum(max(f / 197e12, b / 819e9) for f, b in (
+        kernel_costs.grouped_call(rows=rows, held=held, **product)
+        for product in products)) / 2
+    return 100 * calls * per_call / spent
+
+
+#: what each shared reader reads of ``_traced_run``: the recorded-run tests
+#: of ``device_latent_moe_ms`` and the two ``latent_moe_*`` ratios went on
+#: under the names that took their place (the first three)
+SHARED_READINGS = {
+    # moe_latent, moe_route, the two grouped products and their layout call
+    "device_moe_ms": (0.008 + 0.002 + 0.003 + 0.0035 + 0.0005) / 5 * 1e3,
+    "expert_load_max_over_mean": 2.5,
+    "moe_rows_walked_over_landed": 11264 / 5760,
+    # the block's three kernels and the product beside them
+    "device_attention_ms": (0.0075 + 0.011 + 0.014 + 0.0025) / 5 * 1e3,
+    "flash_fwd_roofline": _flash_share("flash_fwd", 0.0015),
+    "flash_dq_roofline": _flash_share("flash_dq", 0.0022),
+    "flash_dkv_roofline": _flash_share("flash_dkv", 0.0028),
+    # two calls at 5,760 real rows a block of five, the layout call's time
+    # counted with them: 8 held experts, latent 1024, width 2688
+    "grouped_matmul_roofline": _grouped_share(
+        5760.0, 8, PRODUCTS, 2, (0.003 + 0.0035 + 0.0005) / 5),
+}
+
+
+@pytest.mark.parametrize("name", SHARED_METRICS)
+def test_a_shared_reader_reads_this_cell_by_its_own_files(name, tmp_path):
+    assert _reader(name).read(_traced_run(tmp_path)) == pytest.approx(
+        SHARED_READINGS[name], rel=1e-12)
+
+
+def test_the_attention_block_and_the_experts_are_read_by_the_files_keys():
+    """What ``kernel_costs`` makes of ``nemotron3-super.json``: the pattern
+    string's one ``*`` of eleven is an ``attention_full`` of the cell's 4
+    query heads over 1 key-value head, its five ``E`` hold 8 plain experts
+    of 2688 in a latent space of 1024."""
+    arch = harness.load_json(os.path.join(
+        harness.HERE, "configs", "nemotron3-super.json"))
+    bodies = kernel_costs.layer_bodies(arch)
+    assert len(bodies) == 11
+    assert [scope for scope, _, _ in bodies if scope] == ["attention_full"]
+    assert kernel_costs.attention_shapes(arch) == {"attention_full": {
+        k: v for k, v in ATTENTION.items() if k not in ("batch", "tokens")}}
+    assert kernel_costs.routed_experts(arch) == {
+        "bodies": 5, "held": 8, "products": list(PRODUCTS)}
+    # a forward call is 137 GFLOP, 0.70 ms at the peak
+    flops, _ = kernel_costs.flash_call("flash_fwd", **ATTENTION)
+    assert flops == 2.0 * 2 * 4 * (8192 * 8193 // 2) * 2 * 128
+    assert 0.69e-3 < flops / 197e12 < 0.71e-3
+
+
+def test_a_program_without_a_latent_space_reports_its_five_scopes(tmp_path):
+    """``laguna-xs2``'s scopes are five of the six: ``device_moe_ms`` reads
+    there what it read before ``moe_latent`` joined its tuple."""
     run = _traced_run(tmp_path)
     path = os.path.join(os.path.dirname(run.record["trace_dir"]),
                         "telemetry", "programs-p0.jsonl")
@@ -266,4 +357,5 @@ def test_a_program_without_a_latent_space_reports_no_latent_moe_ms(tmp_path):
         text = f.read()
     with open(path, "w") as f:
         f.write(text.replace("moe_latent", "moe_shared"))
-    assert _reader("device_latent_moe_ms").read(run) is None
+    assert _reader("device_moe_ms").read(run) == pytest.approx(
+        SHARED_READINGS["device_moe_ms"])

@@ -161,7 +161,7 @@ def keep_jax_config():
 
 
 def test_the_tiny_cell_writes_what_the_readers_read(tmp_path, capsys,
-                                                    keep_jax_config):
+                                                    keep_jax_config, case):
     bench_path, roots = chipbench_tiny.write(str(tmp_path))
     bench = harness.load_json(bench_path)
     loaded = harness.load_cell(bench, CELL, roots + [harness.HERE])
@@ -187,7 +187,7 @@ def test_the_tiny_cell_writes_what_the_readers_read(tmp_path, capsys,
     trace = {"device_ops": ops, "steps": steps,
              "device_step_ms": len(ops) / steps}
     # the shipped BENCHMARK.json's new entries, read by the harness itself
-    shipped = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
+    shipped = harness.load_json(case.path)
     bench["per_layer"] = [m for m in shipped["per_layer"]
                           if m["name"] in METRICS + ("trace_lower_s",)]
     for metric in bench["per_layer"]:
@@ -295,10 +295,8 @@ def read_recorded(record, reduced, bench_path, roots) -> dict:
         for cell in ("laguna-xs2.seq8k", "resnet50-cifar.dp4")}
 
 
-@pytest.mark.parametrize("appended", [False, True],
-                         ids=["shipped", "appended"])
 def test_no_metric_reads_the_busy_sum_or_the_breakdown(tmp_path, capsys,
-                                                       appended):
+                                                       case):
     """``testdata/recorded_metrics.json`` holds what the parent of the PR
     that took ``control`` out of ``busy_s`` and put the ``breakdown`` in
     shares read here, metric by metric: each of those is that, to the last
@@ -307,14 +305,10 @@ def test_no_metric_reads_the_busy_sum_or_the_breakdown(tmp_path, capsys,
     with open(os.path.join(TESTDATA, "recorded_metrics.json")) as f:
         parent = json.load(f)["metrics"]
     record, reduced = recorded_run(tmp_path)
-    bench_path, roots = os.path.join(REPO, "BENCHMARK.json"), []
-    if appended:
-        bench_path, roots = chipbench_tiny.append(
-            str(tmp_path / "appended"), bench_path)
-    got = read_recorded(record, reduced, bench_path, roots + [harness.HERE])
+    got = read_recorded(record, reduced, case.path, case.roots)
     for cell, metrics in parent.items():
         assert {name: got[cell][name] for name in metrics} == metrics
-        assert ("window_steps" in got[cell]) == appended
+        assert ("window_steps" in got[cell]) == case.appended
     with open(os.path.join(TESTDATA, "recorded.json")) as f:
         recorded = json.load(f)  # the slice as recorded, without a switch
     by_phase = sum(got["resnet50-cifar.dp4"][name] for name in METRICS)

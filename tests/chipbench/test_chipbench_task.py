@@ -176,9 +176,9 @@ def test_the_control_alone_lays_batches_out_as_the_task_does(tmp_path,
     assert "sound" not in row and row["control"]["out_grad_diff"] > 1e-3
 
 
-def test_a_reference_file_that_says_nothing_is_an_image_classifier_under_sgd():
-    bench = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
-    loaded = harness.load_cell(bench, "resnet50-cifar.b512", [harness.HERE])
+def test_a_reference_file_that_says_nothing_is_an_image_classifier_under_sgd(
+        case, bench):
+    loaded = harness.load_cell(bench, "resnet50-cifar.b512", case.roots)
     ref = loaded["reference"]
     for name in ("follow", "first_gradient", "rows", "batches",
                  "train_flops_per_example", "OPTIMIZER_STATE"):

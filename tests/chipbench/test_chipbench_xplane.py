@@ -97,6 +97,87 @@ def test_without_fences_that_pair_up_the_gaps_stay_unlabelled():
     assert [name for name, _ in out["idle_gaps"]] == ["host_other"]
 
 
+#: the host's spans of ``synthetic()``'s two steps, the device clock 1000 ns
+#: ahead of the host's: a loop that fences every step, and the same loop
+#: without its fences (a step's completion is stamped off the main thread)
+FENCED = [("device_sync", (0 - 1000) / 1e9, (70 - 1000) / 1e9),
+          ("data_wait", (72 - 1000) / 1e9, (90 - 1000) / 1e9),
+          ("compiled_step", (90 - 1000) / 1e9, (101 - 1000) / 1e9),
+          ("device_sync", (101 - 1000) / 1e9, (170 - 1000) / 1e9)]
+UNFENCED = [span for span in FENCED if span[0] != "device_sync"]
+
+
+def test_fences_that_pair_give_the_median_to_the_nanosecond():
+    """A fence a step: the offset is the median over the pairs, as before
+    the window's closing fence could stand in, whatever that fence says."""
+    ends = [70, 170]
+    for t_close in (None, (170 - 1000) / 1e9, 5.0):
+        assert xplane.clock_offset_ns(ends, FENCED, t_close) == 1000.0
+    # an odd one out moves a median of three by nothing
+    three = FENCED + [("device_sync", 0.0, (275 - 1000) / 1e9)]
+    assert xplane.clock_offset_ns([70, 170, 270], three, 9.0) == 1000.0
+    assert xplane.clock_offset_ns(ends, FENCED) == 1000.0
+
+
+def test_without_a_fence_a_step_the_closing_fence_ties_the_clocks():
+    """No ``device_sync`` spans, or another count of them than of programs:
+    the last program's end on the device's clock is the window's closing
+    fence on the host's."""
+    t_close = (170 - 1000) / 1e9
+    assert xplane.clock_offset_ns([70, 170], UNFENCED, t_close) == (
+        pytest.approx(1000.0))
+    assert xplane.clock_offset_ns([70, 170], FENCED[:1], t_close) == (
+        pytest.approx(1000.0))
+    assert xplane.clock_offset_ns([70, 170], UNFENCED) is None
+    assert xplane.clock_offset_ns([], UNFENCED, t_close) is None
+    # a window of 170 ns that closes as the last program ends
+    out = xplane.reduce(synthetic(), window_s=170e-9, dispatches=2,
+                        t_open=-1000 / 1e9, host_spans=UNFENCED)
+    assert out["clock_aligned"] is True
+    gaps = dict(out["idle_gaps"])
+    # the gaps go to the main thread's spans: between the steps the host is
+    # fetching the next batch; inside a step nothing of the host's covers
+    # the wait on the collective now that no fence does
+    assert gaps["data_wait"] == pytest.approx(30e-9)
+    assert gaps["host_other"] == pytest.approx(40e-9)
+    assert sum(gaps.values()) == pytest.approx(170e-9 - 100e-9)
+    fenced = xplane.reduce(synthetic(), window_s=170e-9, dispatches=2,
+                           t_open=-1000 / 1e9, host_spans=FENCED)
+    assert dict(fenced["idle_gaps"]) == {
+        "device_sync": pytest.approx(40e-9),
+        "data_wait": pytest.approx(30e-9)}
+    for key in ("busy_s", "device_step_ms", "device_ops", "collective_ms"):
+        assert out[key] == fenced[key]
+
+
+def test_a_span_on_a_second_thread_named_outside_host_spans_takes_no_gap():
+    """The probe keeps the spans the harness names (``HOST_SPANS``): one that
+    a second thread writes under another name, as long as a whole step, is
+    not kept, so no idle gap can go to it."""
+    import threading
+    import time
+
+    from chipbench.adapters import trainer as adapter
+
+    probe = adapter.StepProbe(
+        None, None, open_at=0, seconds=1.0, trace_dir=None,
+        real_per_step=[1], names={}, counters=None)
+    probe.t_open = time.perf_counter()
+    assert "step_done" not in adapter.HOST_SPANS
+    stamper = threading.Thread(target=probe.on_span,
+                               args=("step_done", 70e-9))
+    stamper.start()
+    stamper.join(timeout=10)
+    assert not stamper.is_alive()
+    probe.on_span("data_wait", 18e-9)
+    assert [name for name, _, _ in probe.spans] == ["data_wait"]
+    # and the reduction labels by what it is given: the main thread's spans
+    out = xplane.reduce(synthetic(), window_s=170e-9, dispatches=2,
+                        t_open=-1000 / 1e9, host_spans=UNFENCED)
+    assert {name for name, _ in out["idle_gaps"]} <= {
+        name for name, _, _ in UNFENCED} | {"host_other"}
+
+
 def test_a_trace_in_which_nothing_ran_is_refused():
     with pytest.raises(ValueError):
         xplane.reduce({"devices": {}}, window_s=1.0, dispatches=1)
