@@ -89,15 +89,19 @@ def write_fleet(
             f.write(json.dumps(header) + "\n")
             ts = 1.0
             for step in range(n_steps):
-                for name, dur in (("data_wait", 0.002),
-                                  ("compiled_step", step_s),
-                                  ("device_sync", 0.001)):
+                # a loop that runs ahead with its queue full: the
+                # dispatch holds the backpressure, and the stamper's
+                # thread (tid 2) writes the device's steps beside it
+                for name, dur, tid in (("data_wait", 0.002, 1),
+                                       ("compiled_step", step_s, 1),
+                                       ("device_step", step_s, 2)):
                     f.write(json.dumps({
                         "schema_version": 1, "type": "span", "name": name,
                         "ts_s": round(ts, 6), "dur_s": dur, "pid": host,
-                        "tid": 1, "depth": 0, "step": step,
+                        "tid": tid, "depth": 0, "step": step,
                     }) + "\n")
-                    ts += dur
+                    if tid == 1:
+                        ts += dur
         with open(os.path.join(run_dir, f"health-p{host}.jsonl"), "w") as f:
             f.write(json.dumps({"schema_version": 1, "type": "header",
                                 "pid": host, "policy": "warn"}) + "\n")
@@ -276,13 +280,14 @@ def test_aggregator_flags_straggler_and_lost_host(tmp_path):
     assert h0.step == 29
     assert h0.steps_per_sec == pytest.approx(1 / 0.013, rel=0.1)
     assert h0.phase_p50_s["compiled_step"] == pytest.approx(0.010)
-    assert 0 < h0.data_wait_share < 0.5
+    # the queue is full: every wait for input sits behind a busy device
+    assert h0.data_wait_share == 0.0
     assert h0.health["nonfinite_steps"] == 0
     # fleet rollup + snapshot schema
     assert snap.fleet["n_hosts"] == 4
     assert snap.fleet["step_max"] == 29
     payload = snap.to_json()
-    assert payload["schema_version"] == 1
+    assert payload["schema_version"] == 2
     json.dumps(payload)  # wire-shape must be serializable
 
 
@@ -516,7 +521,7 @@ def test_watch_once_json_schema(tmp_path, capsys):
     assert rc == 1  # alerts firing -> nonzero for scripting
     assert report["schema_version"] == WATCH_SCHEMA_VERSION
     snap = report["snapshot"]
-    assert snap["schema_version"] == 1
+    assert snap["schema_version"] == 2
     assert len(snap["hosts"]) == 4
     assert snap["stragglers"] == [2] and snap["lost"] == [3]
     for h in snap["hosts"]:

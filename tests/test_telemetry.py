@@ -285,11 +285,11 @@ def test_trainer_emits_phase_spans_per_step(devices, telemetry_run):
     spans = [r for r in records if r["type"] == "span"]
     by_step = {}
     for s in spans:
-        if s["name"] in ("data_wait", "compiled_step", "device_sync"):
+        if s["name"] in ("data_wait", "compiled_step", "device_step"):
             by_step.setdefault(s["step"], set()).add(s["name"])
     # acceptance: every one of the 5 steps carries all three phases
     full = {s for s, names in by_step.items()
-            if names >= {"data_wait", "compiled_step", "device_sync"}}
+            if names >= {"data_wait", "compiled_step", "device_step"}}
     assert len(full) == 5, by_step
     # the counters snapshot saw all 5 steps and the recompile counter moved
     counters = [r for r in records if r["type"] == "counters"][-1]
@@ -306,7 +306,7 @@ def test_trainer_chrome_trace_perfetto_loadable(devices, telemetry_run):
     doc = json.loads(open(telemetry_run / "trace-p0.trace.json").read())
     events = doc["traceEvents"]
     xs = [e for e in events if e.get("ph") == "X"]
-    assert {"data_wait", "compiled_step", "device_sync"} <= {
+    assert {"data_wait", "compiled_step", "device_step"} <= {
         e["name"] for e in xs
     }
     for e in xs:
@@ -319,7 +319,7 @@ def test_trainer_run_dir_summarizes(devices, telemetry_run, capsys):
 
     assert cli_main(["trace", "summarize", str(telemetry_run)]) == 0
     out = capsys.readouterr().out
-    for phase in ("data_wait", "compiled_step", "device_sync"):
+    for phase in ("data_wait", "compiled_step", "device_step"):
         assert phase in out
 
 

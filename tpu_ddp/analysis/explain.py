@@ -568,6 +568,32 @@ def measured_phases(run_dir: str) -> Dict[str, dict]:
     return out
 
 
+def data_wait_share(run_dir: str, phases: Dict[str, dict]
+                    ) -> Optional[float]:
+    """The input pipeline's share of the run's time, as the live monitor
+    counts it (``monitor/aggregate.py``): where the step stamper wrote
+    ``device_step`` spans, only the part of a ``data_wait`` that none of
+    them covers, over the wall time they reach across; else a share of
+    the loop's own phases."""
+    from tpu_ddp.monitor.aggregate import DEVICE_PHASE, LOOP_PHASES
+    from tpu_ddp.telemetry.stamper import uncovered_share
+    from tpu_ddp.telemetry.summarize import find_trace_files, read_records
+
+    if DEVICE_PHASE in phases:
+        # one host's clock: ts_s of two hosts share no origin
+        spans = {"data_wait": [], DEVICE_PHASE: []}
+        for rec in read_records(find_trace_files(run_dir)[:1]):
+            if rec.get("type") == "span" and rec.get("name") in spans \
+                    and isinstance(rec.get("ts_s"), (int, float)) \
+                    and isinstance(rec.get("dur_s"), (int, float)):
+                spans[rec["name"]].append(
+                    (rec["ts_s"], rec["ts_s"] + rec["dur_s"]))
+        return uncovered_share(spans["data_wait"],
+                               sorted(spans[DEVICE_PHASE]))
+    loop = [phases.get(p, {}).get("total_s", 0.0) for p in LOOP_PHASES]
+    return loop[0] / sum(loop) if sum(loop) else None
+
+
 def join_measurements(anatomy: StepAnatomy, rl: RooflineReport,
                       run_dir: str, *, chip: Optional[str] = None) -> dict:
     """Static-vs-measured join: what fraction of the roofline the run
@@ -587,10 +613,9 @@ def join_measurements(anatomy: StepAnatomy, rl: RooflineReport,
             joined["mfu_vs"] = spec.key
         if rl.ici_s is not None:
             joined["comm_share_of_step"] = min(rl.ici_s / step_s, 1.0)
-    loop = [phases.get(p, {}).get("total_s", 0.0)
-            for p in ("data_wait", "h2d", "compiled_step", "device_sync")]
-    if sum(loop):
-        joined["data_wait_share"] = loop[0] / sum(loop)
+    share = data_wait_share(run_dir, phases)
+    if share is not None:
+        joined["data_wait_share"] = share
     # measured exposed-comm attribution (`tpu-ddp comms exposure`,
     # docs/comms.md): the comm share that actually stayed exposed, to
     # set against the modeled comm_share_of_step above

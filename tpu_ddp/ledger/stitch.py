@@ -22,19 +22,29 @@ import os
 from typing import Dict, List, Optional
 
 from tpu_ddp.telemetry import parse_trace_name
+from tpu_ddp.telemetry.stamper import SPAN as DEVICE_SPAN
+from tpu_ddp.telemetry.stamper import covered_s
 from tpu_ddp.telemetry.summarize import read_records
 from tpu_ddp.telemetry.watchdog import read_heartbeat
 
 #: span name -> raw ledger bucket. ``step`` is the productive pool the
-#: taxonomy later splits into productive / compile / replayed; every
-#: depth-0 span not named here lands in host_overhead (attributed host
-#: work is still host work).
+#: taxonomy later splits into productive / compile / replayed:
+#: ``device_step`` is the step stamper's span (telemetry/stamper.py): the
+#: time in which the host knows the device had a step to run. Every other
+#: span counts only if the loop's own thread wrote it, and only for the
+#: part of it that no ``device_step`` covers: host work hidden behind the
+#: device costs the run nothing, and a drain inside
+#: ``epoch_metrics_fetch`` is the device working. What is left of
+#: ``compiled_step`` is the compile inside the first dispatches (taken
+#: out of the pool again as ``compile``) and the enqueue of a step on an
+#: idle device. Every depth-0 span not named here lands in host_overhead
+#: (attributed host work is still host work).
 SPAN_BUCKETS = {
     "data_wait": "data_wait",
     "h2d": "host_overhead",
     "epoch_metrics_fetch": "host_overhead",
     "compiled_step": "step",
-    "device_sync": "step",
+    DEVICE_SPAN: "step",
     "checkpoint": "checkpoint_save",
     "checkpoint_wait": "checkpoint_save",
     "checkpoint_restore": "checkpoint_restore",
@@ -129,6 +139,31 @@ def _counter(counters_attrs: Optional[dict], name: str) -> float:
     return float(v) if isinstance(v, (int, float)) else 0.0
 
 
+def span_buckets(spans: List[dict]) -> Dict[str, float]:
+    """Bucket seconds of one incarnation's depth-0 span records, by the
+    rule at ``SPAN_BUCKETS``. The loop's thread is the one that wrote the
+    ``compiled_step`` spans; a trace without any has one thread."""
+    device = sorted(
+        (r["ts_s"], r["ts_s"] + r["dur_s"]) for r in spans
+        if r.get("name") == DEVICE_SPAN
+        and isinstance(r.get("ts_s"), (int, float)))
+    starts = [a for a, _ in device]
+    ends = [b for _, b in device]
+    loop_tid = next((r.get("tid") for r in spans
+                     if r.get("name") == "compiled_step"), None)
+    buckets: Dict[str, float] = {}
+    for r in spans:
+        name, dur, ts = r.get("name"), r["dur_s"], r.get("ts_s")
+        if name != DEVICE_SPAN:
+            if loop_tid is not None and r.get("tid") != loop_tid:
+                continue
+            if isinstance(ts, (int, float)):
+                dur -= covered_s(ts, ts + dur, starts, ends)
+        bucket = SPAN_BUCKETS.get(name, "host_overhead")
+        buckets[bucket] = buckets.get(bucket, 0.0) + max(dur, 0.0)
+    return buckets
+
+
 def load_incarnation(index: int, files: Dict[int, str]) -> IncarnationRecord:
     """Reduce one incarnation's host-0 trace to an IncarnationRecord."""
     rec = IncarnationRecord(index=index, files=dict(files))
@@ -146,6 +181,7 @@ def load_incarnation(index: int, files: Dict[int, str]) -> IncarnationRecord:
     exit_override: Optional[str] = None
     baseline: Optional[dict] = None
     newest_counters: Optional[dict] = None
+    spans: List[dict] = []  # depth-0, with a duration
     for r in records:
         kind = r.get("type")
         ts = r.get("ts_s")
@@ -163,8 +199,7 @@ def load_incarnation(index: int, files: Dict[int, str]) -> IncarnationRecord:
                 continue
             if isinstance(ts, (int, float)):
                 last_span_end = max(last_span_end, ts + dur)
-            bucket = SPAN_BUCKETS.get(name, "host_overhead")
-            rec.buckets[bucket] = rec.buckets.get(bucket, 0.0) + dur
+            spans.append(r)
             attrs = r.get("attrs") or {}
             step = r.get("step")
             if name == "compiled_step":
@@ -211,6 +246,14 @@ def load_incarnation(index: int, files: Dict[int, str]) -> IncarnationRecord:
             if r.get("name") == "counters_baseline" and baseline is None:
                 baseline = r.get("attrs") or {}
             newest_counters = r.get("attrs") or {}
+    rec.buckets = span_buckets(spans)
+    if rec.steps and not any(r.get("name") == DEVICE_SPAN for r in spans):
+        rec.notes.append(
+            f"incarnation {index}: {rec.steps} step(s) dispatched and no "
+            f"{DEVICE_SPAN} span (a trace from before the step stamper, "
+            "or no step completed): the device's time is not in it, so "
+            "productive holds the dispatches alone and the rest reads as "
+            "host overhead")
     if epoch_unix is None:
         rec.notes.append(
             f"incarnation {index}: trace has no wall-clock anchor "

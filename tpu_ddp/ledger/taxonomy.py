@@ -43,8 +43,9 @@ class Category:
 #: to exactly one category; the report's total row re-derives elapsed.
 CATEGORIES = (
     Category("productive", "productive compiled steps",
-             "compiled_step + device_sync spans, minus compile and "
-             "replayed shares"),
+             "device_step spans (the device had a step to run) + the "
+             "part of compiled_step none of them covers, minus compile "
+             "and replayed shares"),
     Category("replayed", "replayed work (rewound to checkpoint)",
              "step-range overlap between incarnation k-1's last executed "
              "step and incarnation k's resume step"),
@@ -55,11 +56,14 @@ CATEGORIES = (
              "checkpoint + checkpoint_wait spans"),
     Category("checkpoint_restore", "checkpoint restore",
              "checkpoint_restore span + checkpoint/restore_seconds"),
-    Category("data_wait", "input pipeline wait", "data_wait spans"),
-    Category("eval", "evaluation", "eval spans"),
+    Category("data_wait", "input pipeline wait",
+             "data_wait spans, the part no device_step covers"),
+    Category("eval", "evaluation",
+             "eval spans, the part no device_step covers"),
     Category("host_overhead", "host overhead",
-             "h2d / metrics-fetch / other host spans, plus all "
-             "in-incarnation wall-clock no span accounts for"),
+             "h2d / metrics-fetch / other spans of the loop's thread, "
+             "the part no device_step covers, plus all in-incarnation "
+             "wall-clock no span accounts for"),
     Category("stall", "stall (dead incarnation's stale tail)",
              "gap between a non-drained incarnation's last span and its "
              "last evidence (trace tail, heartbeat file)"),

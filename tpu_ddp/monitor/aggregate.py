@@ -4,7 +4,7 @@ One ``FleetAggregator`` watches a run dir the way an operator would —
 by its files, with no connection to the training processes:
 
 - ``trace-p<i>.jsonl``     — span records (compiled_step / data_wait /
-  h2d / device_sync phase durations, checkpoint spans) and counters
+  h2d / device_step phase durations, checkpoint spans) and counters
   snapshots, per host, from the telemetry JSONL sink;
 - ``health-p<i>.jsonl``    — the numerics flight recorder's per-step
   loss/grad-norm stats and anomaly flags;
@@ -17,7 +17,7 @@ Each ``poll()`` reads only the NEW complete lines of every file
 derives a schema-versioned :class:`FleetSnapshot`: per-host current
 step, per-phase p50s, data-wait share, steps/sec, heartbeat age, and
 the two fleet verdicts this subsystem exists for — **stragglers**
-(per-host ``compiled_step``/``data_wait`` p50 more than ``k × MAD``
+(per-host ``compiled_step``/``device_step``/``data_wait`` p50 more than ``k × MAD``
 above the fleet median, threshold in :class:`MonitorConfig`) and
 **lost hosts** (stale heartbeat). At pod scale one slow or dead host
 silently sets the whole step time; the snapshot makes it name itself.
@@ -41,19 +41,31 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from tpu_ddp.telemetry.stamper import SPAN as DEVICE_PHASE
+from tpu_ddp.telemetry.stamper import uncovered_share
 from tpu_ddp.telemetry.watchdog import (
     heartbeat_age_seconds,
     read_heartbeat,
 )
 
 #: bump on any breaking change to the FleetSnapshot JSON shape;
-#: ``tpu-ddp watch --json`` consumers key on this.
-SNAPSHOT_SCHEMA_VERSION = 1
+#: ``tpu-ddp watch --json`` consumers key on this. 2: ``phase_p50_s``
+#: holds ``device_step`` where it held ``device_sync``, and
+#: ``data_wait_share`` counts only the wait no ``device_step`` covers
+SNAPSHOT_SCHEMA_VERSION = 2
 
-#: step-loop phases the per-host windows retain (the same set the
-#: analyze join attributes; data_wait's share is the straggler-visible
-#: input-pipeline signal)
-LOOP_PHASES = ("data_wait", "h2d", "compiled_step", "device_sync")
+#: the loop thread's phases (the same set the analyze join attributes).
+#: The loop runs ahead of the device: with a full queue ``compiled_step``
+#: holds the backpressure, with a short epoch its one fetch does, so their
+#: sum is not the run's wall time
+LOOP_PHASES = ("data_wait", "h2d", "compiled_step")
+#: ``DEVICE_PHASE`` is the step stamper's span (telemetry/stamper.py),
+#: windowed beside the loop's: the per-host device time per step that the
+#: straggler rule compares, and what says how much of ``data_wait`` the
+#: device hid
+WINDOWED_PHASES = LOOP_PHASES + (DEVICE_PHASE,)
+#: phases whose per-host p50 the straggler rule sets against the fleet's
+STRAGGLER_PHASES = ("compiled_step", DEVICE_PHASE, "data_wait")
 
 
 @dataclasses.dataclass
@@ -325,12 +337,17 @@ class _HostState:
         self.epoch_unix: Optional[float] = None
         self.run_meta: Optional[dict] = None
         self.phases: Dict[str, deque] = {
-            p: deque(maxlen=window) for p in LOOP_PHASES
+            p: deque(maxlen=window) for p in WINDOWED_PHASES
         }
         # compiled_step durations UN-normalized (one raw entry per span):
         # the data-wait share is a wall-time ratio, so under scan fusion
         # it must weigh the whole K-step span, not the per-step p50 input
         self.compiled_raw: deque = deque(maxlen=window)
+        # (start, end) on the trace's clock of the windowed data_wait and
+        # device_step spans: the share counts the part of a wait that no
+        # device_step covers
+        self.waits: deque = deque(maxlen=window)
+        self.device: deque = deque(maxlen=window)
         # (span_end_ts_s, steps_in_span) for the steps/sec window
         self.step_rate: deque = deque(maxlen=window)
         self.ended = False  # saw the clean-shutdown run_end marker
@@ -363,6 +380,9 @@ class _HostState:
                 self.epoch_unix = rec["epoch_unix"]
             if rec.get("run_meta"):
                 self.run_meta = rec["run_meta"]
+            # a new incarnation's clock starts again
+            self.waits.clear()
+            self.device.clear()
             return
         if kind == "span":
             name, dur = rec.get("name"), rec.get("dur_s")
@@ -377,8 +397,15 @@ class _HostState:
                 self.compiled_raw.append(dur)
                 if isinstance(ts, (int, float)):
                     self.step_rate.append((ts + dur, steps))
+            elif name == DEVICE_PHASE:
+                steps = max(int(attrs.get("steps", 1) or 1), 1)
+                self.phases[name].append(dur / steps)
+                if isinstance(ts, (int, float)):
+                    self.device.append((ts, ts + dur))
             elif name in self.phases:
                 self.phases[name].append(dur)
+                if name == "data_wait" and isinstance(ts, (int, float)):
+                    self.waits.append((ts, ts + dur))
             elif name == "checkpoint" and self.epoch_unix is not None:
                 if isinstance(ts, (int, float)):
                     self.last_checkpoint_wall = self.epoch_unix + ts
@@ -430,9 +457,18 @@ class _HostState:
         return float(v) if isinstance(v, (int, float)) else None
 
     def data_wait_share(self) -> Optional[float]:
-        # wall-time ratio over the windowed loop: RAW compiled spans
-        # (the per-step-normalized entries would understate compute by
-        # steps_per_call and inflate the share on fused runs)
+        """The input pipeline's share of the run's time. Where the step
+        stamper wrote ``device_step`` spans: the part of the windowed
+        ``data_wait`` that none of them covers, over the wall time they
+        reach across: a loader the device hides reads 0 however long the
+        loop sat in it (``stamper.uncovered_share``). A trace without
+        them says nothing of the device: the share is then of the loop's
+        own phases."""
+        if self.device:
+            return uncovered_share(self.waits, list(self.device))
+        # RAW compiled spans (the per-step-normalized entries would
+        # understate compute by steps_per_call and inflate the share on
+        # fused runs)
         total = sum(self.compiled_raw) + sum(
             sum(self.phases[p]) for p in LOOP_PHASES
             if p != "compiled_step"
@@ -652,7 +688,7 @@ class FleetAggregator:
                 step=step,
                 steps_per_sec=st.steps_per_sec(),
                 phase_p50_s={
-                    p: p50 for p in LOOP_PHASES
+                    p: p50 for p in WINDOWED_PHASES
                     if (p50 := _p50(st.phases[p])) is not None
                 },
                 data_wait_share=st.data_wait_share(),
@@ -701,7 +737,7 @@ class FleetAggregator:
                 datapath=datapath_views.get(pid, {}),
             ))
 
-        for phase in ("compiled_step", "data_wait"):
+        for phase in STRAGGLER_PHASES:
             flagged = flag_stragglers(
                 {h.host: h.phase_p50_s.get(phase) for h in hosts},
                 k=cfg.straggler_mad_threshold,
@@ -736,7 +772,7 @@ class FleetAggregator:
             "step_max": max(steps) if steps else None,
             "run_age_s": now - min(epochs) if epochs else None,
             "phase_p50_s": {
-                p: med for p in LOOP_PHASES
+                p: med for p in WINDOWED_PHASES
                 if (med := _p50(
                     [h.phase_p50_s.get(p) for h in hosts])) is not None
             },

@@ -313,7 +313,7 @@ class AlertEngine:
                 found[("DWT001", h.host)] = (
                     f"host {h.host} data-wait share "
                     f"{h.data_wait_share:.0%} > "
-                    f"{cfg.data_wait_share_max:.0%} of the step loop",
+                    f"{cfg.data_wait_share_max:.0%} of the run's time",
                     h.data_wait_share,
                 )
 

@@ -27,6 +27,7 @@ import os
 import sys
 from typing import Dict, List, Optional, Sequence
 
+from tpu_ddp.monitor.aggregate import WINDOWED_PHASES
 from tpu_ddp.profiler.capture import (
     PROFILES_DIRNAME,
     list_bundles,
@@ -162,7 +163,7 @@ def render_bundle(bundle_dir: str, meta: dict, *, top: int = 15,
     phases = meta.get("measured_phases") or {}
     if phases:
         parts = []
-        for name in ("data_wait", "h2d", "compiled_step", "device_sync"):
+        for name in WINDOWED_PHASES:
             p = phases.get(name)
             if p:
                 parts.append(f"{name} {_fmt_s(p.get('total_s'))}")

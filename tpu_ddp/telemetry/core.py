@@ -78,25 +78,39 @@ class Telemetry:
         try:
             yield
         finally:
-            dur = self.clock.now() - t0
+            end = self.clock.now()
             pop_span()
-            self._emit(Event(
-                name=name,
-                kind=SPAN,
-                ts_s=t0,
-                dur_s=dur,
-                step=self.current_step if step is None else step,
-                process_index=self.process_index,
-                thread_id=threading.get_ident() & 0xFFFF,
-                depth=depth,
-                attrs=attrs,
-            ))
-            self.registry.histogram(f"phase/{name}").record(dur)
-            for listener in self._span_listeners:
-                try:
-                    listener(name, dur)
-                except Exception:  # a broken tap must never kill training
-                    pass
+            self.emit_span(name, t0, end, step=step, depth=depth,
+                           attrs=attrs)
+
+    def emit_span(self, name: str, start_s: float, end_s: float, *,
+                  step: Optional[int] = None, depth: int = 0,
+                  attrs: Optional[dict] = None) -> None:
+        """One SPAN event between two readings of ``self.clock``, on the
+        calling thread's row: what ``span`` does on exit, and the way in
+        for a span whose ends were stamped elsewhere (the step stamper's
+        ``device_step``). Recorded into ``phase/<name>`` and shown to
+        the span listeners like every span."""
+        if not self.enabled:
+            return
+        dur = end_s - start_s
+        self._emit(Event(
+            name=name,
+            kind=SPAN,
+            ts_s=start_s,
+            dur_s=dur,
+            step=self.current_step if step is None else step,
+            process_index=self.process_index,
+            thread_id=threading.get_ident() & 0xFFFF,
+            depth=depth,
+            attrs=attrs or {},
+        ))
+        self.registry.histogram(f"phase/{name}").record(dur)
+        for listener in list(self._span_listeners):
+            try:
+                listener(name, dur)
+            except Exception:  # a broken tap must never kill training
+                pass
 
     def instant(self, name: str, step: Optional[int] = None,
                 **attrs) -> None:

@@ -44,8 +44,9 @@ class Sink:
 
 class JsonlTraceSink(Sink):
     """One JSON object per line; first line is a header record carrying the
-    wall-clock anchor of the monotonic epoch (for cross-host alignment)
-    and, when provided, the RUN METADATA (config snapshot, jax version,
+    wall-clock anchor of the monotonic epoch (for cross-host alignment),
+    the epoch's own ``time.monotonic()`` reading (for alignment with
+    anything else this process timed) and, when provided, the RUN METADATA (config snapshot, jax version,
     device kind, mesh shape, strategy) — what lets ``tpu-ddp analyze`` /
     ``bench compare`` label a run and refuse a mismatched one instead of
     treating every trace as anonymous."""
@@ -62,6 +63,10 @@ class JsonlTraceSink(Sink):
             "schema_version": SCHEMA_VERSION,
             "type": "header",
             "epoch_unix": clock.epoch_unix,
+            # the same instant on time.monotonic() (what perf_counter
+            # reads on Linux): epoch_monotonic + ts_s puts any record
+            # on the clock a profiler harness stamps its own spans with
+            "epoch_monotonic": clock.epoch_monotonic,
             "pid": process_index,
         }
         if run_meta:

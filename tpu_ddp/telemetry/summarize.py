@@ -351,9 +351,9 @@ def summarize(path: str) -> str:
     lines += ["", format_phase_table(phases)]
     # multihost: one skew line per loop phase with >= 2 reporting hosts
     # — the post-hoc twin of the live monitor's straggler verdict
-    from tpu_ddp.monitor.aggregate import host_skew
+    from tpu_ddp.monitor.aggregate import STRAGGLER_PHASES, host_skew
 
-    for phase in ("compiled_step", "data_wait"):
+    for phase in STRAGGLER_PHASES:
         skew = host_skew(per_host_phase_p50(records, phase))
         if skew:
             lines.append(

@@ -52,6 +52,8 @@ def write_fleet(run_dir: str, *, n_hosts=4, n_steps=40,
                 straggler_host=None, lost_host=None, nan_host=None):
     """Synthetic per-host run-dir files: the same trace/health/heartbeat
     families a real multihost run leaves behind, with optional faults."""
+    from tpu_ddp.monitor.aggregate import DEVICE_PHASE
+
     now = time.time()
     os.makedirs(run_dir, exist_ok=True)
     run_meta = {
@@ -68,15 +70,19 @@ def write_fleet(run_dir: str, *, n_hosts=4, n_steps=40,
             f.write(json.dumps(header) + "\n")
             ts = 1.0
             for step in range(n_steps):
-                for name, dur in (("data_wait", 0.002),
-                                  ("compiled_step", step_s),
-                                  ("device_sync", 0.001)):
+                # a loop that runs ahead with its queue full: the
+                # dispatch holds the backpressure, and the stamper's
+                # thread (tid 2) writes the device's steps beside it
+                for name, dur, tid in (("data_wait", 0.002, 1),
+                                       ("compiled_step", step_s, 1),
+                                       (DEVICE_PHASE, step_s, 2)):
                     f.write(json.dumps({
                         "schema_version": 1, "type": "span", "name": name,
                         "ts_s": round(ts, 6), "dur_s": dur, "pid": host,
-                        "tid": 1, "depth": 0, "step": step,
+                        "tid": tid, "depth": 0, "step": step,
                     }) + "\n")
-                    ts += dur
+                    if tid == 1:
+                        ts += dur
         with open(os.path.join(run_dir, f"health-p{host}.jsonl"), "w") as f:
             f.write(json.dumps({"schema_version": 1, "type": "header",
                                 "pid": host, "policy": "warn"}) + "\n")

@@ -233,10 +233,11 @@ class TrainConfig:
                                           # telemetry sinks (per-host JSONL
                                           # + Chrome trace + heartbeats);
                                           # None = telemetry disabled.
-                                          # NOTE: per-step phase spans add
-                                          # a block_until_ready fence per
-                                          # step — attribution costs the
-                                          # async-dispatch overlap
+                                          # The loop is the untraced
+                                          # run's: no fence a step; a
+                                          # step's completion is stamped
+                                          # off the main thread as the
+                                          # span device_step
     telemetry_sinks: str = "jsonl,chrome,summary"  # comma-separated subset
     mem_sample_steps: int = 1             # >0: per-step live memory
                                           # sampler stride — device
@@ -743,6 +744,7 @@ class Trainer:
         self._watchers = []
         self._watchdog = None   # HangWatchdog (started in run())
         self._exporter = None   # monitor HTTP endpoint (started in run())
+        self._stamper = None    # StepStamper (started in run(), telemetry on)
         # Numerics flight recorder (docs/health.md): the in-graph half is
         # compiled into the step builders below (health=self._health);
         # this monitor is the host half — JSONL record, spike detection,
@@ -1684,9 +1686,10 @@ class Trainer:
 
     def _release_workers(self) -> None:
         """Stop the host-side helpers: prefetcher (worker thread + slot
-        buffers), monitor exporter, everything in ``_watchers`` (the
-        watchdog among them), and the health monitor (flushes its JSONL
-        footer). Idempotent; does NOT close the telemetry sinks."""
+        buffers), monitor exporter, the step stamper (drained first),
+        everything in ``_watchers`` (the watchdog among them), and the
+        health monitor (flushes its JSONL footer). Idempotent; does NOT
+        close the telemetry sinks."""
         if self._prefetcher is not None:
             self._prefetcher.close()
             self._prefetcher = None
@@ -1698,6 +1701,11 @@ class Trainer:
         if self._exporter is not None:
             self._exporter.close()
             self._exporter = None
+        if self._stamper is not None:
+            # drained before the sinks close: the run-end counters carry
+            # every step's device_step
+            self._stamper.close()
+            self._stamper = None
         # each watcher once, the last told first, so that the watchdog
         # outlives the others' last writes (a capture window still open
         # when the run drains is written as a truncated bundle — a
@@ -2021,9 +2029,18 @@ class Trainer:
 
     def _start_run_watchers(self) -> None:
         """What lives for one ``run``, not for the ``Trainer``: the hang
-        watchdog (its first deadline window starts here) and the monitor
-        exporter. ``_release_workers`` stops both."""
+        watchdog (its first deadline window starts here), the monitor
+        exporter and, with telemetry on, the step stamper.
+        ``_release_workers`` stops all three."""
         c = self.config
+        if self.telemetry.enabled:
+            # the one place a traced run waits for a step: off the main
+            # thread (telemetry/stamper.py), so the loop is the untraced
+            # run's own
+            from tpu_ddp.telemetry.stamper import StepStamper
+
+            self._stamper = StepStamper(
+                self.telemetry, jax.block_until_ready)
         if c.watchdog_deadline_seconds > 0:
             from tpu_ddp.telemetry import HangWatchdog
 
@@ -2050,10 +2067,11 @@ class Trainer:
                 abort_on_hang=c.watchdog_abort,
             ).start()
             # first in the list: its beat comes before every other
-            # watcher hears of the step. Without tracing the dispatch is
-            # async: the beat then means "the host is still submitting
-            # work", which still catches wedged collectives (the host
-            # blocks inside the NEXT dispatch when the device queue jams)
+            # watcher hears of the step. The dispatch is async, with
+            # telemetry on or off: the beat means "the host is still
+            # submitting work", which still catches wedged collectives
+            # (the host blocks inside the NEXT dispatch when the device
+            # queue jams, or in the epoch's one fetch)
             self._watchers.insert(0, self._watchdog)
         if c.monitor_port:
             # Per-host live scrape endpoint (docs/monitoring.md). A bind
@@ -2107,9 +2125,7 @@ class Trainer:
         reg = tel.registry
         self._goodput_baseline = {
             "wall": time.time(),
-            "compiled": reg.histogram("phase/compiled_step").sum,
-            "sync": reg.histogram("phase/device_sync").sum,
-            "compile": reg.histogram("jax/compile_seconds").sum,
+            "device": reg.histogram("phase/device_step").sum,
         }
         if tel.enabled:
             tel.emit_counters(name="counters_baseline")
@@ -2187,25 +2203,28 @@ class Trainer:
                 step_losses.append(epoch_metrics["loss"])  # (K,) if fused
                 step_counters.append(epoch_metrics.get("counters"))
                 n_steps += dn
-                if (self._program_map is not None
-                        and not self._program_map.done):
-                    # while the device runs the step just dispatched; over
-                    # within the first dispatches of the run
-                    self._program_map.after_dispatch(
-                        kind, self.state, dev_batch)
                 if track_step:
                     host_step += dn
                     # the step the OOM forensics stamp on a postmortem
                     # bundle if this very dispatch exhausts HBM
                     self._last_host_step = host_step
                 if tel.enabled:
-                    # Attribution needs a per-step fence: "compiled_step"
-                    # above is the async dispatch, "device_sync" is the
-                    # device finishing the step. This is the one deliberate
-                    # deviation from the fence-free hot loop — tracing IS
-                    # the request to measure it (config docstring).
-                    with tel.span("device_sync"):
-                        jax.block_until_ready(epoch_metrics["loss"])
+                    # No fence, no device read: the loss (kept until the
+                    # epoch's one device_get anyway) goes to the stamper's
+                    # thread, which waits for it there and writes the
+                    # step's completion as the span "device_step" under
+                    # the id this iteration's other spans carry. The loop
+                    # a traced run takes is the untraced run's. The clock
+                    # is read first: this is the dispatch's return.
+                    self._stamper.dispatched(
+                        host_step - dn, dn, tel.clock.now(),
+                        epoch_metrics["loss"])
+                    if (self._program_map is not None
+                            and not self._program_map.done):
+                        # while the device runs the step just dispatched;
+                        # over within the first dispatches of the run
+                        self._program_map.after_dispatch(
+                            kind, self.state, dev_batch)
                     tel.current_step = host_step
                     tel.count("train/steps", dn)
                     tel.count("train/images", n_real)
@@ -2523,10 +2542,13 @@ class Trainer:
 
     def _update_goodput_gauges(self, tel) -> None:
         """Live goodput gauges for /metrics and the watch dashboard:
-        the fraction of THIS incarnation's wall-clock spent in productive
-        step execution (compiled_step + device_sync span time, minus jax
-        compile seconds — the compile happens inside the first spans).
-        Measured as deltas against the run-start baseline so a process-
+        the fraction of THIS incarnation's wall-clock in which the device
+        had a step to run: the sum of the ``device_step`` spans the step
+        stamper has written so far (telemetry/stamper.py). They start at
+        a dispatch's return, so the compile inside the first
+        ``compiled_step`` spans is outside them and nothing is taken off
+        for it; steps still in flight are not in the sum yet.
+        Measured as a delta against the run-start baseline so a process-
         global registry (tests, multiple Trainers per process) can't
         leak another run's sums in. The post-hoc cross-incarnation
         truth is `tpu-ddp goodput` (docs/goodput.md); these gauges are
@@ -2538,12 +2560,7 @@ class Trainer:
         elapsed = time.time() - base["wall"]
         if elapsed <= 0:
             return
-        productive = (
-            (reg.histogram("phase/compiled_step").sum - base["compiled"])
-            + (reg.histogram("phase/device_sync").sum - base["sync"])
-            - max(0.0, reg.histogram("jax/compile_seconds").sum
-                  - base["compile"])
-        )
+        productive = reg.histogram("phase/device_step").sum - base["device"]
         productive = min(max(productive, 0.0), elapsed)
         tel.gauge("goodput/fraction").set(productive / elapsed)
         tel.gauge("goodput/productive_seconds").set(productive)
