@@ -1,13 +1,17 @@
-"""By hand, on the chip: one call of each flash attention kernel at a cell's
-two layer shapes (``laguna-xs2.seq8k``: 2 x 8,192 x 48 x 128, no window, and
-2 x 8,192 x 64 x 128, window 512; 8 key-value heads; bfloat16).
+"""By hand, on the chip: one call of each flash attention kernel at the
+decoder cells' layer shapes (``laguna-xs2.seq8k``: 2 x 8,192 x 48 x 128, no
+window, and 2 x 8,192 x 64 x 128, window 512; 8 key-value heads;
+``joyai-llm-flash.seq8k-v16160``: 2 x 8,192 x 32, keys of 192 over values
+of 128; bfloat16).
 
-    python3 benchmarks/flash_tiles.py [--layers full,window] [--arms ...]
+    python3 benchmarks/flash_tiles.py [--layers ...] [--arms ...]
 
 An arm is
 
 ``fwd``         the forward kernel at ``--blocks``
-``bwd``         the dQ and dK/dV kernels at ``--blocks``
+``bwd``         the backward pass at ``--blocks``: whichever kernels
+                ``_flash_backward`` runs for the shape (``flash_bwd``, or
+                ``flash_dq`` and ``flash_dkv``), each by its scope
 ``blocks:QxK``  the forward kernel at plain ``flash_blocks`` of (Q, K): what
                 a reader would otherwise ask about
 
@@ -38,8 +42,10 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-#: (query heads, window) of the cell's two kinds of layer
-LAYERS = {"full": (48, 0), "window": (64, 512)}
+#: a layer kind's query heads and what it has other than no window,
+#: ``--kv-heads`` and ``--head-dim``
+LAYERS = {"full": dict(heads=48), "window": dict(heads=64, window=512),
+          "latent": dict(heads=32, kv_heads=32, qk_dim=192, v_dim=128)}
 DEFAULT_ARMS = ("fwd,bwd,blocks:256x256,blocks:512x128,blocks:512x1024,"
                 "blocks:1024x512")
 CALL = re.compile(
@@ -70,14 +76,17 @@ def main(argv=None):
                       "interpret": interpret}), flush=True)
 
     for layer in args.layers.split(","):
-        heads, window = LAYERS[layer]
-        shape = (args.batch, args.tokens, heads, args.head_dim)
-        kv_shape = (args.batch, args.tokens, args.kv_heads, args.head_dim)
+        kind = LAYERS[layer]
+        heads, window = kind["heads"], kind.get("window", 0)
+        kv_heads = kind.get("kv_heads", args.kv_heads)
+        qk_dim = kind.get("qk_dim", args.head_dim)
+        v_dim = kind.get("v_dim", args.head_dim)
         keys = jax.random.split(jax.random.key(heads), 4)
-        q, g = (jax.random.normal(k, shape, jnp.bfloat16)
-                for k in keys[:2])
-        k, v = (jax.random.normal(k, kv_shape, jnp.bfloat16)
-                for k in keys[2:])
+        q, g, k, v = (
+            jax.random.normal(key, (args.batch, args.tokens, n, d),
+                              jnp.bfloat16)
+            for key, n, d in zip(keys, (heads, heads, kv_heads, kv_heads),
+                                 (qk_dim, v_dim, qk_dim, v_dim)))
         first = {}
         for arm in args.arms.split(","):
             what, _, tile = arm.partition(":")

@@ -68,8 +68,8 @@ def test_flash_compiles_at_real_widths(topo, shape, causal):
     bwd = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
                    (0, 1, 2))
     assert _text(fwd, x, x, x).count(CUSTOM_CALL) == 1
-    # fwd recompute + dQ kernel + dK/dV kernel, exactly
-    assert _text(bwd, x, x, x).count(CUSTOM_CALL) == 3
+    # fwd recompute + the one backward kernel, exactly
+    assert _text(bwd, x, x, x).count(CUSTOM_CALL) == 2
 
 
 @pytest.mark.parametrize("heads,window", [(64, 512), (48, 0)],
@@ -92,7 +92,8 @@ def test_windowed_grouped_flash_compiles_at_the_decoder_widths(topo, heads,
     bwd = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
                    (0, 1, 2))
     assert _text(fwd, q, kv, kv).count(CUSTOM_CALL) == 1
-    assert _text(bwd, q, kv, kv).count(CUSTOM_CALL) == 3
+    text = _text(bwd, q, kv, kv)
+    assert text.count(CUSTOM_CALL) == 2 and "kernel.flash_bwd" in text
 
 
 def test_latent_flash_compiles_at_the_decoder_widths(topo):
@@ -114,7 +115,26 @@ def test_latent_flash_compiles_at_the_decoder_widths(topo):
     assert text.count(CUSTOM_CALL) == 1
     # the output is of the values' width: nothing of 256 comes back
     assert "bf16[64,8192,128]" in text and "bf16[64,8192,256]{" in text
-    assert _text(bwd, qk, qk, v).count(CUSTOM_CALL) == 3
+    # 25.2 MB of dk / dv carried in VMEM for a head: over Mosaic's default
+    text = _text(bwd, qk, qk, v)
+    assert text.count(CUSTOM_CALL) == 2 and "kernel.flash_bwd" in text
+
+
+def test_a_sequence_over_the_carrys_budget_compiles_the_two_kernels(topo):
+    """32,768 positions at latent attention's widths: 100 MB of dk / dv a
+    head would not fit, so the backward pass is the dQ kernel and the dK/dV
+    kernel, chosen from the shape alone."""
+    from tpu_ddp.ops.flash_attention import flash_attention
+
+    one = _one_chip(topo)
+    qk = jax.ShapeDtypeStruct((1, 32768, 2, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, 32768, 2, 128), jnp.bfloat16, sharding=one)
+    bwd = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, 512, 512, False, causal=True).astype(jnp.float32).sum(),
+        (0, 1, 2))
+    text = _text(bwd, qk, qk, v)
+    assert text.count(CUSTOM_CALL) == 3 and "kernel.flash_bwd" not in text
+    assert "kernel.flash_dq" in text and "kernel.flash_dkv" in text
 
 
 def test_grouped_expert_products_compile_at_the_decoder_widths(topo):

@@ -17,14 +17,21 @@ kernels' programs are the ones a single ``D`` made.
 
 Differentiation: forward AND backward are Pallas kernels (``jax.custom_vjp``).
 The forward additionally emits the per-row logsumexp (broadcast along a
-128-lane minor dim — the TPU-friendly layout for per-row stats); the backward
-is the standard two-kernel split: a dQ kernel iterating kv-blocks innermost
-(dq accumulates in VMEM scratch) and a dK/dV kernel iterating q-blocks
-innermost — both recompute p = exp(s - lse) tile-by-tile instead of
-materializing the (T, T) probability matrix. Every T gets a tiling Mosaic
-accepts (``_plan``): blocks that divide T, else one whole-axis block for
-short sequences, else T zero-padded to a block multiple with the padding
-masked out through ``kv_mask`` — the kernel asked for is the kernel run.
+128-lane minor dim — the TPU-friendly layout for per-row stats). The backward
+is ONE kernel (``_bwd_kernel``) wherever the dk / dv of one key-value head's
+whole sequence fit its VMEM budget (``_backward_fits``: 8,192 positions at
+any width here): the dQ walk, kv-blocks innermost with dq in VMEM scratch,
+whose tile — s, p = exp(s - lse), dP, dS, made once — also adds into the
+whole-sequence dk / dv accumulators. A longer sequence (32,768 at latent
+attention's widths, a pod-scale ring's local block) runs the standard
+two-kernel split: that dQ kernel alone and a dK/dV kernel iterating q-blocks
+innermost, each making the tile again. Either way p is recomputed
+tile-by-tile instead of materializing the (T, T) probability matrix;
+which one runs is decided by the shapes and nothing else. Every T gets a
+tiling Mosaic accepts (``_plan``): blocks that divide T, else one
+whole-axis block for short sequences, else T zero-padded to a block
+multiple with the padding masked out through ``kv_mask`` — the kernel asked
+for is the kernel run.
 
 Masking (round-4 verdict item 3, the decoder regime): ``causal=True``
 leaves out the tiles entirely above the diagonal and masks the tiles the
@@ -40,17 +47,19 @@ of query heads): ``window=W`` lets row ``i`` see columns ``i - W < j <= i``
 (``H % KV == 0``; query heads ``g*H/KV .. (g+1)*H/KV - 1`` read key-value
 head ``g``). The grid's inner dimension is the *band*: for a q block only
 the kv blocks it can see are visited (``_Band``), in the forward and in
-both backward kernels, so a tile wholly outside the band costs neither a
+every backward kernel, so a tile wholly outside the band costs neither a
 product nor a copy, and under ``causal`` the blocks above the diagonal are
-not streamed. The dK/dV kernel sums over the query heads of its group in
-its inner dimension. Tiles that no mask edge crosses take no mask
+not streamed. dk and dv sum over the query heads of their group: a grid
+dimension outside the q blocks in the one-kernel pass, part of the inner
+dimension in the dK/dV kernel. Tiles that no mask edge crosses take no mask
 arithmetic.
 
 The row statistics of the online softmax (``m``, ``l`` and the rescale
 ``alpha``; ``lse`` and ``di`` in the backward kernels) are kept
 lane-replicated, (rows, 128), in the kernels as in their buffers
-(``_lanes``). What that is worth on the chip, and what walking a tile in
-smaller pieces was not, is PERF.md's to say (section 6, PR 30).
+(``_lanes``). What that is worth on the chip, what walking a tile in
+smaller pieces was not, and what one backward kernel is, is PERF.md's to
+say (section 6, PR 30 and PR 40).
 """
 
 from __future__ import annotations
@@ -575,12 +584,115 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *rest,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *rest,
+                scale: float, band: _Band, width: int, group: int,
+                has_mask: bool):
+    """dQ, dK and dV in one walk: ``_dq_kernel``'s (for a query head's q
+    block, the kv blocks of its band innermost, dq carried in (bq, d)
+    scratch), and each tile's p and ds, made once, also add p^T @ do and
+    ds^T @ q into the rows of their kv block in dk / dv accumulators that
+    hold the whole sequence of one key-value head: float32 VMEM scratch,
+    zeroed at the head's first step and written out at its last. The query
+    heads of a group are the grid dimension outside the q blocks and add
+    into the same rows, so a row's sum runs heads outer, q blocks
+    ascending: ``_dkv_kernel``'s order."""
+    if has_mask:
+        mask_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
+    else:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
+        mask_ref = None
+    h = pl.program_id(1)    # query head of the group
+    j = pl.program_id(2)
+    t = pl.program_id(3)
+    kb = band.kv_lo(j) + t
+    bk = band.bk
+
+    def kv_rows(blk):
+        return pl.ds(0 if band.n_k == 1 else pl.multiple_of(blk * bk, bk), bk)
+
+    def each_kv_block(body):
+        """A whole-sequence value a kv block at a time: (T, d) at once is
+        thousands of registers' worth of straight-line code."""
+        if band.n_k == 1:
+            body(0)
+        else:
+            jax.lax.fori_loop(
+                0, band.n_k, lambda blk, _: body(blk), None)
+
+    @pl.when((h == 0) & (j == 0) & (t == 0))
+    def _init_kv():
+        def zero(blk):
+            dk_acc[kv_rows(blk), :] = jnp.zeros((bk, dk_acc.shape[1]),
+                                                jnp.float32)
+            dv_acc[kv_rows(blk), :] = jnp.zeros((bk, dv_acc.shape[1]),
+                                                jnp.float32)
+        each_kv_block(zero)
+
+    @pl.when(t == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    def _compute(masked):
+        q = q_ref[0]
+        k = k_ref[0]
+        do = do_ref[0]
+        rows = kv_rows(kb)
+        p = _tile_p(q, k, lse_ref[0], j, kb, scale, band,
+                    mask_ref[0, 0:1, :] if has_mask else None, masked)
+        dv_acc[rows, :] += jnp.dot(p.astype(do.dtype).T, do,
+                                   preferred_element_type=jnp.float32)
+        dp = jnp.dot(do, v_ref[0].T, preferred_element_type=jnp.float32)
+        ds = p * (dp - _lanes(di_ref[0], dp.shape[1])) * scale
+        dk_acc[rows, :] += jnp.dot(ds.astype(q.dtype).T, q,
+                                   preferred_element_type=jnp.float32)
+        dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32)
+
+    _band_dispatch(band, has_mask, j, kb, kb <= band.kv_hi(j), _compute)
+
+    @pl.when(t == width - 1)
+    def _finalize():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+    @pl.when((h == group - 1) & (j == band.n_q - 1) & (t == width - 1))
+    def _finalize_kv():
+        def write(blk):
+            rows = kv_rows(blk)
+            dk_ref[0, rows, :] = dk_acc[rows, :].astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
+        each_kv_block(write)
+
+
+#: What the one-kernel backward pass may keep in VMEM for a key-value head's
+#: whole sequence: its float32 dk / dv accumulators and the two buffers of
+#: each of their output blocks. A v5e core has 128 MiB; Mosaic's scoped
+#: default of 16 MiB stays for the tiles, as the other kernels have it.
+_FUSED_CARRY_MAX = 32 << 20
+_TILES_VMEM = 16 << 20
+
+
+def _fused_carry_bytes(T: int, d_pad: int, dv_pad: int, itemsize: int) -> int:
+    return T * (d_pad + dv_pad) * (4 + 2 * itemsize)
+
+
+def _backward_fits(T: int, d_pad: int, dv_pad: int, itemsize: int) -> bool:
+    """Does ``_bwd_kernel``'s whole-sequence carry fit its budget? 8,192
+    positions do at either width (16.8 MB at 128 + 128 lanes, 25.2 MB at
+    256 + 128, in bfloat16); 32,768 at 256 + 128 (100 MB) and a pod-scale
+    131,072 do not, and run the dQ and dK/dV kernels. Shapes alone decide:
+    the need is VMEM against a score tile made twice, not a preference."""
+    return _fused_carry_bytes(T, d_pad, dv_pad, itemsize) <= _FUSED_CARRY_MAX
+
+
 def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
                     block_k: int, interpret: bool, causal: bool = False,
                     window: int = 0):
+    """(dq, dk, dv): one kernel (``flash_bwd``) where a key-value head's
+    whole-sequence dk / dv fit its VMEM budget (``_backward_fits``), the
+    dQ kernel and the dK/dV kernel (``flash_dq``, ``flash_dkv``) where they
+    do not. The two share the operands made here and ``_tile_p``."""
     B, T, H, D = q.shape
     group = _check_heads(q, k, v)
-    KV = H // group
     scale = 1.0 / np.sqrt(D)
     bq, bk, d_pad, _ = _unpadded_plan(
         q.shape, block_q, block_k, kv_mask is not None)
@@ -596,6 +708,84 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
         (B * H, T, LANE),
     )
     band = _Band(causal or bool(window), window, bq, bk, T // bq, T // bk)
+    fused = _backward_fits(T, d_pad, dv_pad, k.dtype.itemsize)
+    dq, dk, dv = (_one_kernel_backward if fused else _two_kernel_backward)(
+        qf, kf, vf, gf, lse, di, kv_mask, band=band, group=group,
+        scale=scale, interpret=interpret)
+    return _unfold(dq, q.shape), _unfold(dk, k.shape), _unfold(dv, v.shape)
+
+
+def _one_kernel_backward(qf, kf, vf, gf, lse, di, kv_mask, *, band: _Band,
+                         group: int, scale: float, interpret: bool):
+    """``_bwd_kernel`` over the grid (key-value head, query head of its
+    group, q block, kv block of the band): a score tile, its exponentials
+    and dP are made once a backward pass."""
+    bq, bk = band.bq, band.bk
+    (BH, T, d_pad), dv_pad = qf.shape, vf.shape[-1]
+    BKV = BH // group
+    has_mask = kv_mask is not None
+    width = band.kv_width
+
+    def kv_block(j, t):
+        return _least(band.kv_lo(j) + t, band.kv_hi(j))
+
+    def q_spec(d):
+        return pl.BlockSpec(
+            (1, bq, d), lambda i, h, j, t: (i * group + h, j, 0),
+            memory_space=pltpu.VMEM)
+
+    def kv_inner(d):
+        return pl.BlockSpec(
+            (1, bk, d), lambda i, h, j, t: (i, kv_block(j, t), 0),
+            memory_space=pltpu.VMEM)
+
+    def kv_whole(d):
+        return pl.BlockSpec((1, T, d), lambda i, h, j, t: (i, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    row_spec = q_spec(LANE)
+    in_specs = [q_spec(d_pad), kv_inner(d_pad), kv_inner(dv_pad),
+                q_spec(dv_pad), row_spec, row_spec]
+    args = [qf, kf, vf, gf, lse, di]
+    if has_mask:
+        in_specs.append(pl.BlockSpec(
+            (1, _SUBLANES, bk), lambda i, h, j, t: (i, 0, kv_block(j, t)),
+            memory_space=pltpu.VMEM))
+        args.append(_fold_mask(kv_mask, BKV // kv_mask.shape[0]))
+    with jax.named_scope(kernel_scope("flash_bwd")):
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, scale=scale, band=band,
+                              width=width, group=group, has_mask=has_mask),
+            out_shape=[
+                _sds((BH, T, d_pad), qf.dtype, gf),
+                _sds((BKV, T, d_pad), kf.dtype, gf),
+                _sds((BKV, T, dv_pad), vf.dtype, gf),
+            ],
+            grid=(BKV, group, band.n_q, width),
+            in_specs=in_specs,
+            out_specs=[q_spec(d_pad), kv_whole(d_pad), kv_whole(dv_pad)],
+            scratch_shapes=[
+                pltpu.VMEM((bq, d_pad), jnp.float32),
+                pltpu.VMEM((T, d_pad), jnp.float32),
+                pltpu.VMEM((T, dv_pad), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                                     "arbitrary"),
+                vmem_limit_bytes=_TILES_VMEM + _fused_carry_bytes(
+                    T, d_pad, dv_pad, kf.dtype.itemsize)),
+            interpret=interpret,
+        )(*args)
+
+
+def _two_kernel_backward(qf, kf, vf, gf, lse, di, kv_mask, *, band: _Band,
+                         group: int, scale: float, interpret: bool):
+    """The standard split, for a sequence whose dk / dv ``_bwd_kernel``
+    cannot hold: a dQ kernel iterating kv blocks innermost and a dK/dV
+    kernel iterating q blocks innermost, each making the tile's p."""
+    bq, bk = band.bq, band.bk
+    (BH, T, d_pad), dv_pad = qf.shape, vf.shape[-1]
+    BKV = BH // group
     has_mask = kv_mask is not None
     kparams = dict(scale=scale, band=band, has_mask=has_mask)
 
@@ -619,14 +809,14 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
         in_specs.append(pl.BlockSpec(
             (1, _SUBLANES, bk), lambda i, j, t: (i, 0, kv_block(j, t)),
             memory_space=pltpu.VMEM))
-        args.append(_fold_mask(kv_mask, H))
+        args.append(_fold_mask(kv_mask, BH // kv_mask.shape[0]))
     semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     with jax.named_scope(kernel_scope("flash_dq")):
         dq = pl.pallas_call(
             functools.partial(_dq_kernel, width=band.kv_width, **kparams),
-            out_shape=_sds((B * H, T, d_pad), q.dtype, gf),
-            grid=(B * H, band.n_q, band.kv_width),  # dq carry in scratch
+            out_shape=_sds((BH, T, d_pad), qf.dtype, gf),
+            grid=(BH, band.n_q, band.kv_width),  # dq carry in scratch
             in_specs=in_specs,
             out_specs=q_spec(d_pad),
             scratch_shapes=[pltpu.VMEM((bq, d_pad), jnp.float32)],
@@ -658,16 +848,16 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
         in_specs.append(pl.BlockSpec((1, _SUBLANES, bk),
                                      lambda i, jk, t: (i, 0, jk),
                                      memory_space=pltpu.VMEM))
-        args.append(_fold_mask(kv_mask, KV))
+        args.append(_fold_mask(kv_mask, BKV // kv_mask.shape[0]))
     with jax.named_scope(kernel_scope("flash_dkv")):
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel, width=width, group=group,
                               **kparams),
             out_shape=[
-                _sds((B * KV, T, d_pad), k.dtype, gf),
-                _sds((B * KV, T, dv_pad), v.dtype, gf),
+                _sds((BKV, T, d_pad), kf.dtype, gf),
+                _sds((BKV, T, dv_pad), vf.dtype, gf),
             ],
-            grid=(B * KV, band.n_k, group * width),  # dk/dv carry in scratch
+            grid=(BKV, band.n_k, group * width),  # dk/dv carry in scratch
             in_specs=in_specs,
             out_specs=[kv_spec(d_pad), kv_spec(dv_pad)],
             scratch_shapes=[
@@ -677,7 +867,7 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
             compiler_params=semantics,
             interpret=interpret,
         )(*args)
-    return _unfold(dq, q.shape), _unfold(dk, k.shape), _unfold(dv, v.shape)
+    return dq, dk, dv
 
 
 def _resolve_interpret(interpret):
