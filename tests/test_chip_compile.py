@@ -120,6 +120,28 @@ def test_latent_flash_compiles_at_the_decoder_widths(topo):
     assert text.count(CUSTOM_CALL) == 2 and "kernel.flash_bwd" in text
 
 
+def test_block_mask_flash_compiles_at_the_decoder_widths(topo):
+    """An SDAR-30B-A3B layer's attention at the benchmark cell's shapes:
+    ``[clean ‖ noisy]`` of 4,096 tokens in blocks of 4, 8,192 positions, 32
+    heads of 128 over 4 key-value heads, tiles of 512: the visit lists'
+    index maps and the mask's integer division pass Mosaic, and the backward
+    pass is the one kernel."""
+    from tpu_ddp.ops.flash_attention import flash_attention
+
+    one = _one_chip(topo)
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((2, 8192, 4, 128), jnp.bfloat16, sharding=one)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, 512, 512, False, diffusion=(4096, 4))
+
+    bwd = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                   (0, 1, 2))
+    assert _text(fwd, q, kv, kv).count(CUSTOM_CALL) == 1
+    text = _text(bwd, q, kv, kv)
+    assert text.count(CUSTOM_CALL) == 2 and "kernel.flash_bwd" in text
+
+
 def test_a_sequence_over_the_carrys_budget_compiles_the_two_kernels(topo):
     """32,768 positions at latent attention's widths: 100 MB of dk / dv a
     head would not fit, so the backward pass is the dQ kernel and the dK/dV

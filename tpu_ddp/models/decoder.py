@@ -4,7 +4,8 @@ gate) and a feed-forward that is a dense SwiGLU or routed experts with a
 shared expert (``models/moe.py::DroplessMoE``); RMSNorm, no biases, untied
 embedding and head. One flax module, ``SparseDecoder``, described by a
 ``DecoderSpec``; ``laguna_xs2`` registers poolside's Laguna-XS.2 at its
-published sizes and ``joyai_llm_flash`` JD's JoyAI-LLM-Flash at its own.
+published sizes, ``joyai_llm_flash`` JD's JoyAI-LLM-Flash and
+``sdar_30b_a3b`` JetLM's SDAR-30B-A3B-Chat at theirs.
 
     y = x + Attn(RMSNorm(x));  z = y + FFN(RMSNorm(y));  head(RMSNorm(z_last))
 
@@ -25,9 +26,25 @@ gate, and its ``heads`` and the ``kv_heads`` beside it are the heads held
 here (``parallel.expert_parallel.HeadShare``), whichever of the published
 ones those are.
 
+A ``DecoderSpec`` with ``diffusion`` (``BlockDiffusion``: block length,
+the mask token's id, the least noise level) is a block-diffusion model
+(BD3-LMs, arXiv:2503.09573): it reads ``[x ‖ x~]``, a sequence of L tokens
+and its noised copy after it, 2L positions, which its task builds inside
+the step; position ``i`` has the rotary angle of ``i mod L``; attention is
+the block-diffusion visibility (``ops/flash_attention.py``, ``diffusion``),
+under the module scope ``attention_block``; the final norm and the head run
+on the noisy half only, so the logits are (B, L, rows held), unshifted:
+position ``i`` predicts token ``i``. ``qk_norm`` gives queries and keys an
+RMSNorm over a head's width (one learned scale each, shared by the heads)
+before the rotary turn; ``router_score`` says what the router makes of its
+logits (``sigmoid``, or a ``softmax`` over all experts).
+
 Input ``tokens`` (B, T) int32, output float32 logits (B, T, vocabulary rows
 held). The sequence length is the data's. What the model trains on is its
-``task`` (``train/tasks.py``): tokens in, masked next-token loss out.
+``task`` (``train/tasks.py``): tokens in and a masked next-token loss out
+(with a second term where there is a prediction module), or, for a
+block-diffusion model, ``block_diffusion``: the noise drawn in the step and
+a 1/t-weighted loss at the masked positions.
 
 One chip of an expert-parallel deployment holds a share of each layer:
 ``experts_held`` of the routed experts from ``expert_offset`` up
@@ -132,6 +149,17 @@ class LayerSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """Settings of block-diffusion training: tokens a block, the id that
+    stands for a masked token (inside the vocabulary rows held; the data
+    never holds it), and the least noise level a block draws."""
+
+    block: int
+    mask_id: int
+    t_min: float = 1e-3
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderSpec:
     vocab_rows: int
     hidden: int
@@ -153,14 +181,23 @@ class DecoderSpec:
     mtp: Optional[LayerSpec] = None
     #: weight of the module's term in the loss
     mtp_weight: float = 0.0
+    #: an RMSNorm over a head's width on queries and on keys, before the
+    #: rotary turn (grouped-query layers)
+    qk_norm: bool = False
+    #: what the router makes of its float32 logits: ``sigmoid`` or ``softmax``
+    router_score: str = "sigmoid"
+    #: a block-diffusion model's settings, or None for a causal decoder
+    diffusion: Optional[BlockDiffusion] = None
 
 
-def reference_attention(q, k, v, *, causal=True, window=0):
+def reference_attention(q, k, v, *, causal=True, window=0, diffusion=None):
     """Fused jnp attention (``ops/flash_attention._reference``): every score
-    at once, for sequences a CPU test can hold."""
+    at once, for sequences a CPU test can hold. ``diffusion`` is a
+    visibility of its own, in place of causal."""
     from tpu_ddp.ops.flash_attention import _reference
 
-    return _reference(q, k, v, causal=causal, window=window)
+    return _reference(q, k, v, causal=causal and diffusion is None,
+                      window=window, diffusion=diffusion)
 
 
 class GroupedQueryAttention(nn.Module):
@@ -169,13 +206,20 @@ class GroupedQueryAttention(nn.Module):
     rotary positions (``cos``, ``sin`` of ``spec.rotary``'s tables) and a
     head-wise output gate ``o_h = sigmoid(x w_h) * attn_h`` before the output
     projection. A share of the heads is the same module with fewer of them:
-    its output is then partial, the other shares' to add to."""
+    its output is then partial, the other shares' to add to. ``qk_norm`` =
+    an epsilon: queries and keys are normalised over a head's width
+    (``q_norm``, ``k_norm``) before they are turned. ``diffusion_block`` =
+    B > 0: ``x`` is ``[clean ‖ noisy]`` and the visibility the
+    block-diffusion one over halves of ``T / 2`` in blocks of B, in place of
+    causal."""
 
     spec: LayerSpec
     kv_heads: int
     head_dim: int
     dtype: jnp.dtype = jnp.float32
     attention_impl: Optional[Callable] = None
+    qk_norm: Optional[float] = None
+    diffusion_block: int = 0
 
     @nn.compact
     def __call__(self, x, cos=None, sin=None):
@@ -185,13 +229,28 @@ class GroupedQueryAttention(nn.Module):
             n, use_bias=False, dtype=self.dtype, name=name)
         turn = (lambda a: a) if self.spec.rotary is None else (
             lambda a: rotate(a, cos, sin))
-        q = turn(dense(H * D, "q")(x).reshape(B, T, H, D))
-        k = turn(dense(KV * D, "k")(x).reshape(B, T, KV, D))
+
+        def head_norm(a, name):
+            if self.qk_norm is None:
+                return a
+            return nn.RMSNorm(epsilon=self.qk_norm, dtype=self.dtype,
+                              name=name)(a)
+
+        q = turn(head_norm(dense(H * D, "q")(x).reshape(B, T, H, D),
+                           "q_norm"))
+        k = turn(head_norm(dense(KV * D, "k")(x).reshape(B, T, KV, D),
+                           "k_norm"))
         v = dense(KV * D, "v")(x).reshape(B, T, KV, D)
         attend = self.attention_impl or reference_attention
-        kind = "attention_window" if self.spec.window else "attention_full"
+        if self.diffusion_block:
+            kind = "attention_block"
+            how = dict(diffusion=(T // 2, self.diffusion_block))
+        else:
+            kind = ("attention_window" if self.spec.window
+                    else "attention_full")
+            how = dict(causal=True, window=self.spec.window)
         with jax.named_scope(module_scope(kind)):
-            o = attend(q, k, v, causal=True, window=self.spec.window)
+            o = attend(q, k, v, **how)
         if self.spec.gate:
             o = o * nn.sigmoid(dense(H, "gate")(x))[..., None]
         return dense(C, "o")(o.reshape(B, T, H * D))
@@ -262,7 +321,10 @@ class DecoderLayer(nn.Module):
         if self.spec.latent is None:
             attn = GroupedQueryAttention(
                 self.spec, m.kv_heads, m.head_dim, dtype=self.dtype,
-                attention_impl=self.attention_impl, name="attn")
+                attention_impl=self.attention_impl,
+                qk_norm=m.norm_eps if m.qk_norm else None,
+                diffusion_block=m.diffusion.block if m.diffusion else 0,
+                name="attn")
         else:
             attn = LatentAttention(
                 self.spec, m.norm_eps, dtype=self.dtype,
@@ -277,7 +339,8 @@ class DecoderLayer(nn.Module):
             ExpertShare(m.num_experts, m.experts_held, m.expert_offset),
             top_k=m.top_k, expert_width=m.expert_width,
             shared_width=m.shared_width, scaling=m.routed_scaling,
-            dtype=self.dtype, selection_bias=m.selection_bias, name="moe",
+            dtype=self.dtype, selection_bias=m.selection_bias,
+            score=m.router_score, name="moe",
         )(h)
 
 
@@ -296,6 +359,8 @@ class SparseDecoder(nn.Module):
     @property
     def task(self) -> str:
         """What the model reads from a batch and which loss it takes."""
+        if self.spec.diffusion is not None:
+            return "block_diffusion"
         return "next_token" if self.spec.mtp is None else "next_token_mtp"
 
     @nn.compact
@@ -307,10 +372,24 @@ class SparseDecoder(nn.Module):
         x = embed(tokens)
         tables = {}
         layer_cls = nn.remat(DecoderLayer) if self.remat else DecoderLayer
+        positions = tokens.shape[1]
+        if s.diffusion is not None:
+            if s.mtp is not None or positions % (2 * s.diffusion.block):
+                raise ValueError(
+                    f"a block-diffusion decoder reads [clean, noisy], twice "
+                    f"a whole number of blocks of {s.diffusion.block}, not "
+                    f"{positions} positions; and has no prediction module")
+            positions //= 2  # both halves are positions 0 .. L - 1
+            self.sow("counters", "block_masked_tokens", jnp.sum(
+                tokens[:, positions:] == s.diffusion.mask_id,
+                dtype=jnp.int32))
 
         def layer_of(layer, name):
             if layer.rotary not in tables:
-                tables[layer.rotary] = layer.rotary.tables(tokens.shape[1])
+                cos, sin = layer.rotary.tables(positions)
+                if s.diffusion is not None:
+                    cos, sin = (jnp.concatenate([a, a]) for a in (cos, sin))
+                tables[layer.rotary] = (cos, sin)
             return functools.partial(
                 layer_cls(layer, s, dtype=self.dtype,
                           attention_impl=self.attention_impl, name=name),
@@ -325,6 +404,8 @@ class SparseDecoder(nn.Module):
             s.vocab_rows, use_bias=False, dtype=self.dtype, name="head",
             dot_general=functools.partial(
                 jax.lax.dot_general, preferred_element_type=jnp.float32))
+        if s.diffusion is not None:
+            x = x[:, positions:]  # the noisy half is what is predicted from
         logits = head(final_norm(x)).astype(jnp.float32)
         if s.mtp is None:
             return logits
@@ -417,3 +498,39 @@ def joyai_llm_flash(num_classes: int = 10, bn_cross_replica_axis=None,
                     dtype=jnp.float32, **share):
     del num_classes, bn_cross_replica_axis  # a classifier's
     return SparseDecoder(joyai_llm_flash_spec(**share), dtype=dtype)
+
+
+# -- JetLM/SDAR-30B-A3B-Chat --------------------------------------------------
+# https://huggingface.co/JetLM/SDAR-30B-A3B-Chat/blob/main/config.json
+
+def sdar_30b_a3b_spec(*, num_layers: int = 48, experts_held: int = 128,
+                      expert_offset: int = 0,
+                      vocab_rows: int = 151936) -> DecoderSpec:
+    """The published model (``model_type`` ``sdar_moe``, the ``qwen3_moe``
+    layout): 48 layers alike, 32 query heads over 4 key-value heads of 128
+    (beside a hidden size of 2,048), queries and keys normalised over a
+    head, all 128 dimensions turned (theta 1e6, no scaling), no window, no
+    output gate; 128 routed experts of width 768 with 8 a token by a float32
+    softmax over all 128, the chosen eight renormalised, no scaling factor,
+    no shared expert, no dense layer. Trained by block diffusion: blocks of
+    4 tokens, as the released Chat checkpoints generate with, the mask the
+    last vocabulary row held (the config gives neither the block length nor
+    the schedule: ``chipbench/configs/sdar-30b-a3b.json``, ``assumed``).
+    The arguments are one chip's share; no width changes."""
+    layer = LayerSpec(heads=32, window=0,
+                      rotary=Rotary(dims=128, theta=1000000.0), sparse=True,
+                      gate=False)
+    return DecoderSpec(
+        vocab_rows=vocab_rows, hidden=2048, head_dim=128, kv_heads=4,
+        layers=(layer,) * num_layers, dense_width=6144, num_experts=128,
+        experts_held=experts_held, expert_offset=expert_offset, top_k=8,
+        expert_width=768, shared_width=0, routed_scaling=1.0, qk_norm=True,
+        router_score="softmax",
+        diffusion=BlockDiffusion(block=4, mask_id=vocab_rows - 1))
+
+
+@register("sdar_30b_a3b")
+def sdar_30b_a3b(num_classes: int = 10, bn_cross_replica_axis=None,
+                 dtype=jnp.float32, **share):
+    del num_classes, bn_cross_replica_axis  # a classifier's
+    return SparseDecoder(sdar_30b_a3b_spec(**share), dtype=dtype)

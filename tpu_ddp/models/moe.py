@@ -521,7 +521,8 @@ class DroplessMoE(nn.Module):
 
     ``share`` (``parallel.expert_parallel.ExpertShare``: ``num_experts``,
     ``held``, ``offset``) says which experts this layer holds. The router
-    scores all ``num_experts`` in float32 (sigmoid, the ``top_k`` largest,
+    scores all ``num_experts`` in float32 (``score``: ``sigmoid`` of each
+    logit, or a ``softmax`` over all of them; the ``top_k`` largest,
     normalised to sum to one, times ``scaling``); the (token, choice) pairs
     that name a held expert are sorted by expert, pushed through the grouped
     products, weighted and summed back onto their tokens; the shared expert
@@ -592,10 +593,15 @@ class DroplessMoE(nn.Module):
     gated: bool = True
     latent: int = 0
     selection_bias: bool = False
+    #: ``sigmoid`` or ``softmax``: what the router makes of its logits
+    score: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x):  # (B, T, C) -> (B, T, C)
         from tpu_ddp.telemetry.phases import module_scope
+
+        score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[
+            self.score]
 
         B, T, C = x.shape
         E, held, offset = (self.share.num_experts, self.share.held,
@@ -619,12 +625,12 @@ class DroplessMoE(nn.Module):
             if self.selection_bias:
                 bias = self.param("router_bias", nn.initializers.zeros, (E,),
                                   jnp.float32)
-                scored = jax.nn.sigmoid(checkpoint_name(logits, LOGITS_NAME))
+                scored = score(checkpoint_name(logits, LOGITS_NAME))
                 _, ids = jax.lax.top_k(scored + bias, K)        # (N, K)
                 ids = checkpoint_name(ids, IDS_NAME)
                 scores = checkpoint_name(_chosen(scored, ids, E), SCORES_NAME)
             else:
-                scores, ids = jax.lax.top_k(jax.nn.sigmoid(logits), K)
+                scores, ids = jax.lax.top_k(score(logits), K)
             weights = (scores / scores.sum(axis=-1, keepdims=True)
                        * self.scaling)
             self.sow("intermediates", "expert_ids", ids)

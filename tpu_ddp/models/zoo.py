@@ -12,6 +12,7 @@ MODEL_REGISTRY: dict = {}
 #: trains another model never pays for their imports
 LAZY_MODELS = {"laguna_xs2": "tpu_ddp.models.decoder",
                "joyai_llm_flash": "tpu_ddp.models.decoder",
+               "sdar_30b_a3b": "tpu_ddp.models.decoder",
                "nemotron3_super": "tpu_ddp.models.hybrid"}
 
 

@@ -54,6 +54,19 @@ dimension outside the q blocks in the one-kernel pass, part of the inner
 dimension in the dK/dV kernel. Tiles that no mask edge crosses take no mask
 arithmetic.
 
+A third visibility beside ``causal`` and ``window`` is block diffusion's,
+``diffusion=(L, B)``: the sequence is ``[clean ‖ noisy]``, L positions and
+their L noised copies, in blocks of B tokens; a clean row sees the clean
+columns of its own and earlier blocks, a noisy row the clean columns of
+earlier blocks and the noisy columns of its own block, and no clean row a
+noisy column (``_bhqk_visibility`` states it, ``_tile_visibility`` applies it
+to a tile). It is not a band: a noisy q block visits a run of clean kv blocks
+**and then** its own noisy ones, so what the kernels and the index maps ask
+is a list (``kv_at``, ``visits``, ``kv_index``, ``kv_width``), which ``_Band``
+answers for a band and ``_DiffusionBlocks`` for this mask. The forward kernel
+and the one-kernel backward pass walk either; the two-kernel split walks
+bands only and refuses this mask by name.
+
 The row statistics of the online softmax (``m``, ``l`` and the rescale
 ``alpha``; ``lse`` and ``di`` in the backward kernels) are kept
 lane-replicated, (rows, 128), in the kernels as in their buffers
@@ -89,14 +102,25 @@ NEG = -1e30
 
 
 def _bhqk_visibility(Tq: int, Tk: int, causal: bool, kv_mask,
-                     window: int = 0):
+                     window: int = 0, diffusion=None):
     """(…, Tq, Tk)-broadcastable bool visibility for full-tile jnp paths
     ((B,H,Tq,Tk) score layouts), or None when everything is visible. The
     ONE implementation shared by _reference and the ring's jnp tile/bwd
     fallbacks — these must stay numerically identical to each other (and
-    to the kernels' per-tile _tile_visibility)."""
+    to the kernels' per-tile _tile_visibility). ``diffusion`` = (L, B) is
+    the block-diffusion visibility over ``[clean ‖ noisy]``, 2L positions
+    in blocks of B (``flash_attention``), written here as it is stated."""
     vis = None
-    if causal or window:
+    if diffusion is not None:
+        L, B = diffusion
+        rows = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 1)
+        row_noisy, col_noisy = rows >= L, cols >= L
+        rb, cb = rows % L // B, cols % L // B
+        vis = jnp.where(
+            col_noisy, jnp.logical_and(row_noisy, cb == rb),
+            jnp.where(row_noisy, cb < rb, cb <= rb))[None, None]
+    elif causal or window:
         rows = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 1)
         vis = cols <= rows
@@ -110,21 +134,22 @@ def _bhqk_visibility(Tq: int, Tk: int, causal: bool, kv_mask,
 
 
 def _reference(q, k, v, causal: bool = False, kv_mask=None,
-               window: int = 0):
+               window: int = 0, diffusion=None):
     """Fused jnp attention, the numerics ground truth for the kernels.
     ``causal`` masks col > row (self-aligned square tiles); ``window``
-    also masks col <= row - window; ``kv_mask`` (B, Tk), nonzero = attend,
-    masks key/value columns. ``k``/``v`` with fewer heads than ``q`` are
-    shared by groups of query heads. Rows with no visible key (possible
-    under kv_mask) output exactly 0 — the multiplicative-mask convention
-    the kernels implement."""
+    also masks col <= row - window; ``diffusion`` = (L, B) is the
+    block-diffusion visibility in their place; ``kv_mask`` (B, Tk),
+    nonzero = attend, masks key/value columns. ``k``/``v`` with fewer heads
+    than ``q`` are shared by groups of query heads. Rows with no visible
+    key (possible under kv_mask) output exactly 0 — the
+    multiplicative-mask convention the kernels implement."""
     group = q.shape[2] // k.shape[2]
     if group > 1:
         k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     vis = _bhqk_visibility(s.shape[-2], s.shape[-1], causal, kv_mask,
-                           window)
+                           window, diffusion)
     if vis is not None:
         s = jnp.where(vis, s, NEG)
     p = jax.nn.softmax(s, axis=-1)
@@ -136,13 +161,26 @@ def _reference(q, k, v, causal: bool = False, kv_mask=None,
 
 
 def _tile_visibility(s_shape, q_blk: int, kv_blk: int, causal: bool,
-                     mask_row, window: int = 0):
+                     mask_row, window: int = 0, diffusion=None):
     """(bq, bk) bool visibility for one tile, or None when everything is
     visible. ``q_blk``/``kv_blk`` are the block indices of the tile;
-    ``mask_row`` is the (1, bk) f32 kv-mask slab or None."""
+    ``mask_row`` is the (1, bk) f32 kv-mask slab or None. Under
+    ``diffusion`` = (L, B) a tile lies in one half by its rows and in one
+    by its columns (``_DiffusionBlocks`` sees to it), so which rule holds is
+    two scalars: a clean row sees the clean blocks up to its own, a noisy
+    row those before its own, and of the noisy columns its own block."""
     bq, bk = s_shape
     vis = None
-    if causal:
+    if diffusion is not None:
+        L, B = diffusion
+        row_noisy, col_noisy = q_blk * bq // L, kv_blk * bk // L
+        rb = (q_blk * bq - row_noisy * L + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, bk), 0)) // B
+        cb = (kv_blk * bk - col_noisy * L + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, bk), 1)) // B
+        vis = jnp.logical_and(cb <= rb - row_noisy * (1 - col_noisy),
+                              cb >= rb * col_noisy)
+    elif causal:
         rows = q_blk * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cols = kv_blk * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         vis = cols <= rows
@@ -185,6 +223,22 @@ class _Band(NamedTuple):
             return 0 * j + self.n_k - 1
         return _least((j * self.bq + self.bq - 1) // self.bk, self.n_k - 1)
 
+    # the list a q block visits, as the kernels and the index maps ask it
+    diffusion = None
+
+    def kv_at(self, j, t):
+        """The kv block of step ``t`` of q block ``j``."""
+        return self.kv_lo(j) + t
+
+    def visits(self, j, t, kb):
+        """Is step ``t`` of q block ``j`` (its kv block ``kb``) a visit?"""
+        return kb <= self.kv_hi(j)
+
+    def kv_index(self, j, t):
+        """``kv_at`` for an index map: a step past the list's end repeats
+        the last block, so nothing is copied for it."""
+        return _least(self.kv_lo(j) + t, self.kv_hi(j))
+
     def q_lo(self, jk):
         if not self.causal:
             return 0 * jk
@@ -218,6 +272,94 @@ class _Band(NamedTuple):
                 kv_blk * self.bk <= q_blk * self.bq + self.bq - 1
                 - self.window)
         return crosses
+
+
+def _pick(cond, a, b):
+    """``a if cond else b`` on block indices, Python ints or traced."""
+    if isinstance(cond, (bool, int)):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+class _DiffusionBlocks(NamedTuple):
+    """``_Band``'s part for the block-diffusion visibility: ``half`` clean
+    positions and their ``half`` noisy copies after them, in diffusion
+    blocks of ``size``. The kv blocks a q block visits are not one run: a
+    clean q block visits the clean kv blocks up to the one its last row's
+    block ends in; a noisy one the clean kv blocks that hold a block before
+    its last row's, **and then** the noisy kv blocks its own rows' blocks
+    lie in (one tile where the tiles are square). Step ``t`` of q block
+    ``j`` is found by the same arithmetic on block indices, so it serves
+    program ids, index maps and the static grid width as ``_Band``'s does.
+    A tile lies in one half each way: ``bq`` and ``bk`` divide ``half``.
+    The noisy run comes last and holds every noisy row's own position, so a
+    row that saw nothing in the clean run (the first block's) is wiped
+    clean by it, as a window's first tile is by the diagonal's."""
+
+    half: int
+    size: int
+    bq: int
+    bk: int
+    n_q: int
+    n_k: int
+
+    causal = True   # it has edges: a tile may need the mask arithmetic
+    window = 0
+
+    @property
+    def diffusion(self):
+        return (self.half, self.size)
+
+    def _runs(self, j):
+        """(clean kv blocks visited, from block 0; the first noisy kv block
+        visited; how many of those)."""
+        q_half, k_half = self.half // self.bq, self.half // self.bk
+        noisy = j // q_half                   # 0 a clean q block, 1 a noisy
+        first = (j - noisy * q_half) * self.bq   # its rows, in their half
+        last = first + self.bq - 1
+        # the clean columns a row sees end where its block ends (a clean
+        # row) or starts (a noisy one)
+        reach = (last // self.size + 1 - noisy) * self.size
+        n_clean = _least(-(-reach // self.bk), k_half)
+        lo = k_half + first // self.size * self.size // self.bk
+        hi = k_half + _least(
+            ((last // self.size + 1) * self.size - 1) // self.bk, k_half - 1)
+        return n_clean, lo, noisy * (hi - lo + 1)
+
+    def kv_count(self, j):
+        n_clean, _, n_noisy = self._runs(j)
+        return n_clean + n_noisy
+
+    def kv_at(self, j, t):
+        n_clean, lo, _ = self._runs(j)
+        return _pick(t < n_clean, t, lo + t - n_clean)
+
+    def visits(self, j, t, kb):
+        del kb
+        return t < self.kv_count(j)
+
+    def kv_index(self, j, t):
+        return self.kv_at(j, _least(t, self.kv_count(j) - 1))
+
+    @property
+    def kv_width(self) -> int:
+        return max(self.kv_count(j) for j in range(self.n_q))
+
+    def edge_crosses(self, q_blk, kv_blk):
+        """Is some pair of this visited tile hidden? Clean columns: the
+        tile's last column lies past what its first row sees. Noisy
+        columns: rows and columns are not all of one diffusion block."""
+        q_half, k_half = self.half // self.bq, self.half // self.bk
+        row_noisy, col_noisy = q_blk // q_half, kv_blk // k_half
+        first = (q_blk - row_noisy * q_half) * self.bq
+        col = (kv_blk - col_noisy * k_half) * self.bk
+        block = first // self.size
+        clean = col + self.bk - 1 >= (block + 1 - row_noisy) * self.size
+        noisy = jnp.logical_or(
+            (first + self.bq - 1) // self.size != block,
+            jnp.logical_or(col // self.size != block,
+                           (col + self.bk - 1) // self.size != block))
+        return jnp.where(col_noisy > 0, noisy, clean)
 
 
 def _band_dispatch(band: _Band, has_mask: bool, q_blk, kv_blk, visible,
@@ -255,8 +397,9 @@ def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, band: _Band,
     their scratch is (``_lanes``). The final step also writes the row
     logsumexp (lane-broadcast) — the backward's residual.
 
-    Step ``t`` of q block ``j`` is kv block ``kv_lo(j) + t``; steps past
-    ``kv_hi(j)`` (a q block whose band is narrower than the widest) do
+    Step ``t`` of q block ``j`` is kv block ``band.kv_at(j, t)`` (a band's
+    ``kv_lo(j) + t``); steps past the end of its list (a q block whose band
+    is narrower than the widest: past ``kv_hi(j)``) do
     nothing, and their index map repeats the last block, so nothing is
     copied for them either. ``has_mask`` threads a (1, bk) kv-mask slab
     applied multiplicatively to p, so fully-masked rows accumulate exact
@@ -268,7 +411,7 @@ def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, band: _Band,
         mask_ref = None
     j = pl.program_id(1)
     t = pl.program_id(2)
-    kb = band.kv_lo(j) + t
+    kb = band.kv_at(j, t)
 
     @pl.when(t == 0)
     def _init():
@@ -282,6 +425,7 @@ def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, band: _Band,
         vis = _tile_visibility(
             s.shape, j, kb, band.causal,
             mask_ref[0, 0:1, :] if has_mask else None, band.window,
+            band.diffusion,
         ) if masked else None
         if vis is not None:
             s = jnp.where(vis, s, NEG)
@@ -305,7 +449,7 @@ def _kernel(q_ref, k_ref, v_ref, *rest, scale: float, band: _Band,
         m_ref[:] = m_new
         l_ref[:] = l_new
 
-    _band_dispatch(band, has_mask, j, kb, kb <= band.kv_hi(j), _compute)
+    _band_dispatch(band, has_mask, j, kb, band.visits(j, t, kb), _compute)
 
     @pl.when(t == width - 1)
     def _finalize():
@@ -421,8 +565,28 @@ def _check_heads(q, k, v):
     return H // KV
 
 
+def _visit_lists(T: int, bq: int, bk: int, causal: bool, window: int,
+                 diffusion):
+    """Which kv blocks each q block visits: a band, or the two runs of the
+    block-diffusion visibility. That one is never silently a band: a shape
+    its lists are not written for is refused by name."""
+    if diffusion is None:
+        return _Band(causal or bool(window), window, bq, bk, T // bq,
+                     T // bk)
+    half, size = diffusion
+    if causal or window or T != 2 * half or half % size or (
+            half % bq or half % bk):
+        raise ValueError(
+            f"flash attention: the block-diffusion mask {diffusion} over "
+            f"{T} positions in ({bq}, {bk}) tiles: it takes 2 x {half} "
+            "positions in whole blocks, tiles that divide a half, and "
+            "neither causal nor a window beside it")
+    return _DiffusionBlocks(half, size, bq, bk, T // bq, T // bk)
+
+
 def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
-                   interpret: bool, causal: bool = False, window: int = 0):
+                   interpret: bool, causal: bool = False, window: int = 0,
+                   diffusion=None):
     """Returns (out, lse) — lse is None on the interpreted-under-shard_map
     jnp detour."""
     B, T, H, D = q.shape
@@ -430,18 +594,17 @@ def _flash_forward(q, k, v, kv_mask=None, *, block_q: int, block_k: int,
     scale = 1.0 / np.sqrt(D)
     if _interpreted_under_shard_map(q, interpret):
         return _reference(q, k, v, causal=causal, kv_mask=kv_mask,
-                          window=window), None
+                          window=window, diffusion=diffusion), None
     bq, bk, d_pad, _ = _unpadded_plan(
         q.shape, block_q, block_k, kv_mask is not None)
     dv_pad = _round_up(v.shape[-1], LANE)  # values on their own width
     qf, kf, vf = _fold(q, d_pad), _fold(k, d_pad), _fold(v, dv_pad)
-    band = _Band(causal or bool(window), window, bq, bk, T // bq, T // bk)
+    band = _visit_lists(T, bq, bk, causal, window, diffusion)
     width = band.kv_width
     grid = (B * H, band.n_q, width)  # the band innermost: sequential carry
     has_mask = kv_mask is not None
 
-    def kv_block(j, t):
-        return _least(band.kv_lo(j) + t, band.kv_hi(j))
+    kv_block = band.kv_index
 
     def kv_spec(d):
         return pl.BlockSpec(
@@ -497,7 +660,7 @@ def _tile_p(q, kb, lse, q_blk, kv_blk, scale, band: _Band, mask_row,
     the multiplicative mask."""
     s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
     vis = _tile_visibility(s.shape, q_blk, kv_blk, band.causal, mask_row,
-                           band.window) if masked else None
+                           band.window, band.diffusion) if masked else None
     if vis is not None:
         s = jnp.where(vis, s, NEG)
     p = jnp.exp(s - _lanes(lse, s.shape[1]))
@@ -518,7 +681,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *rest,
         mask_ref = None
     j = pl.program_id(1)
     t = pl.program_id(2)
-    kb = band.kv_lo(j) + t
+    kb = band.kv_at(j, t)
 
     @pl.when(t == 0)
     def _init():
@@ -535,7 +698,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *rest,
         dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
                              preferred_element_type=jnp.float32)
 
-    _band_dispatch(band, has_mask, j, kb, kb <= band.kv_hi(j), _compute)
+    _band_dispatch(band, has_mask, j, kb, band.visits(j, t, kb), _compute)
 
     @pl.when(t == width - 1)
     def _finalize():
@@ -604,7 +767,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *rest,
     h = pl.program_id(1)    # query head of the group
     j = pl.program_id(2)
     t = pl.program_id(3)
-    kb = band.kv_lo(j) + t
+    kb = band.kv_at(j, t)
     bk = band.bk
 
     def kv_rows(blk):
@@ -648,7 +811,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *rest,
         dq_acc[:] += jnp.dot(ds.astype(k.dtype), k,
                              preferred_element_type=jnp.float32)
 
-    _band_dispatch(band, has_mask, j, kb, kb <= band.kv_hi(j), _compute)
+    _band_dispatch(band, has_mask, j, kb, band.visits(j, t, kb), _compute)
 
     @pl.when(t == width - 1)
     def _finalize():
@@ -686,7 +849,7 @@ def _backward_fits(T: int, d_pad: int, dv_pad: int, itemsize: int) -> bool:
 
 def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
                     block_k: int, interpret: bool, causal: bool = False,
-                    window: int = 0):
+                    window: int = 0, diffusion=None):
     """(dq, dk, dv): one kernel (``flash_bwd``) where a key-value head's
     whole-sequence dk / dv fit its VMEM budget (``_backward_fits``), the
     dQ kernel and the dK/dV kernel (``flash_dq``, ``flash_dkv``) where they
@@ -707,8 +870,15 @@ def _flash_backward(q, k, v, o, lse, g, kv_mask=None, *, block_q: int,
                 axis=-1, keepdims=True),
         (B * H, T, LANE),
     )
-    band = _Band(causal or bool(window), window, bq, bk, T // bq, T // bk)
+    band = _visit_lists(T, bq, bk, causal, window, diffusion)
     fused = _backward_fits(T, d_pad, dv_pad, k.dtype.itemsize)
+    if diffusion is not None and not fused:
+        # a clean kv block is seen by two runs of q blocks (its own half's
+        # and the noisy half's): the dK/dV kernel's ``q_lo..q_hi`` is one
+        raise NotImplementedError(
+            f"flash attention: the block-diffusion mask {diffusion} over "
+            f"{T} positions is past the one-kernel backward pass's carry "
+            "(_backward_fits), and the dQ and dK/dV kernels walk bands only")
     dq, dk, dv = (_one_kernel_backward if fused else _two_kernel_backward)(
         qf, kf, vf, gf, lse, di, kv_mask, band=band, group=group,
         scale=scale, interpret=interpret)
@@ -726,8 +896,7 @@ def _one_kernel_backward(qf, kf, vf, gf, lse, di, kv_mask, *, band: _Band,
     has_mask = kv_mask is not None
     width = band.kv_width
 
-    def kv_block(j, t):
-        return _least(band.kv_lo(j) + t, band.kv_hi(j))
+    kv_block = band.kv_index
 
     def q_spec(d):
         return pl.BlockSpec(
@@ -789,8 +958,7 @@ def _two_kernel_backward(qf, kf, vf, gf, lse, di, kv_mask, *, band: _Band,
     has_mask = kv_mask is not None
     kparams = dict(scale=scale, band=band, has_mask=has_mask)
 
-    def kv_block(j, t):
-        return _least(band.kv_lo(j) + t, band.kv_hi(j))
+    kv_block = band.kv_index
 
     def q_spec(d):
         return pl.BlockSpec((1, bq, d), lambda i, j, t: (i, j, 0),
@@ -880,31 +1048,34 @@ def _resolve_interpret(interpret):
     return interpret
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, kv_mask, block_q, block_k, interpret, causal, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, kv_mask, block_q, block_k, interpret, causal, window,
+           diffusion=None):
     out, _ = _flash_forward(
         q, k, v, kv_mask, block_q=block_q, block_k=block_k,
         interpret=_resolve_interpret(interpret), causal=causal,
-        window=window,
+        window=window, diffusion=diffusion,
     )
     return out
 
 
-def _fwd(q, k, v, kv_mask, block_q, block_k, interpret, causal, window):
+def _fwd(q, k, v, kv_mask, block_q, block_k, interpret, causal, window,
+         diffusion):
     out, lse = _flash_forward(
         q, k, v, kv_mask, block_q=block_q, block_k=block_k,
         interpret=_resolve_interpret(interpret), causal=causal,
-        window=window,
+        window=window, diffusion=diffusion,
     )
     return out, (q, k, v, kv_mask, out, lse)
 
 
-def _bwd(block_q, block_k, interpret, causal, window, res, g):
+def _bwd(block_q, block_k, interpret, causal, window, diffusion, res, g):
     q, k, v, kv_mask, o, lse = res
     if lse is None:  # forward took the interpreted-under-shard_map detour
         _, vjp = jax.vjp(
             lambda a, b, c: _reference(a, b, c, causal=causal,
-                                       kv_mask=kv_mask, window=window),
+                                       kv_mask=kv_mask, window=window,
+                                       diffusion=diffusion),
             q, k, v,
         )
         dq, dk, dv = vjp(g)
@@ -912,7 +1083,7 @@ def _bwd(block_q, block_k, interpret, causal, window, res, g):
         dq, dk, dv = _flash_backward(
             q, k, v, o, lse, g, kv_mask, block_q=block_q, block_k=block_k,
             interpret=_resolve_interpret(interpret), causal=causal,
-            window=window,
+            window=window, diffusion=diffusion,
         )
     dm = None if kv_mask is None else jnp.zeros_like(kv_mask)
     return dq, dk, dv, dm
@@ -923,7 +1094,7 @@ _flash.defvjp(_fwd, _bwd)
 
 def flash_attention(q, k, v, block_q: int = 128, block_k: int = 128,
                     interpret: bool | None = None, *, causal: bool = False,
-                    kv_mask=None, window: int = 0):
+                    kv_mask=None, window: int = 0, diffusion=None):
     """(B, T, H, D) attention as a Pallas TPU kernel (fwd + bwd). ``v`` may
     be of another width than ``q`` and ``k`` (``o`` is of ``v``'s); the
     scale is ``1 / sqrt(q.shape[-1])``.
@@ -941,12 +1112,35 @@ def flash_attention(q, k, v, block_q: int = 128, block_k: int = 128,
     with the padded keys masked and the padded rows sliced away — AD of
     the pad/slice keeps the gradients exact. No analog in the reference
     (attention-free CNN, SURVEY.md §5.7); the causal/masked forms cover
-    the decoder workloads the ring-parallel long-context path implies."""
+    the decoder workloads the ring-parallel long-context path implies.
+
+    ``diffusion`` = (L, B) is a third visibility, in place of ``causal``
+    and ``window``: the sequence is ``[clean ‖ noisy]``, L positions and
+    their L noised copies, in blocks of B; with ``b(i) = (i mod L) // B``,
+    a clean row sees the clean columns of blocks ``<= b(i)``, a noisy row
+    the clean columns of blocks ``< b(i)`` and the noisy columns of block
+    ``b(i)``, and no clean row a noisy column (block-diffusion training,
+    arXiv:2503.09573). Only the tiles that hold a visible pair are visited
+    (``_DiffusionBlocks``); the tiles must divide L."""
     B, T = q.shape[:2]
     window = int(window or 0)
     causal = bool(causal or window)
     if kv_mask is not None:
         kv_mask = kv_mask.astype(jnp.float32)
+    if diffusion is not None:
+        # tiles are planned on a half, so that none lies across both: a
+        # short sequence runs as one whole-half block each way
+        diffusion = tuple(int(n) for n in diffusion)
+        plan = _plan((B, diffusion[0]) + q.shape[2:], block_q, block_k,
+                     kv_mask is not None)
+        if plan.t_pad != diffusion[0]:
+            raise ValueError(
+                f"flash attention: blocks ({block_q}, {block_k}) do not "
+                f"tile a half of the block-diffusion mask {diffusion}, and "
+                "it takes no padding")
+        _visit_lists(T, plan.bq, plan.bk, causal, window, diffusion)
+        return _flash(q, k, v, kv_mask, plan.bq, plan.bk, interpret, causal,
+                      window, diffusion)
     plan = _plan(q.shape, block_q, block_k, kv_mask is not None)
     if plan.t_pad == T:
         return _flash(q, k, v, kv_mask, plan.bq, plan.bk, interpret, causal,

@@ -170,7 +170,11 @@ def make_train_step(
     ``task`` (``train/tasks.py``) says which array of the batch the model
     reads and which loss it takes: an image classifier's ``loss_fn`` over
     ``label``, or a decoder's masked next-token loss over its own
-    ``tokens``. Everything else in the step is the same step.
+    ``tokens``. A task with ``prepare`` has its batch made ready here, in
+    the step, from a key folded from ``augment_seed``, ``state.step`` and
+    the shard as ``augment``'s is (block diffusion's noise: new every step,
+    the same for the same three). Everything else in the step is the same
+    step.
 
     ``accum_steps`` > 1 makes the ONE optimizer step over a global batch
     too large to activate at once: each shard splits its rows into
@@ -347,10 +351,14 @@ def make_train_step(
         # Trainer's program map reads it back from the compiled program,
         # and a device trace is split by phase and module from that map.
         with jax.named_scope(INPUT_SCOPE):
-            if augment or mixup_alpha > 0:
+            if augment or mixup_alpha > 0 or task.prepare is not None:
                 key = jax.random.fold_in(
                     jax.random.key(augment_seed), state.step)
                 key = jax.random.fold_in(key, lax.axis_index(data_axis))
+            if task.prepare is not None:
+                # the task's own draw (block diffusion's noise), a stream
+                # apart from crop/flip's and mixup's
+                batch = task.prepare(jax.random.fold_in(key, 2), batch)
             if augment:
                 from tpu_ddp.data.augment import random_crop_flip
 
@@ -514,6 +522,11 @@ def make_eval_step(
 
     def shard_eval(state: TrainState, batch: Batch):
         variables = {"params": state.params, "batch_stats": state.batch_stats}
+        if task.prepare is not None:
+            # one fixed draw a shard, so that two evaluations compare
+            with jax.named_scope(INPUT_SCOPE):
+                batch = task.prepare(jax.random.fold_in(
+                    jax.random.key(0), lax.axis_index(data_axis)), batch)
         with jax.named_scope(FORWARD_SCOPE):
             logits = model.apply(variables, batch[task.input_key],
                                  train=False)

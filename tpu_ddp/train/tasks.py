@@ -10,9 +10,20 @@ batch into the scalar the step differentiates. A model names its task by a
     image classification   {"image", "label", "mask"}        ``loss_fn(logits, label, mask)``
     next-token prediction  {"tokens", "loss_mask", "mask"}   masked next-token NLL
     ... with a prediction module   (the same keys)           ``L_next + weight * L_mtp``
+    block diffusion                (the same keys)           1/t-weighted masked NLL
 
 A loss that is a sum of several terms hands each to the step beside the
 sum, by name, and the step reports each in its metrics.
+
+A task may also **prepare** its batch inside the step (``Task.prepare``):
+what happens to a batch between the loader and the model that has to be
+drawn anew every step, on the device. ``block_diffusion`` draws its noise
+there: for every row and block of ``B`` tokens a level ``t ~ U(t_min, 1]``,
+every token of the block masked with probability ``t``, and the model's
+input ``[x ‖ x~]``, the clean sequence and the noised copy after it. Its
+loss is the cross-entropy of token ``i`` at position ``i`` of the noisy
+half (unshifted), at the masked positions only, each weighted ``1 / t``,
+over the count of real target positions (BD3-LMs, arXiv:2503.09573).
 
 The step builders (``train/steps.py``) take a ``Task`` and know no other
 difference between the two: zero1, accumulation, recomputation, health and
@@ -23,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Callable, Optional
 
 import jax
@@ -51,6 +63,14 @@ class Task:
     #: ``--synthetic-data``, sized by the built model; None for an image
     #: classifier, whose synthetic sets ``load_dataset`` has always made
     synthetic: Optional[Callable] = None
+    #: ``(key, batch) -> batch``: what the step does to a shard's batch
+    #: before the model sees it, under ``tpu_ddp.input``, from a key folded
+    #: from the run's seed, the step and the shard (new every step, the same
+    #: for the same three); None for a task that feeds what the loader made
+    prepare: Optional[Callable] = None
+    #: positions of the sequence ``model.init`` is shown; None = the data's,
+    #: cut to 16
+    example_positions: Optional[int] = None
 
     def example_input(self, inputs):
         """What ``model.init`` is shown, from the training set's first array:
@@ -59,7 +79,8 @@ class Task:
         (``train/state.py::init_model_variables``)."""
         if not self.sequence:
             return None
-        return jnp.zeros((1, min(inputs.shape[1], 16)), inputs.dtype)
+        positions = self.example_positions or min(inputs.shape[1], 16)
+        return jnp.zeros((1, positions), inputs.dtype)
 
 
 def _classification_loss(loss_fn, logits, batch):
@@ -102,10 +123,66 @@ def _next_token_mtp_loss(weight, loss_fn, outputs, batch):
             {"loss_next": loss_next, "loss_mtp": loss_mtp})
 
 
+def block_noise(settings, key, batch):
+    """A batch of ``block_diffusion`` as the model and the loss take it:
+    ``tokens`` becomes ``[x ‖ x~]`` (n, 2L), and beside it the clean
+    ``block_targets`` (n, L), ``block_masked`` (n, L) bool and the level of
+    each position's block ``block_t`` (n, L) float32. One level a row and
+    block, ``1 - u (1 - t_min)`` with ``u ~ U[0, 1)``; a position is masked
+    where its own uniform draw lies under its block's level."""
+    from tpu_ddp.telemetry.phases import module_scope
+
+    tokens = batch["tokens"]
+    n, length = tokens.shape
+    if length % settings.block:
+        raise ValueError(f"sequences of {length} tokens are not whole "
+                         f"blocks of {settings.block}")
+    with jax.named_scope(module_scope("block_noise")):
+        level_key, mask_key = jax.random.split(key)
+        u = jax.random.uniform(level_key, (n, length // settings.block),
+                               jnp.float32)
+        t = jnp.repeat(1.0 - u * (1.0 - settings.t_min), settings.block,
+                       axis=1)
+        masked = jax.random.uniform(mask_key, (n, length), jnp.float32) < t
+        noisy = jnp.where(masked, jnp.asarray(settings.mask_id, tokens.dtype),
+                          tokens)
+        return dict(batch, tokens=jnp.concatenate([tokens, noisy], axis=1),
+                    block_targets=tokens, block_masked=masked, block_t=t)
+
+
+def block_diffusion_loss(logits, batch):
+    """``sum(m * w * CE(logits_i, x_i) / t) / max(sum(w), 1)`` over a
+    prepared batch, ``w`` the real target positions (``loss_mask`` in the
+    rows ``mask`` keeps), in float32; and beside it the plain mean NLL at
+    the masked positions and the share of real positions that were masked."""
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    nll = -jnp.take_along_axis(
+        logp, batch["block_targets"][..., None], axis=-1)[..., 0]
+    w = batch["loss_mask"].astype(jnp.float32)
+    if batch.get("mask") is not None:
+        w = w * batch["mask"][:, None].astype(jnp.float32)
+    masked = w * batch["block_masked"].astype(jnp.float32)
+    loss = jnp.sum(masked * nll / batch["block_t"]) / jnp.maximum(
+        jnp.sum(w), 1.0)
+    return loss, {
+        "nll_masked": jnp.sum(masked * nll) / jnp.maximum(
+            jnp.sum(masked), 1.0),
+        "masked_share": jnp.sum(masked) / jnp.maximum(jnp.sum(w), 1.0)}
+
+
+def _block_diffusion_loss(loss_fn, logits, batch):
+    del loss_fn  # a classifier's
+    return block_diffusion_loss(logits, batch)
+
+
 def _synthetic_tokens(model, size, seed):
     from tpu_ddp.data.tokens import synthetic_tokens
 
-    return synthetic_tokens(size, model.spec.vocab_rows, seed)
+    # a block-diffusion model's data never holds its mask token: the ids
+    # under it (the spec puts the mask on the last row held)
+    diffusion = getattr(model.spec, "diffusion", None)
+    vocab = model.spec.vocab_rows if diffusion is None else diffusion.mask_id
+    return synthetic_tokens(size, vocab, seed)
 
 
 IMAGE_CLASSIFICATION = Task("image_classification", "image", "label",
@@ -124,8 +201,22 @@ def next_token_mtp(weight: float) -> Task:
         loss=functools.partial(_next_token_mtp_loss, weight))
 
 
+def block_diffusion(settings) -> Task:
+    """Block-diffusion training by ``settings`` (a model's
+    ``DecoderSpec.diffusion``: ``block``, ``mask_id``, ``t_min``):
+    ``NEXT_TOKEN``'s batch, noised and doubled in the step; logits of the
+    noisy half out; the weighted loss with its two readings named."""
+    return dataclasses.replace(
+        NEXT_TOKEN, name="block_diffusion", loss=_block_diffusion_loss,
+        prepare=functools.partial(block_noise, settings),
+        # [clean, noisy] of whole blocks, a half a kernel's tile can hold
+        example_positions=2 * math.lcm(settings.block, 8))
+
+
 def task_of(model) -> Task:
     name = getattr(model, "task", IMAGE_CLASSIFICATION.name)
     if name == "next_token_mtp":  # the second term's weight is the model's
         return next_token_mtp(model.spec.mtp_weight)
+    if name == "block_diffusion":  # block, mask and least level: the model's
+        return block_diffusion(model.spec.diffusion)
     return TASKS[name]
