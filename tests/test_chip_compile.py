@@ -314,26 +314,29 @@ def test_a_recomputed_expert_block_walks_its_routed_path_twice(
     assert expert_block_step.as_text().count(" conditional(") == 2
 
 
+def _flash_impl():
+    import functools
+
+    from tpu_ddp.ops.flash_attention import flash_attention
+
+    return functools.partial(flash_attention, block_q=512, block_k=512,
+                             interpret=False)
+
+
 @pytest.fixture(scope="module")
 def sparse_layer_step(topo):
     """Value and gradient of one sparse layer of ``joyai_llm_flash`` at the
     cell's widths (16,384 tokens, latent attention through the flash
     kernels, 8 of 256 experts with a selection bias, 16 held), recomputed
-    without a policy as ``SparseDecoder`` recomputes it, compiled for one
-    described chip."""
-    import functools
-
-    import flax.linen as nn
-
-    from tpu_ddp.models.decoder import DecoderLayer, joyai_llm_flash_spec
-    from tpu_ddp.ops.flash_attention import flash_attention
+    as ``SparseDecoder`` recomputes it (``decoder.recomputed``: all but
+    ``KEPT_NAMES``), compiled for one described chip."""
+    from tpu_ddp.models.decoder import (DecoderLayer, joyai_llm_flash_spec,
+                                        recomputed)
 
     one = _one_chip(topo)
     spec = joyai_llm_flash_spec(num_layers=2, experts_held=16, vocab_rows=512)
-    layer = nn.remat(DecoderLayer)(
-        spec.mtp, spec, dtype=jnp.bfloat16,
-        attention_impl=functools.partial(
-            flash_attention, block_q=512, block_k=512, interpret=False))
+    layer = recomputed(DecoderLayer)(
+        spec.mtp, spec, dtype=jnp.bfloat16, attention_impl=_flash_impl())
     cos, sin = spec.mtp.rotary.tables(8192)
     shapes = jax.eval_shape(lambda: layer.init(
         jax.random.key(0), jnp.zeros((1, 256, spec.hidden), jnp.bfloat16),
@@ -352,21 +355,22 @@ def sparse_layer_step(topo):
         params, x).compile()
 
 
-@pytest.mark.parametrize("step,sorts,temporaries", [
-    ("expert_block_step", 1, 2.95e9), ("sparse_layer_step", 2, 4.0e9),
+@pytest.mark.parametrize("step,temporaries", [
+    ("expert_block_step", 2.95e9), ("sparse_layer_step", 3.7e9),
 ], ids=["nemotron3_super", "joyai_llm_flash"])
 def test_a_recomputed_expert_block_makes_its_routers_choice_once(
-        step, sorts, temporaries, request):
-    """``HybridDecoder`` keeps the router's float32 logits, the chosen ids
-    and their scores too (``moe.LOGITS_NAME``, ``IDS_NAME``,
-    ``SCORES_NAME``: 33.5 MB and twice 1.4 MB a block), so the backward pass
-    makes none of the six-pass product and the ``top_k`` again. The TPU
-    compiler writes ``lax.top_k`` of 22 over 512 as a whole ``sort`` of the
-    (16,384, 512) scores with their places and a slice, and leaves ``top_k``
-    in its ``op_name``: one, the forward pass's, and nothing of the router's
-    product under ``rematted_computation``. ``SparseDecoder`` recomputes a
-    layer without a policy, so its router sorts twice. In both, the chosen
-    scores are read and differentiated as compares against the expert axis
+        step, temporaries, request):
+    """Both decoder stacks keep the router's float32 logits, the chosen ids
+    and their scores (``moe.LOGITS_NAME``, ``IDS_NAME``, ``SCORES_NAME`` of
+    ``decoder.KEPT_NAMES``: 33.5 MB and twice 1.4 MB a block of the hybrid
+    stack), so the backward pass makes none of the six-pass product and the
+    ``top_k`` again. The TPU compiler writes ``lax.top_k`` of 22 over 512
+    (8 over 256) as a whole ``sort`` of the (16,384, experts) scores with
+    their places and a slice, and leaves ``top_k`` in its ``op_name``: one,
+    the forward pass's, and nothing of the router's product under
+    ``rematted_computation`` (``SparseDecoder`` recomputed a layer without
+    a policy until PR 42, and its router sorted twice). The chosen scores
+    are read and differentiated as compares against the expert axis
     (``moe._chosen``): no gather, no scatter and no further sort under
     ``moe_route`` anywhere in the step, and nothing (tokens, choices,
     experts) wide is written (184 M and 34 M places): the hybrid pair of
@@ -378,17 +382,70 @@ def test_a_recomputed_expert_block_makes_its_routers_choice_once(
               if re.search(r'op_name="[^"]*moe_route/', line)]
     assert routed
     sorted_ = [line for line in routed if " sort(" in line]
-    assert len(sorted_) == sorts
+    assert len(sorted_) == 1
     assert all("moe_route/top_k" in line for line in sorted_)
-    # a stack that sorts again makes the product again, and only that one
-    assert bool(re.search(r"rematted_computation[^\"]*moe_route/router",
-                          text)) == (sorts > 1)
+    assert not re.search(r"rematted_computation[^\"]*moe_route/router", text)
     # neither the instructions nor what the compiler made of their parts
     assert not [line for line in routed if re.search(
         r' (gather|scatter)\(|op_name="[^"]*(gather|scatter)', line)]
     wide = re.compile(r"\[16384,(8|22),(256|512)\]\{[^}]*\} fusion\(")
     assert not wide.search(text)
     assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+
+
+def _flash_calls(text: str) -> dict:
+    """{kernel: custom calls} of the program's flash kernels in a compiled
+    text, by the scope each call's ``op_name`` keeps."""
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and CUSTOM_CALL in line]
+    return {kernel: sum(f"tpu_ddp.kernel.{kernel}/" in line for line in calls)
+            for kernel in ("flash_fwd", "flash_bwd")}
+
+
+def test_a_recomputed_sparse_layer_calls_the_forward_kernel_once(
+        sparse_layer_step):
+    """A recomputed layer keeps its attention's output and a float32 a row
+    of the logsumexp (``flash_attention.OUT_NAME``, ``LSE_NAME``), so the
+    forward kernel's second call, which made them again for the backward
+    kernel, is dead code: one ``flash_fwd`` and one ``flash_bwd`` where a
+    bare ``nn.remat`` has two and one; and the temporaries are under the
+    3.97 GB of that (3.62 GB even with the statistics kept lane-broadcast;
+    ISSUE 42's table)."""
+    assert _flash_calls(sparse_layer_step.as_text()) == {
+        "flash_fwd": 1, "flash_bwd": 1}
+    assert sparse_layer_step.memory_analysis().temp_size_in_bytes < 3.7e9
+
+
+def test_a_recomputed_stack_keeps_a_float_a_row_of_its_statistics(topo):
+    """``laguna-xs2.seq8k``'s model (5 layers, 32 experts held, 2 sequences
+    of 8,192, bfloat16), value and gradient, compiled for one described
+    chip: five forward and five backward calls (ten and five without the
+    names), and temporaries of 3.64 GB beside the 3.60 GB of a bare
+    ``nn.remat``. With the logsumexp kept as the kernels hold it, 128 lanes
+    a row, they are 6.05 GB, which the cell's step has no room for: what
+    lives from pass to pass is (B*H, T) float32, and this is the test that
+    holds it there."""
+    from tpu_ddp.models.decoder import SparseDecoder, laguna_xs2_spec
+
+    one = _one_chip(topo)
+    model = SparseDecoder(
+        laguna_xs2_spec(num_layers=5, experts_held=32, vocab_rows=512),
+        dtype=jnp.bfloat16, attention_impl=_flash_impl(), remat=True)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 256), jnp.int32)))["params"]
+    params = jax.tree.map(lambda l: jax.ShapeDtypeStruct(
+        l.shape, l.dtype, sharding=one), shapes)
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one)
+
+    def loss(p, tokens):
+        logits, _ = model.apply({"params": p}, tokens, mutable=["counters"])
+        return logits.sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        params, tokens).compile()
+    assert _flash_calls(compiled.as_text()) == {"flash_fwd": 5,
+                                                "flash_bwd": 5}
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.9e9
 
 
 # ---- the int8 ring's quantize / dequantize ----------------------------------

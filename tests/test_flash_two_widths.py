@@ -76,18 +76,36 @@ def test_keys_of_another_width_than_queries_are_refused():
     assert _check_heads(q, k, v) == 1
 
 
-#: sha256 of the jaxpr of the function below at the parent commit (f4db89a,
-#: ``git archive``, this container's jax), source positions taken out: with
-#: one width the forward kernel, the dQ kernel and the dK/dV kernel, their
-#: grids, block shapes and scratch are what a single ``D`` made. The text is
-#: jax's, so another jax makes another. Since PR 40 a shape this small runs
-#: the one-kernel backward pass (``tests/test_flash_one_backward.py``); the
-#: pair the pin describes serves what that kernel's carry does not fit, and
-#: is asked for here as ``_backward_fits`` would for such a shape.
+#: sha256 of the three ``pallas_call`` equations (forward, dQ, dK/dV) in the
+#: jaxpr of the function below, each printed by itself, source positions
+#: taken out, read at 6673acc (PR 42's parent, ``git archive``, this
+#: container's jax) and equal there and here: with one width the kernels,
+#: their grids, block shapes and scratch are what a single ``D`` made
+#: (PR 36 pinned the whole text at f4db89a; the kernels' equations alone
+#: since PR 42, whose ``_fwd`` names the output and a float32 a row of the
+#: logsumexp and whose ``_bwd`` widens it again: equations between the calls
+#: that are no kernel's). The text is jax's, so another jax makes another.
+#: Since PR 40 a shape this small runs the one-kernel backward pass
+#: (``tests/test_flash_one_backward.py``); the pair the pin describes serves
+#: what that kernel's carry does not fit, and is asked for here as
+#: ``_backward_fits`` would for such a shape.
 PARENT = {
-    0: "7d82a9f688436ddd938cd2688388e9f9b9de812bac4bf00a3d4d1d95fd85064d",
-    8: "9e9b3fb95b4afc1213dcd3fa14159ea9ef6ec17f1fe6bda4d55ae18ff382d7fb",
+    0: "c37086a5d06218c3312b578e80e20c3dbd4730d95f063d0218abca51f13f63b5",
+    8: "880aa2d1adcb731ae043e22b58ce68f9df621ceb5918f1cab9dcaf9cad3b6369",
 }
+
+
+def _kernel_calls(jaxpr) -> list:
+    """Every ``pallas_call`` equation under ``jaxpr``, in order, each as
+    its own text."""
+    calls = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls.append(str(eqn))
+        else:
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                calls += _kernel_calls(inner)
+    return calls
 
 
 @pytest.mark.parametrize("window", list(PARENT))
@@ -104,6 +122,9 @@ def test_with_one_width_the_kernels_are_the_parents(window, monkeypatch):
         return jnp.sum(flash_attention(q, k, v, 16, 16, True, causal=True,
                                        window=window) ** 2)
 
-    text = str(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2)))(q, k, v))
-    text = re.sub(r" at [^\s\]]*flash_attention.py:\d+", "", text)
+    calls = _kernel_calls(
+        jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2)))(q, k, v).jaxpr)
+    assert len(calls) == 3
+    text = re.sub(r" at [^\s\]]*flash_attention.py:\d+", "",
+                  "\n".join(calls))
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT[window]

@@ -573,6 +573,12 @@ def test_the_cli_trains_the_published_model_by_name(devices, capsys):
 #: ``<dir>`` run ``python -c "import sys; sys.path.insert(0, 'tests');
 #: import hashlib, test_hybrid as t; [print(c, hashlib.sha256(
 #: t._lowered(c).encode()).hexdigest()) for c in t.PARENT]"``
+#: ``decoder_remat`` was read again on PR 42's tree (parent 6673acc), whose
+#: ``SparseDecoder`` recomputes a layer under ``decoder.KEPT_NAMES`` and no
+#: longer under a bare ``nn.remat``, and is the same: this decoder attends
+#: through the fused jnp reference, which names nothing, its router has no
+#: selection bias, and no backward rule reads a sparse layer's routed
+#: result, so the policy keeps nothing here and the text is the parent's.
 PARENT = {
     "decoder_plain":
         "e1a42552cc92487e1f8cbff165bb56d91aa7e1b7998f7a3e1327865fcf0c80e9",

@@ -69,9 +69,31 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_ddp.models.moe import DroplessMoE, SwiGLU
+from tpu_ddp.models.moe import (IDS_NAME, LOGITS_NAME, ROUTED_NAME,
+                                SCORES_NAME, DroplessMoE, SwiGLU)
 from tpu_ddp.models.zoo import register
+from tpu_ddp.ops.flash_attention import LSE_NAME, OUT_NAME
 from tpu_ddp.telemetry.phases import module_scope
+
+#: What a recomputed layer or block keeps from its forward pass, by name
+#: (``checkpoint_name``), because its backward pass would otherwise make it
+#: again with a kernel call: the flash kernels' output and one float32 a row
+#: of their logsumexp (``ops/flash_attention.py::_fwd``), so that no
+#: forward kernel runs in the backward pass; an expert layer's routed
+#: result, and under a selection bias its router's float32 logits, chosen
+#: ids and their scores (``models/moe.py``). A name a model does not
+#: produce is simply absent, and one that no backward rule reads (a sparse
+#: layer's routed result: it is only added to the residual stream) is not
+#: kept by the compiler, so one list serves every stack.
+KEPT_NAMES = (OUT_NAME, LSE_NAME, ROUTED_NAME, LOGITS_NAME, IDS_NAME,
+              SCORES_NAME)
+
+
+def recomputed(module_cls):
+    """``module_cls`` recomputed in the backward pass, all but
+    ``KEPT_NAMES``: what ``remat: true`` means to both decoder stacks."""
+    return nn.remat(module_cls, policy=jax.checkpoint_policies
+                    .save_only_these_names(*KEPT_NAMES))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -350,7 +372,11 @@ class SparseDecoder(nn.Module):
     #: ``(q, k, v, *, causal, window) -> o`` on (B, T, H, D) queries and
     #: (B, T, KV, D) keys and values; None = the fused jnp reference
     attention_impl: Optional[Callable] = None
-    #: recompute each layer in the backward pass (``resolve_remat``)
+    #: recompute each layer in the backward pass (``resolve_remat``), all
+    #: but ``KEPT_NAMES``: a layer keeps its attention's output (134-268 MB
+    #: at 16,384 tokens) and a float32 a row of its logsumexp (2-4 MB), so
+    #: ``flash_fwd`` runs once a layer and step, and under a selection bias
+    #: its router's logits, ids and scores (PERF.md section 6, PR 42)
     remat: bool = False
     #: (block_q, block_k) of ``--attention flash``: a decoder's sequences
     #: are long, and a grid step of 128 x 128 is mostly its own overhead
@@ -371,7 +397,7 @@ class SparseDecoder(nn.Module):
                          name="embed")
         x = embed(tokens)
         tables = {}
-        layer_cls = nn.remat(DecoderLayer) if self.remat else DecoderLayer
+        layer_cls = recomputed(DecoderLayer) if self.remat else DecoderLayer
         positions = tokens.shape[1]
         if s.diffusion is not None:
             if s.mtp is not None or positions % (2 * s.diffusion.block):

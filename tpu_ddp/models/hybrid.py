@@ -43,9 +43,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tpu_ddp.models.decoder import GroupedQueryAttention, LayerSpec
-from tpu_ddp.models.moe import (IDS_NAME, LOGITS_NAME, ROUTED_NAME,
-                                SCORES_NAME, DroplessMoE)
+from tpu_ddp.models.decoder import (GroupedQueryAttention, LayerSpec,
+                                    recomputed)
+from tpu_ddp.models.moe import DroplessMoE
 from tpu_ddp.models.zoo import register
 from tpu_ddp.ops.ssd_scan import ssd_scan
 from tpu_ddp.telemetry.phases import module_scope
@@ -198,13 +198,15 @@ class HybridDecoder(nn.Module):
     #: ``(q, k, v, *, causal, window) -> o``; None = the fused jnp reference
     attention_impl: Optional[Callable] = None
     #: recompute each block in the backward pass (``resolve_remat``), all
-    #: but what an expert block names: its routed result (33.5 MB a block
-    #: at 16,384 tokens), so that the ladder's branch runs forward and
-    #: backward and not a third time between (0.9% of the step: PERF.md
-    #: section 6, PR 33), and its router's float32 logits, chosen ids and
-    #: their scores (33.5 MB and twice 1.4 MB), so that the six-pass
-    #: product, the ``top_k`` of 22 over 512 and the gather of the chosen
-    #: scores run once a block and step (PERF.md section 6, PR 34)
+    #: but ``decoder.KEPT_NAMES``: an expert block's routed result (33.5 MB
+    #: a block at 16,384 tokens), so that the ladder's branch runs forward
+    #: and backward and not a third time between (0.9% of the step: PERF.md
+    #: section 6, PR 33: ``latent_up``'s weight gradient reads it); its
+    #: router's float32 logits, chosen ids and their scores (33.5 MB and
+    #: twice 1.4 MB), so that the six-pass product, the ``top_k`` of 22
+    #: over 512 and the gather of the chosen scores run once a block and
+    #: step (PR 34); and an attention block's output and a float32 a row of
+    #: its logsumexp (16.8 MB), so that ``flash_fwd`` runs once (PR 42)
     remat: bool = False
     task = "next_token"
     flash_blocks = (512, 512)  # as the sparse decoder's, and for its reason
@@ -215,12 +217,7 @@ class HybridDecoder(nn.Module):
         s = self.spec
         x = nn.Embed(s.vocab_rows, s.hidden, dtype=self.dtype,
                      name="embed")(tokens)
-        block_cls = HybridBlock
-        if self.remat:
-            block_cls = nn.remat(
-                HybridBlock,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    ROUTED_NAME, LOGITS_NAME, IDS_NAME, SCORES_NAME))
+        block_cls = recomputed(HybridBlock) if self.remat else HybridBlock
         for i, kind in enumerate(s.pattern):
             x = block_cls(kind, s, dtype=self.dtype,
                           attention_impl=self.attention_impl,

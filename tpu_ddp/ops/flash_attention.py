@@ -17,7 +17,12 @@ kernels' programs are the ones a single ``D`` made.
 
 Differentiation: forward AND backward are Pallas kernels (``jax.custom_vjp``).
 The forward additionally emits the per-row logsumexp (broadcast along a
-128-lane minor dim — the TPU-friendly layout for per-row stats). The backward
+128-lane minor dim — the TPU-friendly layout for per-row stats *inside* the
+kernels). What lives from the forward pass to the backward pass is lane 0 of
+it, one float32 a row, widened again before the backward kernels read it
+(``_fwd``, ``_bwd``); it and the output carry names (``OUT_NAME``,
+``LSE_NAME``), so a recomputed layer whose policy keeps them runs the
+forward kernel once a step, not once in each pass. The backward
 is ONE kernel (``_bwd_kernel``) wherever the dk / dv of one key-value head's
 whole sequence fit its VMEM budget (``_backward_fits``: 8,192 positions at
 any width here): the dQ walk, kv-blocks innermost with dq in VMEM scratch,
@@ -70,9 +75,11 @@ bands only and refuses this mask by name.
 The row statistics of the online softmax (``m``, ``l`` and the rescale
 ``alpha``; ``lse`` and ``di`` in the backward kernels) are kept
 lane-replicated, (rows, 128), in the kernels as in their buffers
-(``_lanes``). What that is worth on the chip, what walking a tile in
-smaller pieces was not, and what one backward kernel is, is PERF.md's to
-say (section 6, PR 30 and PR 40).
+(``_lanes``); between the passes the logsumexp is (rows,), since a kept
+(rows, 128) buffer is 268-537 MB a layer of a decoder cell. What that is
+worth on the chip, what walking a tile in smaller pieces was not, what one
+backward kernel is and what a forward kernel run once a step is, is PERF.md's
+to say (section 6, PR 30, PR 40 and PR 42).
 """
 
 from __future__ import annotations
@@ -84,6 +91,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -99,6 +107,12 @@ _WHOLE_AXIS_MAX = 512
 # underflows to exactly 0.0 in f32, while (-inf) - (-inf) would be NaN when
 # an entire tile row is masked.
 NEG = -1e30
+#: what the backward kernels read of the forward pass, by name to a
+#: recomputation policy (``checkpoint_name``): the attention's output and
+#: its rows' logsumexp, one float32 a row. A recomputed layer that keeps
+#: both (``models/decoder.py::recomputed``) has no forward kernel in its
+#: backward pass; a caller without such a policy sees no change.
+OUT_NAME, LSE_NAME = "flash_out", "flash_lse"
 
 
 def _bhqk_visibility(Tq: int, Tk: int, causal: bool, kv_mask,
@@ -1061,11 +1075,22 @@ def _flash(q, k, v, kv_mask, block_q, block_k, interpret, causal, window,
 
 def _fwd(q, k, v, kv_mask, block_q, block_k, interpret, causal, window,
          diffusion):
+    """What lives from the forward pass to the backward pass, beside the
+    operands: ``out`` (``OUT_NAME``) and one float32 a row of the
+    logsumexp, (B*H, T) (``LSE_NAME``): lane 0 of the kernel's
+    lane-replicated buffer, which ``_bwd`` widens again beside ``di``. The
+    (rows, 128) buffer does not outlive its pass: kept under a policy it
+    would be 268-537 MB a layer at the decoder cells' sizes, where a value
+    a row is 2-4 MB. The interpreted-under-``shard_map`` detour has no
+    statistics and names ``out`` alone."""
     out, lse = _flash_forward(
         q, k, v, kv_mask, block_q=block_q, block_k=block_k,
         interpret=_resolve_interpret(interpret), causal=causal,
         window=window, diffusion=diffusion,
     )
+    out = checkpoint_name(out, OUT_NAME)
+    if lse is not None:
+        lse = checkpoint_name(lse[:, :, 0], LSE_NAME)
     return out, (q, k, v, kv_mask, out, lse)
 
 
@@ -1080,6 +1105,8 @@ def _bwd(block_q, block_k, interpret, causal, window, diffusion, res, g):
         )
         dq, dk, dv = vjp(g)
     else:
+        # lane-replicated again, as the kernels read it (``_lanes``)
+        lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (LANE,))
         dq, dk, dv = _flash_backward(
             q, k, v, o, lse, g, kv_mask, block_q=block_q, block_k=block_k,
             interpret=_resolve_interpret(interpret), causal=causal,
