@@ -316,9 +316,12 @@ def run(ctx) -> dict:
     seed = datagen.fold_seed(ctx.seed)
     shards = int(traffic["mesh"]["data"])
     marks = [("imports", time.perf_counter())]
-    data = ctx.dataset.make(traffic["dataset"], seed)
+    # the set and the weights: the seed's, or the mix's own (``fixed_work``)
+    drawn_from = datagen.work_seed(traffic, seed)
+    data = ctx.dataset.make(traffic["dataset"], drawn_from)
     marks.append(("dataset", time.perf_counter()))
-    ref_params = ctx.reference.init_params(arch, seed)
+    ref_params = ctx.reference.init_params(arch, drawn_from)
+    datagen.tell_run_seed(ctx.reference, seed)
     marks.append(("weights", time.perf_counter()))
 
     fields = dict(cfg["train_config"])

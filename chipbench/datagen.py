@@ -23,6 +23,29 @@ def fold_seed(seed: int) -> int:
     return int(seed) % SEED_MODULUS
 
 
+def work_seed(traffic: dict, seed: int) -> int:
+    """The folded seed that the training set and the weights are drawn from.
+    As a rule ``--seed``. A mix that states ``fixed_work.seed`` draws both
+    from that number in every run, and ``--seed`` draws only the order in
+    which the set is fed (the sampler's permutation of each epoch) and what
+    the step itself draws (a block-diffusion step's noise): the same set of
+    sequences through the same routers in another order, so that a window of
+    whole epochs holds the same work on every seed. It is for a cell whose
+    work follows its seeded weights (a router that lands more or fewer pairs
+    on the experts held here; PERF.md section 2)."""
+    fixed = traffic.get("fixed_work")
+    return fold_seed(seed if fixed is None else int(fixed["seed"]))
+
+
+def tell_run_seed(reference, seed: int) -> None:
+    """A reference that draws what the program's step draws from
+    ``TrainConfig.seed`` (a block-diffusion step's noise) keeps the seed
+    ``init_params`` was given; where the weights are not the run's seed's
+    it is told the run's here (``run_seed``), after ``init_params``."""
+    if hasattr(reference, "run_seed"):
+        reference.run_seed(seed)
+
+
 def real_examples_per_step(size: int, shards: int, per_shard_batch: int):
     """Unmasked examples of each step of one epoch, by the sampler's own
     arithmetic (DistributedSampler semantics: every shard holds

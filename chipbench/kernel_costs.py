@@ -103,8 +103,14 @@ def layer_bodies(arch) -> list:
     ``*`` attention, ``E`` experts, anything else neither), or latent
     attention (``kv_lora_rank``) over ``first_k_dense_replace`` dense layers
     with ``num_nextn_predict_layers`` prediction modules after the stack,
-    each one more body whose outermost scope is ``mtp``. Empty for a
-    configuration laid out in none of these ways."""
+    each one more body whose outermost scope is ``mtp``, or a period
+    (``decoder_sparse_step`` with ``mlp_only_layers``: layer ``i`` has the
+    experts where it is not named dense and ``i + 1`` is a multiple of the
+    step). A layer that attends under block diffusion's mask (the
+    configuration has a ``block_length``) has no scope here: that mask is
+    not a band, and its calls are counted by ``block_mask_costs.py`` under
+    names of their own, once. Empty for a configuration laid out in none of
+    these ways."""
     here = arch.get("layers_here")
     if here is None:
         return []
@@ -127,6 +133,12 @@ def layer_bodies(arch) -> list:
                  for i in range(here)]
                 + [("mtp", heads, True)] * arch.get(
                     "num_nextn_predict_layers", 0))
+    if "decoder_sparse_step" in arch:
+        scope = None if "block_length" in arch else "attention_full"
+        step, dense = arch["decoder_sparse_step"], arch["mlp_only_layers"]
+        return [(scope, arch["num_attention_heads"] if scope else 0,
+                 i not in dense and (i + 1) % step == 0)
+                for i in range(here)]
     return []
 
 

@@ -40,7 +40,9 @@ the count of real positions.
 batch as it was fed, before the step noises it, and ``follow`` is not told
 the run's seed. So ``init_params(arch, seed)`` keeps the seed it was called
 with (the harness calls it with the folded ``--seed``, which it also gives
-the program as ``TrainConfig.seed``; ``control.py`` calls it too), and
+the program as ``TrainConfig.seed``; ``control.py`` calls it too; where the
+mix draws the weights from a seed of its own, ``fixed_work``, ``run_seed``
+is told the run's afterwards), and
 ``noise`` makes the draw of (that seed, the step's index from 0, the
 shard): ``key(seed)`` folded with the step, the shard and 2, split in two;
 levels ``1 - u (1 - t_min)`` from the first, a uniform a position from the
@@ -151,6 +153,14 @@ def init_params(arch, seed: int) -> dict:
         return out
 
     return jax.jit(make)(jax.random.key(seed))
+
+
+def run_seed(seed: int) -> None:
+    """The seed the program's step draws its noise by (``TrainConfig.seed``,
+    the run's ``--seed``), where the weights were drawn from another (a mix
+    with ``fixed_work``: ``datagen.tell_run_seed``)."""
+    global _SEED
+    _SEED = int(seed)
 
 
 def program_names(arch) -> dict:

@@ -6,10 +6,13 @@ append them (``chipbench_tiny.append``): the ``case`` / ``bench`` fixtures of
 takes."""
 
 import copy
+import glob
 import json
 import os
 import re
 import sys
+
+import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
@@ -137,6 +140,40 @@ def test_every_cell_is_found_by_name(case, bench):
         assert ONE_NOTCH_LOWER[loaded["config"]["precision"]] in PRECISIONS
 
 
+NUMBERS = ("loss_gap", "grad_gap", "update_gap", "grad_diff",
+           "out_grad_diff")
+
+
+@pytest.mark.parametrize("path", sorted(glob.glob(os.path.join(
+    BENCH, "limits", "*.json"))), ids=os.path.basename)
+def test_a_limit_stands_between_its_two_readings(path):
+    """Where a number's entry under ``readings`` states the largest sound
+    reading (``sound_max``, over ``seeds`` seeds) and the smallest reading
+    of what the number is held against (``fault_min``), the limit lies
+    between them; ``grad_gap`` and ``update_gap``, whose sound readings are
+    heavy-tailed and whose fault reads 1, keep a factor of two on both
+    sides (``chipbench/README.md``, "The rule a limit is set by"). The
+    block-diffusion cell's file states them for all five numbers."""
+    file = harness.load_json(path)
+    limits = file["limits"]
+    assert file["cell"] + ".json" == os.path.basename(path)
+    stated = []
+    for name in NUMBERS:
+        entry = file.get("readings", {}).get(name)
+        if not (isinstance(entry, dict)
+                and {"sound_max", "fault_min"} <= set(entry)):
+            continue
+        stated.append(name)
+        assert isinstance(entry["seeds"], int) and entry["seeds"] >= 1
+        assert entry["read"], "the prose stays beside the keys"
+        assert entry["sound_max"] < limits[name] < entry["fault_min"], name
+        if name in ("grad_gap", "update_gap"):
+            assert limits[name] >= 2 * entry["sound_max"], name
+            assert 2 * limits[name] <= entry["fault_min"], name
+    if file["cell"] == "sdar-30b-a3b.seq4k-v18992":
+        assert stated == list(NUMBERS)
+
+
 def test_every_per_layer_metric_has_its_reader(case, bench):
     for metric in bench["per_layer"]:
         reader = harness.load_module(harness.find(
@@ -167,18 +204,28 @@ def test_every_reader_is_listed_and_every_entry_has_its_file(case, bench):
 def test_one_mechanism_has_one_name_in_every_cell_that_runs_it(bench):
     """A reader is named for a mechanism, not for a configuration: the
     decoder cells share the readers of attention, the flash kernels, the
-    routed experts and their grouped products."""
+    routed experts and their grouped products. The block-diffusion cell
+    runs the routed experts and the flash kernels as the others do, and is
+    on those lists (PR 44); its mask is not a band, so its attention and the
+    two kernels' shares are counted by ``block_mask_costs.py`` under names
+    of their own and the band's readers do not list it: one call, one
+    count. The two-kernel backward pass's readers went when no cell ran it
+    any more."""
     decoders = ["laguna-xs2.seq8k", "nemotron3-super.seq8k-v16384",
                 "joyai-llm-flash.seq8k-v16160"]
+    routed = decoders + ["sdar-30b-a3b.seq4k-v18992"]
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    for name in ("device_moe_ms", "device_attention_ms",
-                 "expert_load_max_over_mean", "moe_rows_walked_over_landed",
-                 "flash_fwd_roofline", "flash_dq_roofline",
-                 "flash_dkv_roofline", "grouped_matmul_roofline"):
+    for name in ("device_moe_ms", "expert_load_max_over_mean",
+                 "moe_rows_walked_over_landed", "grouped_matmul_roofline",
+                 "flash_fwd_calls_per_bwd_call"):
+        assert by_name[name]["workloads"] == routed, name
+    for name in ("device_attention_ms", "flash_fwd_roofline",
+                 "flash_bwd_roofline"):
         assert by_name[name]["workloads"] == decoders, name
     for name in by_name:
         assert not name.startswith(("latent_moe", "mla_flash",
-                                    "device_latent")), name
+                                    "device_latent", "flash_dq",
+                                    "flash_dkv")), name
 
 
 def test_an_entry_appended_after_device_mtp_ms_is_appended_only(bench):

@@ -17,19 +17,15 @@ sys.path.insert(0, REPO)
 sys.path.insert(0, HERE)
 
 from chipbench import run as harness  # noqa: E402
-from test_chipbench_flash_bwd import DECODERS as LISTED  # noqa: E402
+from test_chipbench_flash_bwd import DECODERS as BAND  # noqa: E402
 from test_chipbench_flash_bwd import STEP  # noqa: E402
 from test_chipbench_flash_bwd import _traced as _traced_seconds  # noqa: E402
 
 NAME = "flash_fwd_calls_per_bwd_call"
-#: ``LISTED`` is what the entry lists: the cells of the shared flash readers.
-#: The block mask's cell runs the same kernels under the same recomputed
-#: layer and the reader reads it as the others, but the entry cannot list
-#: it: ``test_chipbench_sdar.py``, an accepted file of the benchmark, holds
-#: that the cell's four readers are the only ones that do (PERF.md section
-#: 7, PR 42: a ``benchmark`` PR's to change)
-UNLISTED = "sdar-30b-a3b.seq4k-v18992"
-DECODERS = LISTED + [UNLISTED]
+#: what the entry lists: the cells of the band's flash readers, and since PR
+#: 44 the block mask's cell, which runs the same kernels under the same
+#: recomputed layer
+DECODERS = BAND + ["sdar-30b-a3b.seq4k-v18992"]
 #: cell: (module scope, layer bodies with attention) of the traced programs
 BODIES = {
     "laguna-xs2.seq8k": ("attention_window", 5),
@@ -86,15 +82,16 @@ def test_the_entry_lists_the_cells_of_the_shared_flash_readers(reader):
     assert entry == {
         "name": NAME, "unit": "ratio", "better": "lower",
         "source": "device_trace", "layer": "models",
-        "moves": "images_per_s_per_chip", "workloads": LISTED}
+        "moves": "images_per_s_per_chip", "workloads": DECODERS}
     assert (reader.UNIT, reader.SOURCE, reader.LAYER, reader.MOVES) == (
         entry["unit"], entry["source"], entry["layer"], entry["moves"])
-    # one name for the mechanism in every cell that runs the shared flash
-    # readers (the contract test's rule, whose own list is an accepted
-    # file's and so is not this PR's to lengthen)
+    # one name for the mechanism in every cell that runs the flash kernels:
+    # the band's readers' cells, and the cell whose mask is not a band
     flash = next(m for m in bench["per_layer"]
                  if m["name"] == "flash_fwd_roofline")
-    assert entry["workloads"] == flash["workloads"]
+    block = next(m for m in bench["per_layer"]
+                 if m["name"] == "block_flash_fwd_roofline")
+    assert entry["workloads"] == flash["workloads"] + block["workloads"]
     assert bench["per_layer"][-1] == entry  # appended, and nothing after it
 
 
@@ -165,8 +162,7 @@ def test_the_harness_reports_it_in_the_cells_it_lists(tmp_path, cell):
     run = _traced(tmp_path, cell, _program(cell, again=False))
     out = harness.per_layer(dict(bench, per_layer=entry), cell,
                             [harness.HERE], run.record, run.trace)
-    assert out == ({NAME: {"value": 1.0, "unit": "ratio"}}
-                   if cell in LISTED else {})
+    assert out == {NAME: {"value": 1.0, "unit": "ratio"}}
     image = _traced(tmp_path, "resnet50-cifar.b512",
                     _program(cell, again=False))
     assert harness.per_layer(dict(bench, per_layer=entry),

@@ -27,6 +27,7 @@ import joyai_tiny as tiny  # noqa: E402
 from chipbench import kernel_costs  # noqa: E402
 from chipbench import run as harness  # noqa: E402
 from test_chipbench_contract import appended_only  # noqa: E402
+from test_chipbench_flash_bwd import PAIR, read_of  # noqa: E402
 
 CELL = "joyai-llm-flash.seq8k-v16160"
 #: the readers of what only this cell runs
@@ -34,8 +35,7 @@ READERS = ("device_mla_ms", "device_mtp_ms")
 #: the readers it shares with the other decoder cells: one name a mechanism
 SHARED_METRICS = ("device_moe_ms", "device_attention_ms",
                   "expert_load_max_over_mean", "moe_rows_walked_over_landed",
-                  "flash_fwd_roofline", "flash_dq_roofline",
-                  "flash_dkv_roofline", "grouped_matmul_roofline")
+                  "flash_fwd_roofline", "grouped_matmul_roofline")
 _CONFIG = ("jax_compilation_cache_dir",
            "jax_persistent_cache_min_compile_time_secs",
            "jax_persistent_cache_min_entry_size_bytes")
@@ -210,16 +210,16 @@ def _reader(name):
         "chipbench_metric_" + name)
 
 
-@pytest.mark.parametrize("name", READERS + SHARED_METRICS)
+@pytest.mark.parametrize("name", READERS + SHARED_METRICS + PAIR)
 def test_a_reader_finds_nothing_in_a_program_without_its_scopes(name,
                                                                  tmp_path):
     """An untraced run, and a traced run of this cell of a program that
     writes no map and keeps no counters (the parent): None, nothing
     raised."""
-    reader = _reader(name)
-    assert reader.read(types.SimpleNamespace(
+    read = read_of(name)
+    assert read(types.SimpleNamespace(
         record={"trace_dir": None}, trace=None)) is None
-    assert reader.read(types.SimpleNamespace(
+    assert read(types.SimpleNamespace(
         record={"steps": 7, "examples": 56}, trace=None)) is None
     root = tmp_path / CELL
     os.makedirs(root / "telemetry")
@@ -230,7 +230,7 @@ def test_a_reader_finds_nothing_in_a_program_without_its_scopes(name,
                 "peak_flops_per_s": 197e12},
         trace={"device_ops": [["fusion.1", 0.5]], "steps": 5,
                "device_step_ms": 100.0})
-    assert reader.read(traced) is None
+    assert read(traced) is None
 
 
 def _traced_run(root, cell=CELL):
@@ -323,8 +323,8 @@ def _grouped_share(rows, held, products, calls, spent):
 SHARED_READINGS = {
     # three forward calls, the module's among them, in 48 ms a step
     "flash_fwd_roofline": _flash_share("flash_fwd", 3, 0.048),
-    "flash_dq_roofline": _flash_share("flash_dq", 1, 0.025),
-    "flash_dkv_roofline": _flash_share("flash_dkv", 1, 0.030),
+    "flash_dq": _flash_share("flash_dq", 1, 0.025),
+    "flash_dkv": _flash_share("flash_dkv", 1, 0.030),
     # the stack's layers: the module's layer is the module's
     "device_attention_ms": (0.080 + 0.085 + 0.125 + 0.150) / 5 * 1e3,
     "device_moe_ms": (0.004 + 0.003 + 0.0005) / 5 * 1e3,
@@ -339,9 +339,9 @@ SHARED_READINGS = {
 }
 
 
-@pytest.mark.parametrize("name", SHARED_METRICS)
+@pytest.mark.parametrize("name", SHARED_METRICS + PAIR)
 def test_a_shared_reader_reads_this_cell_by_its_own_files(name, tmp_path):
-    assert _reader(name).read(_traced_run(tmp_path)) == pytest.approx(
+    assert read_of(name)(_traced_run(tmp_path)) == pytest.approx(
         SHARED_READINGS[name], rel=1e-12)
 
 
@@ -355,5 +355,6 @@ def test_a_call_under_a_scope_the_cells_files_do_not_describe_is_no_guess(
     assert found["arch"]["name"] == "laguna-xs2"
     assert set(kernel_costs.attention_shapes(found["arch"])) == {
         "attention_window", "attention_full"}
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert _reader(kernel + "_roofline").read(elsewhere) is None
+    assert _reader("flash_fwd_roofline").read(elsewhere) is None
+    for kernel in PAIR:
+        assert read_of(kernel)(elsewhere) is None

@@ -16,9 +16,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(HERE))
+sys.path.insert(0, HERE)
 
 import decoder_tiny as tiny  # noqa: E402
 from chipbench import run as harness  # noqa: E402
+from test_chipbench_flash_bwd import PAIR, read_of  # noqa: E402
 
 CELL = "laguna-tiny.t24"
 LIMITS = {"loss_gap": 1e-5, "grad_gap": 1e-3, "update_gap": 0.02,
@@ -173,7 +175,6 @@ def test_a_model_with_a_piece_left_out_is_not_correct(tmp_path, monkeypatch,
 
 NEW_METRICS = ("device_moe_ms", "device_attention_ms",
                "expert_load_max_over_mean", "flash_fwd_roofline",
-               "flash_dq_roofline", "flash_dkv_roofline",
                "grouped_matmul_roofline")
 
 
@@ -183,15 +184,15 @@ def _reader(name):
         "chipbench_metric_" + name)
 
 
-@pytest.mark.parametrize("name", NEW_METRICS)
+@pytest.mark.parametrize("name", NEW_METRICS + PAIR)
 def test_a_reader_finds_nothing_in_a_program_without_its_scopes(name,
                                                                  tmp_path):
     """An untraced run, and a traced run of a program that writes no map and
     keeps no such counters (the parent): None, nothing raised."""
     import types
 
-    reader = _reader(name)
-    assert reader.read(types.SimpleNamespace(
+    read = read_of(name)
+    assert read(types.SimpleNamespace(
         record={"trace_dir": None}, trace=None)) is None
     os.makedirs(tmp_path / "telemetry")
     (tmp_path / "telemetry" / "trace-p0.jsonl").write_text(json.dumps(
@@ -201,7 +202,7 @@ def test_a_reader_finds_nothing_in_a_program_without_its_scopes(name,
                 "peak_flops_per_s": 197e12},
         trace={"device_ops": [["fusion.1", 0.5]], "steps": 5,
                "device_step_ms": 100.0})
-    assert reader.read(traced) is None
+    assert read(traced) is None
 
 
 def test_the_readers_join_the_map_the_trace_and_the_counters(tmp_path):
@@ -264,7 +265,13 @@ def test_the_readers_join_the_map_the_trace_and_the_counters(tmp_path):
     least = max(flops / 197e12, moved / 819e9)
     assert _reader("flash_fwd_roofline").read(run) == pytest.approx(
         100 * least / 0.010)
-    assert _reader("flash_dkv_roofline").read(run) is None  # no such call
+    # the sliding layer's dQ call in 6 ms a step, by the pair's own count
+    flops, moved = kernel_costs.flash_call(
+        "flash_dq", batch=2, tokens=8192, heads=64, kv_heads=8,
+        qk_dim=128, v_dim=128, window=512)
+    assert read_of("flash_dq")(run) == pytest.approx(
+        100 * max(flops / 197e12, moved / 819e9) / 0.006)
+    assert read_of("flash_dkv")(run) is None  # no such call
     # one call of the grouped kernel at 8,000 real rows, its layout call's
     # time counted with it; the two products' least times averaged
     with open(os.path.join(harness.HERE, "configs", "laguna-xs2.json")) as f:

@@ -56,7 +56,7 @@ def test_the_entry_names_the_cells_that_hold_a_share(tmp_path, appended):
         "source": "program_counter", "layer": "models",
         "moves": "images_per_s_per_chip", "workloads": [
             "laguna-xs2.seq8k", "nemotron3-super.seq8k-v16384",
-            "joyai-llm-flash.seq8k-v16160"]}
+            "joyai-llm-flash.seq8k-v16160", "sdar-30b-a3b.seq4k-v18992"]}
 
 
 @pytest.mark.parametrize("gauges,want", [

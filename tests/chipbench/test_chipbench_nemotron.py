@@ -27,6 +27,7 @@ import hybrid_tiny as tiny  # noqa: E402
 from chipbench import kernel_costs, ssd_costs  # noqa: E402
 from chipbench import run as harness  # noqa: E402
 from test_chipbench_contract import appended_only  # noqa: E402
+from test_chipbench_flash_bwd import PAIR, read_of  # noqa: E402
 
 CELL = "nemotron3-super.seq8k-v16384"
 #: the readers of what only this cell runs
@@ -34,8 +35,7 @@ NEW_METRICS = ("device_mamba_ms", "scan_fwd_roofline", "scan_bwd_roofline")
 #: the readers it shares with the other decoder cells: one name a mechanism
 SHARED_METRICS = ("device_moe_ms", "device_attention_ms",
                   "expert_load_max_over_mean", "moe_rows_walked_over_landed",
-                  "flash_fwd_roofline", "flash_dq_roofline",
-                  "flash_dkv_roofline", "grouped_matmul_roofline")
+                  "flash_fwd_roofline", "grouped_matmul_roofline")
 _CONFIG = ("jax_compilation_cache_dir",
            "jax_persistent_cache_min_compile_time_secs",
            "jax_persistent_cache_min_entry_size_bytes")
@@ -170,16 +170,16 @@ def _reader(name):
         "chipbench_metric_" + name)
 
 
-@pytest.mark.parametrize("name", NEW_METRICS + SHARED_METRICS)
+@pytest.mark.parametrize("name", NEW_METRICS + SHARED_METRICS + PAIR)
 def test_a_reader_finds_nothing_in_a_program_without_its_scopes(name,
                                                                  tmp_path):
     """An untraced run, and a traced run of this cell of a program that
     writes no map and keeps no such counters (the parent): None, nothing
     raised."""
-    reader = _reader(name)
-    assert reader.read(types.SimpleNamespace(
+    read = read_of(name)
+    assert read(types.SimpleNamespace(
         record={"trace_dir": None}, trace=None)) is None
-    assert reader.read(types.SimpleNamespace(
+    assert read(types.SimpleNamespace(
         record={"steps": 7, "examples": 56}, trace=None)) is None
     root = tmp_path / CELL
     os.makedirs(root / "telemetry")
@@ -190,7 +190,7 @@ def test_a_reader_finds_nothing_in_a_program_without_its_scopes(name,
                 "peak_flops_per_s": 197e12},
         trace={"device_ops": [["fusion.1", 0.5]], "steps": 5,
                "device_step_ms": 100.0})
-    assert reader.read(traced) is None
+    assert read(traced) is None
 
 
 def _traced_run(root, cell=CELL):
@@ -312,8 +312,8 @@ SHARED_READINGS = {
     # the block's three kernels and the product beside them
     "device_attention_ms": (0.0075 + 0.011 + 0.014 + 0.0025) / 5 * 1e3,
     "flash_fwd_roofline": _flash_share("flash_fwd", 0.0015),
-    "flash_dq_roofline": _flash_share("flash_dq", 0.0022),
-    "flash_dkv_roofline": _flash_share("flash_dkv", 0.0028),
+    "flash_dq": _flash_share("flash_dq", 0.0022),
+    "flash_dkv": _flash_share("flash_dkv", 0.0028),
     # two calls at 5,760 real rows a block of five, the layout call's time
     # counted with them: 8 held experts, latent 1024, width 2688
     "grouped_matmul_roofline": _grouped_share(
@@ -321,9 +321,9 @@ SHARED_READINGS = {
 }
 
 
-@pytest.mark.parametrize("name", SHARED_METRICS)
+@pytest.mark.parametrize("name", SHARED_METRICS + PAIR)
 def test_a_shared_reader_reads_this_cell_by_its_own_files(name, tmp_path):
-    assert _reader(name).read(_traced_run(tmp_path)) == pytest.approx(
+    assert read_of(name)(_traced_run(tmp_path)) == pytest.approx(
         SHARED_READINGS[name], rel=1e-12)
 
 

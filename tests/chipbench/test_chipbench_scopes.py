@@ -309,6 +309,11 @@ def test_no_metric_reads_the_busy_sum_or_the_breakdown(tmp_path, capsys,
     for cell, metrics in parent.items():
         assert {name: got[cell][name] for name in metrics} == metrics
         assert ("window_steps" in got[cell]) == case.appended
+    # the dQ kernel's share that file held until its reader went (PR 44: no
+    # cell runs the two-kernel backward pass), from the function that made it
+    from chipbench import kernel_costs
+    assert kernel_costs.flash_roofline(types.SimpleNamespace(
+        record=record, trace=reduced), "flash_dq") == 78.58576334155758
     with open(os.path.join(TESTDATA, "recorded.json")) as f:
         recorded = json.load(f)  # the slice as recorded, without a switch
     by_phase = sum(got["resnet50-cifar.dp4"][name] for name in METRICS)

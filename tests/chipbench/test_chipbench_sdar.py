@@ -4,11 +4,14 @@ shipped ``zipf_tokens`` generator and ``trainer`` adapter, so the product's
 copy of the shipped BENCHMARK.json with the tiny cell appended
 (``chipbench_tiny_sdar.py``): ``correct`` true, and false with a piece of
 the model or of the objective left out of the program; the entries this
-configuration has in the shipped file; its four per-layer readers on a
-synthetic trace; and ``chipbench/block_mask_costs.py``'s pairs against a
-brute-force count."""
+configuration has in the shipped file; its four per-layer readers, and the
+five it shares with the other decoder cells since PR 44, on a synthetic
+trace; and ``chipbench/block_mask_costs.py``'s pairs against a brute-force
+count."""
 
+import csv
 import json
+import math
 import os
 import sys
 import types
@@ -36,6 +39,14 @@ READERS = {"device_block_attention_ms": ("ms", "lower", "models"),
            "device_block_noise_ms": ("ms", "lower", "step builders"),
            "block_flash_fwd_roofline": ("%", "higher", "kernels"),
            "block_flash_bwd_roofline": ("%", "higher", "kernels")}
+#: the readers of the mechanisms it runs as the other decoder cells do (PR
+#: 44): the routed experts, their grouped products, and how often the flash
+#: forward kernel runs a backward pass. Not the band's three
+#: (``device_attention_ms``, ``flash_fwd_roofline``, ``flash_bwd_roofline``):
+#: its mask is counted under its own four names, and one call is read once
+SHARED = ("device_moe_ms", "expert_load_max_over_mean",
+          "moe_rows_walked_over_landed", "grouped_matmul_roofline",
+          "flash_fwd_calls_per_bwd_call")
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 _CONFIG = ("jax_compilation_cache_dir",
            "jax_persistent_cache_min_compile_time_secs",
@@ -68,6 +79,22 @@ def test_the_new_configuration_is_correct_through_trainer_run(tmp_path,
         "repeated_rows", "loss_gap", "grad_gap", "update_gap", "grad_diff",
         "out_grad_diff"}
     assert "chipbench: tokens_per_s_per_chip=" in capsys.readouterr().out
+
+
+#: the accepted limit ``loss_gap`` may not fall under, and what the two
+#: left-out faults of this file that touch the loss (the weight 1 / t, the
+#: clean half) read of it on the tiny cell: the smaller is the clean half's
+LOSS_GAP_FLOOR, TINY_FAULTS = 0.00045, ("weight", "clean_half")
+
+
+def _readings():
+    folder = os.path.join(REPO, "chipbench", "limits")
+    with open(os.path.join(folder, CELL + ".readings.csv")) as f:
+        rows = list(csv.DictReader(f))
+    by_read = {}
+    for row in rows:
+        by_read.setdefault(row["read"], []).append(row)
+    return by_read, harness.load_json(os.path.join(folder, CELL + ".json"))
 
 
 def _break(fault, monkeypatch):
@@ -122,6 +149,18 @@ def test_a_model_with_a_piece_left_out_is_not_correct(tmp_path, monkeypatch,
         tiny.register()
     assert result["correct"] is False
     assert tiny_cell.failed(result), result["compared"]
+    if fault in TINY_FAULTS:
+        # the shipped ``loss_gap``'s ``fault_min`` is the smaller of what
+        # these two read on this tiny cell, and says so: a hundred times the
+        # shipped limit and more
+        _, file = _readings()
+        entry = file["readings"]["loss_gap"]
+        read = result["compared"]["loss_gap"]["value"]
+        assert "tiny cell" in entry["fault"]
+        assert read >= 0.999 * entry["fault_min"] > 100 * file["limits"][
+            "loss_gap"]
+        if fault == "clean_half":
+            assert read == pytest.approx(entry["fault_min"], rel=1e-3)
 
 
 def test_follow_asks_for_the_seed_it_was_not_given():
@@ -138,23 +177,34 @@ def test_follow_asks_for_the_seed_it_was_not_given():
 def test_the_shipped_file_has_its_configuration_cell_and_readers(bench):
     """Found by name, on the shipped file and on a copy with entries after
     the end of every list: cut where this configuration's entries start, the
-    file is one of which the whole is ``appended_only``; its four readers
-    list its cell alone, and no shipped list names it."""
+    file is one of which the whole is ``appended_only``; its four own
+    readers list its cell alone, the five shared ones list it after the
+    three decoder cells, and nothing else names it."""
     names = lambda group: [e["name"] for e in bench[group]]  # noqa: E731
     at = {"configs": names("configs").index("sdar-30b-a3b"),
           "workloads": names("workloads").index(CELL),
           "per_layer": names("per_layer").index("device_block_attention_ms")}
-    before = dict(bench, **{group: bench[group][:i]
+    # as a program PR appended it (PR 41); the five lists one name longer
+    # are a benchmark PR's edit (PR 44), which ``appended_only`` calls one
+    whole = dict(bench, per_layer=[
+        dict(m, workloads=[w for w in m["workloads"] if w != CELL])
+        if m["name"] in SHARED else m for m in bench["per_layer"]])
+    before = dict(whole, **{group: whole[group][:i]
                             for group, i in at.items()})
-    assert appended_only(before, bench)
+    assert appended_only(before, whole)
+    assert not appended_only(before, bench)
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for name, (unit, better, layer) in READERS.items():
         assert by_name[name] == {
             "name": name, "unit": unit, "better": better,
             "source": "device_trace", "layer": layer,
             "moves": "images_per_s_per_chip", "workloads": [CELL]}
+    for name in SHARED:
+        assert by_name[name]["workloads"][-1] == CELL
+        assert len(by_name[name]["workloads"]) == 4
     for name, metric in by_name.items():
-        assert (CELL in metric.get("workloads", [])) == (name in READERS)
+        assert (CELL in metric.get("workloads", [])) == (
+            name in READERS or name in SHARED), name
     assert {"step_mfu", "device_step_ms", "device_starved_ms"} <= {
         m["name"] for m in bench["per_layer"] if "workloads" not in m}
     entry = bench["configs"][at["configs"]]
@@ -162,7 +212,8 @@ def test_the_shipped_file_has_its_configuration_cell_and_readers(bench):
     cell = bench["workloads"][at["workloads"]]
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
     assert "~1,024 pairs" in cell["why"] and "share" in cell["why"]
-    assert "follows seed's router" in cell["why"]
+    # the rate followed the seed's router until the mix fixed its work
+    assert "the mix's seed" in cell["why"]
 
 
 def test_the_configuration_file_holds_every_published_width():
@@ -195,6 +246,53 @@ def test_the_configuration_file_holds_every_published_width():
     # the data never holds the mask token
     assert mix["dataset"]["vocab_size"] == arch["mask_token_id"]
     assert mix["dataset"]["seq_len"] % arch["block_length"] == 0
+
+
+# -- the limits, by the written rule over every reading -----------------------
+
+def test_the_limits_are_the_written_rule_over_every_reading():
+    """``limits/<cell>.readings.csv`` holds every reading of the cell's five
+    numbers by seed: the program's (``sound``), the float8 control's, the
+    reference's at the stated bfloat16. The limits file's three keys a
+    number are what is in it, and each limit is what the rule of
+    ``chipbench/README.md`` makes of them: the geometric mean of the largest
+    sound reading and the smallest control where they separate, of the
+    largest sound reading and 1 for the two gaps of norms, three times the
+    largest sound reading and no lower than the accepted one for the loss.
+    Every sound reading is at least two times under its limit."""
+    by_read, file = _readings()
+    sound, control = by_read["sound"], by_read["control_float8"]
+    seeds = [row["seed"] for row in sound]
+    assert len(set(seeds)) == len(seeds) >= 23 and "2147485021" in seeds
+    assert len(control) >= 4
+    limits, entries = file["limits"], file["readings"]
+    for name in limits:
+        entry = entries[name]
+        assert entry["seeds"] == len(sound)
+        assert entry["sound_max"] == max(float(r[name]) for r in sound)
+        assert 2 * entry["sound_max"] <= limits[name], name
+    for name in ("out_grad_diff", "grad_diff"):
+        entry = entries[name]
+        assert entry["fault_min"] == min(float(r[name]) for r in control)
+        assert entry["fault_min"] >= 3 * entry["sound_max"]
+        assert limits[name] == pytest.approx(math.sqrt(
+            entry["sound_max"] * entry["fault_min"]), rel=5e-3)
+        # every control fails it
+        assert all(float(r[name]) > limits[name] for r in control)
+    for name in ("grad_gap", "update_gap"):
+        assert entries[name]["fault_min"] == 1.0
+        assert limits[name] == pytest.approx(
+            math.sqrt(entries[name]["sound_max"]), rel=2e-2)
+    assert limits["loss_gap"] == pytest.approx(max(
+        LOSS_GAP_FLOOR, 3 * entries["loss_gap"]["sound_max"]), rel=2e-2)
+    assert limits["loss_gap"] >= LOSS_GAP_FLOOR
+    # what the stated precision alone makes of the seed that read 8%
+    stated = {r["seed"]: r for r in by_read["control_bfloat16"]}
+    program = {r["seed"]: r for r in sound}
+    assert stated["2147485021"]["grad_gap_at"] == "layer_0.moe.router" == (
+        program["2147485021"]["grad_gap_at"])
+    assert 0.05 < float(stated["2147485021"]["grad_gap"]) < 0.1
+    assert 0.05 < float(program["2147485021"]["grad_gap"]) < 0.1
 
 
 # -- the costs, from shapes ---------------------------------------------------
@@ -295,12 +393,95 @@ def test_the_four_readers_read_their_scopes_and_kernels(tmp_path, capsys):
     said = capsys.readouterr().out
     assert "kernel flash_fwd in attention_block: 2 calls a step" in said
     assert "kernel flash_bwd in attention_block: 1 calls a step" in said
-    # the whole line, through the harness: the four and nothing shipped
+    # the whole line, through the harness: the four, and of the shared five
+    # the one that finds something in a trace of attention alone
     bench = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
     listed = [m for m in bench["per_layer"] if "workloads" in m]
     out = harness.per_layer(dict(bench, per_layer=listed), CELL,
                             [harness.HERE], run.record, run.trace)
-    assert sorted(out) == sorted(READERS)
+    assert sorted(out) == sorted(
+        list(READERS) + ["flash_fwd_calls_per_bwd_call"])
+    assert out["flash_fwd_calls_per_bwd_call"]["value"] == 2.0
+
+
+#: a routed layer's rows beside ``_rows``' attention: three module scopes,
+#: the compiler's grouped product twice and the call that lays its groups out
+ROUTED_SECONDS = {"fusion.20": 5 * 0.003, "fusion.21": 5 * 0.005,
+                  "fusion.22": 5 * 0.004, "ragged-dot-none.3": 5 * 0.0010,
+                  "ragged-dot-none.4": 5 * 0.0012,
+                  "ragged-dot-metadata.5": 5 * 0.0001}
+GAUGES = {"model/expert_load_max": 6200.0, "model/expert_load_mean": 1024.0,
+          "model/expert_load_sum": 6 * 16384.0,
+          "model/expert_rows_walked_sum": 6 * 32768.0,
+          "model/expert_rows_walked_max": 32768.0}
+
+
+def _routed_run(tmp_path, kept=False):
+    moe = STEP + "jvp(SparseDecoder)/layer_0/moe/tpu_ddp.module."
+    rows = dict(_rows(), **{
+        "fusion.20": (moe + "moe_route/dot_general", "forward", "moe_route"),
+        "fusion.21": (moe + "moe_dispatch/gather", "forward",
+                      "moe_dispatch"),
+        "fusion.22": (moe + "moe_combine/scatter-add", "forward",
+                      "moe_combine"),
+        "ragged-dot-none.3": ("ragged-dot-none", "forward", "moe_experts"),
+        "ragged-dot-none.4": ("ragged-dot-none", "backward", "moe_experts"),
+        "ragged-dot-metadata.5": ("ragged-dot-metadata", "forward",
+                                  "moe_dispatch")})
+    if kept:  # the layer keeps its attention's output: no second forward call
+        del rows["flash_fwd.2"]
+    run = _traced(tmp_path, CELL, rows, dict(
+        {k: v for k, v in SECONDS.items() if k in rows}, **ROUTED_SECONDS))
+    with open(os.path.join(os.path.dirname(run.record["trace_dir"]),
+                           "telemetry", "trace-p0.jsonl"), "w") as f:
+        f.write(json.dumps({"type": "counters", "attrs": {
+            "tables": {}, "gauges": GAUGES}}) + "\n")
+    return run
+
+
+def test_the_five_shared_readers_read_this_cell_by_its_own_files(tmp_path):
+    """The routed experts as ``sdar-30b-a3b.json`` states them: every one
+    of the six layers sparse (``decoder_sparse_step`` 1, no dense layer
+    named), 16 gated experts of 768 held; the rows that landed a body from
+    the counters; the flash forward kernel once a backward call where the
+    layer keeps its output."""
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "sdar-30b-a3b.json")) as f:
+        arch = json.load(f)
+    assert kernel_costs.layer_bodies(arch) == [(None, 0, True)] * 6
+    assert kernel_costs.routed_experts(arch) == {
+        "bodies": 6, "held": 16, "products": [
+            dict(contraction=2048, columns=2 * 768),
+            dict(contraction=768, columns=2048)]}
+    # the mask is not a band: no shape for the band's readers to count by
+    assert kernel_costs.attention_shapes(arch) == {}
+    run = _routed_run(tmp_path, kept=True)
+    read = {name: _reader(name).read(run) for name in SHARED}
+    assert read["device_moe_ms"] == pytest.approx(
+        1e3 * sum(ROUTED_SECONDS.values()) / 5)
+    assert read["expert_load_max_over_mean"] == 6200.0 / 1024.0
+    assert read["moe_rows_walked_over_landed"] == 2.0
+    assert read["flash_fwd_calls_per_bwd_call"] == 1.0
+    per_call = sum(kernel_costs.least_seconds(*kernel_costs.grouped_call(
+        rows=16384.0, held=16, **product), PEAKS) for product in (
+        dict(contraction=2048, columns=1536),
+        dict(contraction=768, columns=2048))) / 2
+    assert read["grouped_matmul_roofline"] == pytest.approx(
+        100 * 2 * per_call / (0.0010 + 0.0012 + 0.0001))
+    assert 0 < read["grouped_matmul_roofline"] < 100
+    # the whole line: the cell's own four and the five, nothing else listed
+    bench = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
+    listed = [m for m in bench["per_layer"] if "workloads" in m]
+    out = harness.per_layer(dict(bench, per_layer=listed), CELL,
+                            [harness.HERE], run.record, run.trace)
+    assert sorted(out) == sorted(list(READERS) + list(SHARED))
+    # a layer recomputed whole runs the forward kernel in both passes
+    assert _reader("flash_fwd_calls_per_bwd_call").read(
+        _routed_run(tmp_path / "recomputed")) == 2.0
+    # and the band's readers find no shape of theirs under this mask
+    for name in ("flash_fwd_roofline", "flash_bwd_roofline",
+                 "device_attention_ms"):
+        assert _reader(name).read(run) is None
 
 
 def test_a_program_without_the_scopes_reads_nothing(tmp_path):

@@ -176,6 +176,35 @@ def test_the_control_alone_lays_batches_out_as_the_task_does(tmp_path,
     assert "sound" not in row and row["control"]["out_grad_diff"] > 1e-3
 
 
+def _control_row(tmp_path, capsys, *more):
+    _, bench, roots = load_lm(tmp_path)
+    control.main(["--workload", tiny_lm.CELL, "--seeds", "4", "--read",
+                  "control", *more], roots=roots, bench_path=bench,
+                 device_check=False)
+    lines = capsys.readouterr().out.splitlines()
+    row, summary = (json.loads(
+        [ln for ln in lines if ln.startswith(head)][-1].split(":", 1)[1])
+        for head in ("control:", "control summary:"))
+    return row, summary
+
+
+def test_the_control_at_a_precision_of_choice_reads_under_the_notch_below(
+        tmp_path, capsys):
+    """``--precision`` puts the reference in the program's place at the
+    precision named: the stated one (float32 here; bfloat16 in the decoder
+    cells, where it says what that precision alone makes of a reading) reads
+    under the notch below it in ``out_grad_diff``, and that under the next."""
+    read = {}
+    for precision in ("float32_highest", "bfloat16", "float8"):
+        row, summary = _control_row(
+            tmp_path / precision, capsys, "--precision", precision)
+        assert summary["control_precision"] == precision
+        assert row["control_precision"] == precision
+        read[precision] = row["control"]["out_grad_diff"]
+    assert read["float32_highest"] < read["bfloat16"] / 3
+    assert read["bfloat16"] < read["float8"] / 3
+
+
 def test_a_reference_file_that_says_nothing_is_an_image_classifier_under_sgd(
         case, bench):
     loaded = harness.load_cell(bench, "resnet50-cifar.b512", case.roots)
