@@ -242,6 +242,54 @@ def test_the_state_space_scan_compiles_at_the_hybrid_decoder_widths(topo):
     assert backward.memory_analysis().temp_size_in_bytes < 3e9
 
 
+def test_the_selective_scan_compiles_at_the_decoder_hybrid_decoder_widths(
+        topo):
+    """``phi4-mini-flash.seq16k-v25008``'s scan: one sequence of 16,384
+    positions, 5120 channels, 16 states, forward and backward: three
+    kernels in the gradient (the forward one, and the backward pass's
+    own)."""
+    from tpu_ddp.ops.selective_scan import selective_scan
+
+    one = _one_chip(topo)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def loss(x, dt, A, B, C, D):
+        return selective_scan(x, dt, A, B, C, D, interpret=False).astype(
+            jnp.float32).sum()
+
+    text = _text(
+        jax.grad(loss, argnums=tuple(range(6))), shape((1, 16384, 5120)),
+        shape((1, 16384, 5120), jnp.float32), shape((5120, 16), jnp.float32),
+        shape((1, 16384, 16)), shape((1, 16384, 16)),
+        shape((5120,), jnp.float32))
+    assert text.count(CUSTOM_CALL) == 2
+
+
+def test_differential_attention_compiles_one_backward_kernel_at_16k(topo):
+    """``phi4-mini-flash``'s full-attention call: 16,384 positions, 40
+    query heads of 64 over 20 key heads of 64 and value heads of 128; the
+    carry of the one-kernel backward pass is exactly its budget
+    (``_FUSED_CARRY_MAX``), and the compiler takes it."""
+    import importlib
+
+    fa = importlib.import_module("tpu_ddp.ops.flash_attention")
+    assert fa._fused_carry_bytes(16384, 128, 128, 2) == fa._FUSED_CARRY_MAX
+    one = _one_chip(topo)
+    shape = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        return _flash_impl()(q, k, v, causal=True, window=0).astype(
+            jnp.float32).sum()
+
+    text = _text(jax.grad(loss, argnums=(0, 1, 2)), shape(1, 16384, 40, 64),
+                 shape(1, 16384, 20, 64), shape(1, 16384, 20, 128))
+    assert text.count(CUSTOM_CALL) == 2 and "kernel.flash_bwd" in text
+    assert "kernel.flash_dq" not in text and "kernel.flash_dkv" not in text
+
+
 def test_the_routed_ladder_compiles_at_the_latent_experts_widths(topo):
     """``_switch`` over the hybrid cell's ladder: 16,384 tokens x 22 choices
     with 8 of 512 plain experts held, so 11,264 rows and, a token's choices

@@ -91,7 +91,9 @@ KEPT_NAMES = (OUT_NAME, LSE_NAME, ROUTED_NAME, LOGITS_NAME, IDS_NAME,
 
 def recomputed(module_cls):
     """``module_cls`` recomputed in the backward pass, all but
-    ``KEPT_NAMES``: what ``remat: true`` means to both decoder stacks."""
+    ``KEPT_NAMES``: what ``remat: true`` means to the decoder stacks. Their
+    layers are functions of the stream alone; ``models/sambay.py``'s take
+    and hand on further tensors through the same wrapper."""
     return nn.remat(module_cls, policy=jax.checkpoint_policies
                     .save_only_these_names(*KEPT_NAMES))
 

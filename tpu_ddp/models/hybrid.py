@@ -12,6 +12,8 @@ plain ``relu ** 2`` experts, a selection bias). RMSNorm, no biases but the
 convolution's, untied embedding and head. One flax module,
 ``HybridDecoder``, described by a ``HybridSpec``; ``nemotron3_super``
 registers NVIDIA's Nemotron-3-Super-120B-A12B at its published sizes.
+A block here is a function of the residual stream alone; a stack whose
+layers also read another layer's tensors is ``models/sambay.py``.
 
 Input ``tokens`` (B, T) int32, output float32 logits (B, T, vocabulary rows
 held); the task is ``next_token`` (``train/tasks.py``), as the sparse

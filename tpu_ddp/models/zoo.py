@@ -13,7 +13,8 @@ MODEL_REGISTRY: dict = {}
 LAZY_MODELS = {"laguna_xs2": "tpu_ddp.models.decoder",
                "joyai_llm_flash": "tpu_ddp.models.decoder",
                "sdar_30b_a3b": "tpu_ddp.models.decoder",
-               "nemotron3_super": "tpu_ddp.models.hybrid"}
+               "nemotron3_super": "tpu_ddp.models.hybrid",
+               "phi4_mini_flash": "tpu_ddp.models.sambay"}
 
 
 def register(name: str):
