@@ -245,9 +245,12 @@ def test_the_state_space_scan_compiles_at_the_hybrid_decoder_widths(topo):
 def test_the_selective_scan_compiles_at_the_decoder_hybrid_decoder_widths(
         topo):
     """``phi4-mini-flash.seq16k-v25008``'s scan: one sequence of 16,384
-    positions, 5120 channels, 16 states, forward and backward: three
-    kernels in the gradient (the forward one, and the backward pass's
-    own)."""
+    positions, 5120 channels, 16 states, ``x``, ``B`` and ``C`` in bfloat16
+    as the model has them, forward and backward: two kernels in the
+    gradient (the forward one, and the backward pass's own). The kernels
+    read and write the model's arrays: the compiled program holds no
+    float32 copy of a (1, 16384, 5120) bfloat16 array beside ``dt``'s
+    gradient, and nothing replicated over lanes (PR 46)."""
     from tpu_ddp.ops.selective_scan import selective_scan
 
     one = _one_chip(topo)
@@ -259,12 +262,68 @@ def test_the_selective_scan_compiles_at_the_decoder_hybrid_decoder_widths(
         return selective_scan(x, dt, A, B, C, D, interpret=False).astype(
             jnp.float32).sum()
 
-    text = _text(
-        jax.grad(loss, argnums=tuple(range(6))), shape((1, 16384, 5120)),
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        shape((1, 16384, 5120)),
         shape((1, 16384, 5120), jnp.float32), shape((5120, 16), jnp.float32),
         shape((1, 16384, 16)), shape((1, 16384, 16)),
-        shape((5120,), jnp.float32))
+        shape((5120,), jnp.float32)).compile()
+    text = compiled.as_text()
     assert text.count(CUSTOM_CALL) == 2
+    assert "[1,16384,16,128]" not in text
+    # ``ddt`` and the checkpoints, 336 + 42 MB, and small change: a float32
+    # copy of ``x`` or ``y`` would be 336 MB more each
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+    assert compiled.memory_analysis().output_size_in_bytes < 520e6
+
+
+@pytest.fixture(scope="module")
+def mamba_layer_step(topo):
+    """Value and gradient of published layer 16 of ``phi4_mini_flash`` (a
+    Mamba-1 layer, the one that hands its scan's output on) over one
+    sequence of 16,384 tokens in bfloat16, recomputed as ``SambaYDecoder``
+    recomputes it, compiled for one described chip."""
+    from tpu_ddp.models.decoder import recomputed
+    from tpu_ddp.models.sambay import SambaYLayer, phi4_mini_flash_spec
+    from tpu_ddp.parallel import runtime
+
+    one = _one_chip(topo)
+    spec = phi4_mini_flash_spec(first_layer=14, num_layers=6, vocab_rows=512)
+    layer = recomputed(SambaYLayer)(spec.memory_layer, spec,
+                                    dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(lambda: layer.init(
+        jax.random.key(0), jnp.zeros((1, 16, spec.hidden), jnp.bfloat16)))[
+            "params"]
+    params = jax.tree.map(lambda l: jax.ShapeDtypeStruct(
+        l.shape, l.dtype, sharding=one), shapes)
+    h = jax.ShapeDtypeStruct((1, 16384, spec.hidden), jnp.bfloat16,
+                             sharding=one)
+
+    def loss(p, h):
+        out, memory = layer.apply({"params": p}, h)
+        return (out.astype(jnp.float32).sum()
+                + memory.astype(jnp.float32).sum())
+
+    # the mixer asks the runtime whether to interpret its kernels, and the
+    # runtime sees the CPU here: steered in the test, as ``interpret=False``
+    # steers the kernels' own cases
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(runtime, "is_tpu_device", lambda: True)
+        return jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+            params, h).compile()
+
+
+def test_a_recomputed_mamba_layer_calls_the_forward_scan_once(
+        mamba_layer_step):
+    """A recomputed layer keeps everything the forward scan kernel writes,
+    its output and the state at each time block's start
+    (``selective_scan.Y_NAME``, ``CKPT_NAME`` of ``decoder.KEPT_NAMES``),
+    so the kernel's second call, which made them again for the backward
+    kernel, is dead code: one ``selective_scan_fwd`` and one
+    ``selective_scan_bwd`` where the layer had two and one before PR 46."""
+    assert _kernel_calls(
+        mamba_layer_step.as_text(), "selective_scan_fwd",
+        "selective_scan_bwd") == {"selective_scan_fwd": 1,
+                                  "selective_scan_bwd": 1}
 
 
 def test_differential_attention_compiles_one_backward_kernel_at_16k(topo):
@@ -441,13 +500,17 @@ def test_a_recomputed_expert_block_makes_its_routers_choice_once(
     assert compiled.memory_analysis().temp_size_in_bytes < temporaries
 
 
-def _flash_calls(text: str) -> dict:
-    """{kernel: custom calls} of the program's flash kernels in a compiled
+def _kernel_calls(text: str, *kernels) -> dict:
+    """{kernel: custom calls} of the program's ``kernels`` in a compiled
     text, by the scope each call's ``op_name`` keeps."""
     calls = [line for line in text.splitlines()
              if " custom-call(" in line and CUSTOM_CALL in line]
     return {kernel: sum(f"tpu_ddp.kernel.{kernel}/" in line for line in calls)
-            for kernel in ("flash_fwd", "flash_bwd")}
+            for kernel in kernels}
+
+
+def _flash_calls(text: str) -> dict:
+    return _kernel_calls(text, "flash_fwd", "flash_bwd")
 
 
 def test_a_recomputed_sparse_layer_calls_the_forward_kernel_once(

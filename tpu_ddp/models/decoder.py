@@ -73,6 +73,7 @@ from tpu_ddp.models.moe import (IDS_NAME, LOGITS_NAME, ROUTED_NAME,
                                 SCORES_NAME, DroplessMoE, SwiGLU)
 from tpu_ddp.models.zoo import register
 from tpu_ddp.ops.flash_attention import LSE_NAME, OUT_NAME
+from tpu_ddp.ops.selective_scan import CKPT_NAME, Y_NAME
 from tpu_ddp.telemetry.phases import module_scope
 
 #: What a recomputed layer or block keeps from its forward pass, by name
@@ -81,12 +82,14 @@ from tpu_ddp.telemetry.phases import module_scope
 #: of their logsumexp (``ops/flash_attention.py::_fwd``), so that no
 #: forward kernel runs in the backward pass; an expert layer's routed
 #: result, and under a selection bias its router's float32 logits, chosen
-#: ids and their scores (``models/moe.py``). A name a model does not
+#: ids and their scores (``models/moe.py``); a selective scan's output and
+#: the state at each time block's start (``ops/selective_scan.py``: what its
+#: forward kernel writes, so that it too runs once). A name a model does not
 #: produce is simply absent, and one that no backward rule reads (a sparse
 #: layer's routed result: it is only added to the residual stream) is not
 #: kept by the compiler, so one list serves every stack.
 KEPT_NAMES = (OUT_NAME, LSE_NAME, ROUTED_NAME, LOGITS_NAME, IDS_NAME,
-              SCORES_NAME)
+              SCORES_NAME, Y_NAME, CKPT_NAME)
 
 
 def recomputed(module_cls):
