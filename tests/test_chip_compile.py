@@ -218,14 +218,22 @@ def test_the_routed_ladder_compiles_at_the_decoder_widths(topo):
 
 # ---- the hybrid decoder's own shapes (nemotron3-super.seq8k-v16384) ---------
 
-def test_the_state_space_scan_compiles_at_the_hybrid_decoder_widths(topo):
+def test_the_state_space_scan_compiles_at_the_hybrid_decoder_widths(
+        topo, monkeypatch):
     """``ops/ssd_scan.py`` at the benchmark cell's shapes: 2 sequences of
     8,192 positions, 16 heads of 64 on one B/C group of state 128, chunks of
-    128, bfloat16 operands; forward and the hand-written backward pass. The
-    (128, 128) blocks of 64 chunks a head are float32 temporaries, 134 MB
-    each, of which the two passes hold a few at a time."""
+    128, bfloat16 operands; the forward kernel and, in the gradient, the
+    backward pass's own. A chunk's (128, 128) blocks stay in VMEM (PR 47):
+    what the forward call leaves in HBM beside ``y`` is the float32 state
+    at each chunk's start, 67 MB, and the gradient holds that and its
+    results, where the array form's blocks were 134 MB each and the bounds
+    1.5 and 3 GB."""
     from tpu_ddp.ops.ssd_scan import ssd_scan
+    from tpu_ddp.parallel import runtime
 
+    # the scan asks the runtime whether to interpret its kernels, and the
+    # runtime sees the CPU here: steered in the test
+    monkeypatch.setattr(runtime, "is_tpu_device", lambda: True)
     one = _one_chip(topo)
 
     def shape(dims, dtype=jnp.bfloat16):
@@ -235,11 +243,58 @@ def test_the_state_space_scan_compiles_at_the_hybrid_decoder_widths(topo):
                 shape((16,), jnp.float32), shape((2, 8192, 1, 128)),
                 shape((2, 8192, 1, 128)))
     forward = jax.jit(ssd_scan).lower(*operands).compile()
-    assert forward.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert forward.as_text().count(CUSTOM_CALL) == 1
+    assert forward.memory_analysis().temp_size_in_bytes < 1e8
     backward = jax.jit(jax.grad(
         lambda *a: ssd_scan(*a).astype(jnp.float32).sum(),
         range(5))).lower(*operands).compile()
-    assert backward.memory_analysis().temp_size_in_bytes < 3e9
+    text = backward.as_text()
+    assert text.count(CUSTOM_CALL) == 2 and "kernel.ssd_scan_bwd" in text
+    assert backward.memory_analysis().temp_size_in_bytes < 2e8
+
+
+@pytest.fixture(scope="module")
+def mamba2_block_step(topo):
+    """Value and gradient of one Mamba-2 block of the hybrid decoder at the
+    cell's widths (2 sequences of 8,192 positions, hidden 4,096, 16 heads of
+    64 on one group of state 128) in bfloat16, recomputed as
+    ``HybridDecoder`` recomputes it, compiled for one described chip."""
+    from tpu_ddp.models.decoder import recomputed
+    from tpu_ddp.models.hybrid import HybridBlock, nemotron3_super_spec
+    from tpu_ddp.parallel import runtime
+
+    one = _one_chip(topo)
+    spec = nemotron3_super_spec(num_layers=1, experts_held=8, vocab_rows=512,
+                                head_positions=8)
+    block = recomputed(HybridBlock)("M", spec, dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(lambda: block.init(
+        jax.random.key(0), jnp.zeros((1, 256, spec.hidden), jnp.bfloat16)))[
+            "params"]
+    params = jax.tree.map(lambda l: jax.ShapeDtypeStruct(
+        l.shape, l.dtype, sharding=one), shapes)
+    h = jax.ShapeDtypeStruct((2, 8192, spec.hidden), jnp.bfloat16,
+                             sharding=one)
+
+    def loss(p, h):
+        return block.apply({"params": p}, h).astype(jnp.float32).sum()
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(runtime, "is_tpu_device", lambda: True)
+        return jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+            params, h).compile()
+
+
+def test_a_recomputed_mamba2_block_calls_the_forward_scan_in_both_passes(
+        mamba2_block_step):
+    """A recomputed block keeps nothing of its scan: one ``ssd_scan_fwd`` in
+    each pass and one ``ssd_scan_bwd``. Kept by name, ``y`` and the states
+    at the chunk starts (33.5 MB and 67 MB a block) saved the second call,
+    0.46 ms then, and cost the cell's step 5.6 ms: 0.50 GB more took the
+    compiled step to the compiler's memory budget, which it answered by
+    compressing the head's logits gradient (PERF.md section 6, PR 47)."""
+    assert _kernel_calls(
+        mamba2_block_step.as_text(), "ssd_scan_fwd", "ssd_scan_bwd") == {
+            "ssd_scan_fwd": 2, "ssd_scan_bwd": 1}
 
 
 def test_the_selective_scan_compiles_at_the_decoder_hybrid_decoder_widths(
@@ -392,6 +447,7 @@ def expert_block_step(topo):
     widths (16,384 tokens, 22 of 512 experts, 8 held), recomputed, compiled
     for one described chip."""
     from tpu_ddp.models.hybrid import HybridDecoder, nemotron3_super_spec
+    from tpu_ddp.parallel import runtime
 
     one = _one_chip(topo)
     spec = nemotron3_super_spec(num_layers=2, experts_held=8, vocab_rows=512,
@@ -407,7 +463,11 @@ def expert_block_step(topo):
         logits, _ = model.apply({"params": p}, tokens, mutable=["counters"])
         return logits.sum()
 
-    return jax.jit(jax.grad(loss)).lower(params, tokens).compile()
+    # the Mamba block's scan asks the runtime whether to interpret its
+    # kernels: steered in the test
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(runtime, "is_tpu_device", lambda: True)
+        return jax.jit(jax.grad(loss)).lower(params, tokens).compile()
 
 
 def test_a_recomputed_expert_block_walks_its_routed_path_twice(

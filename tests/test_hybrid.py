@@ -262,6 +262,150 @@ def test_the_scan_names_its_passes_for_the_trace():
     assert "tpu_ddp.kernel.ssd_scan_bwd" in text
 
 
+def _kernel_case(t=24, heads=4, groups=2, p=8, n=16, b=2, rate=1.0,
+                 dtype=jnp.float32):
+    """Operands in ``dtype`` (``dt`` and ``A`` float32, as the mixer has
+    them) and the weights of a loss; ``rate`` scales ``A``."""
+    x, dt, A, B, C, weigh = _scan_case(t, groups, seed=t + heads, heads=heads,
+                                       p=p, n=n, b=b)
+    return (x.astype(dtype), dt, A * rate, B.astype(dtype),
+            C.astype(dtype)), weigh
+
+
+def _values_and_gradients(scan, operands, weigh):
+    def loss(*a):
+        y = scan(*a)
+        return jnp.sum(y.astype(jnp.float32) * weigh), y
+
+    (_, y), grads = jax.value_and_grad(loss, argnums=range(5), has_aux=True)(
+        *operands)
+    return (y,) + grads
+
+
+def _under_checkpoint(scan):
+    from tpu_ddp.models import decoder
+
+    return jax.checkpoint(scan, policy=jax.checkpoint_policies
+                          .save_only_these_names(*decoder.KEPT_NAMES))
+
+
+def _over_two_shards(scan):
+    """``scan`` inside a ``shard_map`` over ``data`` = 2, the sequences
+    split and ``A`` the same on both: off the TPU the recurrence stands in
+    for the interpreted kernels there, and ``dA`` is the shards' sum."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    rows = P("data")
+    return jax.shard_map(scan, mesh=mesh,
+                         in_specs=(rows, rows, P(), rows, rows),
+                         out_specs=rows)
+
+
+KERNEL_CASES = {
+    # several heads a group and several groups, in tiles of two heads
+    "heads_and_groups": (dict(t=32, heads=8, groups=2, p=64, n=16), 16, None),
+    "a_head_a_tile": (dict(t=16, heads=2, groups=1, p=128, n=8), 8, None),
+    "every_head_one_tile": (dict(t=16, heads=4, groups=1), 8, None),
+    # five positions of a fourth chunk: padded with ``dt = 0``
+    "ragged": (dict(t=29), 8, None),
+    "chunk_over_length": (dict(t=5), 128, None),
+    "one_chunk": (dict(t=8), 8, None),
+    "many_chunks": (dict(t=48), 4, None),
+    # decays that underflow inside a chunk: ``exp(-inf)`` under the
+    # triangle, never ``inf * 0``
+    "strongly_negative_A": (dict(t=32, rate=120.0), 16, None),
+    "under_checkpoint": (dict(t=16), 8, _under_checkpoint),
+    "two_shards": (dict(t=16), 8, _over_two_shards),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_the_scans_kernels_are_the_recurrence(case, dtype):
+    """``ssd_scan``'s two kernels, interpreted: ``y`` and all five
+    gradients against the recurrence one position at a time on the same
+    operands in float32; in bfloat16 to the roundings of the matrix unit's
+    operands."""
+    from tpu_ddp.ops.ssd_scan import ssd_scan, ssd_scan_stepwise
+
+    sizes, chunk, wrap = KERNEL_CASES[case]
+    operands, weigh = _kernel_case(dtype=dtype, **sizes)
+    scan = lambda *a: ssd_scan(*a, chunk)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        got = _values_and_gradients(wrap(scan) if wrap else scan, operands,
+                                    weigh)
+        want = _values_and_gradients(
+            ssd_scan_stepwise, [a.astype(jnp.float32) for a in operands],
+            weigh)
+    rounded = dtype == jnp.bfloat16
+    for name, g, w, like in zip(("y", "x", "dt", "A", "B", "C"), got, want,
+                                operands[:1] + operands):
+        assert g.shape == w.shape and g.dtype == like.dtype, name
+        g = np.asarray(g.astype(jnp.float32))
+        assert np.isfinite(g).all(), name
+        # ``la``'s gradient is what is left of two sums that cancel: where
+        # the decays underflow there is less gradient in ``ddt`` and ``dA``
+        # than rounding of those sums (the array form this replaced read
+        # the same to four digits), so they are held to be finite
+        if case == "strongly_negative_A" and name in ("dt", "A"):
+            continue
+        loose = (1e-1 if name == "A" else 3e-2) if rounded else 2e-5
+        np.testing.assert_allclose(
+            g, w, atol=loose * float(jnp.max(jnp.abs(w))) + 1e-30,
+            err_msg=name)
+
+
+def test_a_recomputed_caller_runs_the_forward_kernel_in_both_passes():
+    """The scan names nothing for a recomputation policy (PERF.md section
+    6, PR 47: keeping ``y`` and the states cost the cell's step more than
+    the call they save), so under the decoder stacks' policy a recomputed
+    caller has the forward kernel twice and the backward one once, as it
+    has under a policy that keeps nothing."""
+    from tpu_ddp.models import decoder
+    from tpu_ddp.ops.ssd_scan import ssd_scan
+
+    operands, weigh = _kernel_case(t=16)
+
+    def calls(policy):
+        scan = jax.checkpoint(lambda *a: ssd_scan(*a, 8), policy=policy)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(scan(*a) * weigh), range(5)))(*operands))
+        return text.count("pallas_call")
+
+    policies = jax.checkpoint_policies
+    assert calls(policies.nothing_saveable) == 3
+    assert calls(policies.save_only_these_names(*decoder.KEPT_NAMES)) == 3
+
+
+def test_the_scans_kernels_trace_inside_a_shard_map(monkeypatch):
+    """The train steps call the kernels inside a ``shard_map`` over
+    ``data``, where activations vary over the mesh and ``A`` does not: the
+    kernels' results say so, their loops carry nothing from an operand, and
+    ``A``'s gradient is summed over the shards by AD. Traced as the chip
+    compiles it, not lowered."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from tpu_ddp.ops.ssd_scan import ssd_scan
+    from tpu_ddp.parallel import runtime
+
+    monkeypatch.setattr(runtime, "is_tpu_device", lambda: True)
+    (x, dt, A, B, C), _ = _kernel_case(t=16)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+
+    def shard(x, dt, A, B, C):
+        value, dA = jax.value_and_grad(
+            lambda A: jnp.sum(ssd_scan(x, dt, A, B, C, 8)))(A)
+        return jax.lax.pmean(value, "data"), dA
+
+    rows = P("data")
+    text = str(jax.make_jaxpr(jax.shard_map(
+        shard, mesh=mesh, in_specs=(rows, rows, P(), rows, rows),
+        out_specs=(P(), P())))(x, dt, A, B, C))
+    assert text.count("pallas_call") == 2 and "psum" in text
+
+
 # -- the share test ----------------------------------------------------------------
 
 def _mixer_tree(ref, leaves):

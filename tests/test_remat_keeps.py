@@ -116,11 +116,27 @@ def test_a_recomputed_stack_is_the_stored_one_with_one_forward_call_a_layer(
     if case != "hybrid":
         _same_bits(want, got)
     # a forward and a backward kernel a layer, recomputed or not: the
-    # forward kernel's second call went with the names (3 a layer before)
+    # forward kernel's second call went with the names (3 a layer before).
+    # The hybrid stack's two Mamba-2 blocks have the scan's kernels beside
+    # (PR 47), and keep nothing of them: a recomputed one runs the forward
+    # kernel in both passes
+    scans = hybrid_tiny.PATTERN.count("M") if case == "hybrid" else 0
     for model in (stored, recomputed):
-        text = str(jax.make_jaxpr(step[model])(tree, tokens))
-        assert text.count("pallas_call") == 2 * layers, case
-        assert ("remat2" in text) == model.remat
+        traced = jax.make_jaxpr(step[model])(tree, tokens)
+        assert _kernel_calls(traced.jaxpr) == 2 * layers + scans * (
+            3 if model.remat else 2), case
+        assert ("remat2" in str(traced)) == model.remat
+
+
+def _kernel_calls(jaxpr) -> int:
+    """The ``pallas_call`` equations of a jaxpr and of what it calls, each
+    call site counted (the scan's calls are jitted, and a jaxpr's text
+    prints a jitted function once however often it is called)."""
+    return sum(
+        1 if eqn.primitive.name == "pallas_call" else sum(
+            _kernel_calls(sub)
+            for sub in jax.core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns)
 
 
 def _attend(q, k, v, kv_mask, w):

@@ -208,7 +208,11 @@ class HybridDecoder(nn.Module):
     #: twice 1.4 MB), so that the six-pass product, the ``top_k`` of 22
     #: over 512 and the gather of the chosen scores run once a block and
     #: step (PR 34); and an attention block's output and a float32 a row of
-    #: its logsumexp (16.8 MB), so that ``flash_fwd`` runs once (PR 42)
+    #: its logsumexp (16.8 MB), so that ``flash_fwd`` runs once (PR 42).
+    #: A Mamba block keeps nothing of its scan: ``ssd_scan_fwd`` runs again
+    #: in the backward pass, 0.29 ms a block, where keeping what it writes
+    #: (33.5 MB of ``y`` and 67 MB of states a block, 0.50 GB) cost the
+    #: step 5.6 ms (PERF.md section 6, PR 47)
     remat: bool = False
     task = "next_token"
     flash_blocks = (512, 512)  # as the sparse decoder's, and for its reason
