@@ -165,7 +165,7 @@ def main() -> None:
         ),
         "note": "compile-only (deviceless AOT against the real XLA:TPU + "
                 "Mosaic toolchain in libtpu); execution evidence lives in "
-                "bench_tpu.json",
+                "PERF_LEDGER.jsonl",
         "programs": {},
     }
     progs = results["programs"]

@@ -305,8 +305,8 @@ def full_width_cli(run_dir: str, *, device: str = "tpu",
 
 def full_width_step(model, *, image_size: int = 224, batch: int = 64,
                     num_classes: int = 1000, steps: int = 3) -> dict:
-    """A few steps of ``make_train_step`` on one device, built the way
-    ``bench.py::_image224_point`` builds them — and the one-line check that
+    """A few steps of ``make_train_step`` on one device, on one batch
+    staged before the first of them — and the one-line check that
     ``block_until_ready`` is honest: once it returns, fetching a value that
     depends on every step must cost no more than a copy."""
     import jax

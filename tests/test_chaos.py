@@ -415,8 +415,8 @@ def test_cross_layout_elastic_resume_is_bit_consistent(tmp_path):
     """Kill at step N on an 8-device mesh, restart on 4 devices: the
     zero1 opt shards AND the grad-compress error-feedback residual must
     re-scatter bit-consistently through the de-sharded checkpoint
-    layout, and training must continue finite (the chaos demo's curves
-    gate covers 'rejoins the seed band' end-to-end)."""
+    layout, and training must continue finite ('rejoins the seed band'
+    end to end is checked by no test)."""
     import jax
     import jax.tree_util as jtu
 

@@ -245,9 +245,6 @@ def test_only_the_helper_sets_a_cache_directory():
 @pytest.mark.parametrize("module,path", [
     ("tpu_ddp.cli.launch", "."),
     ("tpu_ddp.elastic.supervisor", "."),
-    ("bench", "."),
-    ("tpu_curve", "benchmarks"),
-    ("tpu_recipe", "benchmarks"),
 ])
 def test_parents_that_start_children_stay_off_jax(module, path):
     """A parent that has touched jax holds the chip, and its child then

@@ -1,9 +1,9 @@
 """Comms observatory (docs/comms.md): α-β fits, link-model lookup
 rules, the COM001 collapse alert, and stuck-collective forensics.
 
-Everything here is stdlib-only and sub-second — the live circuit
-(measured microbenchmarks, a real comm_stall, a real watchdog hang) is
-``make comms-demo``'s job.
+All but one test here is stdlib-only and sub-second; that one runs
+``comms bench`` for real. The live circuit (a real comm_stall, a real
+watchdog hang) is checked by no test.
 """
 
 from __future__ import annotations
@@ -174,6 +174,33 @@ def test_comms_artifact_classifies_and_gates_both_directions(tmp_path):
     res = compare(old, normalize_artifact(art(1.01e9)), tolerance=0.05)
     assert not res["regressions"] and not any(
         "achieved_bw" in r for r in res["improvements"])
+
+
+def test_comms_bench_measures_rings_into_monotone_fits(tmp_path):
+    """``tpu-ddp comms bench`` on four virtual devices: the XLA
+    all-reduce and the f32 and int8 rings are really run at two payload
+    sizes, every fitted line rises with the bytes on the wire, the int8
+    ring moves fewer bytes than the f32 ring at equal payload (from the
+    measured rows), and the artifact records as kind ``comms``."""
+    from tpu_ddp.comms.cli import main as comms_main
+    from tpu_ddp.registry.store import record_artifact
+
+    out = tmp_path / "comms.json"
+    assert comms_main([
+        "bench", "--mesh", "data=4",
+        "--kinds", "all-reduce,ring-all-reduce", "--ring-modes", "f32,int8",
+        "--sizes", "4096,16384", "--reps", "1", "--out", str(out)]) == 0
+    comms = json.loads(out.read_text())["comms"]
+    assert {"ring-all-reduce/f32/data",
+            "ring-all-reduce/s8/data"} <= set(comms["links"])
+    for link in comms["links"].values():
+        assert link["alpha_s"] >= 0.0 and link["beta_bytes_per_s"] > 0.0
+    wire = {(r["dtype"], r["size"]): r["wire_bytes"]
+            for r in comms["sweeps"] if r["kind"] == "ring-all-reduce"}
+    for size in (4096, 16384):
+        assert wire[("s8", size)] < wire[("f32", size)]
+    entry = record_artifact(str(tmp_path / "reg"), str(out))
+    assert entry.artifact_kind == "comms"
 
 
 # -- the hop monitor's health file -----------------------------------------

@@ -294,8 +294,8 @@ def test_f32_mode_step_matches_plain(devices):
 
 def test_int8_step_trajectory_close(devices):
     """int8 + error feedback stays close to the uncompressed trajectory
-    over a few steps (the compress-demo gate pins 20 steps; here a tight
-    smoke bound)."""
+    over a few steps (``tpu-ddp curves diff`` holds 20 steps to 0.05; here
+    a tight smoke bound)."""
     mesh = create_mesh(MeshSpec(data=4), devices[:4])
     model = _model()
 

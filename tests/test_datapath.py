@@ -3,7 +3,7 @@ batch-provenance determinism audit, loader microbenchmarks, DAT001, and
 the tuner's input-bound floor (docs/data.md).
 
 All CPU-only; the fast tier runs no Trainer compile (the end-to-end
-staged run lives in the slow tier and ``make data-demo``).
+staged run lives in the slow tier).
 """
 
 import json

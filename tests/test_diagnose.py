@@ -14,6 +14,7 @@ exit 2 on future-schema artifacts (docs/diagnose.md).
 import glob
 import json
 import os
+import sys
 
 import pytest
 
@@ -30,7 +31,10 @@ from tpu_ddp.diagnose.rules import (
     likely_cause,
     rule_counts,
 )
-from tpu_ddp.tools.monitor_demo import write_fleet
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+from fleet_fixture import write_fleet  # noqa: E402
 
 
 # -- fault builders: one synthetic run dir per chaos kind -------------------

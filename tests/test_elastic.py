@@ -5,7 +5,8 @@ elastic.jsonl decision log, and the goodput join (docs/resilience.md).
 Everything here is stdlib-fast: the supervisor under test drives an
 injected ``run_child`` that fabricates trace evidence, so the loop's
 classify → decide → re-mesh → verify → log circuit is pinned without
-compiling a Trainer (the real-subprocess circuit is ``make chaos-demo``).
+compiling a Trainer (a real child under the supervisor is checked by no
+test).
 """
 
 from __future__ import annotations

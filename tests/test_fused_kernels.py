@@ -230,6 +230,29 @@ def test_krn001_fail_closed_names_kernel_and_fallback():
     assert "fallback" in text  # ...and so is the path actually taken
 
 
+def test_ops_bench_parity_gate_fails_closed_by_name(tmp_path, capsys):
+    """``tpu-ddp ops bench`` measures a kernel against its jnp reference
+    and fits it a cost line only while every point is bit-identical: a
+    corrupted output (the hidden ``--corrupt``) exits 1 and names the
+    kernel, so a bad lowering cannot ship a cost model."""
+    from tpu_ddp.ops.cli import main as ops_main
+
+    out = tmp_path / "ops.json"
+    args = ["bench", "--kernels", "fused_quant", "--sizes", "4096,16384",
+            "--reps", "1", "--out", str(out)]
+    assert ops_main(args) == 0
+    ops = json.loads(out.read_text())["ops"]
+    assert ops["parity_ok"] and ops["backend"] == "interpret"
+    for side in ("fused", "xla"):
+        assert ops["kernels"]["fused_quant"][side]["s_per_elem"] > 0
+    capsys.readouterr()
+    assert ops_main(args + ["--corrupt", "fused_quant"]) == 1
+    err = capsys.readouterr().err
+    assert "PARITY GATE FAILED" in err and "fused_quant" in err
+    assert json.loads(out.read_text())["ops"]["parity_failures"] == [
+        "fused_quant"]
+
+
 # ---- the ops artifact kind and cost model --------------------------------
 
 

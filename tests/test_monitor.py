@@ -5,12 +5,13 @@ The synthetic-fleet tests write the same per-host file families a real
 multihost run leaves in its run dir (``trace-p<i>.jsonl``,
 ``health-p<i>.jsonl``, ``heartbeat-p<i>.json``) with an injected
 straggler / lost host / NaN step, and assert the aggregator + rule
-engine flag exactly those hosts and rule ids — the acceptance contract
-``make monitor-demo`` gates in CI.
+engine flag exactly those hosts and rule ids.
 """
 
+import functools
 import json
 import os
+import sys
 import threading
 import time
 import urllib.error
@@ -41,6 +42,11 @@ from tpu_ddp.telemetry.watchdog import (
     read_heartbeat,
 )
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+import fleet_fixture  # noqa: E402
+
+
 @pytest.fixture(autouse=True)
 def _isolate_registry():
     """The counters registry is process-wide by design; the Trainer runs
@@ -63,62 +69,8 @@ RUN_META = {
 }
 
 
-def write_fleet(
-    run_dir,
-    *,
-    n_hosts=4,
-    n_steps=30,
-    straggler_host=None,
-    straggler_factor=3.0,
-    lost_host=None,
-    nan_host=None,
-    now=None,
-):
-    """A believable multihost run dir: per-host trace/health/heartbeat
-    files, optionally with one slow host, one dead host, one NaN step."""
-    now = time.time() if now is None else now
-    os.makedirs(run_dir, exist_ok=True)
-    for host in range(n_hosts):
-        step_s = 0.010 * (straggler_factor if host == straggler_host else 1)
-        epoch = now - 120.0
-        with open(os.path.join(run_dir, f"trace-p{host}.jsonl"), "w") as f:
-            header = {"schema_version": 1, "type": "header",
-                      "epoch_unix": epoch, "pid": host}
-            if host == 0:
-                header["run_meta"] = RUN_META
-            f.write(json.dumps(header) + "\n")
-            ts = 1.0
-            for step in range(n_steps):
-                # a loop that runs ahead with its queue full: the
-                # dispatch holds the backpressure, and the stamper's
-                # thread (tid 2) writes the device's steps beside it
-                for name, dur, tid in (("data_wait", 0.002, 1),
-                                       ("compiled_step", step_s, 1),
-                                       ("device_step", step_s, 2)):
-                    f.write(json.dumps({
-                        "schema_version": 1, "type": "span", "name": name,
-                        "ts_s": round(ts, 6), "dur_s": dur, "pid": host,
-                        "tid": tid, "depth": 0, "step": step,
-                    }) + "\n")
-                    if tid == 1:
-                        ts += dur
-        with open(os.path.join(run_dir, f"health-p{host}.jsonl"), "w") as f:
-            f.write(json.dumps({"schema_version": 1, "type": "header",
-                                "pid": host, "policy": "warn"}) + "\n")
-            for step in range(n_steps):
-                nan = host == nan_host and step == n_steps // 2
-                rec = {"schema_version": 1, "type": "health", "step": step,
-                       "pid": host, "loss": 2.0 - 0.01 * step,
-                       "grad_norm": 1.0, "all_finite": not nan}
-                if nan:
-                    rec["anomaly"] = "nonfinite"
-                f.write(json.dumps(rec) + "\n")
-        hb_wall = now - (600.0 if host == lost_host else 1.0)
-        with open(os.path.join(run_dir, f"heartbeat-p{host}.json"), "w") as f:
-            json.dump({"schema_version": 1, "wall_time": hb_wall,
-                       "step": n_steps - 1, "pid": 1234,
-                       "process_index": host}, f)
-    return now
+write_fleet = functools.partial(fleet_fixture.write_fleet, n_steps=30,
+                                run_meta=RUN_META)
 
 
 # -- OpenMetrics rendering -------------------------------------------------

@@ -298,7 +298,7 @@ def test_flash_attention_lowers_to_mosaic_for_tpu():
     lower to Mosaic (`tpu_custom_call`) on a CPU-only host. This validates
     block specs, memory spaces, and kernel structure for the real chip
     without needing one — the strongest pre-chip guarantee available (the
-    on-chip numerics check lives in bench.py::_bench_attention)."""
+    on-chip numerics check is chip_smoke.py's kernels phase)."""
     q, k, v = _qkv()
 
     fwd = lambda a, b, c: flash_attention(a, b, c, 128, 128, False)

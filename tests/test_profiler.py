@@ -519,7 +519,7 @@ def test_alert_history_pairs_episodes():
 
 def test_watch_once_json_includes_profiles_and_history(tmp_path):
     from tpu_ddp.monitor.watch import main as watch_main
-    from tpu_ddp.tools.monitor_demo import write_fleet
+    from fleet_fixture import write_fleet
 
     run_dir = str(tmp_path)
     write_fleet(run_dir)

@@ -553,99 +553,7 @@ def test_validate_top_runs_measured_trial(devices, tmp_path):
         measured["measured_vs_model"]
 
 
-# -- satellites: bench --config, memplan --json ---------------------------
-
-
-def test_bench_reads_winner_artifact(tmp_path):
-    import bench
-
-    winner = {"tune_winner_schema_version": 1,
-              "config": {"model": "netresdeep", "per_shard_batch": 8}}
-    path = tmp_path / "winner.json"
-    path.write_text(json.dumps(winner))
-    assert bench._read_winner_config(str(path)) == winner["config"]
-    # the full tune --json shape works too
-    full = {"tune_schema_version": 1,
-            "winner_config": {"model": "netresdeep"}}
-    path2 = tmp_path / "tune.json"
-    path2.write_text(json.dumps(full))
-    assert bench._read_winner_config(str(path2)) == {"model": "netresdeep"}
-    # future winner schema refused
-    path3 = tmp_path / "future.json"
-    path3.write_text(json.dumps({"tune_winner_schema_version": 99,
-                                 "config": {}}))
-    with pytest.raises(ValueError, match="newer"):
-        bench._read_winner_config(str(path3))
-    path4 = tmp_path / "empty.json"
-    path4.write_text("{}")
-    with pytest.raises(ValueError, match="config"):
-        bench._read_winner_config(str(path4))
-
-
-def test_bench_config_child_fails_loudly_on_error(tmp_path, capsys,
-                                                  monkeypatch):
-    """A failed winner measurement must exit nonzero — a CI step
-    gating on `bench.py --config` can never read 0.0 as a pass."""
-    import bench
-
-    monkeypatch.setattr(bench, "_require_tpu", lambda: ("tpu", "test"))
-    with pytest.raises(SystemExit) as exc:
-        bench.config_child_main(str(tmp_path / "missing.json"))
-    assert exc.value.code == 1
-    record = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert record["ok"] is False
-    assert record["value"] == 0.0 and "error" in record
-
-
-@pytest.mark.parametrize("child", ["config_child_main", "child_main"])
-def test_bench_child_refuses_a_platform_that_is_not_tpu(tmp_path, capsys,
-                                                        child):
-    """bench.py measures the chip or nothing: on the CPU the child exits
-    non-zero with ``"ok": false`` and the device it found, before any
-    leg runs."""
-    import bench
-
-    args = (str(tmp_path / "winner.json"),) if "config" in child else ()
-    with pytest.raises(SystemExit) as exc:
-        getattr(bench, child)(*args)
-    assert exc.value.code == 1
-    record = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert record["ok"] is False and "value" not in record
-    assert record["device"]["platform"] == "cpu"
-
-
-@pytest.mark.parametrize("fault", ["deadline", "error"])
-def test_bench_child_fails_an_incomplete_record(capsys, monkeypatch, fault):
-    """A leg the deadline left no room for, like a leg that raised, is
-    named in the record and fails the run: ``"ok": false``, exit 1 — an
-    incomplete record must not read as a whole one."""
-    import bench
-
-    legs = [n for n in dir(bench)
-            if n.startswith("_bench_") or n == "_attention_op_microbench"]
-    row = {"images_per_sec_per_chip": 1.0, "mfu": None}
-    for name in legs:
-        monkeypatch.setattr(bench, name, lambda *a, **k: dict(row))
-    monkeypatch.setattr(bench, "_require_tpu", lambda: ("tpu", "test"))
-    if fault == "deadline":  # the flagship runs, no later leg has room
-        monkeypatch.setenv(bench._DEADLINE_ENV, "0")
-    else:
-        def boom():
-            raise RuntimeError("leg blew up")
-        monkeypatch.setattr(bench, "_bench_zero1", boom)
-    with pytest.raises(SystemExit) as exc:
-        bench.child_main()
-    assert exc.value.code == 1
-    record = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert record["ok"] is False
-    assert record["provenance"]["device_kind"] == "test"
-    if fault == "deadline":
-        assert "failed_legs" not in record
-        assert len(record["skipped_legs"]) == 7
-        assert record["compute_bound"] == {"skipped": "deadline"}
-    else:
-        assert record["failed_legs"] == ["zero1_weight_update_sharding"]
-        assert "skipped_legs" not in record
+# -- satellite: memplan --json ---------------------------------------------
 
 
 def test_memplan_json_flag(tmp_path, monkeypatch):

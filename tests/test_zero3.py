@@ -106,8 +106,7 @@ def test_zero3_plain_parity(devices):
     assert int(s_z.step) == _STEPS
 
 
-@pytest.mark.slow  # ~37s (GSPMD fsdp compile) — make test-all; the
-# Trainer-scope twin of this gate runs in CI as `make zero3-demo`
+@pytest.mark.slow  # ~37s (GSPMD fsdp compile) — make test-all
 def test_zero3_fsdp_oracle_parity(devices):
     """The independent oracle: XLA's GSPMD ZeRO-3 (the in-tree fsdp
     strategy) from the IDENTICAL initial state lands on the same loss

@@ -18,7 +18,7 @@ The **fingerprints** double as a parallelism-correctness regression net:
 each strategy has a pinned set of collective kinds its compiled step must
 (and must not) contain — an accidental extra all-gather in the dp step,
 or the int8 ring silently degrading to f32, flips the verdict on CPU,
-devicelessly, before any TPU run (``make analyze-demo`` gates CI on it).
+devicelessly, before any TPU run (``tests/test_analysis.py`` holds it).
 """
 
 from __future__ import annotations
@@ -144,7 +144,7 @@ def check_fingerprint(anatomy: StepAnatomy,
 # -- building an anatomy for a strategy -----------------------------------
 
 def _tiny_model(strategy: str, num_classes: int, dtype):
-    """Small per-family models for fast CPU analysis (the demo / test
+    """Small per-family models for fast CPU analysis (the tests'
     path; pass ``model_name`` for the real zoo)."""
     if strategy in ("sp", "pp", "tp", "fsdp_tp", "fsdp"):
         from tpu_ddp.models.vit import ViT

@@ -620,7 +620,7 @@ def cached_compile(key: Any, build) -> Any:
     lifetime. Callers key on everything that determines the compiled
     program — (strategy, model, shapes, dtype, flags, topology) — so a
     sweep comparing layouts of the same program (memplan's
-    ``--zero1 --grad-compress`` tables, the analyze demo's fingerprint
+    ``--zero1 --grad-compress`` tables, ``tpu-ddp analyze --strategy all``'s
     loop) compiles each distinct program once."""
     if key in _COMPILE_CACHE:
         _CACHE_STATS["hits"] += 1

@@ -259,8 +259,8 @@ class ChaosInjector:
 
     def on_step(self, step: int) -> None:
         """Fire every due, unfired, this-host fault, in spec order (two
-        faults due at one step fire in list order — the ordering the
-        demo's corrupt-then-kill sequence depends on)."""
+        faults due at one step fire in list order — the ordering a
+        corrupt-then-kill spec depends on)."""
         self._last_step = int(step)
         for fault_id, fault in enumerate(self.faults):
             if (not self._mine(fault) or self._fired(fault_id)

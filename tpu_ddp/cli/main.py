@@ -47,7 +47,7 @@ Subcommands:
   ``--against <registry>`` judges it against the seed band of archived
   baseline runs sharing its seed-invariant quality digest (CRV001-004
   findings, exit 1 on any); ``tpu-ddp curves diff A B`` is the
-  step-aligned overlay-parity verdict ``make compress-demo`` gates on
+  step-aligned overlay-parity verdict of two recorded runs
   (docs/curves.md).
 - ``tpu-ddp mem <run_dir>`` — memory truth loop: the live sampler's
   per-host HBM timeline, measured high-water reconciled against the

@@ -22,7 +22,7 @@ which :func:`match_program_order` checks against the PR 6
 collective-permute in HLO).
 
 Everything here is stdlib-only (importable from the supervisor/monitor
-side with jax never loaded); only ``join_schedule`` — the CLI/demo
+side with jax never loaded); only ``join_schedule`` — the CLI's
 convenience that rebuilds the recorded program's order — imports jax,
 lazily.
 """

@@ -13,7 +13,7 @@ intact. Four stdlib-only modules:
   archived baseline runs sharing a *seed-invariant* ``quality_digest``,
   and judge a candidate against it with lint-style CRV findings.
 - ``diff``      — step-aligned paired A/B comparison for overlay-parity
-  verdicts (the oracle ``make compress-demo`` gates on, and the
+  verdicts (``tests/test_curves.py`` checks it both ways; the
   contract future ZeRO-3/Pallas PRs pin against).
 - ``report``    — the ``tpu-ddp curves`` CLI: sparkline render, band
   verdicts with fix hints, ``--json`` artifacts the perf registry

@@ -10,7 +10,7 @@ a recipe whose seeds agree tightly doesn't flag ordinary jitter.
 
 A candidate run is judged against the band with lint-``RULES``-style
 findings (stable id + severity + fix hint — the single source behind
-the report, the docs/curves.md table, and the CI demo's exact-id
+the report, the docs/curves.md table, and the tests' exact-id
 assertions):
 
 - CRV001  final eval metric below the band          (critical)

@@ -14,8 +14,8 @@ sampled steps:
   per-batch quantization noise on a healthy int8 run decorrelates the
   raw per-step losses by a few hundredths (reported, not gated), while
   a genuine divergence moves the smoothed curve by whole units. This
-  is the same 20-step/0.05 discipline ``make compress-demo`` pinned by
-  hand since PR 4, now shared as one oracle;
+  is the 20-step/0.05 discipline the int8 trajectory check has held
+  since PR 4, now shared as one oracle;
 - **final eval loss drift** — gated at ``eval_tolerance`` (default 3×
   the trajectory tolerance: one evaluation point at the churniest end
   of training carries more variance than the smoothed curve) when both

@@ -9,7 +9,7 @@ ledger where it can, and carries the citations its decision rests on
 plus a concrete next action. A clean run fires nothing.
 
 Thresholds are deliberately conservative: the chaos-verified contract
-(``make diagnose-demo``) is that every injected fault kind is
+(``tests/test_diagnose.py``) is that every injected fault kind is
 diagnosed as EXACTLY its own root cause, so a rule that could fire on
 a healthy run's noise is a bug here, not an operator judgment call.
 """

@@ -23,7 +23,7 @@ This module gives the death a paper trail:
   static plan inside an OOM handler would be asking a drowning process
   to swim. The plan side (:func:`attach_plan`: memplan-convention peak +
   the top-k largest buffers of the recorded program's compiled HLO) is
-  attached at REPORT time by ``tpu-ddp mem``/the demo, the same
+  attached at REPORT time by ``tpu-ddp mem``, the same
   rebuild-at-read-time contract as the profiler's per-op table.
 - the Trainer also emits an ``oom_abort`` trace instant, which
   ``ledger/stitch.py`` classifies as the new ``oom`` exit class

@@ -185,7 +185,7 @@ def measured_summary(run_dir: str) -> dict:
 
 
 #: the one-line caveat every live-array-accounted (deviceless) join
-#: carries — asserted verbatim by the mem-demo CI gate
+#: carries — asserted verbatim by tests/test_memtrack.py
 CPU_DEGRADATION_NOTE = (
     "measured via live-array accounting (this backend exposes no device "
     "memory_stats): resident framework buffers only, XLA temp workspace "

@@ -5,7 +5,7 @@ The rule registry mirrors the graph-lint registry
 a kind (``threshold`` / ``trend`` / ``staleness``), and a one-line fix
 hint — the single source behind the findings, the ``tpu-ddp watch``
 display, and the docs/monitoring.md rule table. Stable ids are the
-contract: CI (``make monitor-demo``) injects a straggler and a NaN
+contract: ``tests/test_monitor.py`` injects a straggler and a NaN
 spike and asserts exactly their ids fire, and downstream automation
 (the future elastic controller's re-mesh trigger) keys on them.
 

@@ -132,7 +132,7 @@ class MonitorExporter:
     """Serve one process's metrics over HTTP until ``close()``.
 
     ``port=0`` binds an ephemeral port (read it back from ``.port`` —
-    the CI/demo path); the Trainer maps its own ``monitor_port == 0``
+    the tests' path); the Trainer maps its own ``monitor_port == 0``
     to "disabled" before ever constructing one of these.
     ``watchdog_provider`` is a callable returning the live HangWatchdog
     (or None): the Trainer builds the watchdog after the exporter, so
@@ -361,7 +361,7 @@ class MonitorExporter:
 
     def _write_endpoint_file(self) -> None:
         """``exporter-p<i>.json`` beside the trace files: scrape-target
-        discovery for the demo/fleet tooling (atomic, best-effort)."""
+        discovery for the fleet tooling (atomic, best-effort)."""
         if not self.run_dir:
             return
         path = os.path.join(

@@ -9,8 +9,8 @@ The alert engine runs inside the watcher, so watching a run is also
 what *writes* ``alerts.jsonl`` (and fires the log/webhook actions).
 
 ``--once --json`` emits one schema-versioned report (snapshot +
-alerts) and exits — the scripting/CI surface ``make monitor-demo``
-gates on; the exit code is 1 when any alert is firing, so a cron probe
+alerts) and exits — the scripting surface ``tests/test_monitor.py``
+holds; the exit code is 1 when any alert is firing, so a cron probe
 needs no JSON parsing.
 
 Stdlib-only, like every read-back CLI in-tree — EXCEPT ``--roofline``,

@@ -16,8 +16,8 @@ Bit-parity contract
 The kernel must be a drop-in for the optax chain ``make_optimizer``
 builds — params, opt_state (counts, moments, EMA) and the returned
 update tree must be BIT-identical to the XLA path, step after step
-(pinned by ``tests/test_fused_kernels.py`` and the ``kernels-demo``
-trainer-step parity gate). That means every expression here mirrors the
+(pinned by ``tests/test_fused_kernels.py``, down to its slow
+trainer-step parity case). That means every expression here mirrors the
 optax 0.2.3 / in-repo source form exactly:
 
 * clip:   ``select(g_norm < max_norm, t, (t / g_norm.astype(t.dtype)) *

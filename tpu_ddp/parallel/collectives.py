@@ -124,7 +124,7 @@ def prefetched_block_gather(blocks, axis: str, *, prefetch: bool = True):
     ``prefetch=False`` is the serialized (no-lookahead) schedule kept
     ONLY as the injected violation: every block gathered just-in-time
     under one ``tpu_ddp.zero3_serial_gather`` scope, no handoff chain —
-    the program ``tools/zero3_demo.py`` feeds the linter to prove the
+    the program ``tests/test_zero3.py`` feeds the linter to prove the
     pin trips.
     """
     if not prefetch:

@@ -38,7 +38,7 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
 def enable_compile_cache() -> str:
     """Turn on jax's persistent compilation cache and return its directory.
     Every entry point calls this before its first trace (the CLI, the
-    Trainer, bench.py's child, chip_smoke.py, benchmarks/prewarm_cache.py).
+    Trainer, chip_smoke.py, chipbench/run.py through the Trainer).
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax has read it itself and
     nothing here sets a directory; otherwise the cache goes to

@@ -9,7 +9,7 @@ artifacts were missing:
 
 - ``store.py`` — an append-only JSONL archive (``registry.jsonl`` in a
   workspace dir). Every artifact the framework already emits —
-  ``bench.py`` records, ``benchmarks/aot_v5e.py`` captures, ``tpu-ddp
+  the ``benchmarks/aot_v5e.py`` capture, ``tpu-ddp
   analyze/lint/goodput/trace summarize --json``, ``watch --once
   --json`` — ingests through ``analysis/regress.py``'s artifact loader
   into one metric namespace and is stamped with provenance: git commit

@@ -39,8 +39,7 @@ REGISTRY_SCHEMA_VERSION = 1
 
 REGISTRY_FILE = "registry.jsonl"
 
-#: env var naming the default workspace (CI exports it so every demo
-#: gate records into one accumulating registry)
+#: env var naming the default workspace
 REGISTRY_ENV = "TPU_DDP_REGISTRY"
 
 #: top-level/program keys that are MEASURED, higher-is-better rates —
@@ -129,7 +128,7 @@ def extract_metrics(programs: Dict[str, dict]) -> Dict[str, float]:
             if isinstance(v, (int, float)):
                 out[f"{name}/quality/{k}"] = float(v)
         _measured_of(rec, name, out)
-        # bench.py `rows` (named measurement rows of one bench run)
+        # `rows`: the named measurement rows of one recorded run
         rows = rec.get("rows")
         if isinstance(rows, dict):
             for rname, row in rows.items():
@@ -341,25 +340,6 @@ def record_artifact(
     os.makedirs(registry_dir, exist_ok=True)
     with open(os.path.join(registry_dir, REGISTRY_FILE), "a") as f:
         f.write(json.dumps(entry.to_record()) + "\n")
-    return entry
-
-
-def record_if_env(artifact_path: str,
-                  note: Optional[str] = None) -> Optional[RegistryEntry]:
-    """Record ``artifact_path`` into the ``$TPU_DDP_REGISTRY`` workspace
-    when that env var is set; no-op otherwise. Best-effort by design —
-    the CI demo gates call this so their artifacts ACCUMULATE into one
-    registry uploaded as a build artifact, and an ingest problem must
-    fail the registry demo, not every demo."""
-    registry_dir = os.environ.get(REGISTRY_ENV)
-    if not registry_dir:
-        return None
-    try:
-        entry = record_artifact(registry_dir, artifact_path, note=note)
-    except (OSError, ValueError, json.JSONDecodeError) as e:
-        print(f"registry: could not record {artifact_path}: {e}")
-        return None
-    print(f"registry: recorded {entry.label()} -> {registry_dir}")
     return entry
 
 

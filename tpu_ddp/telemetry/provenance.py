@@ -1,7 +1,7 @@
 """Provenance stamping: git identity + deterministic config digests.
 
 Every durable artifact the framework emits — the run-metadata header the
-sinks write, ``bench.py``/``benchmarks/aot_v5e.py`` captures, ``tpu-ddp
+sinks write, the ``benchmarks/aot_v5e.py`` capture, ``tpu-ddp
 analyze/lint --json`` — should be able to say WHICH commit produced it
 and which logical configuration it measured, because the perf registry
 (``tpu_ddp/registry``) archives those artifacts across runs and commits

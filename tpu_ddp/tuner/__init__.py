@@ -23,7 +23,7 @@ the best candidates, joined through the PR 5 run-metadata header, and
 re-ranks on measurement.
 
 The winner is emitted as a ready-to-run artifact (a ``TrainConfig``
-JSON ``bench.py --config`` and ``tpu-ddp train`` consume, plus the
+JSON of the fields ``tpu-ddp train`` takes as flags, plus the
 equivalent CLI line); the full ranked table is a schema-versioned
 ``tune --json`` artifact that ``tpu-ddp registry record`` archives and
 ``tpu-ddp bench compare`` / ``registry trend`` gate like every other

@@ -16,8 +16,8 @@ Artifacts:
   predicted throughput/step drift, ``bench compare`` gates it.
 - ``--emit-config winner.json`` — the ready-to-run winner: a
   ``TrainConfig`` field dict (validated before writing) plus the
-  equivalent ``tpu-ddp train`` CLI line. ``bench.py --config
-  winner.json`` measures it verbatim.
+  equivalent ``tpu-ddp train`` CLI line (its ``cli`` key), which is
+  how the winner is run.
 - ``--validate-top K`` — short measured trials of the top K candidates
   (``validate.py``), re-ranked on measurement.
 """
@@ -385,7 +385,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "bench-compare-able)")
     ap.add_argument("--emit-config", default=None, metavar="OUT.json",
                     help="write the winner's ready-to-run TrainConfig "
-                         "artifact here (bench.py --config consumes it)")
+                         "artifact here (its `cli` key is the "
+                         "tpu-ddp train line that runs it)")
     ap.add_argument("--validate-top", type=int, default=0, metavar="K",
                     help="run short measured trials of the top K "
                          "candidates and re-rank on measurement")
@@ -583,7 +584,7 @@ def _run(args) -> int:
         with open(args.emit_config, "w") as f:
             json.dump(winner_art, f, indent=1)
         print(f"tpu-ddp tune: wrote {args.emit_config} (run it: "
-              f"python bench.py --config {args.emit_config})", flush=True)
+              f"{cli_line})", flush=True)
     return 0
 
 

@@ -14,9 +14,9 @@ re-rank on measurement; each trial also records its
 ``measured_vs_model`` ratio + device kind — the calibration food
 ``calibrate.py`` reads back from archived tune artifacts.
 
-``bench.py --config <tune-winner.json>`` reuses :func:`measure_config`
-verbatim, so the tuner's emitted winner artifact is runnable (and
-measurable) exactly as emitted.
+:func:`measure_config` takes the same ``TrainConfig`` fields the
+winner artifact holds, so what ``tune --emit-config`` writes is what
+:func:`validate_top` measured.
 """
 
 from __future__ import annotations
